@@ -36,6 +36,12 @@ gateway port, and each service's ``environment`` map is applied to
 configs are constructed (shard children inherit it).  CLI flags win
 over the config file.
 
+The shards' backend follows ``JAX_PLATFORMS`` (the launcher prints its
+choice): unset or ``cpu``, they serve from the CPU reference core and
+any number may run; anything else names an accelerator, the shard
+process claims it, and since a chip belongs to one process at a time
+only ``--shards 1`` is accepted there.
+
 ``--smoke`` connects one raw-session client through the gateway, makes
 an edit, waits for the acked round-trip, verifies the text server-side,
 then curls every spawned process's admin plane (``/healthz``,
@@ -249,13 +255,35 @@ def main(argv=None) -> int:
     )
     gconfig = GatewayConfig(port=gw_port)
 
+    # the shards' backend follows JAX_PLATFORMS: unset or pinned to the
+    # CPU, they serve from the CPU reference core; anything else names an
+    # accelerator, and the shard process claims it
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    backend = "cpu" if platforms in ("", "cpu") else "auto"
+    if backend != "cpu" and shards > 1:
+        # every child would claim the same chip, and a chip belongs to
+        # one process: the second child fails or hangs at start-up.
+        # This process cannot count the chips either (touching JAX here
+        # would take one).
+        print(
+            "ytpu-cluster: refusing %d shards with JAX_PLATFORMS=%s: each "
+            "shard process claims the accelerator, a chip belongs to one "
+            "process at a time, and nothing maps a shard to a chip of its "
+            "own yet.  Run --shards 1, or JAX_PLATFORMS=cpu for CPU-core "
+            "shards." % (shards, platforms),
+            file=sys.stderr,
+        )
+        return 2
     sup = Supervisor(
-        shards, wal_root, docs_per_shard=args.docs_per_shard, config=cconfig
+        shards, wal_root, docs_per_shard=args.docs_per_shard,
+        config=cconfig, backend=backend,
     ).start()
     gw = Gateway(sup, config=gconfig).start()
     print(
-        "ytpu-cluster: %d shard(s) up, gateway on %s:%d, wal-root %s"
-        % (shards, gw.config.host, gw.port, wal_root)
+        "ytpu-cluster: %d shard(s) up (backend=%s, JAX_PLATFORMS=%s), "
+        "gateway on %s:%d, wal-root %s"
+        % (shards, backend, platforms or "unset", gw.config.host, gw.port,
+           wal_root)
     )
     for row in sup.recovery_report()["shards"]:
         print(

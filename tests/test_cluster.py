@@ -302,6 +302,94 @@ def test_hung_shard_convicted_by_heartbeat_probe(tmp_path):
         sup.close()
 
 
+def test_busy_provider_is_not_a_hung_shard(tmp_path):
+    """A provider busy under its lock (a cold compile or a bulk flush on
+    a device-backed shard: seconds) is not a hung shard.  The probe
+    rides a connection of its own and the shard answers it outside the
+    provider lock, so neither the lock nor a data call stuck behind it
+    on the supervisor's data connection can fail a heartbeat."""
+    import threading
+
+    from yjs_tpu.cluster.rpc import RpcClient
+    from yjs_tpu.cluster.shard import ShardServer
+
+    shard = ShardServer(0, str(tmp_path / "wal"), n_docs=4)
+    sup = Supervisor(
+        1, str(tmp_path / "sup"),
+        config=ClusterConfig(probe_timeout_s=0.5, **FAST),
+    )
+    sp = sup._shards[0]
+    sp.port = shard.port
+    sp.client = RpcClient("127.0.0.1", shard.port)
+    release = threading.Event()
+    held = threading.Event()
+
+    def hold():
+        with shard._plock:
+            held.set()
+            release.wait(30)
+
+    holder = threading.Thread(target=hold)
+    holder.start()
+    stuck = threading.Thread(
+        target=lambda: sp.client.call("text", {"guid": "room"})
+    )
+    try:
+        assert held.wait(5)
+        stuck.start()  # head of the data connection's line, behind _plock
+        time.sleep(0.2)
+        for _ in range(3):
+            assert sup._probe(sp) and sp.probe_fails == 0
+        assert stuck.is_alive()
+    finally:
+        release.set()
+        holder.join()
+        stuck.join(10)
+        sp.client.close()
+        shard.close(checkpoint=False)
+
+
+def test_slow_shard_never_costs_a_session_its_liveness(tmp_path):
+    """Session liveness is the transport's: while every call into the
+    cluster blocks for longer than the peers' whole liveness window (the
+    facade lock held here; a shard compiling there), the gateway's
+    keepalive thread answers for the sessions that cannot tick, no peer
+    declares the connection dead, and the edit made meanwhile lands.
+    Every timeout is the default."""
+    from yjs_tpu.cluster import LocalCluster
+    from yjs_tpu.fleet import FleetRouter
+
+    fleet = FleetRouter(
+        n_shards=1, docs_per_shard=8, backend="cpu",
+        wal_dir=str(tmp_path / "wal"),
+    )
+    cluster = LocalCluster(fleet)
+    gw = Gateway(cluster, config=GatewayConfig(port=0)).start()
+    pairs = []
+    try:
+        pairs = [_connect(gw.port, "slow-room", cid) for cid in (21, 22)]
+        with pairs[0][1].lock:
+            pairs[0][0].get_text("text").insert(0, "before. ")
+        _wait_equal(pairs)
+        window_s = pairs[0][1].session.config.liveness * 0.05
+        with cluster._lock:  # every facade call now waits, the tick's too
+            with pairs[1][1].lock:
+                pairs[1][0].get_text("text").insert(0, "[meanwhile]")
+            time.sleep(2 * window_s)
+        _wait_equal(pairs, require=("[meanwhile]",))
+        for _doc, conn in pairs:
+            with conn.lock:
+                snap = conn.session.snapshot()
+            assert snap["liveness_timeouts"] == 0, snap
+            assert snap["state"] == "live", snap
+            assert snap["full_resyncs"] == 1, snap
+    finally:
+        for _doc, conn in pairs:
+            conn.close()
+        gw.close()
+        cluster.close()
+
+
 def test_spawn_ready_timeout_kills_silent_child(tmp_path):
     """A child that starts but never prints its ready line must fail
     the spawn at ``spawn_timeout_s`` — not block the caller forever
@@ -330,6 +418,20 @@ def test_spawn_ready_timeout_kills_silent_child(tmp_path):
     )
     with pytest.raises(RuntimeError, match="exited before ready"):
         sup._read_ready(proc)
+
+
+def test_spawn_failure_carries_the_childs_stderr(tmp_path):
+    """A child that dies before its ready line takes its reason with it
+    unless the supervisor keeps its stderr: the error names it."""
+    sup = Supervisor(
+        1, str(tmp_path / "wal"), backend="no-such-backend",
+        config=ClusterConfig(**FAST),
+    )
+    try:
+        with pytest.raises(RuntimeError, match="unknown policy 'no-such-backend'"):
+            sup.start()
+    finally:
+        sup.close()
 
 
 def test_supervisor_facade_and_federated_metrics(tmp_path):
@@ -492,6 +594,22 @@ def test_cluster_launcher_parses_compose_shaped_config():
     assert launcher.parse_compose({"services": {"redis": {}}}) == {
         "shards": None, "gateway_port": None, "env": {},
     }
+
+
+def test_cluster_launcher_refuses_shards_that_would_share_a_chip(
+    tmp_path, monkeypatch, capsys
+):
+    """With JAX_PLATFORMS naming an accelerator every shard child claims
+    it, and a chip belongs to one process: more than one such shard is
+    refused before anything is spawned, with the way out in the
+    message."""
+    launcher = _load_script("ytpu_cluster")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    rc = launcher.main(["--shards", "3", "--wal-root", str(tmp_path / "wal")])
+    assert rc == 2
+    err = capsys.readouterr().err
+    assert "refusing 3 shards" in err and "--shards 1" in err
+    assert not (tmp_path / "wal").exists()
 
 
 def test_cluster_launcher_smoke_round_trips_an_edit(tmp_path):

@@ -1,5 +1,5 @@
 """Engine-path typed events vs the CPU doc's YEvent on the same traffic
-(r2-VERDICT item 6: observe for engine-hosted docs, reference
+(observe for engine-hosted docs, reference
 YEvent.js:85-187, AbstractType.js:360-389)."""
 
 import pytest
@@ -170,8 +170,7 @@ def test_events_after_demotion(rng):
 
 
 def test_engine_to_delta_matches_cpu(rng):
-    """Mirror-served attributed delta vs the CPU doc (r2-VERDICT item 9,
-    reference YText.toDelta YText.js:936-1030)."""
+    """Mirror-served attributed delta vs the CPU doc (reference YText.toDelta YText.js:936-1030)."""
     a = Y.Doc(gc=False); a.client_id = 31
     b = Y.Doc(gc=False); b.client_id = 32
     updates = []
@@ -281,7 +280,7 @@ def test_engine_xml_string_matches_cpu(rng):
 
 
 # ---------------------------------------------------------------------------
-# VERDICT r4 item 6: event-path INDEX parity.  getPathTo (YEvent.js:207-228)
+# Event-path INDEX parity.  getPathTo (YEvent.js:207-228)
 # counts undeleted ITEMS before the nested type — a count that depends on
 # run-merge state, which differs between the CPU store (merges eagerly at
 # cleanup) and the mirror (merges only at compaction).  These sessions put

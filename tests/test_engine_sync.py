@@ -120,7 +120,7 @@ class TestMirrorEmission:
 
 class TestBatchedSyncKernels:
     """Sync step 1 + 2 across many docs in single kernel dispatches
-    (VERDICT item 5; reference encoding.js:490-526,94-116 batched)."""
+    (reference encoding.js:490-526,94-116 batched)."""
 
     def _make_engine(self, n):
         import yjs_tpu as Y

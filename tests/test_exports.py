@@ -1,5 +1,5 @@
-"""Export-contract parity with the reference public API (VERDICT r4
-item 10): every name the reference exports from src/index.js:2-76 must
+"""Export-contract parity with the reference public API:
+every name the reference exports from src/index.js:2-76 must
 exist on ``yjs_tpu`` under the same (camelCase/JS) name.  The list is
 parsed from the reference source itself so drift is impossible.
 

@@ -8,7 +8,7 @@ Skipped unless YTPU_FUZZ_ITERS is set, e.g.:
     YTPU_FUZZ_ITERS=10000 JAX_PLATFORMS=cpu python -m pytest \
         tests/test_extensive.py -q
 
-Covers all three layers VERDICT item 8 names: the CPU reference core
+Covers all three layers: the CPU reference core
 (ported op tables under the disconnect/reconnect connector), the batch
 engine, and the sharded engine on the virtual 8-device mesh.  Recorded
 runs live in tests/EXTENSIVE_RUNS.md.
@@ -35,8 +35,8 @@ pytestmark = pytest.mark.skipif(
 
 
 # -- r5 op-table extensions: undo + snapshot ops mixed into the fuzz ---------
-# (VERDICT r4 item 7: the deep fuzz must also drive the undo and snapshot
-# machinery, not only plain edits)
+# (the deep fuzz must also drive the undo and snapshot machinery, not
+# only plain edits)
 
 
 def _undo_mod_for(type_getter, attr):

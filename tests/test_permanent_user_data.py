@@ -80,7 +80,7 @@ def test_permanent_user_data():
 
 
 def test_engine_room_user_data_parity():
-    """Engine-path attribution (VERDICT r4 Missing #3): clients maintain
+    """Engine-path attribution: clients maintain
     PermanentUserData in the room as usual; the provider answers
     user_by_client_id / user_by_deleted_id from mirror columns and must
     agree with a CPU PermanentUserData fed the same traffic."""

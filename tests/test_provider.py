@@ -208,7 +208,7 @@ def _apply_step2(doc, reply):
 
 
 class TestUpdateEmission:
-    """VERDICT item 7: after flush() the engine emits per-doc incremental
+    """After flush() the engine emits per-doc incremental
     updates (reference Transaction.js:339-352) so a server can broadcast
     to peers; a third replica stays in sync purely from emitted updates."""
 

@@ -77,7 +77,7 @@ def test_rel_pos_missing_client_returns_none():
 
 
 # ---------------------------------------------------------------------------
-# Engine-path cursors (VERDICT r4 item 4): create/resolve straight from
+# Engine-path cursors: create/resolve straight from
 # mirror columns, parity-pinned against the CPU reference path under
 # concurrent edits, compaction, and undo/redo (redone chains).
 # ---------------------------------------------------------------------------

@@ -655,7 +655,7 @@ class TestBlockwiseDispatch:
 
 
 class TestCompaction:
-    """Run-merge + GC keep the device table bounded (VERDICT item 3; the
+    """Run-merge + GC keep the device table bounded (the
     engine-side analogue of reference Transaction.js:165-238,299-332)."""
 
     def _long_append_trace(self, eng, doc, n_flushes, per_flush=20):
@@ -849,7 +849,7 @@ class TestChunkedFlushStress:
 
 
 class TestLaneBucketing:
-    """_bucket_lanes (VERDICT r4 item 9): mantissa-quantized lane widths
+    """_bucket_lanes: mantissa-quantized lane widths
     cap padding waste at 12.5% while keeping compiled shapes bounded."""
 
     def test_properties(self):
@@ -928,8 +928,7 @@ class TestLaneBucketing:
         eng.flush()
         occ = eng.last_flush_metrics["schedule_occupancy"]
         # >=0.90 at this 32-doc scale (the fixed 64/64/8/64 minimum-width
-        # floors are ~5% of demand here); the 1024-doc distinct fixture
-        # measures 0.96+ (BASELINE.md r5), vs 0.844 with pure powers of two
+        # floors are ~5% of demand here)
         assert occ >= 0.90, occ
         # second engine, ~5% different demand -> identical lane widths
         import yjs_tpu.ops.engine as engine_mod

@@ -29,6 +29,15 @@ with the BUSY envelope (the peer retransmits — zero acked loss) and
 y-websocket frames are dropped and counted (stock clients carry no ack
 to lose; they re-sync on reconnect).
 
+Liveness is the transport's, not the shard's: raw-session frames and
+session ticks run under one gateway lock, and a frame that needs the
+owner shard holds it for as long as the shard takes (a flush there can
+be a cold compile or a bulk load: seconds with a device behind it).  A
+keepalive thread that never calls a shard watches the tick loop; when
+the sessions have not ticked for a heartbeat interval it answers every
+session peer's pings itself (a stateless PONG), so a slow shard delays
+a room but never makes its peers declare the connection dead.
+
 Failover/migration rehoming: the facade's ``on_epoch`` fires after a
 routing change; session connections :meth:`~SyncSession.rehome` (digest
 → targeted repair, not full resync) and y-websocket rooms get a fresh
@@ -51,7 +60,12 @@ from ..lib0.encoding import Encoder
 from ..obs import dist as obs_dist
 from ..obs import global_registry
 from ..sync import protocol
-from ..sync.session import SessionConfig, SyncSession, encode_busy
+from ..sync.session import (
+    SessionConfig,
+    SyncSession,
+    encode_busy,
+    encode_pong,
+)
 from .config import GatewayConfig
 from .rpc import FrameConn, RpcBusy, RpcError, SocketTransport
 
@@ -695,6 +709,17 @@ class Gateway:
         self._ticker = threading.Thread(
             target=self._tick_loop, name="ytpu-gateway-tick", daemon=True
         )
+        # the keepalive thread's view (module docstring): when the
+        # sessions last ticked, and the raw-session transports, kept
+        # under a lock of their own so it never waits on self._lock
+        self._ticked_at = time.monotonic()
+        self._ka_lock = threading.Lock()
+        self._ka_transports: tuple = ()
+        self._keepalive = threading.Thread(
+            target=self._keepalive_loop,
+            name="ytpu-gateway-keepalive",
+            daemon=True,
+        )
         self.admin = None  # started alongside the loops in start()
         cluster.on_update = self._on_room_update
         cluster.on_epoch = self._on_epoch
@@ -706,6 +731,7 @@ class Gateway:
     def start(self) -> "Gateway":
         self._accept.start()
         self._ticker.start()
+        self._keepalive.start()
         from ..obs.admin import AdminServer
 
         try:
@@ -752,6 +778,8 @@ class Gateway:
             self._accept.join(timeout=5.0)
         if self._ticker.is_alive():
             self._ticker.join(timeout=5.0)
+        if self._keepalive.is_alive():
+            self._keepalive.join(timeout=5.0)
         with self._lock:
             conns = list(self._conns)
             sessions = [
@@ -795,6 +823,25 @@ class Gateway:
                         tick()
                     except Exception:
                         pass
+            self._ticked_at = time.monotonic()
+
+    def _keepalive_loop(self) -> None:
+        """Answer the session peers' pings while the sessions cannot
+        (module docstring).  Calls no shard and takes no lock a shard
+        call is made under."""
+        cfg = self.session_config
+        every = (cfg.heartbeat or 8) * self.config.tick_s
+        pong = encode_pong()
+        while not self._stop.wait(every):
+            if time.monotonic() - self._ticked_at < every:
+                continue
+            with self._ka_lock:
+                transports = self._ka_transports
+            for t in transports:
+                if t.send(pong):
+                    self.metrics.frames.labels(
+                        dir="tx", kind="keepalive"
+                    ).inc()
 
     # -- room registry -------------------------------------------------------
 
@@ -804,6 +851,7 @@ class Gateway:
             self._rooms.setdefault(conn.room, set()).add(conn)
             n_conns = len(self._conns)
             n_rooms = len(self._rooms)
+            self._publish_transports()
         self.metrics.conns.set(n_conns)
         self.metrics.rooms.set(n_rooms)
 
@@ -817,8 +865,18 @@ class Gateway:
                     self._rooms.pop(conn.room, None)
             n_conns = len(self._conns)
             n_rooms = len(self._rooms)
+            self._publish_transports()
         self.metrics.conns.set(n_conns)
         self.metrics.rooms.set(n_rooms)
+
+    def _publish_transports(self) -> None:
+        """Refresh the keepalive thread's snapshot of the registry."""
+        with self._lock:
+            transports = tuple(
+                c.transport for c in self._conns if c.transport is not None
+            )
+            with self._ka_lock:
+                self._ka_transports = transports
 
     def _room_conns(self, room: str) -> list:
         with self._lock:

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import logging
 import os
 import signal
 import sys
@@ -36,6 +37,8 @@ from ..obs import dist as obs_dist
 from ..obs.admin import AdminConfig, AdminServer
 from .config import ClusterConfig
 from .rpc import RpcBusy, RpcServer, b64d, b64e
+
+logger = logging.getLogger("yjs_tpu.cluster.shard")
 
 
 class ShardServer:
@@ -226,13 +229,21 @@ class ShardServer:
         )
 
     def _tick_loop(self) -> None:
+        failures = 0
         while not self._stop.wait(self.tick_s):
             with self._plock:
                 try:
                     self.provider.flush_tick()
                     self.provider.tick_sessions()
                 except Exception:
-                    pass  # a failed tick retries next round
+                    # a failed tick retries next round; say so, but not
+                    # at tick rate: a device error would repeat 20x a second
+                    failures += 1
+                    if failures in (1, 10, 100) or failures % 1000 == 0:
+                        logger.exception(
+                            "shard %d: tick failed (%d so far)",
+                            self.shard_id, failures,
+                        )
 
     # -- RPC ingress seam ----------------------------------------------------
 
@@ -243,6 +254,14 @@ class ShardServer:
         delegates data traffic to the provider's own seams
         (``receive_update`` / ``handle_sync_message``) which feed the
         WAL, admission, and SLO pipelines."""
+        if method == "heartbeat":
+            # the liveness probe must not queue behind the provider: a
+            # flush holds _plock for as long as a cold compile or a bulk
+            # load takes (seconds to tens of seconds with a device
+            # behind it), and the supervisor restarts a shard whose
+            # heartbeat goes unanswered.  provider.heartbeat() reads
+            # host-side counters only, so it is answered lock-free.
+            return self.provider.heartbeat()
         with obs_dist.use_context(ctx):
             with self._plock:
                 return self._dispatch(method, payload)
@@ -259,8 +278,6 @@ class ShardServer:
                 "port": self.server.port,
                 "recovery": self.recovery,
             }
-        if method == "heartbeat":
-            return prov.heartbeat()
         if method == "sync":
             guid = payload["guid"]
             frame = b64d(payload["frame"])

@@ -35,6 +35,7 @@ updates are lost across the outage window.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import subprocess
@@ -209,15 +210,35 @@ class Supervisor:
             "--admin-port", "0",
         ]
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        if self.backend == "cpu":
+            # a CPU-core shard must not claim an accelerator: a chip
+            # belongs to one process at a time.  Any other backend
+            # inherits the environment, so the child owns the device.
+            env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.Popen(
             cmd,
             stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL,
+            stderr=subprocess.PIPE,
             text=True,
             env=env,
         )
-        ready = self._read_ready(proc)
+        # the child's last stderr lines, drained for its whole lifetime
+        # like stdout: they say why it died when it never becomes ready
+        err_tail: collections.deque = collections.deque(maxlen=40)
+        err_thread = threading.Thread(
+            target=err_tail.extend,
+            args=(proc.stderr,),
+            name=f"ytpu-shard-stderr-{proc.pid}",
+            daemon=True,
+        )
+        err_thread.start()
+        try:
+            ready = self._read_ready(proc)
+        except RuntimeError as e:
+            err_thread.join(timeout=5.0)  # the child is dead: EOF is near
+            raise RuntimeError(
+                f"{e}; its stderr ended:\n{''.join(err_tail.copy())}"
+            ) from None
         client = RpcClient(
             self.config.host,
             ready["port"],
@@ -558,11 +579,13 @@ class Supervisor:
     def _monitor_loop(self) -> None:
         next_snap = time.monotonic() + self.config.snapshot_s
         # the hang lane: a shard whose process is alive and socket open
-        # but which stopped serving (e.g. deadlocked under its provider
-        # lock) is invisible to poll()/alive — only an unanswered
-        # heartbeat RPC convicts it.  Probes run at a coarser cadence
-        # than the poll loop; each one blocks this thread for at most
-        # probe_timeout_s.
+        # but which stopped serving (stopped, wedged in native code) is
+        # invisible to poll()/alive — only an unanswered heartbeat RPC
+        # convicts it.  The shard answers the heartbeat outside its
+        # provider lock, so a provider that is merely busy (a cold
+        # compile, a bulk flush, a checkpoint) is never convicted.
+        # Probes run at a coarser cadence than the poll loop; each one
+        # blocks this thread for at most probe_timeout_s.
         probe_every = max(
             self.config.heartbeat_s, self.config.probe_timeout_s / 2.0
         )
@@ -603,14 +626,22 @@ class Supervisor:
         """One heartbeat RPC against a live-looking shard; False means
         hung.  Two consecutive unanswered probes (timeout or connection
         loss) convict — a remote *error* is still an answer, and one
-        slow response (checkpoint, first-flush compile) gets a second
-        chance before a restart is forced."""
-        client = sp.client
-        if client is None:
-            return False
+        slow response (a starved host) gets a second chance before a
+        restart is forced."""
+        # a connection of its own: a shard serves each connection's
+        # requests in order, so on the data connection the heartbeat
+        # would queue behind whatever call the provider is busy with
+        timeout = self.config.probe_timeout_s
         try:
-            client.call("heartbeat", timeout=self.config.probe_timeout_s)
-        except RpcClosed:
+            probe = RpcClient(
+                self.config.host, sp.port,
+                timeout=timeout, connect_timeout=timeout,
+            )
+            try:
+                probe.call("heartbeat")
+            finally:
+                probe.close()
+        except (RpcClosed, OSError):
             sp.probe_fails += 1
             return sp.probe_fails < 2
         except RpcError:

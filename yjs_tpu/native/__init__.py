@@ -1,15 +1,22 @@
-"""ctypes loader for the native transcoder (transcode.cpp).
+"""ctypes loader for the native core (transcode.cpp + plancore.cpp).
 
-Builds lazily with g++ on first use (cached as _transcode.so next to the
-source); unavailable when no toolchain exists or YTPU_NO_NATIVE is set —
-callers fall back to the pure-Python codec.  Unavailability is logged ONCE
-(a silent 10-50x host-path slowdown would otherwise be invisible,
-r1-VERDICT "silent degradation"); set YTPU_NO_NATIVE to opt out quietly.
+One build, from the committed sources: the shared object is named after
+a hash of their content (and of the compile command), so a binary that
+was built from other sources -- copied in with a checkout, or left over
+from an earlier commit -- is never loaded.  The first ``load()`` of a
+checkout compiles with ``g++``; later processes find the keyed file.
+
+``YTPU_NO_NATIVE`` opts out (callers use the pure-Python codec).  When
+the build or the load fails, ``load()`` logs the compiler's message
+once and returns None, and ``load_error()`` keeps the message for
+callers that must not run without the core (``chip_smoke.py``).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -19,65 +26,75 @@ import numpy as np
 logger = logging.getLogger("yjs_tpu.native")
 
 _DIR = os.path.dirname(__file__)
-_SRC = os.path.join(_DIR, "transcode.cpp")
-_SRC_PLAN = os.path.join(_DIR, "plancore.cpp")
-_SRC_WIRE = os.path.join(_DIR, "wire.h")
-_SO = os.path.join(_DIR, "_transcode.so")
+_SOURCES = ("transcode.cpp", "plancore.cpp", "wire.h")
+_CXX = ("g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread")
+_SO_PREFIX = "_ytpu_native."
 
 _lib = None
 _tried = False
+_error: str | None = None
 
 
-def _build() -> bool:
-    try:
-        srcs = [_SRC] + ([_SRC_PLAN] if os.path.exists(_SRC_PLAN) else [])
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-             "-o", _SO] + srcs,
-            check=True,
-            capture_output=True,
-            timeout=240,
-        )
-        return True
-    except subprocess.CalledProcessError as e:
-        logger.warning(
-            "native transcoder failed to compile (pure-Python codec will "
-            "serve the host path, 10-50x slower): %s",
-            (e.stderr or b"").decode(errors="replace")[-500:],
-        )
-        return False
-    except Exception as e:
-        logger.warning(
-            "native transcoder unavailable (%s: %s); pure-Python codec "
-            "will serve the host path, 10-50x slower",
-            type(e).__name__, e,
-        )
-        return False
+def build(src_dir: str = _DIR) -> str:
+    """Path of the shared object for the sources in ``src_dir`` as they
+    are on disk, compiled now unless a file under that content key is
+    already there.  Binaries under any other key are removed: they match
+    no source in the directory."""
+    h = hashlib.blake2b(" ".join(_CXX).encode(), digest_size=8)
+    for name in _SOURCES:
+        with open(os.path.join(src_dir, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    so = os.path.join(src_dir, f"{_SO_PREFIX}{h.hexdigest()}.so")
+    if not os.path.exists(so):
+        # shard children of one supervisor may race to build the same
+        # key: compile to a private name, then rename atomically
+        tmp = f"{so}.{os.getpid()}.tmp"
+        srcs = [
+            os.path.join(src_dir, n) for n in _SOURCES if n.endswith(".cpp")
+        ]
+        try:
+            subprocess.run(
+                [*_CXX, "-o", tmp, *srcs],
+                check=True, capture_output=True, timeout=600,
+            )
+            os.replace(tmp, so)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+    for other in glob.glob(os.path.join(src_dir, _SO_PREFIX + "*.so")):
+        if other != so:
+            try:
+                os.unlink(other)
+            except OSError:
+                pass
+    return so
+
+
+def load_error() -> str | None:
+    """Why the last ``load()`` returned None (the compiler's stderr, the
+    loader's message, or the opt-out), else None."""
+    return _error
 
 
 def load():
-    """The loaded library, or None if unavailable."""
-    global _lib, _tried
+    """The loaded library, or None if unavailable (see ``load_error``)."""
+    global _lib, _tried, _error
     if _lib is not None or _tried:
         return _lib
     _tried = True
     if os.environ.get("YTPU_NO_NATIVE"):
+        _error = "YTPU_NO_NATIVE is set"
         return None
-    # a shipped .so with no source is fine (binary-only install); rebuild
-    # only when a source file exists and is newer
-    needs_build = not os.path.exists(_SO) or any(
-        os.path.exists(s) and os.path.getmtime(_SO) < os.path.getmtime(s)
-        for s in (_SRC, _SRC_PLAN, _SRC_WIRE)
-    )
-    if needs_build:
-        if not _build():
-            return None
     try:
-        lib = ctypes.CDLL(_SO)
-    except OSError as e:
+        lib = ctypes.CDLL(build())
+    except subprocess.CalledProcessError as e:
+        _error = "g++ failed:\n" + (e.stderr or b"").decode(errors="replace")
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _error = f"{type(e).__name__}: {e}"
+    if _error is not None:
         logger.warning(
-            "native transcoder failed to load (%s); pure-Python codec "
-            "will serve the host path, 10-50x slower", e,
+            "native core unavailable; the pure-Python codec and planner "
+            "will serve the host path, 10-50x slower: %s", _error[-2000:],
         )
         return None
     u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -100,153 +117,108 @@ def load():
         + [i64p] * 3 + [ctypes.c_uint64] + [i64p] * 2     # ds groups
         + [u8p, ctypes.c_uint64]                          # out
     )
-    # plan-core (plancore.cpp) entry points; absent in a stale binary-only
-    # .so — the caller checks has_plancore()
-    try:
-        i64 = ctypes.c_int64
-        u64 = ctypes.c_uint64
-        vp = ctypes.c_void_p
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        u32p = ctypes.POINTER(ctypes.c_uint32)
-        lib.ymx_new.restype = vp
-        lib.ymx_free.argtypes = [vp]
-        lib.ymx_add_buf.restype = i64
-        lib.ymx_add_buf.argtypes = [vp, u8p, u64]
-        lib.ymx_n_bufs.restype = i64
-        lib.ymx_n_bufs.argtypes = [vp]
-        lib.ymx_buf_len.restype = i64
-        lib.ymx_buf_len.argtypes = [vp, i64]
-        lib.ymx_prepare.restype = ctypes.c_int
-        lib.ymx_prepare.argtypes = [vp, i64p, i64p, i64, ctypes.c_int, i64p]
-        vpp = ctypes.POINTER(vp)
-        lib.ymx_prepare_many.restype = None
-        lib.ymx_prepare_many.argtypes = [vpp, i64, i64p, i64p, i64p,
-                                         ctypes.c_int, ctypes.c_int, i64p,
-                                         i64p]
-        for pack_name in ("ymx_pack_apply", "ymx_pack_apply16"):
-            fn = getattr(lib, pack_name)
-            fn.restype = None
-            fn.argtypes = [vpp, i64p, i64, i64, i64, i64, i64, i64, i64,
-                           ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-                           vp, i64p]
-        for name, args in [
-            ("ymx_plan_splits", [vp, i64p]),
-            ("ymx_plan_sched", [vp, i64p]),
-            ("ymx_plan_sched8", [vp, i64p, i64p]),
-            ("ymx_plan_deletes", [vp, i64p]),
-            ("ymx_plan_applied_ds", [vp, i64p]),
-            ("ymx_plan_links", [vp, i64p, i64p]),
-            ("ymx_links", [vp, i64p]),
-            ("ymx_heads", [vp, i64p]),
-            ("ymx_plan_heads", [vp, i64p, i64p]),
-            ("ymx_clients", [vp, i64p]),
-            ("ymx_state", [vp, i64p]),
-            ("ymx_segs", [vp, i64p, i64p, i64p, i64p, i64p]),
-            ("ymx_strings", [vp, u8p]),
-            ("ymx_chain", [vp, i64, i64p]),
-            ("ymx_ds", [vp, i64p, i64p, i64p]),
-        ]:
-            getattr(lib, name).restype = None
-            getattr(lib, name).argtypes = args
-        lib.ymx_frag_counts.restype = None
-        lib.ymx_frag_counts.argtypes = [vp, i64p]
-        lib.ymx_frag.restype = None
-        lib.ymx_frag.argtypes = [vp, i64, i64p, i64p]
-        lib.ymx_drop_bufs_from.restype = None
-        lib.ymx_drop_bufs_from.argtypes = [vp, i64]
-        for name in ("ymx_n_rows", "ymx_n_slots", "ymx_n_segs",
-                     "ymx_pending_depth", "ymx_ds_count"):
-            getattr(lib, name).restype = i64
-            getattr(lib, name).argtypes = [vp]
-        lib.ymx_gen.restype = u64
-        lib.ymx_gen.argtypes = [vp]
-        lib.ymx_strings_len.restype = u64
-        lib.ymx_strings_len.argtypes = [vp]
-        lib.ymx_chain_len.restype = i64
-        lib.ymx_chain_len.argtypes = [vp, i64]
-        lib.ymx_has_pending.restype = ctypes.c_int
-        lib.ymx_has_pending.argtypes = [vp]
-        lib.ymx_rows.restype = None
-        lib.ymx_rows.argtypes = [vp, i64] + [i64p] * 21
-        lib.ymx_static_cols.restype = None
-        lib.ymx_static_cols.argtypes = [vp, i64, u32p] + [i32p] * 5
-        lib.ymx_copy_bytes.restype = ctypes.c_int
-        lib.ymx_copy_bytes.argtypes = [vp, i64, i64, i64, u8p]
-        lib.ymx_encode_bound.restype = i64
-        lib.ymx_encode_bound.argtypes = [vp]
-        lib.ymx_encode_diff.restype = i64
-        lib.ymx_encode_diff.argtypes = [vp, i64p, i64p, i64, i64p, i64,
-                                        ctypes.c_int, u8p, u64]
-        lib.ymx_encode_diff_v2.restype = i64
-        lib.ymx_encode_diff_v2.argtypes = [vp, i64p, i64p, i64, i64p, i64,
-                                           ctypes.c_int, u8p, u64]
-        lib.ymx_compact.restype = i64
-        lib.ymx_compact.argtypes = [vp, i32p, u8p, i32p, i64, ctypes.c_int,
-                                    i32p, u8p, i32p, i64]
-        lib._has_plancore = True
-    except AttributeError:
-        lib._has_plancore = False
-    # per-feature probes: symbols added after r3 degrade gracefully on a
-    # stale binary-only .so instead of disabling the whole planner
-    try:
-        p32 = ctypes.POINTER(ctypes.c_int32)
-        pu8 = ctypes.POINTER(ctypes.c_uint8)
-        lib.ymx_compact_self.restype = ctypes.c_int64
-        lib.ymx_compact_self.argtypes = [
-            ctypes.c_void_p, ctypes.c_int, p32, pu8, p32, ctypes.c_int64,
-        ]
-        lib._has_compact_self = True
-    except AttributeError:
-        lib._has_compact_self = False
-    try:
-        # r5 diagnostic: ymx_prepare_many's worker-pool width (surfaced as
-        # last_flush_metrics["plan_threads"])
-        lib.ymx_plan_threads.restype = ctypes.c_int
-        lib.ymx_plan_threads.argtypes = []
-        lib._has_plan_threads = True
-    except AttributeError:
-        lib._has_plan_threads = False
-    try:
-        # r5: one ctypes crossing registers every staged buffer of a flush
-        lib.ymx_add_bufs_many.restype = None
-        lib.ymx_add_bufs_many.argtypes = [
-            ctypes.POINTER(ctypes.c_void_p),
-            ctypes.POINTER(ctypes.c_char_p),
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64),
-        ]
-        lib._has_add_bufs_many = True
-    except AttributeError:
-        lib._has_add_bufs_many = False
-    try:
-        # r9: deep state clone — the frontier-keyed plan cache replays a
-        # cached post-prepare mirror state onto another doc's handle
-        lib.ymx_clone_state.restype = ctypes.c_int64
-        lib.ymx_clone_state.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
-        lib._has_clone_state = True
-    except AttributeError:
-        lib._has_clone_state = False
-    try:
-        # r15: emit_row chain-run anchor adoption — Python mirrors the
-        # YTPU_PLAN_SEGMENT knob into the lib and diffs the hit/lookup
-        # totals around each flush for the shared metrics schema
-        lib.ymx_set_plan_segment.restype = None
-        lib.ymx_set_plan_segment.argtypes = [ctypes.c_int]
-        lib.ymx_plan_segment_stats.restype = None
-        lib.ymx_plan_segment_stats.argtypes = [
-            ctypes.POINTER(ctypes.c_int64)
-        ]
-        lib._has_plan_segment = True
-    except AttributeError:
-        lib._has_plan_segment = False
+    # plan-core (plancore.cpp) entry points
+    i64 = ctypes.c_int64
+    u64 = ctypes.c_uint64
+    vp = ctypes.c_void_p
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    u32p = ctypes.POINTER(ctypes.c_uint32)
+    lib.ymx_new.restype = vp
+    lib.ymx_free.argtypes = [vp]
+    lib.ymx_add_buf.restype = i64
+    lib.ymx_add_buf.argtypes = [vp, u8p, u64]
+    lib.ymx_n_bufs.restype = i64
+    lib.ymx_n_bufs.argtypes = [vp]
+    lib.ymx_buf_len.restype = i64
+    lib.ymx_buf_len.argtypes = [vp, i64]
+    lib.ymx_prepare.restype = ctypes.c_int
+    lib.ymx_prepare.argtypes = [vp, i64p, i64p, i64, ctypes.c_int, i64p]
+    vpp = ctypes.POINTER(vp)
+    lib.ymx_prepare_many.restype = None
+    lib.ymx_prepare_many.argtypes = [vpp, i64, i64p, i64p, i64p,
+                                     ctypes.c_int, ctypes.c_int, i64p,
+                                     i64p]
+    for pack_name in ("ymx_pack_apply", "ymx_pack_apply16"):
+        fn = getattr(lib, pack_name)
+        fn.restype = None
+        fn.argtypes = [vpp, i64p, i64, i64, i64, i64, i64, i64, i64,
+                       ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+                       vp, i64p]
+    for name, args in [
+        ("ymx_plan_splits", [vp, i64p]),
+        ("ymx_plan_sched", [vp, i64p]),
+        ("ymx_plan_sched8", [vp, i64p, i64p]),
+        ("ymx_plan_deletes", [vp, i64p]),
+        ("ymx_plan_applied_ds", [vp, i64p]),
+        ("ymx_plan_links", [vp, i64p, i64p]),
+        ("ymx_links", [vp, i64p]),
+        ("ymx_heads", [vp, i64p]),
+        ("ymx_plan_heads", [vp, i64p, i64p]),
+        ("ymx_clients", [vp, i64p]),
+        ("ymx_state", [vp, i64p]),
+        ("ymx_segs", [vp, i64p, i64p, i64p, i64p, i64p]),
+        ("ymx_strings", [vp, u8p]),
+        ("ymx_chain", [vp, i64, i64p]),
+        ("ymx_ds", [vp, i64p, i64p, i64p]),
+    ]:
+        getattr(lib, name).restype = None
+        getattr(lib, name).argtypes = args
+    lib.ymx_frag_counts.restype = None
+    lib.ymx_frag_counts.argtypes = [vp, i64p]
+    lib.ymx_frag.restype = None
+    lib.ymx_frag.argtypes = [vp, i64, i64p, i64p]
+    lib.ymx_drop_bufs_from.restype = None
+    lib.ymx_drop_bufs_from.argtypes = [vp, i64]
+    for name in ("ymx_n_rows", "ymx_n_slots", "ymx_n_segs",
+                 "ymx_pending_depth", "ymx_ds_count"):
+        getattr(lib, name).restype = i64
+        getattr(lib, name).argtypes = [vp]
+    lib.ymx_gen.restype = u64
+    lib.ymx_gen.argtypes = [vp]
+    lib.ymx_strings_len.restype = u64
+    lib.ymx_strings_len.argtypes = [vp]
+    lib.ymx_chain_len.restype = i64
+    lib.ymx_chain_len.argtypes = [vp, i64]
+    lib.ymx_has_pending.restype = ctypes.c_int
+    lib.ymx_has_pending.argtypes = [vp]
+    lib.ymx_rows.restype = None
+    lib.ymx_rows.argtypes = [vp, i64] + [i64p] * 21
+    lib.ymx_static_cols.restype = None
+    lib.ymx_static_cols.argtypes = [vp, i64, u32p] + [i32p] * 5
+    lib.ymx_copy_bytes.restype = ctypes.c_int
+    lib.ymx_copy_bytes.argtypes = [vp, i64, i64, i64, u8p]
+    lib.ymx_encode_bound.restype = i64
+    lib.ymx_encode_bound.argtypes = [vp]
+    lib.ymx_encode_diff.restype = i64
+    lib.ymx_encode_diff.argtypes = [vp, i64p, i64p, i64, i64p, i64,
+                                    ctypes.c_int, u8p, u64]
+    lib.ymx_encode_diff_v2.restype = i64
+    lib.ymx_encode_diff_v2.argtypes = [vp, i64p, i64p, i64, i64p, i64,
+                                       ctypes.c_int, u8p, u64]
+    lib.ymx_compact_self.restype = i64
+    lib.ymx_compact_self.argtypes = [vp, ctypes.c_int, i32p, u8p, i32p, i64]
+    # ymx_prepare_many's worker-pool width (surfaced as
+    # last_flush_metrics["plan_threads"])
+    lib.ymx_plan_threads.restype = ctypes.c_int
+    lib.ymx_plan_threads.argtypes = []
+    # one ctypes crossing registers every staged buffer of a flush
+    lib.ymx_add_bufs_many.restype = None
+    lib.ymx_add_bufs_many.argtypes = [
+        vpp, ctypes.POINTER(ctypes.c_char_p), u64p, i64, i64p,
+    ]
+    # deep state clone: the frontier-keyed plan cache replays a cached
+    # post-prepare mirror state onto another doc's handle
+    lib.ymx_clone_state.restype = i64
+    lib.ymx_clone_state.argtypes = [vp, vp]
+    # emit_row chain-run anchor adoption: Python mirrors the
+    # YTPU_PLAN_SEGMENT knob into the lib and diffs the hit/lookup
+    # totals around each flush for the shared metrics schema
+    lib.ymx_set_plan_segment.restype = None
+    lib.ymx_set_plan_segment.argtypes = [ctypes.c_int]
+    lib.ymx_plan_segment_stats.restype = None
+    lib.ymx_plan_segment_stats.argtypes = [i64p]
     _lib = lib
     return _lib
-
-
-def has_plancore() -> bool:
-    lib = load()
-    return bool(lib is not None and getattr(lib, "_has_plancore", False))
 
 
 # content-source kinds for ytpu_encode_v1 (must match transcode.cpp)

@@ -153,17 +153,16 @@ def read_knee(store, start: float, end: float) -> int:
 
 def sessions_per_device(result: dict) -> dict:
     """Fold a ramp result into the published figure: knee sessions
-    divided by the visible device count (1 when jax is absent)."""
-    try:
-        import jax
+    divided by the visible device count, with the platform those
+    devices are on (a CPU count is not a chip count)."""
+    import jax
 
-        n_devices = max(1, len(jax.devices()))
-    except Exception:
-        n_devices = 1
+    devices = jax.devices()
     knee = int(result.get("sessions_at_slo", 0))
     return {
         "sessions_at_slo": knee,
-        "n_devices": n_devices,
-        "sessions_per_device": round(knee / n_devices, 2),
+        "platform": devices[0].platform,
+        "n_devices": len(devices),
+        "sessions_per_device": round(knee / len(devices), 2),
         "ceiling_hit": bool(result.get("ceiling_hit")),
     }

@@ -2,8 +2,7 @@
 
 The flush hot path (ingest -> prepare_step -> static_columns) runs entirely
 inside yjs_tpu/native/plancore.cpp — the per-item Python interpreter cost
-that dominated the distinct-doc benchmark (r2 VERDICT: 11.6ms/doc of plan
-building) drops to one ctypes call per flush.  Everything *outside* the hot
+that dominated the distinct-doc benchmark drops to one ctypes call per flush.  Everything *outside* the hot
 path — exports, wire encodes, event payloads — is served by lazily syncing
 the C++ columns into a shadow :class:`DocMirror` and delegating to its
 (pure-read) methods, so the two implementations cannot drift in behavior:
@@ -38,7 +37,6 @@ from ..native import (
     SRC_NONE,
     SRC_UTF8,
     SRC_V2LAZY,
-    has_plancore,
     load,
 )
 from .columns import (
@@ -58,16 +56,14 @@ _u32p = ctypes.POINTER(ctypes.c_uint32)
 
 
 def native_plan_available() -> bool:
-    # env opt-out first: has_plancore() may trigger the g++ build
-    return not os.environ.get("YTPU_NO_NATIVE_PLAN") and has_plancore()
+    # env opt-out first: load() may trigger the g++ build
+    return not os.environ.get("YTPU_NO_NATIVE_PLAN") and load() is not None
 
 
 def _sync_plan_segment(lib) -> None:
     """Mirror the YTPU_PLAN_SEGMENT knob into the core's emit_row gate
     so the ``off`` A/B lane also disables the native chain-run anchor
-    adoption (ISSUE 15).  No-op on a stale binary-only .so."""
-    if lib is None or not getattr(lib, "_has_plan_segment", False):
-        return
+    adoption (ISSUE 15)."""
     from . import segment_planner
 
     lib.ymx_set_plan_segment(
@@ -78,14 +74,11 @@ def _sync_plan_segment(lib) -> None:
 def plan_segment_stats() -> tuple[int, int]:
     """Cumulative (chain-run adoptions, fragment-search lookups) across
     every native prepare in the process; callers diff around a flush.
-    (0, 0) when the core (or the symbol) is unavailable."""
+    (0, 0) when the core is unavailable."""
     if not native_plan_available():
         return (0, 0)
-    lib = load()
-    if lib is None or not getattr(lib, "_has_plan_segment", False):
-        return (0, 0)
     out = np.zeros(2, np.int64)
-    lib.ymx_plan_segment_stats(_p64(out))
+    load().ymx_plan_segment_stats(_p64(out))
     return (int(out[0]), int(out[1]))
 
 
@@ -215,7 +208,7 @@ class NativeMirror:
 
     def __init__(self, root_name: str = "text"):
         lib = load()
-        if lib is None or not getattr(lib, "_has_plancore", False):
+        if lib is None:
             raise RuntimeError("native plan core unavailable")
         self._lib = lib
         self._h = lib.ymx_new()
@@ -473,74 +466,24 @@ class NativeMirror:
 
     def rebuild_compacted_self(self, gc: bool):
         """Compact from the mirror's own list state — no device read-back
-        (the flush invariant keeps mirror links == device links).  On a
-        stale binary-only .so without ymx_compact_self, the same inputs
-        are synthesized host-side from the core's link/head exports and
-        fed to the original ymx_compact — still zero device traffic."""
+        (the flush invariant keeps mirror links == device links)."""
         lib, h = self._lib, self._h
         n = self.n_rows
         nseg = self.n_segs
         new_right = np.full(max(1, n), NULL, np.int32)
         new_del = np.zeros(max(1, n), np.uint8)
         new_heads = np.full(max(1, nseg), NULL, np.int32)
-        if getattr(lib, "_has_compact_self", False):
-            n_new = lib.ymx_compact_self(
-                h, int(bool(gc)), _p32(new_right),
-                new_del.ctypes.data_as(_u8p), _p32(new_heads),
-                len(new_heads),
-            )
-            self._realized.clear()
-            # compaction-from-self is a pure function of state already in
-            # the chain: a deterministic fold, so two docs compacted at
-            # the same point keep aliasing each other's cache entries
-            self.plan_frontier = _pc.fold(
-                self.plan_frontier, b"compact-self", b"g" if gc else b"-"
-            )
-            _pc.note_invalidation("compact")
-            return (
-                new_right[:n_new],
-                new_del[:n_new].astype(bool),
-                new_heads,
-            )
-        links = np.full(max(1, n), NULL, np.int64)
-        if n:
-            lib.ymx_links(h, _p64(links))
-        heads = np.full(max(1, nseg), NULL, np.int64)
-        if nseg:
-            lib.ymx_heads(h, _p64(heads))
-        deleted = np.zeros(max(1, n), bool)
-        for r in self._host_deleted_rows:
-            deleted[r] = True
-        return self.rebuild_compacted(
-            links.astype(np.int32), deleted, heads.astype(np.int32), gc
-        )
-
-    def rebuild_compacted(self, right_link, deleted, head_of_seg, gc: bool):
-        lib, h = self._lib, self._h
-        n = self.n_rows
-        nseg = self.n_segs
-        right = np.ascontiguousarray(np.asarray(right_link)[: max(1, n)],
-                                     np.int32)
-        dele = np.ascontiguousarray(
-            np.asarray(deleted)[: max(1, n)].astype(np.uint8)
-        )
-        heads = np.ascontiguousarray(np.asarray(head_of_seg), np.int32)
-        new_right = np.full(max(1, n), NULL, np.int32)
-        new_del = np.zeros(max(1, n), np.uint8)
-        new_heads = np.full(max(1, nseg), NULL, np.int32)
-        n_new = lib.ymx_compact(
-            h, _p32(right), dele.ctypes.data_as(_u8p), _p32(heads),
-            len(heads), int(bool(gc)), _p32(new_right),
-            new_del.ctypes.data_as(_u8p), _p32(new_heads), len(new_heads),
+        n_new = lib.ymx_compact_self(
+            h, int(bool(gc)), _p32(new_right),
+            new_del.ctypes.data_as(_u8p), _p32(new_heads),
+            len(new_heads),
         )
         self._realized.clear()
-        # link/deleted/head inputs come from the caller, so fold their
-        # content in: same inputs -> same chain, anything else diverges
+        # compaction-from-self is a pure function of state already in
+        # the chain: a deterministic fold, so two docs compacted at
+        # the same point keep aliasing each other's cache entries
         self.plan_frontier = _pc.fold(
-            self.plan_frontier,
-            b"compact",
-            right.tobytes() + dele.tobytes() + heads.tobytes()
-            + (b"g" if gc else b"-"),
+            self.plan_frontier, b"compact-self", b"g" if gc else b"-"
         )
         _pc.note_invalidation("compact")
         return (
@@ -849,8 +792,7 @@ def prepare_many(work, want_levels: bool = False, want_sched: bool = True,
     consumer will read it, e.g. the bulk-apply flush with no event
     listeners; ``ymx_prepare``/``prepare_step`` always build it.
 
-    Replaces the per-doc ctypes round trip that made the host planner
-    72% of distinct-doc flush time (BENCH_r03 host_phase_timers).
+    Replaces the per-doc ctypes round trip.
     """
     t0 = time.perf_counter()
     n = len(work)
@@ -858,63 +800,45 @@ def prepare_many(work, want_levels: bool = False, want_sched: bool = True,
     _sync_plan_segment(lib)
     handles = (ctypes.c_void_p * n)()
     buf_ofs = np.zeros(n + 1, np.int64)
-    if getattr(lib, "_has_add_bufs_many", False):
-        # batched staging: ONE native call registers every staged buffer.
-        # The c_char_p array extracts each bytes object's pointer in C
-        # (no per-buffer numpy view); the bytes stay pinned via _py_bufs.
-        all_bytes: list[bytes] = []
-        v2_list: list[int] = []
-        buf_hs = []
-        for k, (_i, m) in enumerate(work):
-            staged = m._incoming
-            buf_ofs[k + 1] = buf_ofs[k] + len(staged)
-            for u, v2 in staged:
-                all_bytes.append(u)
-                v2_list.append(1 if v2 else 0)
-                buf_hs.append(m._h)
-            handles[k] = m._h
-        nb_tot = len(all_bytes)
-        ids_flat = np.zeros(max(1, nb_tot), np.int64)
-        v2_flat = np.asarray(v2_list or [0], np.int64)
-        if nb_tot:
-            ptrs = (ctypes.c_char_p * nb_tot)(*all_bytes)
-            lens = np.fromiter(
-                (len(u) for u in all_bytes), np.uint64, nb_tot
-            )
-            bhs = (ctypes.c_void_p * nb_tot)(*buf_hs)
-            lib.ymx_add_bufs_many(
-                bhs, ptrs,
-                lens.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-                nb_tot,
-                _p64(ids_flat),
-            )
-        staged_info = []
-        o = 0
-        for k, (_i, m) in enumerate(work):
-            staged = m._incoming
-            nb = len(staged)
-            ids = ids_flat[o : o + nb]
-            for j, (u, _v2) in enumerate(staged):
-                m._py_bufs[int(ids[j])] = (u, None)
-            staged_info.append((staged, np.asarray(ids, np.int64)))
-            o += nb
-    else:  # stale binary-only .so: per-doc staging
-        ids_parts, v2_parts, staged_info = [], [], []
-        for k, (_i, m) in enumerate(work):
-            staged, ids, v2s = m._stage_bufs()
-            nb = len(staged)
-            staged_info.append((staged, ids))
-            buf_ofs[k + 1] = buf_ofs[k] + nb
-            if nb:
-                ids_parts.append(ids[:nb])
-                v2_parts.append(v2s[:nb])
-            handles[k] = m._h
-        ids_flat = (
-            np.concatenate(ids_parts) if ids_parts else np.zeros(1, np.int64)
+    # batched staging: ONE native call registers every staged buffer.
+    # The c_char_p array extracts each bytes object's pointer in C
+    # (no per-buffer numpy view); the bytes stay pinned via _py_bufs.
+    all_bytes: list[bytes] = []
+    v2_list: list[int] = []
+    buf_hs = []
+    for k, (_i, m) in enumerate(work):
+        staged = m._incoming
+        buf_ofs[k + 1] = buf_ofs[k] + len(staged)
+        for u, v2 in staged:
+            all_bytes.append(u)
+            v2_list.append(1 if v2 else 0)
+            buf_hs.append(m._h)
+        handles[k] = m._h
+    nb_tot = len(all_bytes)
+    ids_flat = np.zeros(max(1, nb_tot), np.int64)
+    v2_flat = np.asarray(v2_list or [0], np.int64)
+    if nb_tot:
+        ptrs = (ctypes.c_char_p * nb_tot)(*all_bytes)
+        lens = np.fromiter(
+            (len(u) for u in all_bytes), np.uint64, nb_tot
         )
-        v2_flat = (
-            np.concatenate(v2_parts) if v2_parts else np.zeros(1, np.int64)
+        bhs = (ctypes.c_void_p * nb_tot)(*buf_hs)
+        lib.ymx_add_bufs_many(
+            bhs, ptrs,
+            lens.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+            nb_tot,
+            _p64(ids_flat),
         )
+    staged_info = []
+    o = 0
+    for k, (_i, m) in enumerate(work):
+        staged = m._incoming
+        nb = len(staged)
+        ids = ids_flat[o : o + nb]
+        for j, (u, _v2) in enumerate(staged):
+            m._py_bufs[int(ids[j])] = (u, None)
+        staged_info.append((staged, np.asarray(ids, np.int64)))
+        o += nb
     counts = np.zeros((n, 16), np.int64)
     rcs = np.zeros(n, np.int64)
     lib.ymx_prepare_many(

@@ -16,29 +16,26 @@ Axes:
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map_impl
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8: VMA checking is on by default; our kernels create
-    # unvarying intermediates inside the mapped fn, so disable it
-    from jax import shard_map as _shard_map_impl
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
 
 from ..obs.prof import profiled
 from ..ops import kernels
+
+logger = logging.getLogger("yjs_tpu.parallel")
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    # VMA checking is on by default; the kernels create unvarying
+    # intermediates inside the mapped fn, so disable it
+    return _shard_map_impl(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def doc_mesh(
@@ -86,6 +83,11 @@ def shard_meshes(
     if devices_per_shard is None:
         devices_per_shard = len(devs) // n_shards
     if devices_per_shard < 1 or len(devs) < n_shards * devices_per_shard:
+        logger.warning(
+            "%d shards asked for %d device(s) each and the %s backend has "
+            "%d: every shard runs unmeshed on the default device",
+            n_shards, max(1, devices_per_shard), devs[0].platform, len(devs),
+        )
         return [None] * n_shards
     import numpy as np
 

@@ -135,6 +135,16 @@ def encode_busy(retry_after: int) -> bytes:
     return enc.to_bytes()
 
 
+def encode_pong() -> bytes:
+    """One PONG envelope frame: ``121 | K_PONG``.  Stateless, so an
+    endpoint's keepalive thread can send it without the session lock;
+    any inbound frame refreshes the receiver's liveness window."""
+    enc = Encoder()
+    encoding.write_var_uint(enc, MESSAGE_YTPU_SESSION)
+    encoding.write_var_uint(enc, K_PONG)
+    return enc.to_bytes()
+
+
 def _env_int(name: str, default: int, lo: int = 0,
              hi: int = 1 << 30) -> int:
     try:
