@@ -26,6 +26,7 @@ from yjs_tpu.analysis.drift import (  # noqa: E402
     KNOB_RE,
     documented_metrics,
     live_comparison,
+    live_metric_names,
 )
 
 
@@ -37,34 +38,8 @@ def documented_names(readme_text: str) -> set[str]:
 
 
 def registered_names() -> set[str]:
-    from yjs_tpu.analysis.runner import register_lint_metric
-    from yjs_tpu.fleet import FleetRouter
-    from yjs_tpu.obs import global_registry
-    from yjs_tpu.provider import TpuProvider
-
-    prov = TpuProvider(1)
-    # the smallest possible fleet registers every ytpu_fleet_* family
-    # on the global registry (ISSUE 6); the lint counter is part of the
-    # documented contract too
-    FleetRouter(1, 1)
-    register_lint_metric()
-    # cluster families are lazily-registered process-global singletons —
-    # touch each holder so the live set includes them
-    from yjs_tpu.cluster.gateway import _GatewayMetricsSingleton
-    from yjs_tpu.cluster.rpc import rpc_metrics
-    from yjs_tpu.cluster.supervisor import _ClusterMetrics
-
-    _GatewayMetricsSingleton.get()
-    rpc_metrics()
-    _ClusterMetrics()
-    from yjs_tpu.obs.admin import admin_metrics
-    from yjs_tpu.obs.federate import fed_metrics
-
-    admin_metrics()
-    fed_metrics()
-    return set(prov.engine.obs.registry.names()) | set(
-        global_registry().names()
-    )
+    """Live family names, every lazily registered holder touched."""
+    return live_metric_names()
 
 
 def resilience_knobs_in_code() -> set[str]:

@@ -1,0 +1,304 @@
+"""One span API on the profiler's clock (``yjs_tpu/obs/trace.py``).
+
+A small ``TpuProvider`` with a WAL takes updates and flushes, with a
+compaction and an update-log fold forced, under ``jax.profiler``.  What
+the benchmark's ``trace_reduce.read_xplane`` reads back holds every span
+of the program's contract (``tests/bench/data/spans_synthetic.json``,
+which the per-layer readers are held to as well) under its bare name,
+each child inside its parent on one thread.  With the profiler off the
+ring holds what it held before the two systems met; under
+``YTPU_OBS_DISABLED=1`` it holds nothing and the profiler still sees
+every span.
+"""
+
+import collections
+import json
+import sys
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parent.parent
+if str(ROOT) not in sys.path:  # the benchmark's reader, as tests/bench does
+    sys.path.insert(0, str(ROOT))
+
+import yjs_tpu as Y
+from yjs_tpu.admission import AdmissionConfig
+from yjs_tpu.persistence import WalConfig
+from yjs_tpu.provider import TpuProvider
+
+jax = pytest.importorskip("jax")
+
+from benchmarks import trace_reduce  # noqa: E402
+
+PARENTS = json.loads(
+    (ROOT / "tests" / "bench" / "data" / "spans_synthetic.json").read_text()
+)["parents"]
+# spans the ring held before this PR (the journal's record of an append
+# among them, now a span the journal opens itself), spans it holds from
+# this PR on (one a flush), and the per-update child it must never hold
+RING_BEFORE = {
+    "ytpu.provider.receive_update", "ytpu.provider.flush", "ytpu.flush",
+    "ytpu.compact", "ytpu.plan", "ytpu.pack", "ytpu.dispatch", "ytpu.emit",
+    "ytpu.wal.append",
+}
+PROFILER_ONLY = {"ytpu.slo.receive"}
+PER_UPDATE = PROFILER_ONLY | {
+    "ytpu.wal.append", "ytpu.provider.receive_update",
+}
+RING_NEW = set(PARENTS) - RING_BEFORE - PROFILER_ONLY
+FLUSH_EVERY = 10
+
+
+def keystrokes(n: int, client: int) -> list[bytes]:
+    """One update a keystroke, as a y-websocket client sends them; a
+    deletion now and then so a compaction has runs to merge."""
+    doc = Y.Doc(gc=False)
+    doc.client_id = client
+    out: list[bytes] = []
+    doc.on("update", lambda u, *_: out.append(u))
+    text = doc.get_text("text")
+    for k in range(n):
+        text.insert(len(str(text)), "ab" if k % 3 else "c")
+        if k % 5 == 4:
+            text.delete(0, 1)
+    return out
+
+
+def drive(prov, updates) -> int:
+    """Every update into one room, a flush every ten and at the end:
+    more than 64 log entries (a fold) and a table that doubles (a
+    compaction).  Returns the number of flushes."""
+    flushes = 0
+    for k, u in enumerate(updates):
+        assert prov.receive_update("room", u)
+        if k % FLUSH_EVERY == FLUSH_EVERY - 1:
+            prov.flush()
+            flushes += 1
+    prov.flush()
+    prov.slo_snapshot()  # the burn pass from another caller than visible()
+    return flushes + 1
+
+
+def provider(tmp_path, **kw):
+    prov = TpuProvider(
+        4, wal_dir=str(tmp_path / "wal"), wal_config=WalConfig(fsync="never"),
+        **kw,
+    )
+    prov.engine.compact_min_rows = 8
+    prov.on_update(lambda guid, update: None)
+    return prov
+
+
+def traced(tmp_path, run):
+    """``run()`` under the profiler, read back as the benchmark reads a
+    traced run: ``[plane, line, name, start_ns, duration_ns]``."""
+    trace_dir = tmp_path / "trace"
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        out = run()
+    finally:
+        jax.profiler.stop_trace()
+    events = trace_reduce.read_xplane(trace_reduce.find_xplane(trace_dir))
+    return out, [e for e in events if e[2].startswith("ytpu.")]
+
+
+@pytest.fixture(scope="module")
+def run(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("span_clock")
+    prov = provider(tmp)
+    updates = keystrokes(70, 7)
+    flushes, events = traced(tmp, lambda: drive(prov, updates))
+    assert prov.engine.last_compaction and not prov.engine.fallback
+    ring = prov.engine.obs.tracer.trace_events()
+    prov.close(checkpoint=False)
+    return {
+        "events": events, "ring": ring, "updates": len(updates),
+        "flushes": flushes,
+    }
+
+
+def test_the_profiler_holds_the_contract_and_nothing_else(run):
+    """Bare names: a span whose arguments came back in its name would
+    show here as a name outside the contract."""
+    assert {e[2] for e in run["events"]} == set(PARENTS)
+    assert len({(e[0], e[1]) for e in run["events"]}) == 1  # one thread
+
+
+@pytest.mark.parametrize("name", sorted(PARENTS))
+def test_span_on_the_profilers_clock_inside_its_parent(run, name):
+    mine = [e for e in run["events"] if e[2] == name]
+    assert mine, f"{name} is not in the device trace"
+    if name in PER_UPDATE:
+        assert len(mine) == run["updates"]
+    elif name in ("ytpu.provider.flush", "ytpu.flush", "ytpu.slo.visible",
+                  "ytpu.cost.on_flush", "ytpu.compact", "ytpu.emit",
+                  "ytpu.compact.scan", "ytpu.emit.fold"):
+        assert len(mine) == run["flushes"]
+    parent = PARENTS[name]
+    if parent is None:
+        return
+    # ytpu.slo.burn also runs for state() and snapshot(), whose caller
+    # is not the program's: there it has no parent
+    orphans = 1 if name == "ytpu.slo.burn" else 0
+    around = [
+        (e[3], e[3] + e[4]) for e in run["events"]
+        if e[2] == parent and (e[0], e[1]) == (mine[0][0], mine[0][1])
+    ]
+    outside = [
+        e for e in mine
+        if not any(a <= e[3] and e[3] + e[4] <= b for a, b in around)
+    ]
+    assert len(outside) == orphans, (name, parent, outside[:3])
+
+
+@pytest.mark.parametrize("name", sorted(set(PARENTS) | {"ytpu.convergence"}))
+def test_ring_holds_what_it_held_and_the_per_flush_spans(run, name):
+    """Profiler on or off the ring is the same code path; ``run`` had it
+    on, ``test_profiler_off_ring`` below has it off."""
+    check_ring(run["ring"], run["updates"], run["flushes"], name)
+
+
+def check_ring(ring, updates, flushes, name):
+    got = collections.Counter(
+        (e["name"], e["ph"]) for e in ring if e["ph"] != "M"
+    )
+    if name == "ytpu.convergence":  # one arrow an update, both ends
+        assert got[(name, "s")] == got[(name, "f")] == updates
+    elif name in ("ytpu.provider.receive_update", "ytpu.wal.append"):
+        assert got[(name, "X")] == updates  # one an update, as before
+    elif name == "ytpu.slo.receive":
+        assert (name, "X") not in got
+    elif name in ("ytpu.plan", "ytpu.pack", "ytpu.dispatch"):
+        assert got[(name, "X")] >= flushes  # one a chunk
+    elif name in RING_BEFORE or name in (
+        "ytpu.slo.visible", "ytpu.cost.on_flush", "ytpu.compact.scan",
+        "ytpu.emit.fold",
+    ):
+        assert got[(name, "X")] == flushes
+    else:
+        assert name in RING_NEW and 1 <= got[(name, "X")] <= flushes + 1
+    assert {n for n, _ in got} == set(PARENTS) - {"ytpu.slo.receive"} | {
+        "ytpu.convergence"
+    }
+
+
+def test_profiler_off_ring(tmp_path):
+    prov = provider(tmp_path)
+    updates = keystrokes(70, 9)
+    flushes = drive(prov, updates)
+    ring = prov.engine.obs.tracer.trace_events()
+    for name in sorted(set(PARENTS) | {"ytpu.convergence"}):
+        check_ring(ring, len(updates), flushes, name)
+    # nesting in the ring as before: a phase inside ytpu.flush inside
+    # ytpu.provider.flush, by the ring's own clock
+    spans = [e for e in ring if e["ph"] == "X"]
+
+    def inside(child, parent):
+        return any(
+            p["name"] == parent and p["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= p["ts"] + p["dur"]
+            for p in spans
+        )
+
+    for e in spans:
+        parent = PARENTS.get(e["name"])
+        if parent and e["name"] != "ytpu.slo.burn":
+            assert inside(e, parent), e["name"]
+    # the journal's span keeps the argument its record had
+    assert all(
+        e["args"] == {"kind": "update"} for e in spans
+        if e["name"] == "ytpu.wal.append"
+    )
+    # the phase spans carry no argument dicts any more; the receive span
+    # keeps its guid
+    assert all(
+        "args" not in e for e in spans if e["name"] in
+        ("ytpu.plan", "ytpu.pack", "ytpu.dispatch")
+    )
+    assert all(
+        e["args"]["guid"] == "room" for e in spans
+        if e["name"] == "ytpu.provider.receive_update"
+    )
+    prov.close(checkpoint=False)
+
+
+def test_obs_disabled_empties_the_ring_and_keeps_the_profiler(
+    tmp_path, monkeypatch
+):
+    monkeypatch.setenv("YTPU_OBS_DISABLED", "1")
+    prov = provider(tmp_path)
+    assert not prov.engine.obs.tracer.enabled
+    updates = keystrokes(70, 11)
+    _, events = traced(tmp_path, lambda: drive(prov, updates))
+    assert prov.engine.obs.tracer.trace_events() == []
+    # the tracker is off with the registry and returns before its two
+    # passes; everything else reaches the profiler as with obs on
+    assert {e[2] for e in events} == set(PARENTS) - {
+        "ytpu.slo.visible", "ytpu.slo.burn",
+    }
+    prov.close(checkpoint=False)
+
+
+def test_queued_update_is_spanned_for_the_profiler_only(tmp_path):
+    """The admission queue's branch of receive_update: its span reaches
+    the profiler and stays out of the ring, as that branch always did;
+    the journal's span is the journal's, whichever branch appends."""
+    prov = provider(tmp_path, admission_config=AdmissionConfig(
+        enabled=True, tenant_rate=0.0, tenant_burst=1, doc_rate=0.0,
+        doc_burst=1, queue_max=64,
+    ))
+    updates = keystrokes(3, 13)[:3]
+
+    def go():
+        for u in updates:
+            assert prov.receive_update("room", u)
+        assert prov.admission.snapshot()["queued"] == 2
+
+    _, events = traced(tmp_path, go)
+    names = collections.Counter(e[2] for e in events)
+    assert names["ytpu.provider.receive_update"] == 3
+    assert names["ytpu.wal.append"] == 3
+    assert names["ytpu.slo.receive"] == 1  # the tracker waits for the drain
+    ring = collections.Counter(
+        e["name"] for e in prov.engine.obs.tracer.trace_events()
+    )
+    assert ring["ytpu.provider.receive_update"] == 1
+    assert ring["ytpu.wal.append"] == 3
+    prov.close(checkpoint=False)
+
+
+def test_tracker_without_a_tracer_spans_on_the_one_it_is_handed():
+    """The session and supervisor trackers are built without a tracer
+    and handed one at ``visible``: both passes open their span on it."""
+    from yjs_tpu.obs import MetricsRegistry, Tracer
+    from yjs_tpu.obs.slo import ConvergenceTracker
+
+    tracker, tracer = ConvergenceTracker(MetricsRegistry()), Tracer()
+    key = tracker.receive(keystrokes(1, 15)[0])
+    tracker.integrated(key)
+    assert tracker.visible(tracer=tracer) == 1
+    assert tracker.visible() == 0 and tracker.state() == "ok"  # no tracer
+    assert sorted(
+        e["name"] for e in tracer.trace_events() if e["ph"] == "X"
+    ) == ["ytpu.slo.burn", "ytpu.slo.visible"]
+
+
+def test_importing_obs_does_not_load_jax():
+    """The annotation is jax's, and is imported by the first Tracer:
+    sessions, the lint and the CLIs import ``yjs_tpu.obs`` without it."""
+    import subprocess
+
+    code = (
+        "import sys, yjs_tpu.obs, yjs_tpu.obs.trace, yjs_tpu.sync.session\n"
+        "assert 'jax' not in sys.modules, 'jax loaded'\n"
+        "yjs_tpu.obs.trace.Tracer(enabled=False)\n"
+        "assert 'jax.profiler' in sys.modules\n"
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, capture_output=True,
+        text=True, timeout=120,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]

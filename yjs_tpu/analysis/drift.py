@@ -275,19 +275,13 @@ class DriftChecker(Checker):
                 )
 
 
-def live_comparison(root) -> list:
-    """The original check_metrics_schema live diff: registered metric
-    names (instantiating TpuProvider + FleetRouter) vs README's table,
-    plus the curated-knob README/code cross-check.  Returns a list of
-    human-readable problem strings (empty = in agreement).  Imports the
-    package — callers needing a jax-free path use :class:`DriftChecker`.
-    """
-    from pathlib import Path
-
-    root = Path(root)
-    readme = (root / "README.md").read_text()
-    problems: list = []
-
+def live_metric_names() -> set:
+    """Every metric family a process can register, live: a provider and
+    the smallest fleet are instantiated and EVERY lazily registered
+    holder is touched, so the answer does not depend on what the
+    process happened to import or run before (the one list of holders;
+    ``scripts/check_metrics_schema.py`` and :func:`live_comparison`
+    both read it).  Empty under ``YTPU_OBS_DISABLED``."""
     from yjs_tpu.fleet import FleetRouter
     from yjs_tpu.obs import global_registry
     from yjs_tpu.provider import TpuProvider
@@ -325,9 +319,25 @@ def live_comparison(root) -> list:
     from yjs_tpu.obs.tsdb import tsdb_metrics
 
     tsdb_metrics()
-    live = set(prov.engine.obs.registry.names()) | set(
+    return set(prov.engine.obs.registry.names()) | set(
         global_registry().names()
     )
+
+
+def live_comparison(root) -> list:
+    """The original check_metrics_schema live diff: registered metric
+    names (instantiating TpuProvider + FleetRouter) vs README's table,
+    plus the curated-knob README/code cross-check.  Returns a list of
+    human-readable problem strings (empty = in agreement).  Imports the
+    package — callers needing a jax-free path use :class:`DriftChecker`.
+    """
+    from pathlib import Path
+
+    root = Path(root)
+    readme = (root / "README.md").read_text()
+    problems: list = []
+
+    live = live_metric_names()
     if not live:
         return []  # obs disabled (YTPU_OBS_DISABLED) — nothing to check
     doc = documented_metrics(readme)

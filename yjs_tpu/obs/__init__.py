@@ -7,8 +7,9 @@ Four pieces (ISSUE 1 tentpole):
 - :mod:`.history` — the bounded flush-history ring superseding the
   overwrite-only ``last_flush_metrics`` (which remains as a
   compatibility view of the newest entry);
-- :mod:`.trace` — host-side phase spans exported as Chrome-trace JSON,
-  layered on the existing ``jax.profiler.TraceAnnotation`` wrappers;
+- :mod:`.trace` — the program's one span API: every span lands on the
+  JAX profiler's clock and, tracer enabled, in a host ring exported as
+  Chrome-trace JSON;
 - :mod:`.expo` — Prometheus text dump + JSON snapshot.
 
 Fleet-wide observability (ISSUE 11):
@@ -23,8 +24,9 @@ Fleet-wide observability (ISSUE 11):
   keep per-shard series, histograms merge) shared by
   ``FleetRouter.metrics_snapshot``, ``ytpu_top`` and ``ytpu_stats``.
 
-Env knobs: ``YTPU_OBS_DISABLED=1`` (no-op registry + tracer; the flush
-history stays on so ``last_flush_metrics`` keeps its contract),
+Env knobs: ``YTPU_OBS_DISABLED=1`` (no-op registry, empty trace ring:
+spans still reach an active profiler trace; the flush history stays on
+so ``last_flush_metrics`` keeps its contract),
 ``YTPU_OBS_HISTORY`` (ring size, default 128), ``YTPU_TRACE_PATH``
 (write a merged Chrome trace at interpreter exit), ``YTPU_TRACE_EVENTS``
 (per-tracer event cap, default 200k).

@@ -33,6 +33,7 @@ and a short window (long/12), each divided by the error budget
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -62,6 +63,13 @@ _STATE_CODES = {"ok": 0, "warning": 1, "page": 2}
 # the same id.  A keyed hash is stable under truncation AND matches
 # across providers/processes, which is what lets one update's
 # convergence arrows stitch into a single cross-peer trace.
+
+
+def _span(tracer, name):
+    """``tracer.span(name)``, or nothing for a tracker without a tracer."""
+    if tracer is None:
+        return contextlib.nullcontext()
+    return tracer.span(name)
 
 
 def update_key(update: bytes, v2: bool = False) -> tuple[int, int]:
@@ -272,80 +280,84 @@ class ConvergenceTracker:
             return 0
         if tracer is None:
             tracer = self.tracer
-        t = self._now()
-        with self._lock:
-            done = [
-                (k, self._pending.pop(k))
-                for k in [
-                    k for k, rec in self._pending.items()
-                    if rec[2] is not None
-                ]
-            ]
-        for k, rec in done:
-            t_origin, t_recv, t_int, flow_id, trace_hex = rec
-            total = max(0.0, t - t_origin)
-            self._latency.observe(total)
-            self._stage["receive"].observe(max(0.0, t_recv - t_origin))
-            self._stage["integrate"].observe(max(0.0, t_int - t_recv))
-            self._stage["visible"].observe(max(0.0, t - t_int))
-            breached = total * 1000.0 > self.target_ms
-            self._m_completed.inc()
-            if breached:
-                self._m_breaches.inc()
+        with _span(tracer, "ytpu.slo.visible"):
+            t = self._now()
             with self._lock:
-                self._events.append((t, breached))
-            self._completed += 1
-            if tracer is not None:
-                args = {
-                    "latency_ms": round(total * 1000.0, 3),
-                    "breached": breached,
-                }
-                if trace_hex is not None:
-                    args["trace"] = trace_hex
-                tracer.flow_end("ytpu.convergence", flow_id, **args)
-        if done:
-            self._update_state()
-        return len(done)
+                done = [
+                    (k, self._pending.pop(k))
+                    for k in [
+                        k for k, rec in self._pending.items()
+                        if rec[2] is not None
+                    ]
+                ]
+            for k, rec in done:
+                t_origin, t_recv, t_int, flow_id, trace_hex = rec
+                total = max(0.0, t - t_origin)
+                self._latency.observe(total)
+                self._stage["receive"].observe(max(0.0, t_recv - t_origin))
+                self._stage["integrate"].observe(max(0.0, t_int - t_recv))
+                self._stage["visible"].observe(max(0.0, t - t_int))
+                breached = total * 1000.0 > self.target_ms
+                self._m_completed.inc()
+                if breached:
+                    self._m_breaches.inc()
+                with self._lock:
+                    self._events.append((t, breached))
+                self._completed += 1
+                if tracer is not None:
+                    args = {
+                        "latency_ms": round(total * 1000.0, 3),
+                        "breached": breached,
+                    }
+                    if trace_hex is not None:
+                        args["trace"] = trace_hex
+                    tracer.flow_end("ytpu.convergence", flow_id, **args)
+            if done:
+                self._update_state(tracer)
+            return len(done)
 
     # -- burn-rate state ----------------------------------------------
 
-    def _update_state(self) -> None:
-        now = self._now()
-        budget = max(1e-9, 1.0 - self.objective)
-        burns = {}
-        windows = {}
-        with self._lock:
-            events = tuple(self._events)
-        for wname, wlen in (
-            ("short", self.short_window_s), ("long", self.window_s)
-        ):
-            total = breached = 0
-            for t, b in reversed(events):
-                if now - t > wlen:
-                    break
-                total += 1
-                if b:
-                    breached += 1
-            frac = breached / total if total else 0.0
-            burns[wname] = frac / budget
-            windows[wname] = {
-                "total": total,
-                "breached": breached,
-                "breach_fraction": frac,
-            }
-        worst_common = min(burns.values())
-        if worst_common >= PAGE_BURN:
-            state = "page"
-        elif worst_common >= WARN_BURN:
-            state = "warning"
-        else:
-            state = "ok"
-        self._burns = burns
-        self._windows = windows
-        self._state = state
-        self._burn["short"].set(burns["short"])
-        self._burn["long"].set(burns["long"])
-        self._m_state.set(_STATE_CODES[state])
+    def _update_state(self, tracer=None) -> None:
+        if tracer is None:
+            tracer = self.tracer
+        with _span(tracer, "ytpu.slo.burn"):
+            now = self._now()
+            budget = max(1e-9, 1.0 - self.objective)
+            burns = {}
+            windows = {}
+            with self._lock:
+                events = tuple(self._events)
+            for wname, wlen in (
+                ("short", self.short_window_s), ("long", self.window_s)
+            ):
+                total = breached = 0
+                for t, b in reversed(events):
+                    if now - t > wlen:
+                        break
+                    total += 1
+                    if b:
+                        breached += 1
+                frac = breached / total if total else 0.0
+                burns[wname] = frac / budget
+                windows[wname] = {
+                    "total": total,
+                    "breached": breached,
+                    "breach_fraction": frac,
+                }
+            worst_common = min(burns.values())
+            if worst_common >= PAGE_BURN:
+                state = "page"
+            elif worst_common >= WARN_BURN:
+                state = "warning"
+            else:
+                state = "ok"
+            self._burns = burns
+            self._windows = windows
+            self._state = state
+            self._burn["short"].set(burns["short"])
+            self._burn["long"].set(burns["long"])
+            self._m_state.set(_STATE_CODES[state])
 
     def state(self) -> str:
         """Current burn-rate verdict (``ok``/``warning``/``page``),

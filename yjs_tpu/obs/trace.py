@@ -1,11 +1,14 @@
-"""Host-side span tracing with Chrome-trace-format JSON export.
+"""The program's one span API, and its host ring with Chrome-trace export.
 
-Records phase spans (compact/plan/pack/dispatch/emit, planner-pool and
-demotion events) into an in-memory bounded buffer and exports the
-Chrome ``traceEvents`` JSON that Perfetto / chrome://tracing load
-directly.  This LAYERS ON the existing ``jax.profiler.TraceAnnotation``
-wrappers (which only surface inside an active device profiler trace) —
-the host spans are always available, profiler attached or not.
+``Tracer.span(name)`` is the only way the program opens a span.  Every
+span opens a ``jax.profiler.TraceAnnotation(name)``, which the profiler
+records while a trace is being captured (``jax.profiler.start_trace``)
+on the same clock as the device's ``XLA Ops``, and which costs next to
+nothing otherwise.  An enabled tracer also records the span into an
+in-memory bounded ring, always available, profiler attached or not,
+and exports it as the Chrome ``traceEvents`` JSON that Perfetto /
+chrome://tracing load directly.  Instants and flow arrows live in the
+ring only.
 
 ``YTPU_TRACE_PATH=<file>`` makes every tracer created while the variable
 is set register for an atexit dump: all their events merge into one
@@ -26,16 +29,19 @@ DEFAULT_MAX_EVENTS = 200_000
 
 
 class _Span:
-    """Reusable context manager recording one complete ("X") event."""
+    """One span on both clocks: the profiler's annotation around one
+    complete ("X") ring event."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
-    def __init__(self, tracer, name, args):
+    def __init__(self, tracer, name, args, ann):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._ann = ann
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -51,26 +57,19 @@ class _Span:
             self._args,
             None,
         ))
+        self._ann.__exit__(exc_type, exc, tb)
         return False
-
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class Tracer:
     """Bounded in-memory span/event recorder (oldest events evicted)."""
 
     def __init__(self, enabled: bool = True, max_events: int | None = None):
+        # imported here, not with the module: yjs_tpu.obs stays
+        # importable (sessions, the lint, the CLIs) without loading jax
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self.enabled = enabled
         if max_events is None:
             try:
@@ -88,11 +87,17 @@ class Tracer:
         if enabled and os.environ.get("YTPU_TRACE_PATH"):
             _register_for_exit_dump(self)
 
-    def span(self, name: str, **args):
-        """Context manager recording a complete span around its body."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args or None)
+    def span(self, name: str, _ring: bool = True, **args):
+        """Context manager around one span's body.  The profiler sees
+        the span under its bare ``name`` whenever a trace is being
+        captured, enabled tracer or not; ``args`` go to the ring.
+        ``_ring=False`` keeps a per-update child span out of the ring,
+        which already takes that update's span, flow start and journal
+        record (costs measured: PERF.md 6, PR 25)."""
+        ann = self._annotation(name)
+        if not (_ring and self.enabled):
+            return ann
+        return _Span(self, name, args or None, ann)
 
     def instant(self, name: str, **args) -> None:
         """Record a zero-duration marker (demotion, pool event, ...)."""

@@ -159,35 +159,6 @@ def _done_token(table):
     return table[:, 0].sum()
 
 
-def _phase(name: str):
-    """jax.profiler annotation around one flush phase — visible in any
-    active jax.profiler trace (the per-phase tracing SURVEY.md §5 calls
-    for); free when no trace is being captured."""
-    return jax.profiler.TraceAnnotation(f"ytpu.{name}")
-
-
-class _PhasePair:
-    """Two stacked phase contexts without ExitStack overhead — _phase_ctx
-    sits on the per-flush hot path (7 entries per flush)."""
-
-    __slots__ = ("_outer", "_inner")
-
-    def __init__(self, outer, inner):
-        self._outer = outer
-        self._inner = inner
-
-    def __enter__(self):
-        self._outer.__enter__()
-        self._inner.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        try:
-            return self._inner.__exit__(*exc)
-        finally:
-            self._outer.__exit__(*exc)
-
-
 def _pipeline_on() -> bool:
     """YTPU_FLUSH_PIPELINE knob: pipelined flush is the default; ``0`` /
     ``false`` / ``off`` restores the fully synchronous dispatch (the A/B
@@ -918,12 +889,13 @@ class BatchEngine:
         analogue of the reference's per-transaction merge/GC passes,
         Transaction.js:165-238,299-332).  Keeps row count bounded by the
         doc's true run structure instead of its edit history."""
-        todo = [
-            i
-            for i, m in enumerate(self.mirrors)
-            if i not in self.fallback
-            and m.n_rows >= max(self.compact_min_rows, 2 * self._rows_at_compact[i])
-        ]
+        with self._phase_ctx("compact.scan"):
+            todo = [
+                i
+                for i, m in enumerate(self.mirrors)
+                if i not in self.fallback
+                and m.n_rows >= max(self.compact_min_rows, 2 * self._rows_at_compact[i])
+            ]
         if not todo or self._right is None:
             return
         self.last_compaction = self._compact_rows(todo, self.gc)
@@ -936,32 +908,38 @@ class BatchEngine:
         flush invariant (YTPU_EXPORT_DEVICE pins it), so merges are
         decided WITHOUT any device read-back; the device gets the
         rebuilt rows in one write-only scatter."""
-        idx = self._put_r(np.asarray(todo, np.int32))
+        span = self._phase_ctx
         cap1 = self._cap + 1
         seg1 = self._seg_cap + 1
-        new_right = np.full((len(todo), cap1), NULL, np.int32)
-        new_deleted = np.zeros((len(todo), cap1), bool)
-        new_starts = np.full((len(todo), seg1), NULL, np.int32)
+        with span("compact.alloc"):
+            new_right = np.full((len(todo), cap1), NULL, np.int32)
+            new_deleted = np.zeros((len(todo), cap1), bool)
+            new_starts = np.full((len(todo), seg1), NULL, np.int32)
         stats = []
-        for j, i in enumerate(todo):
-            # a fresh rebuild supersedes any still-pending hydration
-            self._pending_hydration.pop(i, None)
-            m = self.mirrors[i]
-            old_n = m.n_rows
-            r, d, h = m.rebuild_compacted_self(gc)
-            n_new = len(r)
-            new_right[j, :n_new] = r
-            new_deleted[j, :n_new] = d
-            new_starts[j, : len(h)] = h
-            self._rows_at_compact[i] = n_new
-            self._uploaded_rows[i] = 0  # renumbered: statics re-upload
-            stats.append(
-                {"doc": i, "rows_before": old_n, "rows_after": n_new}
+        with span("compact.rebuild"):
+            for j, i in enumerate(todo):
+                # a fresh rebuild supersedes any still-pending hydration
+                self._pending_hydration.pop(i, None)
+                m = self.mirrors[i]
+                old_n = m.n_rows
+                r, d, h = m.rebuild_compacted_self(gc)
+                n_new = len(r)
+                new_right[j, :n_new] = r
+                new_deleted[j, :n_new] = d
+                new_starts[j, : len(h)] = h
+                self._rows_at_compact[i] = n_new
+                self._uploaded_rows[i] = 0  # renumbered: statics re-upload
+                stats.append(
+                    {"doc": i, "rows_before": old_n, "rows_after": n_new}
+                )
+        with span("compact.put"):
+            rows = (
+                self._put_r(np.asarray(todo, np.int32)),
+                self._put_r(new_right), self._put_r(new_deleted),
+                self._put_r(new_starts),
             )
-        self._dispatch(
-            "rows", idx, self._put_r(new_right), self._put_r(new_deleted),
-            self._put_r(new_starts),
-        )
+        with span("compact.scatter"):
+            self._dispatch("rows", *rows)
         return stats
 
     def compact_docs(self, docs, gc: bool = True) -> list[dict]:
@@ -1090,11 +1068,11 @@ class BatchEngine:
 
     # -- flush: run one device integration step ----------------------------
 
-    def _phase_ctx(self, name: str, **args):
-        """One flush phase: the jax.profiler annotation (visible inside an
-        active device profiler trace) stacked with an obs host span (always
-        recorded, exported via export_chrome_trace)."""
-        return _PhasePair(_phase(name), self.obs.tracer.span(f"ytpu.{name}", **args))
+    def _phase_ctx(self, name: str):
+        """One flush phase or a part of one: a span of the program's one
+        span API (obs/trace.py: the profiler's clock, and the host ring
+        behind export_chrome_trace)."""
+        return self.obs.tracer.span(f"ytpu.{name}")
 
     def _finish_flush(self, metrics: dict) -> None:
         """The single exit point of every flush path: append to the flush
@@ -1523,10 +1501,11 @@ class BatchEngine:
             # every doc that reached emit integrated cleanly this flush
             for i in plans:
                 self.health.record_success(i)
-        for i in plans:
-            m = self.mirrors[i]
-            if len(self._update_log[i]) > 64 and not m.has_pending():
-                self._update_log[i] = [(m.encode_state_as_update(), False)]
+        with self._phase_ctx("emit.fold"):
+            for i in plans:
+                m = self.mirrors[i]
+                if len(self._update_log[i]) > 64 and not m.has_pending():
+                    self._update_log[i] = [(m.encode_state_as_update(), False)]
         if emitting:
             for i, p in plans.items():
                 u = self.mirrors[i].encode_step_update(pre_svs[i], p)
@@ -1671,8 +1650,7 @@ class BatchEngine:
             chunk = items[c0 : c0 + chunk_sz]
             t0 = time.perf_counter()
             if native:
-                with self._phase_ctx("plan", chunk=c0 // chunk_sz,
-                                     docs=len(chunk)):
+                with self._phase_ctx("plan"):
                     chunk_ok = self._plan_chunk_native(chunk, pre_svs, acc)
             else:
                 chunk_ok = chunk
@@ -1680,7 +1658,7 @@ class BatchEngine:
             t_plan_acc += t1 - t0
             if not chunk_ok:
                 continue
-            with self._phase_ctx("pack", chunk=c0 // chunk_sz), pl.pack():
+            with self._phase_ctx("pack"), pl.pack():
                 if native:
                     slot, key, stats, max_rows = self._pack_chunk_native(
                         chunk_ok, b_loc, n_shards
@@ -1705,7 +1683,7 @@ class BatchEngine:
             # async dispatch: the device consumes this chunk's staged lanes
             # while the next loop iteration plans and packs on the host
             # (the staging slot fences its buffer against premature reuse)
-            with self._phase_ctx("dispatch", chunk=c0 // chunk_sz):
+            with self._phase_ctx("dispatch"):
                 self._dispatch("lanes", slot.buf, key, slot=slot)
             t_disp_acc += time.perf_counter() - t2
         metrics["n_demoted"] = acc.demoted

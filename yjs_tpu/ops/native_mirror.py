@@ -20,6 +20,7 @@ Scope fallbacks keep semantics identical to the Python mirror:
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import time
@@ -785,7 +786,8 @@ def prepare_many(work, want_levels: bool = False, want_sched: bool = True,
     ``obs`` (an :class:`yjs_tpu.obs.EngineObs`) records each call's wall
     time and doc count into the ``ytpu_native_prepare_many_*`` histograms
     — the planner-pool visibility the engine's per-flush timers cannot
-    give once flushes span multiple chunks.
+    give once flushes span multiple chunks — and puts the one native
+    call under the ``ytpu.plan.native`` span.
 
     ``want_sched=False`` skips building each plan's sched section
     (``NativePlan.sched`` then reads back empty) — ONLY safe when no
@@ -841,11 +843,17 @@ def prepare_many(work, want_levels: bool = False, want_sched: bool = True,
         o += nb
     counts = np.zeros((n, 16), np.int64)
     rcs = np.zeros(n, np.int64)
-    lib.ymx_prepare_many(
-        handles, n, _p64(buf_ofs), _p64(ids_flat), _p64(v2_flat),
-        1 if want_levels else 0, 1 if want_sched else 0, _p64(counts),
-        _p64(rcs),
-    )
+    # the native call alone: what is left of ytpu.plan around it is
+    # Python (this function's marshalling, the plan cache, finish)
+    with (
+        obs.tracer.span("ytpu.plan.native") if obs is not None
+        else contextlib.nullcontext()
+    ):
+        lib.ymx_prepare_many(
+            handles, n, _p64(buf_ofs), _p64(ids_flat), _p64(v2_flat),
+            1 if want_levels else 0, 1 if want_sched else 0, _p64(counts),
+            _p64(rcs),
+        )
     dt = time.perf_counter() - t0
     if obs is not None:
         obs.native_prepare(n, dt)

@@ -32,10 +32,10 @@ Env knobs (constructor args win over env):
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import re
-import threading
 import time
 from pathlib import Path
 
@@ -276,37 +276,38 @@ class WriteAheadLog:
             raise RuntimeError("WAL abandoned (simulated crash)")
         if self._closed:
             raise RuntimeError("WAL is closed")
-        t0 = time.perf_counter()
-        rec = encode_record(kind, guid, payload, v2)
-        if self._f is None or self._size >= self.config.segment_bytes:
-            self._seal()
-            self._open_next()
-        offset = self._size
-        self._f.write(rec)
-        # flush to the OS on every append: in-process readers (tests,
-        # the crash harness) must see exactly what a crashed process
-        # would leave behind — fsync is the only policy-gated cost
-        self._f.flush()
-        self._size += len(rec)
-        self._appends += 1
-        self.metrics.records.labels(kind=KIND_NAMES[kind]).inc()
-        self.metrics.bytes.inc(len(rec))
-        cfg = self.config
-        if cfg.fsync == "always" or (
-            cfg.fsync == "interval" and self._appends % cfg.fsync_interval == 0
-        ):
-            os.fsync(self._f.fileno())
-            self.metrics.fsyncs.inc()
-        dt = time.perf_counter() - t0
-        self.metrics.append_seconds.observe(dt)
-        if self._tracer is not None and self._tracer.enabled:
-            # record as a completed span (retroactively: the duration is
-            # already known, no context-manager overhead on the hot path)
-            self._tracer._events.append((
-                "ytpu.wal.append", "X",
-                (t0 - self._tracer._t0) * 1e6, dt * 1e6,
-                threading.get_ident(), {"kind": KIND_NAMES[kind]}, None,
-            ))
+        # every append of every kind is one span, on the profiler's
+        # clock and (tracer enabled) in the ring: the journal's latency
+        # inside the provider's receive/flush timeline
+        span = (
+            self._tracer.span("ytpu.wal.append", kind=KIND_NAMES[kind])
+            if self._tracer is not None
+            else contextlib.nullcontext()
+        )
+        with span:
+            t0 = time.perf_counter()
+            rec = encode_record(kind, guid, payload, v2)
+            if self._f is None or self._size >= self.config.segment_bytes:
+                self._seal()
+                self._open_next()
+            offset = self._size
+            self._f.write(rec)
+            # flush to the OS on every append: in-process readers (tests,
+            # the crash harness) must see exactly what a crashed process
+            # would leave behind — fsync is the only policy-gated cost
+            self._f.flush()
+            self._size += len(rec)
+            self._appends += 1
+            self.metrics.records.labels(kind=KIND_NAMES[kind]).inc()
+            self.metrics.bytes.inc(len(rec))
+            cfg = self.config
+            if cfg.fsync == "always" or (
+                cfg.fsync == "interval"
+                and self._appends % cfg.fsync_interval == 0
+            ):
+                os.fsync(self._f.fileno())
+                self.metrics.fsyncs.inc()
+            self.metrics.append_seconds.observe(time.perf_counter() - t0)
         return (self._path, offset, len(rec))
 
     # -- compaction ----------------------------------------------------------

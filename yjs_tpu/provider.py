@@ -554,29 +554,34 @@ class TpuProvider:
             # slot, and a queued update takes its slot at drain time
             verdict = adm.admit_update(self, guid, len(update))
         ctx = self._trace_ingress(update)
+        span = self.engine.obs.tracer.span
         if verdict == "queue":
-            if self.wal is not None:
-                # journaled at ENQUEUE: the queue is host memory, and
-                # zero acked-update loss must hold across a crash.  SLO
-                # bookkeeping waits for the drain — queue age is traffic
-                # the controller chose to shed, and letting it page the
-                # interactive SLO would feed the brownout its own
-                # shedding as an overload signal (self-sustaining
-                # degradation, the flap hysteresis exists to prevent)
-                self.wal.append(KIND_UPDATE, guid, update, v2=v2)
-                self.cost.wal_bytes(guid, len(update))
-            self._m_updates_rx.inc()
-            self._m_ingress_bytes.inc(len(update))
-            adm.enqueue(
-                self, guid, bytes(update), v2, undoable, None, trace=ctx
-            )
-            return True
+            # profiler only: the ring never held this branch
+            with span("ytpu.provider.receive_update", _ring=False):
+                if self.wal is not None:
+                    # journaled at ENQUEUE: the queue is host memory, and
+                    # zero acked-update loss must hold across a crash.  SLO
+                    # bookkeeping waits for the drain — queue age is traffic
+                    # the controller chose to shed, and letting it page the
+                    # interactive SLO would feed the brownout its own
+                    # shedding as an overload signal (self-sustaining
+                    # degradation, the flap hysteresis exists to prevent)
+                    self.wal.append(KIND_UPDATE, guid, update, v2=v2)
+                    self.cost.wal_bytes(guid, len(update))
+                self._m_updates_rx.inc()
+                self._m_ingress_bytes.inc(len(update))
+                adm.enqueue(
+                    self, guid, bytes(update), v2, undoable, None, trace=ctx
+                )
+                return True
         doc = self.doc_id(guid)
-        with obs_dist.use_context(ctx), self.engine.obs.tracer.span(
+        with obs_dist.use_context(ctx), span(
             "ytpu.provider.receive_update", guid=guid,
             **({"trace": ctx.trace_hex} if ctx.sampled else {}),
         ):
-            key = self.slo.receive(update, v2=v2, guid=guid, trace=ctx)
+            # profiler only: the stamp's flow arrow is its ring record
+            with span("ytpu.slo.receive", _ring=False):
+                key = self.slo.receive(update, v2=v2, guid=guid, trace=ctx)
             if self.wal is not None:
                 # journal BEFORE integrating (write-ahead): a crash between
                 # append and flush replays the update; the reverse order
@@ -750,7 +755,8 @@ class TpuProvider:
                 # cost attribution (ISSUE 19): split this flush's
                 # device/host seconds across the docs staged since the
                 # last one, weighted by staged bytes
-                self.cost.on_flush(self.engine.last_flush_metrics)
+                with tracer.span("ytpu.cost.on_flush"):
+                    self.cost.on_flush(self.engine.last_flush_metrics)
             except Exception as e:
                 self._dirty = True  # flush incomplete: retry next call
                 # an unhandled flush exception is exactly what the
