@@ -8,6 +8,7 @@ same element order as the CPU core.
 
 import random
 
+import numpy as np
 import pytest
 
 import yjs_tpu as Y
@@ -761,6 +762,163 @@ class TestCompactionScale:
         eng.flush()
         for i in (0, 55, n_docs - 1):
             assert eng.text(i) == docs[i].get_text("text").to_string()
+
+
+def _stage_full_width(eng):
+    """The staging as it was before a block followed its rooms, kept as
+    the reference: every block ``cap + 1`` and ``seg_cap + 1`` wide."""
+
+    def full(todo, rebuild, n_rows, n_segs):
+        k = len(todo)
+        new_right = np.full((k, eng._cap + 1), -1, np.int32)
+        new_deleted = np.zeros((k, eng._cap + 1), bool)
+        new_starts = np.full((k, eng._seg_cap + 1), -1, np.int32)
+        for j, i in enumerate(todo):
+            r, d, h = rebuild(i)
+            new_right[j, : len(r)] = r
+            new_deleted[j, : len(d)] = d
+            new_starts[j, : len(h)] = h
+        eng._dispatch(
+            "rows", eng._put_r(np.asarray(todo, np.int32)),
+            eng._put_r(new_right), eng._put_r(new_deleted),
+            eng._put_r(new_starts),
+        )
+
+    eng._scatter_rebuilt = full
+
+
+class TestStagedWidth:
+    """A compaction or hydration stages a block as wide as its rooms
+    need (``_scatter_rebuilt``), and must leave the device tables, over
+    their whole ``cap + 1``, as full-width staging leaves them."""
+
+    N = 16
+    WIDE = 3  # the room that sets the table width
+    SMALL = [*range(WIDE), *range(WIDE + 1, N)]
+
+    def _engines(self, mesh):
+        if mesh:
+            from yjs_tpu.parallel import doc_mesh
+
+            try:
+                mesh = doc_mesh(4, backend="cpu")
+            except RuntimeError as e:  # YTPU_TEST_PLATFORM=tpu: one chip
+                pytest.skip(f"no CPU mesh beside this backend: {e}")
+        # no compaction but the ones the case asks for
+        kw = dict(gc=True, compact_min_rows=1 << 30, mesh=mesh or None)
+        eng, ref = BatchEngine(self.N, **kw), BatchEngine(self.N, **kw)
+        _stage_full_width(ref)
+        return eng, ref
+
+    def _fragment(self, engines):
+        """One wide room (700 rows, cap 1024) and fifteen small ones of
+        some hundred rows that a rebuild merges into a few: typed at the
+        end a keystroke an update, erased again from the front."""
+        docs = [make_doc(500 + i) for i in range(self.N)]
+        typed: list[list[bytes]] = [[] for _ in docs]
+        for d, out in zip(docs, typed):
+            d.on("update", lambda u, _origin, _doc, out=out: out.append(u))
+        for rnd in range(14):
+            for i, d in enumerate(docs):
+                t = d.get_text("text")
+                if i != self.WIDE:
+                    for ch in f"r{rnd:02d}d{i % 5}, "[: 6 + i % 3]:
+                        t.insert(len(t.to_string()), ch)
+                    if rnd % 3 == 2:
+                        t.delete(0, 4 + i % 3)
+                elif rnd == 0:
+                    for _ in range(700):
+                        t.insert(0, "x")
+                for e in engines:
+                    for u in typed[i]:
+                        e.queue_update(i, u)
+                typed[i].clear()
+            for e in engines:
+                e.flush()
+        return docs
+
+    @staticmethod
+    def _tables(eng):
+        return [np.asarray(t) for t in (eng._right, eng._deleted, eng._starts)]
+
+    def _assert_same_tables(self, eng, ref):
+        assert eng._cap == ref._cap == 1024
+        for got, want in zip(self._tables(eng), self._tables(ref)):
+            assert got.shape == want.shape
+            np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+    @pytest.mark.parametrize("case", ["narrow", "one_wide", "hydrate"])
+    def test_narrow_staging_leaves_full_width_tables(self, case, mesh):
+        eng, ref = self._engines(mesh)
+        docs = self._fragment((eng, ref))
+        todo = self.SMALL if case == "narrow" else list(range(self.N))
+        for e in (eng, ref):
+            stats = e.compact_docs(todo)
+            assert [s["doc"] for s in stats] == todo
+        small = [s for s in stats if s["doc"] != self.WIDE]
+        # a stale tail to blank: the rooms shrank to under half their rows
+        assert all(
+            s["rows_after"] < 32 and 64 < s["rows_before"] <= 128
+            for s in small
+        )
+        if case == "hydrate":
+            # park four rooms, blank their slots, bring them back into
+            # each other's: the next flush scatters them through the
+            # same staging
+            moved = [1, 6, 9, 14]
+            for e in (eng, ref):
+                parked = [e.export_doc_columns(i) for i in moved]
+                for i in moved:
+                    e.reset_doc(i)
+                for i, m in zip(moved, reversed(parked)):
+                    e.hydrate_doc_columns(i, m)
+                e.flush()
+            docs[1], docs[14] = docs[14], docs[1]
+            docs[6], docs[9] = docs[9], docs[6]
+        self._assert_same_tables(eng, ref)
+        right, deleted, _starts = self._tables(eng)
+        for s in small:
+            # every cell behind a rebuilt room is at fill
+            assert (right[s["doc"], s["rows_after"]:] == -1).all()
+            assert not deleted[s["doc"], s["rows_after"]:].any()
+        for i in (0, 1, self.WIDE, 9, self.N - 1):
+            assert eng.text(i) == docs[i].get_text("text").to_string()
+        if case == "hydrate":
+            # the flush reports the wide room's block of 16 and the four
+            # hydrated rooms' own
+            assert eng.last_flush_metrics["rows_staged_bytes"] == (
+                16 * (1024 * 5 + 8 * 4) + 4 * (64 * 5 + 8 * 4)
+            )
+
+    def test_staged_and_held_bytes_read_what_the_shapes_say(self):
+        eng, _ref = self._engines(False)
+        self._fragment((eng,))
+        eng.flush()
+        assert eng.last_flush_metrics["rows_staged_bytes"] == 0
+        assert eng.last_flush_metrics["rows_held_bytes"] == 0
+        stats = eng.compact_docs(self.SMALL)
+        wide = eng.compact_docs([self.WIDE])
+        eng.flush()  # the next flush reports what was staged since the last
+        m = eng.last_flush_metrics
+        k = len(self.SMALL)
+        # 15 rooms that held 65 to 128 rows and one list head: 128 and
+        # 8 wide; the wide room alone: 1024 (the bucket of its 700 rows)
+        assert m["rows_staged_bytes"] == (
+            k * (128 * 5 + 8 * 4) + (1024 * 5 + 8 * 4)
+        )
+        rows = sum(s["rows_after"] for s in stats + wide)
+        heads = sum(eng.mirrors[i].n_segs for i in range(self.N))
+        assert m["rows_held_bytes"] == rows * 5 + heads * 4
+        reg = eng.obs.registry
+        assert reg.get("ytpu_flush_rows_staged_bytes_total").value == (
+            m["rows_staged_bytes"]
+        )
+        assert reg.get("ytpu_flush_rows_held_bytes_total").value == (
+            m["rows_held_bytes"]
+        )
+        eng.flush()
+        assert eng.last_flush_metrics["rows_staged_bytes"] == 0
 
 
 class TestChunkedFlushStress:

@@ -151,6 +151,12 @@ FLUSH_METRICS_SCHEMA: dict = {
     # previous flush); realloc_bytes is the growth cost when it is 0
     "flush_donated": 0,
     "realloc_bytes": 0,
+    # bytes the compactions and hydrations since the previous flush
+    # staged for scatter_rows (host allocation = transfer = device
+    # writes), and the bytes of rebuilt rows the rooms in those blocks
+    # hold: held / staged is how full a staged block is
+    "rows_staged_bytes": 0,
+    "rows_held_bytes": 0,
     # max device dispatches in flight at once (0 = no dispatch or
     # synchronous mode; the double-buffered staging pair bounds it)
     "pipeline_depth": 0,
@@ -389,6 +395,18 @@ class EngineObs:
             "a donated steady-state flush avoids)",
             unit="bytes",
         )
+        self._flush_rows_staged_bytes = r.counter(
+            "ytpu_flush_rows_staged_bytes_total",
+            "Bytes staged for the row scatter by compactions and "
+            "hydrations (host allocation, transfer and device writes)",
+            unit="bytes",
+        )
+        self._flush_rows_held_bytes = r.counter(
+            "ytpu_flush_rows_held_bytes_total",
+            "Bytes of rebuilt rows the staged rooms hold (over the "
+            "staged bytes: how full a staged block is)",
+            unit="bytes",
+        )
 
     # -- hot-path recording hooks -------------------------------------
 
@@ -423,6 +441,9 @@ class EngineObs:
             self._flush_donated.inc()
         if metrics["realloc_bytes"]:
             self._flush_realloc_bytes.inc(metrics["realloc_bytes"])
+        if metrics["rows_staged_bytes"]:
+            self._flush_rows_staged_bytes.inc(metrics["rows_staged_bytes"])
+            self._flush_rows_held_bytes.inc(metrics["rows_held_bytes"])
 
     def demoted(self, doc: int, reason: str) -> None:
         ctx = current_context()

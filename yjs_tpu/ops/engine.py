@@ -427,6 +427,11 @@ class BatchEngine:
         # device-table bytes (re)allocated during the current flush — 0 in
         # steady state, where every dispatch donates in place
         self._flush_realloc_bytes = 0
+        # bytes the compactions and hydrations since the last flush's end
+        # staged, and the bytes of rows those rooms hold (their ratio: how
+        # full a staged block is)
+        self._flush_rows_staged_bytes = 0
+        self._flush_rows_held_bytes = 0
         # slots that ever accepted traffic (cleared by reset_doc): feeds
         # the ytpu_prof_slot_occupancy gauge in O(1) per update
         self._active_docs: set[int] = set()
@@ -907,31 +912,67 @@ class BatchEngine:
         The mirror's host list/deleted state equals the device arrays by
         flush invariant (YTPU_EXPORT_DEVICE pins it), so merges are
         decided WITHOUT any device read-back; the device gets the
-        rebuilt rows in one write-only scatter."""
-        span = self._phase_ctx
-        cap1 = self._cap + 1
-        seg1 = self._seg_cap + 1
-        with span("compact.alloc"):
-            new_right = np.full((len(todo), cap1), NULL, np.int32)
-            new_deleted = np.zeros((len(todo), cap1), bool)
-            new_starts = np.full((len(todo), seg1), NULL, np.int32)
+        rebuilt rows in one write-only scatter, staged as wide as the
+        widest room is BEFORE its rebuild (``rows_before``): the cells a
+        room has written since its slot was last blanked are its first
+        ``rows_before``, so that width blanks the stale tail behind the
+        shorter rebuilt table, and every cell beyond it is at fill
+        already."""
         stats = []
+
+        def rebuild(i):
+            # a fresh rebuild supersedes any still-pending hydration
+            self._pending_hydration.pop(i, None)
+            m = self.mirrors[i]
+            old_n = m.n_rows
+            r, d, h = m.rebuild_compacted_self(gc)
+            self._rows_at_compact[i] = len(r)
+            self._uploaded_rows[i] = 0  # renumbered: statics re-upload
+            stats.append(
+                {"doc": i, "rows_before": old_n, "rows_after": len(r)}
+            )
+            return r, d, h
+
+        mirrors = [self.mirrors[i] for i in todo]
+        self._scatter_rebuilt(
+            todo, rebuild,
+            max(m.n_rows for m in mirrors), max(m.n_segs for m in mirrors),
+        )
+        return stats
+
+    def _scatter_rebuilt(self, todo, rebuild, n_rows: int, n_segs: int) -> None:
+        """Stage rebuilt rooms and scatter them into the device tables:
+        the one staging path of compactions and hydrations.
+
+        ``rebuild(doc)`` gives a doc of ``todo`` its ``(right, deleted,
+        heads)`` as ``rebuild_compacted_self`` does; ``n_rows`` and
+        ``n_segs`` bound the rows and list heads any of these slots has
+        held since it was last blanked.  The block is ``len(todo) x w``
+        with ``w = _bucket(n_rows)`` (the power-of-two rule of the table
+        widths, so the widths a process meets, and the ``scatter_rows``
+        programs it compiles, stay few), not the table's ``cap + 1``:
+        host allocation, transfer and device scatter scale with what the
+        rooms hold.  One wide room makes its block wide."""
+        span = self._phase_ctx
+        k = len(todo)
+        w = min(_bucket(n_rows), self._cap + 1)
+        ws = min(_bucket(n_segs, 8), self._seg_cap + 1)
+        with span("compact.alloc"):
+            new_right = np.full((k, w), NULL, np.int32)
+            new_deleted = np.zeros((k, w), bool)
+            new_starts = np.full((k, ws), NULL, np.int32)
+        held = 0
         with span("compact.rebuild"):
             for j, i in enumerate(todo):
-                # a fresh rebuild supersedes any still-pending hydration
-                self._pending_hydration.pop(i, None)
-                m = self.mirrors[i]
-                old_n = m.n_rows
-                r, d, h = m.rebuild_compacted_self(gc)
-                n_new = len(r)
-                new_right[j, :n_new] = r
-                new_deleted[j, :n_new] = d
+                r, d, h = rebuild(i)
+                new_right[j, : len(r)] = r
+                new_deleted[j, : len(d)] = d
                 new_starts[j, : len(h)] = h
-                self._rows_at_compact[i] = n_new
-                self._uploaded_rows[i] = 0  # renumbered: statics re-upload
-                stats.append(
-                    {"doc": i, "rows_before": old_n, "rows_after": n_new}
-                )
+                held += r.nbytes + d.nbytes + h.nbytes
+        self._flush_rows_staged_bytes += (
+            new_right.nbytes + new_deleted.nbytes + new_starts.nbytes
+        )
+        self._flush_rows_held_bytes += held
         with span("compact.put"):
             rows = (
                 self._put_r(np.asarray(todo, np.int32)),
@@ -940,7 +981,6 @@ class BatchEngine:
             )
         with span("compact.scatter"):
             self._dispatch("rows", *rows)
-        return stats
 
     def compact_docs(self, docs, gc: bool = True) -> list[dict]:
         """Forced tombstone/GC compaction of specific docs (the tier GC
@@ -1010,9 +1050,13 @@ class BatchEngine:
 
     def _apply_pending_hydrations(self) -> None:
         """Scatter every deferred hydration into the device tables in
-        ONE write-only pass (the ``_compact_rows`` idiom).  Called at
-        the top of flush and before any device read-back; a no-op when
-        nothing is pending."""
+        ONE write-only pass, through ``_compact_rows``' staging.  Called
+        at the top of flush and before any device read-back; a no-op
+        when nothing is pending.
+
+        ``reset_doc`` left each of these slots at fill (``hydrate_doc_
+        columns`` refuses any other), so the block is as wide as the
+        widest room it brings."""
         if not self._pending_hydration:
             return
         pend = self._pending_hydration
@@ -1020,25 +1064,16 @@ class BatchEngine:
         todo = sorted(pend)
         if self._right is None:
             self._ensure_capacity(1, 1)
-        cap1 = self._cap + 1
-        seg1 = self._seg_cap + 1
-        new_right = np.full((len(todo), cap1), NULL, np.int32)
-        new_deleted = np.zeros((len(todo), cap1), bool)
-        new_starts = np.full((len(todo), seg1), NULL, np.int32)
-        for j, i in enumerate(todo):
-            r, d, h = pend[i]
-            new_right[j, : len(r)] = r
-            new_deleted[j, : len(d)] = d
-            new_starts[j, : len(h)] = h
-        idx = self._put_r(np.asarray(todo, np.int32))
         # hydrations land as stage-0 dispatches of the flush pipeline (or
         # immediately before a device read-back): the donating row scatter
         # sequences ahead of this flush's integrate dispatches on the
         # device stream, so the integrate kernels always see hydrated rows
-        self._dispatch(
-            "rows", idx, self._put_r(new_right), self._put_r(new_deleted),
-            self._put_r(new_starts),
-        )
+        with self._phase_ctx("compact"):
+            self._scatter_rebuilt(
+                todo, pend.__getitem__,
+                max(len(r) for r, _d, _h in pend.values()),
+                max(len(h) for _r, _d, h in pend.values()),
+            )
 
     def reset_doc(self, doc: int) -> None:
         """Return one slot to its just-constructed state (provider
@@ -1089,6 +1124,9 @@ class BatchEngine:
             pl.n_dispatches > 0 and self._flush_realloc_bytes == 0
         )
         metrics["realloc_bytes"] = self._flush_realloc_bytes
+        metrics["rows_staged_bytes"] = self._flush_rows_staged_bytes
+        metrics["rows_held_bytes"] = self._flush_rows_held_bytes
+        self._flush_rows_staged_bytes = self._flush_rows_held_bytes = 0
         self.obs.record_flush(metrics, row_capacity=self._cap)
         if self.obs.enabled:
             self._record_device_memory()

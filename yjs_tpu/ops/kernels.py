@@ -479,16 +479,29 @@ def apply_plan_shared(dyn, lanes, k_l, k_h, k_d):
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
 def scatter_rows(right, deleted, starts, idx, new_right, new_deleted,
                  new_starts):
-    """Whole-row rebuild scatter: replace docs ``idx``'s link/deleted/head
-    rows with freshly packed host columns (compaction rebuilds, deferred
-    warm-promotion hydrations).  The resident tables are donated, so the
-    rebuild updates device state in place instead of materializing a
-    second B x cap copy per array — the same donation contract as the
-    flush dispatch kernels (ISSUE 12)."""
+    """Rebuild scatter: replace the head of docs ``idx``'s link/deleted/
+    head rows with freshly packed host columns (compaction rebuilds,
+    deferred warm-promotion hydrations).
+
+    A staged block is as wide as the rooms in it need, not as the table:
+    ``table[idx, :w]`` is written from a block of width ``w`` (any ``w``
+    up to the table's own; each table takes its own block's width), and
+    columns ``>= w`` of those docs are left as they are.  The caller
+    picks ``w`` to cover every cell the docs have written since their
+    slots were last blanked (``BatchEngine._scatter_rebuilt``), so a
+    narrow block leaves the same tables as a full-width one.
+
+    The resident tables are donated, so the rebuild updates device state
+    in place instead of materializing a second B x cap copy per array —
+    the same donation contract as the flush dispatch kernels (ISSUE 12)."""
+
+    def put(table, block):
+        return table.at[idx, : block.shape[1]].set(block)
+
     return (
-        right.at[idx].set(new_right),
-        deleted.at[idx].set(new_deleted),
-        starts.at[idx].set(new_starts),
+        put(right, new_right),
+        put(deleted, new_deleted),
+        put(starts, new_starts),
     )
 
 

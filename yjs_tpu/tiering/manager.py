@@ -352,11 +352,13 @@ class TierManager:
         slot, and a ``KIND_TIER`` "hot" marker is journaled so recovery
         knows the demote marker no longer stands.
 
-        Pipeline note (ISSUE 12): hydration only STAGES host rows; the
-        device scatter is deferred to the next flush, where it rides
-        the engine's single ``_dispatch`` seam as a donated
-        ``scatter_rows`` stage.  The staged host copy belongs to the
-        engine, so the warm mirror released here never aliases a
+        Pipeline note (ISSUE 12): hydration only rebuilds the host
+        rows; the device scatter is deferred to the next flush, where it
+        rides the engine's single ``_dispatch`` seam as a donated
+        ``scatter_rows`` stage.  The engine stages them there, in a
+        fresh host block of its own as wide as the widest room it
+        hydrates (``BatchEngine._scatter_rebuilt``, shared with
+        compaction), so the warm mirror released here never aliases a
         donated device buffer."""
         src = self.tier_of(guid)
         if src not in (WARM, COLD):
