@@ -229,6 +229,45 @@ def test_tracer_save(tmp_path):
         assert json.load(f)["traceEvents"]
 
 
+def test_release_spans_and_blanked_bytes_counter():
+    """A release opens ``ytpu.release`` around the engine branch of
+    ``release_doc`` and ``ytpu.release.blank`` around the dispatch of
+    the blanking program inside it; the bytes blanked are counted by
+    the next flush (``release_blanked_bytes``) and by the registry."""
+    prov = TpuProvider(4)
+    for room in ("a", "b", "c"):
+        prov.receive_update(room, _update(room * 40))
+    prov.flush()
+    eng = prov.engine
+    for room in ("a", "c"):
+        prov.release_doc(room)
+    spans = {
+        name: [
+            e for e in eng.export_chrome_trace()["traceEvents"]
+            if e["ph"] == "X" and e["name"] == name
+        ]
+        for name in ("ytpu.release", "ytpu.release.blank")
+    }
+    assert [len(v) for v in spans.values()] == [2, 2]
+    for outer, inner in zip(*spans.values()):
+        assert outer["ts"] <= inner["ts"]
+        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    counter = eng.obs.registry.get("ytpu_release_blanked_bytes_total")
+    assert counter.value == 0  # counted by the flush that follows
+    prov.receive_update("b", _update("more"))
+    prov.flush()
+    row = (eng._cap + 1) * 5 + (eng._seg_cap + 1) * 4
+    assert eng.last_flush_metrics["release_blanked_bytes"] == 2 * row
+    assert counter.value == 2 * row
+    assert "ytpu_release_blanked_bytes_total" in prov.metrics_text()
+    # a room that never reached the device has no rows to blank
+    cold = TpuProvider(2, backend="cpu")
+    cold.receive_update("a", _update())
+    cold.release_doc("a")
+    names = [e["name"] for e in cold.engine.export_chrome_trace()["traceEvents"]]
+    assert "ytpu.release" in names and "ytpu.release.blank" not in names
+
+
 # -- exposition --------------------------------------------------------------
 
 

@@ -419,6 +419,90 @@ def test_full_release_reuse_and_eviction_counter(tmp_path):
     assert sorted(rec._guids) == ["alpha", "gamma"]
 
 
+def _wal_records(path):
+    from yjs_tpu.persistence.recovery import iter_file_events
+
+    segs = list_segments(path)
+    return [
+        (ev[1].kind, ev[1].guid, ev[1].payload)
+        for j, (_i, p) in enumerate(segs)
+        for ev in iter_file_events(p, final=j == len(segs) - 1)
+        if ev[0] == "record"
+    ]
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+def test_release_of_a_device_room_journals_what_table_copies_did(
+    tmp_path, mesh
+):
+    """A release on the device path (rows blanked by one donated
+    program) leaves the journal a release by whole-table copies left —
+    the reference kept in test_tpu_engine.py — record for record: the
+    KIND_RELEASE with the final state, then the KIND_DLQ that keeps the
+    slot's dead letters; and recovery honours both."""
+    from test_tpu_engine import _reset_by_table_copies
+    from yjs_tpu.persistence import KIND_DLQ, KIND_RELEASE
+
+    if mesh:
+        from yjs_tpu.parallel import doc_mesh
+
+        mesh = doc_mesh(4, backend="cpu")
+    streams = phased_streams(seed=27, rooms=("alpha", "beta", "gamma"))
+    provs = [
+        TpuProvider(4, mesh=mesh or None, wal_dir=tmp_path / name,
+                    wal_config=SMALL)
+        for name in ("rows", "copies")
+    ]
+    _reset_by_table_copies(provs[1].engine)
+    finals = []
+    for prov in provs:
+        for room, (p1, _p2) in streams.items():
+            for u in p1:
+                prov.receive_update(room, u)
+        prov.flush()
+        assert prov.engine._right is not None and not prov.engine.fallback
+        # a poisoned update: beta rolls back onto the CPU core, its
+        # device rows stay behind until the release blanks them
+        prov.receive_update("beta", b"\x01\xff\xff\xff")
+        prov.flush()
+        finals.append((prov.release_doc("beta"), prov.release_doc("gamma")))
+        for u in streams["alpha"][1]:
+            prov.receive_update("alpha", u)
+        # the slot's next tenant, journaled after the release
+        prov.receive_update("delta", streams["beta"][0][0])
+        prov.flush()
+        prov.wal.abandon()
+    assert finals[0] == finals[1]
+    got, want = (_wal_records(tmp_path / n) for n in ("rows", "copies"))
+    assert got == want
+    released = [r for r in got if r[0] == KIND_RELEASE]
+    assert [r[1:] for r in released] == [
+        ("beta", finals[0][0]), ("gamma", finals[0][1])
+    ]
+    released = released[0]
+    # the poisoned bytes ride the DLQ record that follows the release
+    after = got[got.index(released) + 1]
+    assert after[0] == KIND_DLQ and b"evicted 'beta'" in after[2]
+
+    rec = TpuProvider.recover(tmp_path / "rows", n_docs=4, mesh=mesh or None)
+    assert rec.last_recovery["released"] == 2
+    assert sorted(rec._guids) == ["alpha", "delta"]
+    assert any("'beta'" in e["reason"] for e in rec.dead_letters())
+    oracle = Y.Doc(gc=False)
+    Y.apply_update(oracle, streams["beta"][0][0])
+    assert rec.text("delta") == str(oracle.get_text("text"))
+    oracle = Y.Doc(gc=False)
+    for u in streams["alpha"][0] + streams["alpha"][1]:
+        Y.apply_update(oracle, u)
+    assert rec.text("alpha") == str(oracle.get_text("text"))
+    gamma = Y.Doc(gc=False)
+    for u in streams["gamma"][0]:
+        Y.apply_update(gamma, u)
+    assert Y.merge_updates([finals[0][1]]) == Y.merge_updates(
+        [Y.encode_state_as_update(gamma)]
+    )
+
+
 def test_release_unknown_room_raises():
     prov = TpuProvider(1, backend="cpu")
     with pytest.raises(KeyError):

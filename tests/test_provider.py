@@ -276,3 +276,83 @@ def test_server_demo_runs():
     import examples.server_demo as demo
 
     demo.main(n_rooms=4)
+
+
+class TestReleaseDoc:
+    """``release_doc``'s contract does not depend on how the engine
+    blanks the slot: beside a provider whose engine still resets by
+    whole-table copies (the reference kept in test_tpu_engine.py), it
+    returns the same bytes, preserves the same dead letters, and hands
+    the slot to a next tenant that is byte-identical with a CPU doc."""
+
+    ROOMS = [f"room{i}" for i in range(8)]
+
+    def _pair(self, mesh):
+        from test_tpu_engine import _reset_by_table_copies
+
+        if mesh:
+            from yjs_tpu.parallel import doc_mesh
+
+            mesh = doc_mesh(4, backend="cpu")
+        prov, ref = (TpuProvider(8, mesh=mesh or None) for _ in range(2))
+        _reset_by_table_copies(ref.engine)
+        gen = random.Random(27)
+        docs = {}
+        for k, room in enumerate(self.ROOMS):
+            d = Y.Doc(gc=False)
+            d.client_id = 500 + k
+            for _ in range(20 + 30 * (k % 3)):
+                client_edit(gen, d)
+            docs[room] = d
+            for p in (prov, ref):
+                p.receive_update(room, Y.encode_state_as_update(d))
+        for p in (prov, ref):
+            p.flush()
+            # a poisoned update: the room rolls back, the bytes become
+            # a dead letter of its slot
+            p.receive_update("room2", b"\x01\xff\xff\xff")
+            p.flush()
+            assert len(p.dead_letters("room2")) == 1
+        return prov, ref, docs
+
+    @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+    def test_same_bytes_same_letters_same_next_tenant(self, mesh):
+        import numpy as np
+
+        prov, ref, docs = self._pair(mesh)
+        assert prov.engine._right is not None  # device rows to blank
+        gone = ["room2", "room5", "room7"]
+        slots = {room: prov.doc_id(room) for room in gone}
+        for room in gone:
+            final, want = prov.release_doc(room), ref.release_doc(room)
+            assert final == want
+            assert Y.merge_updates([final]) == Y.merge_updates(
+                [Y.encode_state_as_update(docs[room])]
+            )
+            assert not prov.has_doc(room)
+        # the poisoned bytes stayed, named for the room that left
+        for p in (prov, ref):
+            (letter,) = [e for e in p.dead_letters() if e["doc"] == -1]
+            assert "'room2'" in letter["reason"]
+        assert [e["reason"] for e in prov.dead_letters()] == [
+            e["reason"] for e in ref.dead_letters()
+        ]
+        # the freed slots are re-let, last released first, and start empty
+        for k, room in enumerate(reversed(gone)):
+            d = Y.Doc(gc=False)
+            d.client_id = 900 + k
+            d.get_text("text").insert(0, f"next tenant {k} " * (2 + k))
+            for p in (prov, ref):
+                assert p.doc_id(f"new{k}") == slots[room]
+                assert p.text(f"new{k}") == ""
+                p.receive_update(f"new{k}", Y.encode_state_as_update(d))
+                p.flush()
+            assert prov.text(f"new{k}") == d.get_text("text").to_string()
+            assert Y.merge_updates(
+                [prov.engine.encode_state_as_update(slots[room])]
+            ) == Y.merge_updates([Y.encode_state_as_update(d)])
+        for name in ("_right", "_deleted", "_starts"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(prov.engine, name)),
+                np.asarray(getattr(ref.engine, name)),
+            )

@@ -787,6 +787,21 @@ def _stage_full_width(eng):
     eng._scatter_rebuilt = full
 
 
+def _engine_pair(n, mesh):
+    """Two engines alike, on one device or on the tests' four-device
+    CPU mesh: the one under test and the one a reference is put into."""
+    if mesh:
+        from yjs_tpu.parallel import doc_mesh
+
+        try:
+            mesh = doc_mesh(4, backend="cpu")
+        except RuntimeError as e:  # YTPU_TEST_PLATFORM=tpu: one chip
+            pytest.skip(f"no CPU mesh beside this backend: {e}")
+    # no compaction but the ones the case asks for
+    kw = dict(gc=True, compact_min_rows=1 << 30, mesh=mesh or None)
+    return BatchEngine(n, **kw), BatchEngine(n, **kw)
+
+
 class TestStagedWidth:
     """A compaction or hydration stages a block as wide as its rooms
     need (``_scatter_rebuilt``), and must leave the device tables, over
@@ -797,16 +812,7 @@ class TestStagedWidth:
     SMALL = [*range(WIDE), *range(WIDE + 1, N)]
 
     def _engines(self, mesh):
-        if mesh:
-            from yjs_tpu.parallel import doc_mesh
-
-            try:
-                mesh = doc_mesh(4, backend="cpu")
-            except RuntimeError as e:  # YTPU_TEST_PLATFORM=tpu: one chip
-                pytest.skip(f"no CPU mesh beside this backend: {e}")
-        # no compaction but the ones the case asks for
-        kw = dict(gc=True, compact_min_rows=1 << 30, mesh=mesh or None)
-        eng, ref = BatchEngine(self.N, **kw), BatchEngine(self.N, **kw)
+        eng, ref = _engine_pair(self.N, mesh)
         _stage_full_width(ref)
         return eng, ref
 
@@ -919,6 +925,198 @@ class TestStagedWidth:
         )
         eng.flush()
         assert eng.last_flush_metrics["rows_staged_bytes"] == 0
+
+
+def _reset_by_table_copies(eng):
+    """``reset_doc``'s device half as it was before a release wrote rows,
+    kept as the plain reference: three ``.at[doc].set`` outside ``jit``,
+    each a new whole table."""
+
+    def reset(doc):
+        tables = eng._right, eng._deleted, eng._starts
+        eng._right = None  # the host half alone: no table, no blanking
+        BatchEngine.reset_doc(eng, doc)
+        eng._right = tables[0].at[doc].set(-1)
+        eng._deleted = tables[1].at[doc].set(False)
+        eng._starts = tables[2].at[doc].set(-1)
+
+    eng.reset_doc = reset
+
+
+class TestReleaseBlanking:
+    """``reset_doc`` blanks a slot's rows by one donated program
+    (``kernels.blank_rows``) and must leave the device tables, over
+    their whole ``cap + 1``, as the whole-table copies left them."""
+
+    N = TestStagedWidth.N
+    WIDE = TestStagedWidth.WIDE
+    # rooms of different widths, one at least in each block of four
+    # slots (a chip's share on the tests' four-device mesh)
+    GONE = [1, WIDE, 6, 9, 14, 15]
+
+    def _engines(self, mesh):
+        eng, ref = _engine_pair(self.N, mesh)
+        _reset_by_table_copies(ref)
+        return eng, ref
+
+    def _grown(self, mesh):
+        eng, ref = self._engines(mesh)
+        docs = TestStagedWidth()._fragment((eng, ref))
+        for e in (eng, ref):
+            # something in the scratch column to blank as well
+            e._right = e._right.at[:, -1].set(7)
+        return eng, ref, docs
+
+    @staticmethod
+    def _pointers(eng):
+        return [
+            [s.data.unsafe_buffer_pointer() for s in t.addressable_shards]
+            for t in (eng._right, eng._deleted, eng._starts)
+        ]
+
+    @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+    def test_reset_leaves_the_tables_of_whole_table_copies(self, mesh):
+        eng, ref, docs = self._grown(mesh)
+        kept = TestStagedWidth._tables(eng)
+        widths = {eng.mirrors[i].n_rows for i in self.GONE}
+        assert len(widths) >= 3 and max(widths) >= 700
+        for e in (eng, ref):
+            for i in self.GONE:
+                e.reset_doc(i)
+        got, want = TestStagedWidth._tables(eng), TestStagedWidth._tables(ref)
+        for g, w, k, fill in zip(got, want, kept, (-1, False, -1)):
+            assert g.shape == w.shape == k.shape
+            np.testing.assert_array_equal(g, w)
+            assert (g[self.GONE] == fill).all()  # scratch column included
+            stay = [i for i in range(self.N) if i not in self.GONE]
+            np.testing.assert_array_equal(g[stay], k[stay])
+        for i in (0, 5, 8, 12):
+            assert eng.text(i) == docs[i].get_text("text").to_string()
+
+    @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+    def test_resident_tables_are_donated_and_no_second_table_made(self, mesh):
+        from yjs_tpu.ops import kernels
+
+        eng, _ref, _docs = self._grown(mesh)
+        eng.reset_doc(0)  # the program is met
+        programs = kernels.blank_rows.__wrapped__._cache_size()
+        for i in self.GONE:
+            old = (eng._right, eng._deleted, eng._starts)
+            where = self._pointers(eng)
+            eng.reset_doc(i)
+            assert all(t.is_deleted() for t in old)
+            # written where the old tables lay, on every device
+            assert self._pointers(eng) == where
+        # the slot is an argument, not a constant: one program for all
+        assert kernels.blank_rows.__wrapped__._cache_size() == programs
+        eng.flush()
+        row = (eng._cap + 1) * 5 + (eng._seg_cap + 1) * 4
+        blanked = (1 + len(self.GONE)) * row
+        assert eng.last_flush_metrics["release_blanked_bytes"] == blanked
+        assert eng.obs.registry.get(
+            "ytpu_release_blanked_bytes_total"
+        ).value == blanked
+        eng.flush()
+        assert eng.last_flush_metrics["release_blanked_bytes"] == 0
+
+    @pytest.mark.parametrize("slot", [-1, TestStagedWidth.N])
+    @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+    def test_a_slot_out_of_range_blanks_nothing(self, mesh, slot):
+        """The host list wraps a negative index and the device write
+        clamps its start: ``reset_doc`` refuses before either picks a
+        row, and host and device stay as they were."""
+        eng, _ref, docs = self._grown(mesh)
+        kept = TestStagedWidth._tables(eng)
+        mirrors = list(eng.mirrors)
+        with pytest.raises(IndexError, match="no slot"):
+            eng.reset_doc(slot)
+        assert all(a is b for a, b in zip(eng.mirrors, mirrors))
+        for g, k in zip(TestStagedWidth._tables(eng), kept):
+            np.testing.assert_array_equal(g, k)
+        assert eng.text(self.N - 1) == docs[self.N - 1].get_text("text").to_string()
+
+    @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+    def test_next_tenant_is_byte_identical_with_the_cpu_doc(self, mesh):
+        eng, ref, _docs = self._grown(mesh)
+        tenants = {}
+        for i in self.GONE:
+            d = make_doc(9000 + i)
+            t = d.get_text("text")
+            t.insert(0, f"tenant {i} " * (3 + i))
+            t.delete(2, 5)
+            tenants[i] = d
+        for e in (eng, ref):
+            for i in self.GONE:
+                e.reset_doc(i)
+            for i, d in tenants.items():
+                e.queue_update(i, Y.encode_state_as_update(d))
+            e.flush()
+        for i, d in tenants.items():
+            assert_engine_matches(eng, d, idx=i)
+            assert Y.merge_updates([eng.encode_state_as_update(i)]) == (
+                Y.merge_updates([Y.encode_state_as_update(d)])
+            )
+        for g, w in zip(
+            TestStagedWidth._tables(eng), TestStagedWidth._tables(ref)
+        ):
+            np.testing.assert_array_equal(g, w)
+        if mesh:
+            # a room that left and came back lies on its own chip's
+            # block: slot i is row i % 4 of device i // 4's shard
+            per = self.N // 4
+            right = np.asarray(eng._right)
+            shards = {
+                s.index[0].start // per: np.asarray(s.data)
+                for s in eng._right.addressable_shards
+            }
+            assert sorted(shards) == [0, 1, 2, 3]
+            for i in self.GONE:
+                assert (right[i] != -1).any()
+                np.testing.assert_array_equal(
+                    shards[i // per][i % per], right[i]
+                )
+
+
+def test_a_mesh_reuses_a_program_whose_lanes_cover_the_chunk():
+    """On a mesh a lane width is the widest shard's sum: the same rooms
+    re-let into other slots wander over a bucket's edge.  A program the
+    mesh already has that covers the chunk at no more than a quarter
+    more lanes is used before another is issued; one device keeps
+    its exact keys."""
+    from yjs_tpu.parallel import doc_mesh
+
+    def prepended(client, n):
+        d = make_doc(client)
+        for _ in range(n):
+            d.get_text("text").insert(0, "x")
+        return d
+
+    eng = BatchEngine(8, mesh=doc_mesh(4, backend="cpu"))
+    first = prepended(1, 200)
+    eng.queue_update(0, Y.encode_state_as_update(first))
+    eng.flush()
+    (key,) = eng._sharded_apply
+    assert key[0] == 208  # 200 dense link writes, bucketed
+    # the room comes back a little smaller and on another chip's block
+    eng.reset_doc(0)
+    second = prepended(2, 180)
+    eng.queue_update(5, Y.encode_state_as_update(second))
+    eng.flush()
+    assert set(eng._sharded_apply) == {key}
+    assert_engine_matches(eng, second, idx=5)
+    assert eng._covering_key((192, 64, 8, 64)) == key
+    # too narrow for the padding to be worth it, or wider: its own
+    for other in ((64, 64, 8, 64), (224, 64, 8, 64), (208, 64, 8, 128)):
+        assert eng._covering_key(other) == other
+    third = prepended(3, 40)
+    eng.queue_update(2, Y.encode_state_as_update(third))
+    eng.flush()
+    assert set(eng._sharded_apply) == {key, (64, 64, 8, 64)}
+    assert_engine_matches(eng, third, idx=2)
+    one = BatchEngine(8)
+    one.queue_update(0, Y.encode_state_as_update(first))
+    one.flush()
+    assert one._covering_key((192, 64, 8, 64)) == (192, 64, 8, 64)
 
 
 class TestChunkedFlushStress:

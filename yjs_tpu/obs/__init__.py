@@ -157,6 +157,9 @@ FLUSH_METRICS_SCHEMA: dict = {
     # hold: held / staged is how full a staged block is
     "rows_staged_bytes": 0,
     "rows_held_bytes": 0,
+    # bytes of device rows the releases since the previous flush blanked
+    # in place (reset_doc: one whole row of each resident table a slot)
+    "release_blanked_bytes": 0,
     # max device dispatches in flight at once (0 = no dispatch or
     # synchronous mode; the double-buffered staging pair bounds it)
     "pipeline_depth": 0,
@@ -407,6 +410,12 @@ class EngineObs:
             "staged bytes: how full a staged block is)",
             unit="bytes",
         )
+        self._release_blanked_bytes = r.counter(
+            "ytpu_release_blanked_bytes_total",
+            "Bytes of device rows blanked in place by room releases "
+            "(one whole row of each resident table a released slot)",
+            unit="bytes",
+        )
 
     # -- hot-path recording hooks -------------------------------------
 
@@ -444,6 +453,8 @@ class EngineObs:
         if metrics["rows_staged_bytes"]:
             self._flush_rows_staged_bytes.inc(metrics["rows_staged_bytes"])
             self._flush_rows_held_bytes.inc(metrics["rows_held_bytes"])
+        if metrics["release_blanked_bytes"]:
+            self._release_blanked_bytes.inc(metrics["release_blanked_bytes"])
 
     def demoted(self, doc: int, reason: str) -> None:
         ctx = current_context()

@@ -361,6 +361,8 @@ class BatchEngine:
         self._sharded_sv: dict[int, object] = {}
         # cached sharded bulk-apply callables keyed by lane bucket shape
         self._sharded_apply: dict[tuple, object] = {}
+        # lane keys the packer has issued on a mesh (_covering_key)
+        self._mesh_keys: set[tuple] = set()
         # explicit placement: a meshed engine pins EVERY host->device
         # transfer to the mesh's devices so it can never touch the default
         # backend (the mesh may be a virtual CPU mesh while the default
@@ -432,6 +434,9 @@ class BatchEngine:
         # full a staged block is)
         self._flush_rows_staged_bytes = 0
         self._flush_rows_held_bytes = 0
+        # bytes of device rows the releases since the last flush's end
+        # blanked (reset_doc: one whole row of each table a slot)
+        self._release_blanked_bytes = 0
         # slots that ever accepted traffic (cleared by reset_doc): feeds
         # the ytpu_prof_slot_occupancy gauge in O(1) per update
         self._active_docs: set[int] = set()
@@ -1083,6 +1088,10 @@ class BatchEngine:
         nothing.  The dead-letter queue is NOT touched here (the caller
         decides whether the slot's letters travel with the evicted
         doc)."""
+        if not 0 <= doc < self.n_docs:
+            # the host list would wrap a negative index and the device
+            # write clamps one out of range: neither may pick a row
+            raise IndexError(f"reset_doc: no slot {doc} of {self.n_docs}")
         self.mirrors[doc] = make_mirror(self.root_name)
         plan_cache.note_invalidation("reset")
         self.fallback.pop(doc, None)
@@ -1094,12 +1103,21 @@ class BatchEngine:
         self._event_listeners.pop(doc, None)
         self.health.reset(doc)
         if self._right is not None:
-            # blank the slot's device rows in place (same fills as the
-            # initial allocation); statics re-upload from row 0 is
-            # already forced by _uploaded_rows above
-            self._right = self._right.at[doc].set(NULL)
-            self._deleted = self._deleted.at[doc].set(False)
-            self._starts = self._starts.at[doc].set(NULL)
+            # blank the slot's whole device rows in place (same fills as
+            # the initial allocation, scratch column included): one
+            # donated program writes three rows, the tables are not
+            # copied.  Dispatched here, not at the next flush: the slot
+            # may be re-let as soon as this returns.  Statics re-upload
+            # from row 0 is already forced by _uploaded_rows above
+            with self._phase_ctx("release.blank"):
+                # np.int32: a traced scalar to jit and to the kernel
+                # profiler alike (a Python int is a new signature a slot)
+                self._right, self._deleted, self._starts = kernels.blank_rows(
+                    self._right, self._deleted, self._starts, np.int32(doc)
+                )
+            self._release_blanked_bytes += (
+                self._right.nbytes + self._deleted.nbytes + self._starts.nbytes
+            ) // self.n_docs
 
     # -- flush: run one device integration step ----------------------------
 
@@ -1127,6 +1145,8 @@ class BatchEngine:
         metrics["rows_staged_bytes"] = self._flush_rows_staged_bytes
         metrics["rows_held_bytes"] = self._flush_rows_held_bytes
         self._flush_rows_staged_bytes = self._flush_rows_held_bytes = 0
+        metrics["release_blanked_bytes"] = self._release_blanked_bytes
+        self._release_blanked_bytes = 0
         self.obs.record_flush(metrics, row_capacity=self._cap)
         if self.obs.enabled:
             self._record_device_memory()
@@ -1940,6 +1960,28 @@ class BatchEngine:
         chunk_ok.sort(key=lambda t: t[0])
         return chunk_ok
 
+    def _covering_key(self, key):
+        """The lane widths a chunk is packed and dispatched at.  On a
+        mesh a width is the widest shard's sum, and which rooms share a
+        shard changes whenever freed slots are re-let, so the same rooms
+        wander over a bucket's edge from one flush to the next: a key
+        this packer has issued before whose lanes cover the chunk's, at
+        no more than a quarter more of them (two steps of
+        ``_bucket_lanes``), is issued again before a new one, which the
+        dispatcher would have to compile (seconds on a chip against
+        microseconds of padding).  One device: the key as it is, since
+        there the same rooms always give the same key."""
+        if self.mesh is None or key in self._mesh_keys:
+            return key
+        fits = [
+            k for k in self._mesh_keys
+            if all(a >= b for a, b in zip(k, key)) and 4 * sum(k) <= 5 * sum(key)
+        ]
+        if fits:
+            return min(fits, key=sum)
+        self._mesh_keys.add(key)
+        return key
+
     def _pack_chunk_native(self, chunk_ok, b_loc, n_shards):
         """Stage one planned native chunk: grow capacity, size the
         per-shard lane widths, pick the int16 downshift, and run the
@@ -1976,7 +2018,9 @@ class BatchEngine:
             if max(oob_r, oob_s, int(link.max(initial=0))) <= 32767
             else np.int32
         )
-        key = (k_dn, k_sp, k_h, k_d)
+        key = k_dn, k_sp, k_h, k_d = self._covering_key(
+            (k_dn, k_sp, k_h, k_d)
+        )
         lane_w = 4 * b_loc + k_dn + 2 * k_sp + 2 * k_h + k_d
         slot = self._pl.acquire((n_shards, lane_w), lane_dtype)
         lanes, stats = pack_apply_lanes(
@@ -2047,6 +2091,7 @@ class BatchEngine:
         k_sp = widths(sp_r, 64)
         k_h = widths(hd_s, 8)
         k_d = widths(dl_r, 64)
+        k_dn, k_sp, k_h, k_d = self._covering_key((k_dn, k_sp, k_h, k_d))
         oob_s = np.int32(self._seg_cap + 1)
 
         def fill(out, parts, pad_val):

@@ -505,6 +505,28 @@ def scatter_rows(right, deleted, starts, idx, new_right, new_deleted,
     )
 
 
+@profiled("blank_rows")
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def blank_rows(right, deleted, starts, doc):
+    """Release blanking: doc ``doc``'s whole link/deleted/head rows
+    (scratch column included) back to the fills a new engine allocates.
+
+    ``doc`` is a traced scalar — one program for every slot — and the
+    resident tables are donated, so a release writes one row of each
+    table in place instead of copying the tables.  On doc-sharded tables
+    the partitioner keeps the write on the shard that owns the row (no
+    collective, nothing gathered): the other shards' blocks pass
+    through.  ``0 <= doc < n_docs`` is the caller's to hold
+    (``BatchEngine.reset_doc`` raises): the write clamps its start, so
+    a slot out of range would blank the first or the last row."""
+
+    def put(table, fill):
+        row = jnp.full((1, table.shape[1]), fill, table.dtype)
+        return lax.dynamic_update_slice(table, row, (doc, 0))
+
+    return put(right, NULL), put(deleted, False), put(starts, NULL)
+
+
 # ---------------------------------------------------------------------------
 # segment-sorted planning kernels (ISSUE 9)
 # ---------------------------------------------------------------------------

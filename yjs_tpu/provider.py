@@ -1604,20 +1604,21 @@ class TpuProvider:
             }
             self._m_evicted.inc()
             return final
-        self.flush()
-        final = self.engine.encode_state_as_update(i)
-        if self.wal is not None:
-            self.wal.append(KIND_RELEASE, guid, final)
-        letters = [
-            {
-                "v2": bool(e.v2),
-                "reason": e.reason,
-                "update": base64.b64encode(e.update).decode("ascii"),
-            }
-            for e in self.engine.dead_letters.take(doc=i)
-        ]
-        self._preserve_released_letters(guid, letters)
-        self.engine.reset_doc(i)
+        with self.engine.obs.tracer.span("ytpu.release"):
+            self.flush()
+            final = self.engine.encode_state_as_update(i)
+            if self.wal is not None:
+                self.wal.append(KIND_RELEASE, guid, final)
+            letters = [
+                {
+                    "v2": bool(e.v2),
+                    "reason": e.reason,
+                    "update": base64.b64encode(e.update).decode("ascii"),
+                }
+                for e in self.engine.dead_letters.take(doc=i)
+            ]
+            self._preserve_released_letters(guid, letters)
+            self.engine.reset_doc(i)
         del self._guids[guid]
         del self._guid_of[i]
         self._undo.pop(guid, None)
