@@ -29,6 +29,14 @@ and a short window (long/12), each divided by the error budget
 (1 - ``YTPU_SLO_OBJECTIVE``).  Both windows >= 14.4 -> ``page``; both
 >= 6 -> ``warning``; else ``ok``.  The convergence target is
 ``YTPU_SLO_CONVERGENCE_MS`` (default 250 ms).
+
+A window holds the completions no older than its length among the last
+``max_events`` (65,536) of the tracker, so that cap bounds both windows'
+``total``.  Each window keeps its completions and a running count of
+the breached ones: an evaluation (at every flush that completed an
+update, and at every ``state()`` / ``snapshot()``) costs the completions
+that arrived plus those that left since the last one, whatever the
+windows hold, and then reads four integers.
 """
 
 from __future__ import annotations
@@ -127,12 +135,44 @@ def origin_clock() -> OriginClock:
     return _ORIGINS
 
 
+class _BurnWindow:
+    """One burn window: its completions ``(t_visible, breached)``,
+    oldest first, and the running count of the breached ones.  Not
+    locked: the tracker calls it under its own lock."""
+
+    __slots__ = ("wlen", "cap", "events", "breached")
+
+    def __init__(self, wlen: float, cap: int):
+        self.wlen = wlen
+        self.cap = cap
+        self.events: deque = deque()
+        self.breached = 0
+
+    def add(self, event) -> None:
+        self.events.append(event)
+        self.breached += event[1]
+        if len(self.events) > self.cap:  # pushed out of the last ``cap``
+            self.breached -= self.events.popleft()[1]
+
+    def age(self, now: float) -> tuple[int, int]:
+        """Drop what left by age; ``(total, breached)`` of what stays."""
+        events = self.events
+        while events and now - events[0][0] > self.wlen:
+            self.breached -= events.popleft()[1]
+        return len(events), self.breached
+
+
 class ConvergenceTracker:
     """Per-provider convergence pipeline timestamps + SLO burn state.
 
     ``now`` is injectable for deterministic tests; instruments register
     on the provider's engine registry so one exposition call covers
-    them.  All hooks are no-ops under a disabled registry."""
+    them.  All hooks are no-ops under a disabled registry.
+
+    ``now`` never runs backwards (``time.perf_counter``; the tests'
+    clock only adds) and is read once per ``visible()``, so completions
+    are stamped in order and those inside a window are always the
+    newest: a window ages from its oldest end and never looks further."""
 
     def __init__(
         self,
@@ -167,14 +207,16 @@ class ConvergenceTracker:
             else _env_float("YTPU_SLO_OBJECTIVE", DEFAULT_OBJECTIVE)
         )
         self.max_pending = max_pending
-        # guards _pending and _events: exposition scrapes re-evaluate
-        # the burn windows from other threads while a flush completes
-        # pipelines (deque/dict iteration tears under mutation)
+        # guards _pending and _inside: exposition scrapes re-evaluate
+        # (and so age) the burn windows from other threads while a flush
+        # completes pipelines
         self._lock = threading.Lock()
         # key -> [t_origin, t_receive, t_integrate, flow_id, trace_hex]
         self._pending: OrderedDict = OrderedDict()
-        # (t_visible, breached) completions feeding the burn windows
-        self._events: deque = deque(maxlen=max_events)
+        self._inside = {
+            "short": _BurnWindow(self.short_window_s, max_events),
+            "long": _BurnWindow(self.window_s, max_events),
+        }
         self._completed = 0
         self._state = "ok"
         self._burns = {"short": 0.0, "long": 0.0}
@@ -290,6 +332,7 @@ class ConvergenceTracker:
                         if rec[2] is not None
                     ]
                 ]
+            arrived = []
             for k, rec in done:
                 t_origin, t_recv, t_int, flow_id, trace_hex = rec
                 total = max(0.0, t - t_origin)
@@ -301,9 +344,7 @@ class ConvergenceTracker:
                 self._m_completed.inc()
                 if breached:
                     self._m_breaches.inc()
-                with self._lock:
-                    self._events.append((t, breached))
-                self._completed += 1
+                arrived.append((t, breached))
                 if tracer is not None:
                     args = {
                         "latency_ms": round(total * 1000.0, 3),
@@ -313,6 +354,11 @@ class ConvergenceTracker:
                         args["trace"] = trace_hex
                     tracer.flow_end("ytpu.convergence", flow_id, **args)
             if done:
+                with self._lock:
+                    for window in self._inside.values():
+                        for event in arrived:
+                            window.add(event)
+                self._completed += len(done)
                 self._update_state(tracer)
             return len(done)
 
@@ -322,22 +368,18 @@ class ConvergenceTracker:
         if tracer is None:
             tracer = self.tracer
         with _span(tracer, "ytpu.slo.burn"):
-            now = self._now()
             budget = max(1e-9, 1.0 - self.objective)
             burns = {}
             windows = {}
             with self._lock:
-                events = tuple(self._events)
-            for wname, wlen in (
-                ("short", self.short_window_s), ("long", self.window_s)
-            ):
-                total = breached = 0
-                for t, b in reversed(events):
-                    if now - t > wlen:
-                        break
-                    total += 1
-                    if b:
-                        breached += 1
+                # the clock is read under the lock, so passes age the
+                # windows in the order in which they read it
+                now = self._now()
+                counts = {
+                    wname: window.age(now)
+                    for wname, window in self._inside.items()
+                }
+            for wname, (total, breached) in counts.items():
                 frac = breached / total if total else 0.0
                 burns[wname] = frac / budget
                 windows[wname] = {
@@ -363,13 +405,13 @@ class ConvergenceTracker:
         """Current burn-rate verdict (``ok``/``warning``/``page``),
         re-evaluated so aged-out windows decay — cheap enough for the
         admission controller to poll every tick."""
-        if self.enabled and self._events:  # ytpu-lint: disable=lock-discipline -- benign racy precheck: deque truthiness is atomic; _update_state snapshots under the lock
+        if self.enabled and self._completed:
             self._update_state()
         return self._state
 
     def snapshot(self) -> dict:
         """JSON-able SLO state (served as ``provider.slo_snapshot()``)."""
-        if self.enabled and self._events:  # ytpu-lint: disable=lock-discipline -- benign racy precheck: deque truthiness is atomic; _update_state snapshots under the lock
+        if self.enabled and self._completed:
             self._update_state()  # re-evaluate: windows age out over time
         return {
             "target_ms": self.target_ms,
