@@ -133,12 +133,15 @@ def test_exactly_one_flush_dispatch_path():
 
 
 @pytest.mark.parametrize("pipeline", [True, False])
-@pytest.mark.parametrize("kernel", ["apply", "levels", "seq"])
-def test_schema_complete_on_every_path(kernel, pipeline, monkeypatch):
-    """Every flush entry point (native batched apply, python apply,
-    device-YATA levels/seq) emits the ONE shared metrics schema —
-    including the pipeline fields — in both pipeline modes."""
-    monkeypatch.setenv("YTPU_KERNEL", kernel)
+@pytest.mark.parametrize("planner", ["native", "python"])
+def test_schema_complete_on_every_path(planner, pipeline, monkeypatch):
+    """Both ways into the one device write path (the native batched
+    planner, the per-doc Python planner) emit the ONE shared metrics
+    schema — including the pipeline fields — in both pipeline modes."""
+    if planner == "python":
+        monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+    elif not native_plan_available():
+        pytest.skip("native plancore unavailable")
     updates = make_trace("interleaved", seed=3, n_ops=20)
     _s, _t, _d, keysets, eng = run_engine(updates, 2, pipeline, monkeypatch)
     assert keysets == {frozenset(FLUSH_METRICS_SCHEMA)}
@@ -150,13 +153,6 @@ def test_schema_complete_on_every_path(kernel, pipeline, monkeypatch):
         # sync A/B path: each dispatch is drained before the next, so
         # the pipeline never reports depth
         assert m["pipeline_depth"] == 0
-
-
-def test_python_mirror_path_emits_schema(monkeypatch):
-    monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
-    updates = make_trace("interleaved", seed=4, n_ops=20)
-    _s, _t, _d, keysets, _e = run_engine(updates, 2, True, monkeypatch)
-    assert keysets == {frozenset(FLUSH_METRICS_SCHEMA)}
 
 
 def _distinct_doc_engine(n_docs, monkeypatch, mode="device"):

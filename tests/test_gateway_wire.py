@@ -165,6 +165,31 @@ def gw():
     fleet.close()
 
 
+def seed_room(gw, room: str, update: bytes) -> None:
+    """Seed ``room`` and return once the seeding flush's own broadcast
+    has gone out.  The flush emits the room's merged update, which the
+    cluster facade hands to the gateway on its event thread; a client
+    that registers before that thread runs gets the broadcast (an
+    update frame) ahead of the reply to its step 1."""
+    fanned = threading.Event()
+    fan = gw.cluster.on_update
+
+    def fan_and_tell(guid, merged):
+        try:
+            fan(guid, merged)
+        finally:
+            if guid == room:
+                fanned.set()
+
+    gw.cluster.on_update = fan_and_tell
+    try:
+        assert gw.cluster.receive_update(room, update)
+        gw.cluster.flush(room)
+        assert fanned.wait(20), "the seeding flush emitted nothing"
+    finally:
+        gw.cluster.on_update = fan
+
+
 def test_ws_handshake_opens_with_step1(gw):
     c = WsClient(gw.port, "hs-room")
     inner = c.read_sync()
@@ -191,8 +216,7 @@ def test_compat_fixture_step2_byte_identical(gw, name, root, getter):
     fx = FIXTURES[name]
     old = base64.b64decode(fx["oldDoc"])
     room = f"compat-{root}"
-    assert gw.cluster.receive_update(room, old)
-    gw.cluster.flush(room)
+    seed_room(gw, room, old)
     reference = gw.cluster.diff_update(room, b"\x00")
 
     c = WsClient(gw.port, room)

@@ -25,12 +25,8 @@ COLS = (
 
 def assert_step_equal(pm, nm, pp, np_, ctx=""):
     assert pm.n_rows == nm.n_rows, ctx
-    assert pp.n_levels == np_.n_levels, ctx
-    assert getattr(pp, "max_width", 0) == np_.max_width, ctx
     assert pp.splits == list(map(tuple, np_.splits.tolist())), ctx
     assert pp.sched == list(map(tuple, np_.sched.tolist())), ctx
-    assert pp.sched8 == list(map(tuple, np_.sched8.tolist())), ctx
-    assert pp.levels == np_.levels.tolist(), ctx
     assert sorted(pp.delete_rows) == sorted(np_.delete_rows.tolist()), ctx
     assert sorted(pp.applied_ds) == sorted(np_.applied_ds), ctx
     # bulk-apply form: final link/head values must agree exactly
@@ -48,9 +44,6 @@ def assert_state_equal(pm, nm, ctx="", encode=True):
     assert pm.state_vector() == nm.state_vector(), ctx
     assert pm.has_pending() == nm.has_pending(), ctx
     assert pm.pending_depth() == nm.pending_depth(), ctx
-    sp, sn = pm.static_columns(), nm.static_columns()
-    for k in sp:
-        assert (sp[k] == sn[k]).all(), f"static {k} {ctx}"
     assert pm.map_chain == {
         k: list(v) for k, v in nm.map_chain.items()
     }, ctx
@@ -70,14 +63,25 @@ def assert_state_equal(pm, nm, ctx="", encode=True):
         ) == Y.decode_state_vector(Y.encode_state_vector(b)), ctx
 
 
+def host_tables(m):
+    """What the device tables of ``m``'s doc must hold, from the host
+    mirror alone: right links, deleted bits, segment heads."""
+    import numpy as np
+
+    n = m.n_rows
+    deleted = np.zeros(n, bool)
+    deleted[sorted(m._host_deleted_rows)] = True
+    return np.asarray(m.list_next)[:n], deleted, np.asarray(m.head_of_seg)
+
+
 def run_differential(updates, v2=False, flush_every=1):
     pm, nm = DocMirror("text"), NativeMirror("text")
     for j, u in enumerate(updates):
         pm.ingest(u, v2)
         nm.ingest(u, v2)
         if (j + 1) % flush_every == 0 or j == len(updates) - 1:
-            pp = pm.prepare_step(want_levels=True)
-            np_ = nm.prepare_step(want_levels=True)
+            pp = pm.prepare_step()
+            np_ = nm.prepare_step()
             assert_step_equal(pm, nm, pp, np_, ctx=f"flush after update {j}")
     assert_state_equal(pm, nm, ctx="final")
     return pm, nm
@@ -242,40 +246,52 @@ def test_compaction_parity(rng):
     assert texts["native"][0] == a.get_text("text").to_string()
 
 
-def test_apply_vs_levels_vs_seq_device_state(rng):
-    """The three kernel paths (bulk apply / level-parallel YATA / per-item
-    YATA scan) must produce identical device link state and exports."""
-    import os
-
-    from yjs_tpu.ops import BatchEngine
+@pytest.mark.parametrize("flush_every", [1, 7])
+@pytest.mark.parametrize("session", ["plain", "rich", "astral"])
+def test_device_tables_equal_across_planners(
+    rng, monkeypatch, session, flush_every
+):
+    """The one device write path under both planners: the resident tables
+    (``_right``, ``_deleted``, ``_starts``) the native and the Python
+    planner leave are equal, equal the planner's own host mirror, and the
+    text, map and list read back equal the CPU core's (``core.py``)."""
     import numpy as np
 
-    updates, a, _ = two_client_session(rng, 50, rich=True)
+    from yjs_tpu.ops import BatchEngine
+
+    updates, a, _ = two_client_session(
+        rng, 50, rich=session == "rich", astral=session == "astral"
+    )
     states = {}
-    for mode in ("apply", "levels", "seq"):
-        os.environ["YTPU_KERNEL"] = mode
-        try:
-            eng = BatchEngine(2)
-            for j, u in enumerate(updates):
-                eng.queue_update(0, u)
-                eng.queue_update(1, u)
-                if j % 7 == 6:
-                    eng.flush()
-            eng.flush()
-            n = eng.mirrors[0].n_rows
-            states[mode] = (
-                np.asarray(eng._right)[:, :n].tolist(),
-                np.asarray(eng._deleted)[:, :n].tolist(),
-                np.asarray(eng._starts).tolist(),
-                eng.text(0),
-                eng.map_json(0, "meta"),
-                eng.to_json(0, "list"),
-            )
-        finally:
-            os.environ.pop("YTPU_KERNEL", None)
-    assert states["apply"] == states["levels"]
-    assert states["apply"] == states["seq"]
-    assert states["apply"][3] == a.get_text("text").to_string()
+    for planner in ("native", "python"):
+        if planner == "python":
+            monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+        eng = BatchEngine(2)
+        for j, u in enumerate(updates):
+            eng.queue_update(0, u)
+            eng.queue_update(1, u)
+            if (j + 1) % flush_every == 0:
+                eng.flush()
+        eng.flush()
+        m = eng.mirrors[0]
+        assert isinstance(m, NativeMirror) == (planner == "native")
+        n, n_segs = m.n_rows, m.n_segs
+        right = np.asarray(eng._right)[:, :n]
+        deleted = np.asarray(eng._deleted)[:, :n]
+        starts = np.asarray(eng._starts)[:, :n_segs]
+        # both docs got the same updates: each equals the host mirror
+        for table, host in zip((right, deleted, starts), host_tables(m)):
+            assert (table == host[None]).all()
+        states[planner] = (
+            right.tolist(), deleted.tolist(), starts.tolist(),
+            eng.text(0), eng.map_json(0, "meta"), eng.to_json(0, "list"),
+        )
+    assert states["native"] == states["python"]
+    assert states["native"][3:] == (
+        a.get_text("text").to_string(),
+        a.get_map("meta").to_json(),
+        a.get_array("list").to_json(),
+    )
 
 
 def test_host_links_match_device(rng):
@@ -306,10 +322,9 @@ def test_host_links_match_device(rng):
 
 def test_deleted_run_split_stays_deleted():
     """Splitting an already-deleted run in a LATER flush must ship the new
-    fragment's deleted bit on the bulk-apply path (r3 review finding: the
-    levels/seq kernels copy it in their on-device split surgery, the apply
-    path has none — without the host-emitted delete lane the fragment's
-    text resurrected)."""
+    fragment's deleted bit (r3 review finding: the device does no split
+    surgery of its own — without the host-emitted delete lane the
+    fragment's text resurrected), under either planner."""
     import os
 
     from yjs_tpu.ops import BatchEngine
@@ -330,20 +345,17 @@ def test_deleted_run_split_stays_deleted():
     Y.apply_update(a, u3)
     expect = a.get_text("text").to_string()
     assert expect == "X"
-    for mode in ("apply", "levels", "seq"):
-        os.environ["YTPU_KERNEL"] = mode
+    for planner in ("native", "python"):
+        if planner == "python":
+            os.environ["YTPU_NO_NATIVE_PLAN"] = "1"
         try:
             eng = BatchEngine(1)
-            for u in (u1,):
+            for u in (u1, u2, u3):
                 eng.queue_update(0, u)
-            eng.flush()
-            eng.queue_update(0, u2)
-            eng.flush()
-            eng.queue_update(0, u3)
-            eng.flush()
-            assert eng.text(0) == expect, f"{mode}: {eng.text(0)!r}"
+                eng.flush()
+            assert eng.text(0) == expect, f"{planner}: {eng.text(0)!r}"
         finally:
-            os.environ.pop("YTPU_KERNEL", None)
+            os.environ.pop("YTPU_NO_NATIVE_PLAN", None)
 
 
 def test_native_v2_encode_byte_parity(rng):
@@ -409,11 +421,12 @@ def test_host_export_matches_device(rng):
 
 
 def test_broadcast_kernels_agree(rng):
-    """The broadcast YATA kernel (batch_step_levels_shared: one schedule,
-    vmap in_axes=None) and the broadcast bulk apply (apply_plan_shared:
-    host-resolved final links) produce identical device state — the
-    kernel-level form of the apply/levels/seq engine cross-check, on the
-    B4-replay shape."""
+    """The broadcast bulk apply (apply_plan_shared: ONE doc's final links
+    fanned out to every doc of the batch) leaves the tables that
+    apply_plan2 leaves when each doc gets the same lanes of its own, and
+    both equal the planner's host mirror (``list_next``,
+    ``head_of_seg``) — the B4-replay shape held to the production
+    kernel."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -424,38 +437,11 @@ def test_broadcast_kernels_agree(rng):
     mirror = DocMirror("text")
     for u in updates:
         mirror.ingest(u)
-    plan = mirror.prepare_step(want_levels=True)
+    plan = mirror.prepare_step()
     n = mirror.n_rows
     n_docs = 4
-    w_pad = max((plan.max_width, 1))
-    cap = max(64, n + 2 * w_pad)
+    cap = max(64, n)
     seg_cap = max(8, mirror.n_segs)
-    cols = mirror.static_columns()
-
-    def pad_col(key, fill, dtype):
-        arr = np.full((cap + 1,), fill, dtype)
-        arr[:n] = cols[key]
-        return arr
-
-    statics = {
-        "client_key": jnp.asarray(pad_col("client_key", 0, np.uint32)),
-        "origin_slot": jnp.asarray(pad_col("origin_slot", NULL, np.int32)),
-        "origin_clock": jnp.asarray(pad_col("origin_clock", 0, np.int32)),
-        "right_slot": jnp.asarray(pad_col("right_slot", NULL, np.int32)),
-        "right_clock": jnp.asarray(pad_col("right_clock", 0, np.int32)),
-        "origin_row": jnp.asarray(pad_col("origin_row", NULL, np.int32)),
-    }
-    packed = plan.packed_levels()
-    lv = np.full((max(1, len(packed)), w_pad, 8), NULL, np.int32)
-    for j, entries in enumerate(packed):
-        if entries:
-            lv[j, : len(entries)] = entries
-    splits = np.full((max(1, len(plan.splits)), 2), NULL, np.int32)
-    if plan.splits:
-        splits[: len(plan.splits)] = np.asarray(plan.splits, np.int32)
-    dels = np.full((max(1, len(plan.delete_rows)),), NULL, np.int32)
-    if plan.delete_rows:
-        dels[: len(plan.delete_rows)] = np.asarray(plan.delete_rows, np.int32)
 
     def fresh():
         return (
@@ -463,11 +449,6 @@ def test_broadcast_kernels_agree(rng):
             jnp.zeros((n_docs, cap + 1), bool),
             jnp.full((n_docs, seg_cap + 1), NULL, jnp.int32),
         )
-
-    out_yata = kernels.batch_step_levels_shared(
-        statics, fresh(), jnp.asarray(splits), jnp.asarray(lv),
-        jnp.asarray(dels), jnp.full((n_docs,), n, jnp.int32),
-    )
 
     def pad_lanes(idx, vals, minimum, oob):
         k = len(idx)
@@ -484,16 +465,40 @@ def test_broadcast_kernels_agree(rng):
     segs_p, hvals_p = pad_lanes(plan.head_segs, plan.head_vals, 8, seg_cap + 1)
     dels_p = pad_lanes(plan.delete_rows, None, 64, cap + 1)
     lanes = jnp.asarray(np.concatenate([rows_p, vals_p, segs_p, hvals_p, dels_p]))
-    out_apply = kernels.apply_plan_shared(
+    out_shared = kernels.apply_plan_shared(
         fresh(), lanes, len(rows_p), len(segs_p), len(dels_p)
     )
-    for name, x, y in zip(("right", "deleted", "starts"), out_yata, out_apply):
+
+    # the same lanes once per doc, in apply_plan2's layout: the per-doc
+    # counts header, then every doc's real (row, value) lanes back to back
+    def per_doc(idx, vals, minimum, oob):
+        return pad_lanes(
+            list(idx) * n_docs,
+            None if vals is None else list(vals) * n_docs,
+            minimum, oob,
+        )
+
+    sp_r, sp_v = per_doc(plan.link_rows, plan.link_vals, 64, cap + 1)
+    h_s, h_v = per_doc(plan.head_segs, plan.head_vals, 8, seg_cap + 1)
+    d_r = per_doc(plan.delete_rows, None, 64, cap + 1)
+    counts = np.concatenate([
+        np.zeros(n_docs, np.int32),  # no dense sections
+        np.full(n_docs, len(plan.link_rows), np.int32),
+        np.full(n_docs, len(plan.head_segs), np.int32),
+        np.full(n_docs, len(plan.delete_rows), np.int32),
+    ])
+    lanes2 = jnp.asarray(np.concatenate([counts, sp_r, sp_v, h_s, h_v, d_r]))
+    out_apply = kernels.apply_plan2(
+        fresh(), lanes2, 0, len(sp_r), len(h_s), len(d_r)
+    )
+    for name, x, y, h in zip(
+        ("right", "deleted", "starts"), out_shared, out_apply,
+        host_tables(mirror),
+    ):
         xa, ya = np.asarray(x), np.asarray(y)
-        if name != "starts":
-            xa, ya = xa[:, :n], ya[:, :n]
-        else:
-            xa, ya = xa[:, : mirror.n_segs], ya[:, : mirror.n_segs]
+        xa, ya = xa[:, : len(h)], ya[:, : len(h)]
         assert (xa == ya).all(), name
+        assert (xa == h[None]).all(), name
 
 
 def test_pool_width_engine_state_identical(monkeypatch):

@@ -136,22 +136,21 @@ def test_new_flush_metrics_rejects_unknown_keys():
 
 
 def test_flush_metrics_schema_identical_across_modes():
-    """apply / levels / seq / pure-Python planner: one key set
-    (FLUSH_METRICS_SCHEMA), no mode-specific drift."""
+    """Native planner / pure-Python planner / a flush with nothing
+    staged: one key set (FLUSH_METRICS_SCHEMA), no mode-specific drift."""
     keysets = {}
-    for mode in ("native", "apply", "levels", "seq", "python"):
+    for mode in ("native", "python"):
         if mode == "python":
             os.environ["YTPU_NO_NATIVE_PLAN"] = "1"
-        elif mode != "native":
-            os.environ["YTPU_KERNEL"] = mode
         try:
             eng = BatchEngine(2)
             eng.queue_update(0, _update())
             eng.queue_update(1, _update("other"))
             eng.flush()
             keysets[mode] = set(eng.last_flush_metrics)
+            eng.flush()
+            keysets[mode + ", empty"] = set(eng.last_flush_metrics)
         finally:
-            os.environ.pop("YTPU_KERNEL", None)
             os.environ.pop("YTPU_NO_NATIVE_PLAN", None)
     for mode, keys in keysets.items():
         assert keys == set(FLUSH_METRICS_SCHEMA), mode

@@ -89,26 +89,40 @@ def test_engine_batched_svs_use_sharded_kernel(mesh8):
     assert eng._sharded_sv
 
 
-def test_sharded_levels_kernel_path(mesh8):
-    """YTPU_KERNEL=levels keeps the shard_map YATA step working on the
-    mesh (the on-device integration form; default is the sharded bulk
-    apply)."""
-    import os
-
-    os.environ["YTPU_KERNEL"] = "levels"
-    try:
-        n = 8
-        docs = build_docs(n)
-        eng = BatchEngine(n, mesh=mesh8)
-        for i, d in enumerate(docs):
-            eng.queue_update(i, Y.encode_state_as_update(d))
-        eng.flush()
-        assert eng.last_metrics is not None
-        assert eng.last_metrics["integrated"] > 0
+def test_meshed_tables_equal_unmeshed(mesh8):
+    """The sharded bulk apply leaves the tables the one-device apply
+    leaves: the same eight docs through a meshed and an unmeshed engine,
+    two flushes each, give equal ``_right``/``_deleted``/``_starts``, and
+    the mesh's psum'd counters saw the work."""
+    n = 8
+    docs = build_docs(n)
+    first = [Y.encode_state_as_update(d) for d in docs]
+    second = []
+    for d in docs:
+        sv = Y.encode_state_vector(d)
+        d.get_text("text").insert(3, "more")
+        second.append(Y.encode_state_as_update(d, sv))
+    tables = {}
+    for name, mesh in (("mesh", mesh8), ("one", None)):
+        eng = BatchEngine(n, mesh=mesh)
+        for round_ in (first, second):
+            for i, u in enumerate(round_):
+                eng.queue_update(i, u)
+            eng.flush()
+        if mesh is not None:
+            assert eng.last_metrics is not None
+            assert eng.last_metrics["integrated"] > 0
         for i, d in enumerate(docs):
             assert eng.text(i) == d.get_text("text").to_string()
-    finally:
-        os.environ.pop("YTPU_KERNEL", None)
+        rows = max(m.n_rows for m in eng.mirrors)
+        segs = max(m.n_segs for m in eng.mirrors)
+        tables[name] = (
+            np.asarray(eng._right)[:, :rows],
+            np.asarray(eng._deleted)[:, :rows],
+            np.asarray(eng._starts)[:, :segs],
+        )
+    for x, y in zip(tables["mesh"], tables["one"]):
+        assert (x == y).all()
 
 
 def test_meshed_engine_arrays_stay_on_mesh(mesh8):
@@ -126,7 +140,6 @@ def test_meshed_engine_arrays_stay_on_mesh(mesh8):
             "_right": eng._right,
             "_deleted": eng._deleted,
             "_starts": eng._starts,
-            **{f"statics[{k}]": v for k, v in (eng._statics or {}).items()},
         }
         for name, arr in arrays.items():
             if arr is None:
@@ -140,8 +153,8 @@ def test_meshed_engine_arrays_stay_on_mesh(mesh8):
         eng.queue_update(i, Y.encode_state_as_update(d))
     eng.flush()
     check_all()
-    # second flush: exercises the statics scatter, capacity growth, and
-    # (compact_min_rows=4) the compaction read-back/scatter path
+    # second flush: exercises capacity growth and (compact_min_rows=4)
+    # the compaction scatter path
     for i, d in enumerate(docs):
         sv = Y.encode_state_vector(d)
         d.get_text("text").insert(0, "x" * 40)

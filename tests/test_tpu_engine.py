@@ -584,35 +584,25 @@ class TestMapConvergence:
 
 
 class TestChainStitching:
-    """Cross-group chain stitching (StepPlan.assign_levels): sequential
-    typing must flatten to O(1) levels, and broken chains must still
-    converge through the deferred fallback's original-gap inputs."""
+    """Typing chains across clients: every run's origin is another run's
+    tail, and a concurrent insert mid-chain breaks it — the planner's
+    links must leave the device with the CPU core's order either way."""
 
-    def test_sequential_typing_one_level(self):
+    def test_sequential_typing_converges(self):
         # alternating clients typing at their own cursors, fully synced:
-        # every run's origin is a prior run's tail -> everything stitches
+        # every run's origin is a prior run's tail
         a, b = make_doc(1), make_doc(2)
         for i in range(30):
             d, o = (a, b) if i % 2 == 0 else (b, a)
             t = d.get_text("text")
             t.insert(len(t.to_string()), f"w{i} ")
             Y.apply_update(o, Y.encode_state_as_update(d, Y.encode_state_vector(o)))
-        from yjs_tpu.ops.columns import DocMirror
-
-        m = DocMirror("text")
-        m.ingest(Y.encode_state_as_update(a))
-        plan = m.prepare_step()
-        assert plan.n_levels == 1
-        # stitched entries carry their true gap in the fb fields
-        stitched = [e for e in plan.sched8 if (e[3], e[2]) != (e[6], e[7])]
-        assert stitched
         eng = replay_into_engine([Y.encode_state_as_update(a)])
         assert_engine_matches(eng, a)
 
     def test_concurrent_insert_breaks_chain_but_converges(self):
         # two clients insert concurrently at the same position mid-chain:
-        # the stitch's fast check fails on one side and the deferred
-        # fallback must use the ORIGINAL gap (fb fields), not the head's
+        # the planner's YATA walk orders them, not the chain's shortcut
         a, b = make_doc(1), make_doc(2)
         a.get_text("text").insert(0, "base ")
         Y.apply_update(b, Y.encode_state_as_update(a))
@@ -627,32 +617,6 @@ class TestChainStitching:
         assert a.get_text("text").to_string() == b.get_text("text").to_string()
         eng = replay_into_engine([ua, ub])
         assert_engine_matches(eng, a)
-
-
-class TestBlockwiseDispatch:
-    """Level-axis tiling of long schedules (the long-context analogue,
-    SURVEY.md §5): forcing one-level blocks must integrate identically to
-    the single-dispatch path."""
-
-    def test_forced_single_level_blocks_converge(self, monkeypatch):
-        monkeypatch.setenv("YTPU_BLOCK_LEVELS", "1")
-        gen = random.Random(99)
-        docs = [make_doc(i + 1) for i in range(3)]
-        for _ in range(60):
-            d = docs[gen.randrange(3)]
-            t = d.get_text("text")
-            ln = len(t.to_string())
-            if gen.random() < 0.7 or ln == 0:
-                t.insert(gen.randint(0, ln), gen.choice(["x", "yy", "z "]))
-            else:
-                pos = gen.randrange(ln)
-                t.delete(pos, min(gen.randint(1, 2), ln - pos))
-        updates = [Y.encode_state_as_update(d) for d in docs]
-        for d in docs:
-            for u in updates:
-                Y.apply_update(d, u)
-        eng = replay_into_engine([Y.encode_state_as_update(docs[0])])
-        assert_engine_matches(eng, docs[0])
 
 
 class TestCompaction:
@@ -1232,17 +1196,14 @@ class TestLaneBucketing:
         demand differs by <12.5% reuse the SAME padded widths (= the
         dispatch hits the jit cache by construction).
 
-        Specific to the NATIVE bulk-apply lane packing: the levels/seq
-        cross-check kernels report schedule (not lane) occupancy, and
-        the Python-planner fallback takes the non-batched pack path."""
+        Specific to the NATIVE lane packing: the Python-planner fallback
+        takes the non-batched pack path."""
         import os as _os
 
         import pytest as _pytest
 
-        if _os.environ.get("YTPU_KERNEL", "apply") != "apply" or _os.environ.get(
-            "YTPU_NO_NATIVE_PLAN"
-        ):
-            _pytest.skip("bulk-apply native lane packing only")
+        if _os.environ.get("YTPU_NO_NATIVE_PLAN"):
+            _pytest.skip("native lane packing only")
         import yjs_tpu as Y
         from yjs_tpu.ops import BatchEngine
 

@@ -38,7 +38,7 @@ def dotted_name(node) -> str | None:
 
 
 def call_func_name(call: ast.Call) -> str | None:
-    """Terminal dotted name of a call's callee (``kernels.batch_step``)."""
+    """Terminal dotted name of a call's callee (``kernels.apply_plan2``)."""
     return dotted_name(call.func)
 
 

@@ -122,7 +122,6 @@ def load():
     u64 = ctypes.c_uint64
     vp = ctypes.c_void_p
     i32p = ctypes.POINTER(ctypes.c_int32)
-    u32p = ctypes.POINTER(ctypes.c_uint32)
     lib.ymx_new.restype = vp
     lib.ymx_free.argtypes = [vp]
     lib.ymx_add_buf.restype = i64
@@ -132,12 +131,11 @@ def load():
     lib.ymx_buf_len.restype = i64
     lib.ymx_buf_len.argtypes = [vp, i64]
     lib.ymx_prepare.restype = ctypes.c_int
-    lib.ymx_prepare.argtypes = [vp, i64p, i64p, i64, ctypes.c_int, i64p]
+    lib.ymx_prepare.argtypes = [vp, i64p, i64p, i64, i64p]
     vpp = ctypes.POINTER(vp)
     lib.ymx_prepare_many.restype = None
     lib.ymx_prepare_many.argtypes = [vpp, i64, i64p, i64p, i64p,
-                                     ctypes.c_int, ctypes.c_int, i64p,
-                                     i64p]
+                                     ctypes.c_int, i64p, i64p]
     for pack_name in ("ymx_pack_apply", "ymx_pack_apply16"):
         fn = getattr(lib, pack_name)
         fn.restype = None
@@ -147,7 +145,6 @@ def load():
     for name, args in [
         ("ymx_plan_splits", [vp, i64p]),
         ("ymx_plan_sched", [vp, i64p]),
-        ("ymx_plan_sched8", [vp, i64p, i64p]),
         ("ymx_plan_deletes", [vp, i64p]),
         ("ymx_plan_applied_ds", [vp, i64p]),
         ("ymx_plan_links", [vp, i64p, i64p]),
@@ -183,8 +180,6 @@ def load():
     lib.ymx_has_pending.argtypes = [vp]
     lib.ymx_rows.restype = None
     lib.ymx_rows.argtypes = [vp, i64] + [i64p] * 21
-    lib.ymx_static_cols.restype = None
-    lib.ymx_static_cols.argtypes = [vp, i64, u32p] + [i32p] * 5
     lib.ymx_copy_bytes.restype = ctypes.c_int
     lib.ymx_copy_bytes.argtypes = [vp, i64, i64, i64, u8p]
     lib.ymx_encode_bound.restype = i64

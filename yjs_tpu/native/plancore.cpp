@@ -1,7 +1,7 @@
 // Native plan builder: the persistent host mirror of one document's struct
 // columns, with the full flush pipeline (wire scan -> causal schedule ->
-// pre-split -> row assignment -> level-parallel schedule) implemented in
-// C++.  This is the C++ twin of yjs_tpu/ops/columns.py DocMirror
+// pre-split -> row assignment -> YATA placement -> final links) implemented
+// in C++.  This is the C++ twin of yjs_tpu/ops/columns.py DocMirror
 // (reference pipeline: src/utils/encoding.js:127-198,225-321 decode +
 // dependency-stack integration, src/structs/Item.js:84-120 splitItem,
 // :354-397 getMissing, :403-517 integrate; recast as the columnar plan of
@@ -49,10 +49,6 @@ using namespace ytpu_wire;
 namespace {
 
 constexpr int64_t kNull = -1;
-// sched8 sentinels (shared with yjs_tpu/ops/kernels.py)
-constexpr int64_t kNoLeftWrite = -3;
-constexpr int64_t kGatherSucc = -2;
-
 // content-source kinds (superset of yjs_tpu/native/__init__.py SRC_*)
 constexpr int64_t kKindNone = 0;     // GC row
 constexpr int64_t kKindDeleted = 1;  // ContentDeleted: length only
@@ -106,10 +102,6 @@ struct Plan {
   std::vector<std::array<int64_t, 4>> sched;
   std::vector<int64_t> delete_rows;
   std::vector<std::array<int64_t, 3>> applied_ds;
-  std::vector<std::array<int64_t, 8>> sched8;
-  std::vector<int64_t> levels;
-  int64_t n_levels = 0;
-  int64_t max_width = 0;
   // bulk-apply form: FINAL link/head values of everything this step
   // changed (host-resolved YATA; see Mirror::list_insert).  Dedup rides
   // epoch marks in the Mirror (mark_link/mark_head); the finalize pass
@@ -123,10 +115,6 @@ struct Plan {
     sched.clear();
     delete_rows.clear();
     applied_ds.clear();
-    sched8.clear();
-    levels.clear();
-    n_levels = 0;
-    max_width = 0;
     dirty_links.clear();
     dirty_heads.clear();
     link_rows.clear();
@@ -1027,10 +1015,9 @@ struct Mirror {
   // ---- the flush pipeline (DocMirror.prepare_step twin) -----------------
 
   int prepare(const int64_t* buf_ids, const int64_t* v2_flags,
-              int64_t n_updates, bool want_levels, bool want_sched = true) {
-    // the bulk-apply path never reads the sched section unless events are
-    // observed; skipping it saves a 32-byte append per integrated row
-    want_sched = want_sched || want_levels;
+              int64_t n_updates, bool want_sched = true) {
+    // nothing reads the sched section unless events are observed;
+    // skipping it saves a 32-byte append per integrated row
     const bool timing = std::getenv("YMX_TIMING") != nullptr;
     auto t0 = std::chrono::steady_clock::now();
     auto lap = [&](const char* what) {
@@ -1560,11 +1547,6 @@ struct Mirror {
     lww_pass(touched_map_segs);
     lap("lww");
     plan.n_rows = n_rows();
-    // the level-parallel schedule serves only the YATA device kernels
-    // (YTPU_KERNEL=levels/seq and the sharded step); the default bulk
-    // path ships final links and skips the level assignment entirely
-    if (want_levels) assign_levels();
-    lap("levels");
     // ascending row/seg order = the Python twin's `sorted(plan._dl)`.
     // When the dirty set is DENSE in the row range (bulk first flush),
     // recollect it ascending by scanning the dl_mark epoch array — O(range)
@@ -1611,135 +1593,6 @@ struct Mirror {
     lap("finalize");
     gen++;
     return 0;
-  }
-
-  // ---- level assignment (StepPlan.assign_levels twin) -------------------
-
-  void assign_levels() {
-    const bool timing = std::getenv("YMX_TIMING") != nullptr;
-    auto t0 = std::chrono::steady_clock::now();
-    auto lap = [&](const char* what) {
-      if (!timing) return;
-      auto t1 = std::chrono::steady_clock::now();
-      std::fprintf(stderr, "[ymx-lv] %-12s %8.1f us\n", what,
-                   std::chrono::duration<double, std::micro>(t1 - t0).count());
-      t0 = t1;
-    };
-    auto& sched = plan.sched;
-    size_t n = sched.size();
-    // group by (left, right, seg) preserving first-appearance order
-    struct Group {
-      int64_t left, right, seg;
-      std::vector<int64_t> members;  // row ids, sched order
-    };
-    std::vector<Group> groups;
-    groups.reserve(n);
-    std::unordered_map<uint64_t, std::vector<uint32_t>> gmap;  // hash -> idxs
-    gmap.reserve(n * 2);
-    auto ghash = [](int64_t l, int64_t r, int64_t s) -> uint64_t {
-      uint64_t h = 1469598103934665603ull;
-      for (uint64_t v : {(uint64_t)l, (uint64_t)r, (uint64_t)s}) {
-        h ^= v + 0x9e3779b97f4a7c15ull;
-        h *= 1099511628211ull;
-      }
-      return h;
-    };
-    for (size_t i = 0; i < n; i++) {
-      int64_t left = sched[i][1], right = sched[i][2], sg = sched[i][3];
-      auto& cands = gmap[ghash(left, right, sg)];
-      int32_t found = -1;
-      for (uint32_t gi : cands) {
-        Group& g = groups[gi];
-        if (g.left == left && g.right == right && g.seg == sg) {
-          found = (int32_t)gi;
-          break;
-        }
-      }
-      if (found < 0) {
-        cands.push_back((uint32_t)groups.size());
-        groups.push_back({left, right, sg, {sched[i][0]}});
-      } else {
-        groups[(size_t)found].members.push_back(sched[i][0]);
-      }
-    }
-    lap("grouping");
-    plan.sched8.clear();
-    plan.levels.clear();
-    plan.sched8.reserve(n);
-    plan.levels.reserve(n);
-    // row -> level scratch (0 = unassigned this pass)
-    std::vector<int64_t> lev_of_row((size_t)n_rows(), 0);
-    auto lev_of = [&](int64_t row) {
-      return (row >= 0 && row < (int64_t)lev_of_row.size())
-                 ? lev_of_row[(size_t)row]
-                 : 0;
-    };
-    // per-gap used levels (tiny sorted vectors; usually length 1)
-    std::unordered_map<int64_t, std::vector<int64_t>> used;
-    used.reserve(groups.size() * 2);
-    // open chain tails: tail row -> (entry idx, head check, head right, lev)
-    std::unordered_map<int64_t, std::array<int64_t, 4>> tails;
-    tails.reserve(groups.size() * 2);
-    int64_t n_levels = 0;
-    for (auto& g : groups) {
-      int64_t left = g.left, right = g.right, sg = g.seg;
-      auto& members = g.members;
-      if (members.size() > 1)
-        std::stable_sort(members.begin(), members.end(),
-                         [&](int64_t a, int64_t b) {
-                           return row_client(a) < row_client(b);
-                         });
-      auto tit = left != kNull ? tails.find(left) : tails.end();
-      if (tit != tails.end() && tit->second[2] == right &&
-          plan.sched8[(size_t)tit->second[0]][5] == sg) {
-        // stitch: continue the chain ending at `left` in place
-        auto [idx0, hchk, hr0, lev] = tit->second;
-        plan.sched8[(size_t)idx0][4] = members[0];
-        for (size_t j = 0; j < members.size(); j++) {
-          int64_t row = members[j];
-          int64_t succ = j + 1 < members.size() ? members[j + 1] : kGatherSucc;
-          plan.sched8.push_back(
-              {{row, kNoLeftWrite, hr0, hchk, succ, sg, left, right}});
-          plan.levels.push_back(lev);
-          lev_of_row[(size_t)row] = lev;
-        }
-        tails.erase(left);
-        tails[members.back()] = {(int64_t)plan.sched8.size() - 1, hchk, hr0,
-                                 lev};
-        continue;
-      }
-      int64_t base = 1 + std::max(lev_of(left), lev_of(right));
-      int64_t gap = left != kNull ? left : ~sg;  // head writes keyed per seg
-      int64_t lev = base;
-      {
-        auto& lvls = used[gap];
-        auto it = std::lower_bound(lvls.begin(), lvls.end(), lev);
-        while (it != lvls.end() && *it == lev) {
-          ++lev;
-          ++it;
-        }
-        lvls.insert(it, lev);
-      }
-      for (size_t j = 0; j < members.size(); j++) {
-        int64_t row = members[j];
-        int64_t entry_left = j == 0 ? left : kNoLeftWrite;
-        int64_t succ = j + 1 < members.size() ? members[j + 1] : kGatherSucc;
-        plan.sched8.push_back(
-            {{row, entry_left, right, left, succ, sg, left, right}});
-        plan.levels.push_back(lev);
-        lev_of_row[(size_t)row] = lev;
-      }
-      tails[members.back()] = {(int64_t)plan.sched8.size() - 1, left, right,
-                               lev};
-      n_levels = std::max(n_levels, lev);
-    }
-    lap("main-loop");
-    plan.n_levels = n_levels;
-    // width of the widest level (for the engine's padded pack)
-    std::vector<int64_t> width((size_t)n_levels, 0);
-    for (int64_t lv : plan.levels) width[(size_t)(lv - 1)]++;
-    plan.max_width = 0;
-    for (int64_t w : width) plan.max_width = std::max(plan.max_width, w);
   }
 
   // ---- compaction (DocMirror.rebuild_compacted twin) --------------------
@@ -2570,22 +2423,21 @@ int64_t ymx_buf_len(void* h, int64_t idx) {
 }
 
 // run the flush pipeline over the staged updates (buf ids + v2 flags).
-// out_counts (int64[12]): n_rows, n_splits, n_sched, n_sched8, n_levels,
-// max_width, n_delete_rows, n_applied_ds, has_pending, pending_depth,
-// n_slots, n_segs.  Returns 0 or an error code (<0).
+// out_counts (int64[14]): n_rows, n_splits, n_sched, [3..5] reserved (0:
+// cached plans and the packer index this layout), n_delete_rows,
+// n_applied_ds, has_pending, pending_depth, n_slots, n_segs, n_links,
+// n_heads.  Returns 0 or an error code (<0).
 int ymx_prepare(void* h, const int64_t* buf_ids, const int64_t* v2_flags,
-                int64_t n_updates, int want_levels, int64_t* out_counts) {
+                int64_t n_updates, int64_t* out_counts) {
   Mirror* m = static_cast<Mirror*>(h);
-  int rc = m->prepare(buf_ids, v2_flags, n_updates, want_levels != 0);
+  int rc = m->prepare(buf_ids, v2_flags, n_updates);
   if (rc != 0) return rc;
   int64_t depth = (int64_t)m->pending_ds.size();
   for (auto& [c, q] : m->pending) depth += (int64_t)q.size();
   out_counts[0] = m->plan.n_rows;
   out_counts[1] = (int64_t)m->plan.splits.size();
   out_counts[2] = (int64_t)m->plan.sched.size();
-  out_counts[3] = (int64_t)m->plan.sched8.size();
-  out_counts[4] = m->plan.n_levels;
-  out_counts[5] = m->plan.max_width;
+  out_counts[3] = out_counts[4] = out_counts[5] = 0;
   out_counts[6] = (int64_t)m->plan.delete_rows.size();
   out_counts[7] = (int64_t)m->plan.applied_ds.size();
   out_counts[8] = (m->pending.empty() && m->pending_ds.empty()) ? 0 : 1;
@@ -2636,13 +2488,12 @@ void ymx_plan_segment_stats(int64_t* out) {
 // the same handle twice in one call.
 void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
                       const int64_t* ids_flat, const int64_t* v2_flat,
-                      int want_levels, int want_sched, int64_t* out_counts,
-                      int64_t* out_rc) {
+                      int want_sched, int64_t* out_counts, int64_t* out_rc) {
   auto plan_one = [&](int64_t i) {
     Mirror* m = static_cast<Mirror*>(hs[i]);
     int64_t lo = buf_ofs[i], hi = buf_ofs[i + 1];
     int rc = m->prepare(ids_flat + lo, v2_flat + lo, hi - lo,
-                        want_levels != 0, want_sched != 0);
+                        want_sched != 0);
     out_rc[i] = rc;
     int64_t* c = out_counts + i * 16;
     if (rc != 0) {
@@ -2654,9 +2505,7 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
     c[0] = m->plan.n_rows;
     c[1] = (int64_t)m->plan.splits.size();
     c[2] = (int64_t)m->plan.sched.size();
-    c[3] = (int64_t)m->plan.sched8.size();
-    c[4] = m->plan.n_levels;
-    c[5] = m->plan.max_width;
+    c[3] = c[4] = c[5] = 0;
     c[6] = (int64_t)m->plan.delete_rows.size();
     c[7] = (int64_t)m->plan.applied_ds.size();
     c[8] = (m->pending.empty() && m->pending_ds.empty()) ? 0 : 1;
@@ -2808,8 +2657,7 @@ int64_t ymx_clone_state(void* dst_h, void* src_h) {
   for (const auto& fc : s->frag_clock)
     bytes += (int64_t)(fc.size() * 2 * sizeof(int64_t));
   bytes += (int64_t)((s->plan.link_rows.size() + s->plan.link_vals.size() +
-                      s->plan.sched.size() * 4 + s->plan.sched8.size() * 8 +
-                      s->plan.levels.size() + s->plan.delete_rows.size()) *
+                      s->plan.sched.size() * 4 + s->plan.delete_rows.size()) *
                      sizeof(int64_t));
   for (const auto& [cl, q] : s->pending)
     bytes += (int64_t)(q.size() * sizeof(PendRef));
@@ -2957,13 +2805,6 @@ void ymx_plan_sched(void* h, int64_t* out) {
     for (int i = 0; i < 4; i++) *out++ = s[i];
 }
 
-void ymx_plan_sched8(void* h, int64_t* out8, int64_t* out_lv) {
-  Mirror* m = static_cast<Mirror*>(h);
-  for (auto& s : m->plan.sched8)
-    for (int i = 0; i < 8; i++) *out8++ = s[i];
-  for (int64_t lv : m->plan.levels) *out_lv++ = lv;
-}
-
 void ymx_plan_deletes(void* h, int64_t* out) {
   Mirror* m = static_cast<Mirror*>(h);
   for (int64_t r : m->plan.delete_rows) *out++ = r;
@@ -3006,30 +2847,6 @@ void ymx_rows(void* h, int64_t start,
     src_count[i] = c.count; src_v2[i] = c.v2;
     host_deleted[i] = m->r_host_deleted[r];
     lww_deleted[i] = m->r_lww_deleted[r];
-  }
-}
-
-// device static columns for rows [start:] (engine _upload_statics shapes)
-void ymx_static_cols(void* h, int64_t start, uint32_t* client_key,
-                     int32_t* oslot, int32_t* oclock, int32_t* rslot,
-                     int32_t* rclock, int32_t* origin_row) {
-  Mirror* m = static_cast<Mirror*>(h);
-  int64_t n = m->n_rows();
-  for (int64_t r = start; r < n; r++) {
-    int64_t i = r - start;
-    client_key[i] = (uint32_t)m->client_of_slot[(size_t)m->r_slot[r]];
-    oslot[i] = (int32_t)m->r_oslot[r];
-    oclock[i] = (int32_t)m->r_oclock[r];
-    rslot[i] = (int32_t)m->r_rslot[r];
-    rclock[i] = (int32_t)m->r_rclock[r];
-    if (m->r_oslot[r] == kNull) {
-      origin_row[i] = (int32_t)kNull;
-    } else {
-      int64_t fi = m->frag_containing(m->r_oslot[r], m->r_oclock[r]);
-      origin_row[i] =
-          (int32_t)(fi == kNull ? kNull
-                                : m->frag_row[(size_t)m->r_oslot[r]][(size_t)fi]);
-    }
   }
 }
 

@@ -31,7 +31,7 @@ def _loaded_mirror(updates: list[bytes], v2: bool):
     m = NativeMirror("") if native_plan_available() else DocMirror("")
     for u in updates:
         m.ingest(u, v2)
-    m.prepare_step(want_levels=False)
+    m.prepare_step()
     return m
 
 

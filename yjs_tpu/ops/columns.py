@@ -45,9 +45,6 @@ from ..native import SRC_DELETED, SRC_FRAMED, SRC_NONE, SRC_SPILL, SRC_UTF8
 from . import plan_cache as _pc
 
 NULL = -1  # null id / null row sentinel in every int column
-# sched8 sentinels (shared with the level kernel, yjs_tpu/ops/kernels.py)
-NO_LEFT_WRITE = -3  # chain member: placed by its predecessor's succ write
-GATHER_SUCC = -2  # succ: the old successor of `check` (== right when fast)
 
 
 # ---------------------------------------------------------------------------
@@ -444,19 +441,10 @@ class StepPlan:
     # delete ranges applied this step (client, clock, len) — the DS section
     # of the step's emitted incremental update
     applied_ds: list[tuple[int, int, int]] = field(default_factory=list)
-    # 8-field bulk schedule (row, left, right, check, succ, seg, fb_left,
-    # fb_right) with dependency levels (1-based): see assign_levels
-    sched8: list[tuple[int, int, int, int, int, int, int, int]] = field(
-        default_factory=list
-    )
-    levels: list[int] = field(default_factory=list)
-    n_levels: int = 0
-    max_width: int = 0  # widest level (engine pack bucket sizing)
-    # bulk-apply form (the default device path): FINAL right-link values of
+    # bulk-apply form (the device write path): FINAL right-link values of
     # every row whose link changed this step, plus segment-head updates —
     # the host planner resolves YATA placement against its own list state,
-    # so the device applies one conflict-free scatter (the sort/rank-style
-    # layout; the YATA scan kernels remain as the levels/seq paths)
+    # so the device applies one conflict-free scatter
     link_rows: list[int] = field(default_factory=list)
     link_vals: list[int] = field(default_factory=list)
     head_segs: list[int] = field(default_factory=list)
@@ -464,113 +452,6 @@ class StepPlan:
     # structs placed by the segment-sorted conflict-free fast path
     # instead of the sequential YATA walk (ISSUE 9 accounting)
     fastpath_structs: int = 0
-
-    def assign_levels(self, client_of_row) -> None:
-        """Rewrite the causal schedule into the level-parallel bulk form.
-
-        Items sharing a splice gap (same resolved left & right in the same
-        segment) necessarily share (origin, rightOrigin) — post-split, a
-        left row determines the origin id and vice versa — so YATA orders
-        them by ascending client (reference Item.js case 1, :447-455).  The
-        host pre-links each such group into a chain spliced in ONE bulk
-        write; remaining items get one entry each.
-
-        Chains also extend ACROSS groups: when a group's gap-left is the
-        current tail of an already-emitted chain and its right matches the
-        chain's right (sequential typing: each new run's origin is the last
-        id of the previous run), the group joins that chain at the SAME
-        level — the whole typing session splices in one bulk write instead
-        of one level per run.  This flattens the reference's inherently
-        sequential insertion chains (Item.js fast path :432-434) into O(1)
-        levels for the common editing texture.
-
-        Each sched8 entry is (row, left, right, check, succ, seg, fb_left,
-        fb_right):
-        - fast iff rl[check] == right (check==NULL: head test
-          starts[seg]==right); all members of one chain share (check,
-          right), so a chain is fast or deferred as a whole
-        - splice: rl[left] = row (left>=0), starts[seg] = row (left==NULL),
-          rl[row] = succ, where succ==GATHER_SUCC means the gathered old
-          successor of `check`
-        - on fast-check failure the item integrates sequentially with
-          (row, fb_left, fb_right, seg) — its ORIGINAL YATA gap, which for
-          stitched groups differs from the chain-head's (check, right).
-        """
-        groups: dict[tuple[int, int, int], list[int]] = {}
-        order: list[tuple[int, int, int]] = []
-        for i, (row, left, right, seg) in enumerate(self.sched):
-            key = (left, right, seg)
-            g = groups.get(key)
-            if g is None:
-                groups[key] = [i]
-                order.append(key)
-            else:
-                g.append(i)
-
-        self.sched8 = []
-        self.levels = []
-        lev_of_row: dict[int, int] = {}
-        used: set[tuple[int, object]] = set()
-        # chain tails open for stitching: tail row -> (entry idx, head
-        # check, head right, level)
-        tails: dict[int, tuple[int, int, int, int]] = {}
-        n_levels = 0
-        for key in order:
-            left, right, seg = key
-            idxs = groups[key]
-            members = [self.sched[i][0] for i in idxs]
-            if len(members) > 1:
-                members.sort(key=client_of_row)
-            t = tails.get(left) if left != NULL else None
-            if t is not None and t[2] == right and self.sched8[t[0]][5] == seg:
-                # stitch: continue the chain ending at `left` in place
-                idx0, hchk, hr0, lev = t
-                e = self.sched8[idx0]
-                self.sched8[idx0] = e[:4] + (members[0],) + e[5:]
-                for j, row in enumerate(members):
-                    succ = (
-                        members[j + 1] if j + 1 < len(members) else GATHER_SUCC
-                    )
-                    self.sched8.append(
-                        (row, NO_LEFT_WRITE, hr0, hchk, succ, seg, left, right)
-                    )
-                    self.levels.append(lev)
-                    lev_of_row[row] = lev
-                del tails[left]
-                tails[members[-1]] = (len(self.sched8) - 1, hchk, hr0, lev)
-                # n_levels already covers lev: the head chain raised it
-                continue
-            base = 1 + max(lev_of_row.get(left, 0), lev_of_row.get(right, 0))
-            # write-target key: rl[left] for real lefts, the segment's head
-            # slot otherwise (distinct segments' head writes may share a
-            # level — they scatter to distinct starts[] cells)
-            gap: object = left if left != NULL else ("h", seg)
-            lev = base
-            while (lev, gap) in used:
-                lev += 1
-            used.add((lev, gap))
-            for j, row in enumerate(members):
-                entry_left = left if j == 0 else NO_LEFT_WRITE
-                succ = members[j + 1] if j + 1 < len(members) else GATHER_SUCC
-                self.sched8.append(
-                    (row, entry_left, right, left, succ, seg, left, right)
-                )
-                self.levels.append(lev)
-                lev_of_row[row] = lev
-            tails[members[-1]] = (len(self.sched8) - 1, left, right, lev)
-            n_levels = max(n_levels, lev)
-        self.n_levels = n_levels
-        width = [0] * n_levels
-        for lev in self.levels:
-            width[lev - 1] += 1
-        self.max_width = max(width, default=0)
-
-    def packed_levels(self):
-        """The 8-field schedule grouped level-major ([L, W, 8] device pack)."""
-        out: list[list[tuple[int, ...]]] = [[] for _ in range(self.n_levels)]
-        for entry, lev in zip(self.sched8, self.levels):
-            out[lev - 1].append(entry)
-        return out
 
 
 # ---------------------------------------------------------------------------
@@ -925,8 +806,7 @@ class DocMirror:
         if row in self._host_deleted_rows:
             self._host_deleted_rows.add(new_row)
             # the new fragment's device deleted bit must ship too: the
-            # bulk-apply path has no on-device split surgery to copy it
-            # (levels/seq copy dl[orig] in their split pre-pass)
+            # device has no split surgery of its own to copy it
             plan.delete_rows.append(new_row)
         if seg != NULL and self.seg_is_map(seg):
             # fragments of a map-chain entry sit adjacent in its chain
@@ -1150,28 +1030,21 @@ class DocMirror:
 
     # -- the flush pipeline -------------------------------------------------
 
-    def plan_key(self, want_levels: bool | None = None,
-                 want_sched: bool = True):
+    def plan_key(self):
         """Plan-cache key for the staged work (ISSUE 9): kind + frontier
-        + staged content digest + plan-shape flag."""
-        return (
-            "p",
-            self.plan_frontier,
-            _pc.staged_digest(self._incoming),
-            want_levels is None or bool(want_levels),
-            True,
-        )
+        + staged content digest (this planner builds one plan shape)."""
+        return ("p", self.plan_frontier, _pc.staged_digest(self._incoming))
 
-    def prepare_step(self, want_levels: bool | None = None) -> StepPlan:
+    def prepare_step(self) -> StepPlan:
         """Consume queued updates and produce the device step plan — the
         cold planning path; advances the plan frontier on success and
         poisons it on any failure (the mirror may be mid-step then, see
         the inner docstring).  Equivalent to ``prepare_step_begin()``
-        followed by ``prepare_step_finish(token, "auto", …)`` — the
+        followed by ``prepare_step_finish(token, "auto")`` — the
         engine uses the split form to co-plan whole chunks of cold docs
         in one segment-planner call (ISSUE 15)."""
         token = self.prepare_step_begin()
-        return self.prepare_step_finish(token, "auto", want_levels)
+        return self.prepare_step_finish(token, "auto")
 
     def prepare_step_begin(self):
         """Phase A of the cold plan: decode, causal scheduling, DS
@@ -1191,8 +1064,7 @@ class DocMirror:
         ctx.sd = sd
         return ctx
 
-    def prepare_step_finish(self, token, seg_plan,
-                            want_levels: bool | None = None) -> StepPlan:
+    def prepare_step_finish(self, token, seg_plan) -> StepPlan:
         """Phase B of the cold plan: integration (bulk fast-set runs +
         the sequential YATA fallback for the conflict residue), delete
         resolution and plan finalization.  ``seg_plan`` is the
@@ -1209,7 +1081,7 @@ class DocMirror:
                 seg_plan = _sp.plan_doc(
                     token.queries, snapshot=self._segment_snapshot
                 )
-            plan = self._prepare_phase_b(token, seg_plan, want_levels)
+            plan = self._prepare_phase_b(token, seg_plan)
         except BaseException:
             self.plan_frontier = _pc.poison_frontier()
             _pc.note_invalidation("plan-error")
@@ -1379,8 +1251,7 @@ class DocMirror:
         ctx.sd = None
         return ctx
 
-    def _prepare_phase_b(self, ctx, seg_plan,
-                         want_levels: bool | None = None) -> StepPlan:
+    def _prepare_phase_b(self, ctx, seg_plan) -> StepPlan:
         """Integration + finalization (phase B of the cold plan).
 
         ``seg_plan`` carries the device-computed answer: verified anchor
@@ -1571,8 +1442,6 @@ class DocMirror:
         plan.segment_residue = seg_residue if seg_plan is not None else 0
         if seg_plan is not None:
             _pc.note_segment(seg_fast, plan.segment_residue)
-        if want_levels is None or want_levels:
-            plan.assign_levels(self._row_client)
         # finalize the bulk-apply deltas: FINAL values after all splices
         plan.link_rows = sorted(plan._dl)
         plan.link_vals = [self.list_next[r] for r in plan.link_rows]
@@ -2306,39 +2175,6 @@ class DocMirror:
             if sub is not None:
                 encoder.write_string(sub)
         self.realized_content(row).write(encoder, offset)
-
-    def origin_rows(self, start: int = 0) -> np.ndarray:
-        """For rows [start:], the row *containing* each origin id (NULL if
-        no origin) — the columnar get_item(store, o.origin) of the case-2
-        conflict check (reference src/structs/Item.js:447-470)."""
-        n = self.n_rows
-        out = np.full(n - start, NULL, np.int32)
-        oslot = np.asarray(self.row_origin_slot[start:], np.int32)
-        oclock = np.asarray(self.row_origin_clock[start:], np.int64)
-        for s in range(len(self.client_of_slot)):
-            mask = oslot == s
-            if not mask.any():
-                continue
-            fc = np.asarray(self.frag_clock[s], np.int64)
-            fr = np.asarray(self.frag_row[s], np.int32)
-            idx = np.searchsorted(fc, oclock[mask], side="right") - 1
-            out[np.nonzero(mask)[0]] = fr[np.clip(idx, 0, len(fr) - 1)]
-        return out
-
-    def static_columns(self, start: int = 0) -> dict[str, np.ndarray]:
-        """The immutable device columns for rows [start:] — host cost scales
-        with the delta when the caller keeps earlier rows resident."""
-        return {
-            "client_key": np.asarray(
-                [self.client_of_slot[s] for s in self.row_slot[start:]],
-                np.uint32,
-            ),
-            "origin_slot": np.asarray(self.row_origin_slot[start:], np.int32),
-            "origin_clock": np.asarray(self.row_origin_clock[start:], np.int32),
-            "right_slot": np.asarray(self.row_right_slot[start:], np.int32),
-            "right_clock": np.asarray(self.row_right_clock[start:], np.int32),
-            "origin_row": self.origin_rows(start),
-        }
 
     def has_pending(self) -> bool:
         return bool(self.pending) or bool(self.pending_ds)

@@ -13,7 +13,6 @@ Provider gating seam.
 
 from __future__ import annotations
 
-import functools
 import os
 import time
 from types import SimpleNamespace
@@ -106,44 +105,6 @@ def _bucket_lanes(n: int, minimum: int = 64) -> int:
         return _bucket(n, minimum)
     e = max(0, (n - 1).bit_length() - 1 - bits)
     return ((n + (1 << e) - 1) >> e) << e
-
-
-# target size of one level-axis schedule tile (entries per doc-batch block);
-# big enough that kernel launch overhead amortizes, small enough that the
-# padded [B, block, W, 8] tile stays modest at any log length
-_BLOCK_BUDGET = 1 << 22
-
-
-def _block_levels(n_docs: int, w_lv: int) -> int:
-    return _bucket(max(1, _BLOCK_BUDGET // max(1, n_docs * w_lv)), 1)
-
-
-# resident immutable device columns, in packed-row order for the one-
-# transfer statics scatter (client_key rides bitcast through the i32 pack)
-_STATIC_COLS = (
-    ("client_key", 0, "uint32"),
-    ("origin_slot", NULL, "int32"),
-    ("origin_clock", 0, "int32"),
-    ("right_slot", NULL, "int32"),
-    ("right_clock", 0, "int32"),
-    ("origin_row", NULL, "int32"),
-)
-
-
-@profiled("scatter_statics")
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_statics(statics, packed):
-    """All six resident-column updates in ONE device dispatch from ONE
-    packed [8, K] i32 transfer (rows: doc idx, row idx, then the six
-    value columns in _STATIC_COLS order)."""
-    d, r = packed[0], packed[1]
-    out = {}
-    for j, (key, _fill, dtype) in enumerate(_STATIC_COLS):
-        v = packed[2 + j]
-        if dtype == "uint32":
-            v = jax.lax.bitcast_convert_type(v, jnp.uint32)
-        out[key] = statics[key].at[d, r].set(v)
-    return out
 
 
 @jax.jit
@@ -355,7 +316,6 @@ class BatchEngine:
         # observe/observeDeep, AbstractType.js:360-389)
         self._event_listeners: dict[int, list] = {}
         self._metrics_dev: dict | None = None
-        self._sharded_step = None
         # cached sharded state-vector callables keyed by n_slots (jit's
         # cache is per function identity — rebuilding retraces every call)
         self._sharded_sv: dict[int, object] = {}
@@ -381,9 +341,6 @@ class BatchEngine:
 
             self._ns_batch = NamedSharding(mesh, PartitionSpec(doc_axis))
             self._ns_repl = NamedSharding(mesh, PartitionSpec())
-            from ..parallel.mesh import sharded_batch_step
-
-            self._sharded_step = sharded_batch_step(mesh, doc_axis)
         self.mirrors: list = [make_mirror(root_name) for _ in range(n_docs)]
         # CPU fallback docs (Provider gating): doc idx -> Doc
         self.fallback: dict[int, Doc] = {}
@@ -415,11 +372,6 @@ class BatchEngine:
         self._right = None
         self._deleted = None
         self._starts = None
-        # resident immutable columns, updated by per-flush row scatters —
-        # steady-state flush transfer scales with the DELTA, not with B*cap
-        self._statics: dict | None = None
-        # rows per doc already uploaded and still valid on device
-        self._uploaded_rows = [0] * n_docs
         # pipelined flush state (ISSUE 12): double-buffered staging pair +
         # in-flight dispatch markers persist ACROSS flushes so steady
         # state neither reallocates nor stalls; per-flush counters reset
@@ -613,7 +565,6 @@ class BatchEngine:
         self.mirrors[doc] = DocMirror(self.root_name)  # dead mirror
         plan_cache.note_invalidation("demote")
         self._update_log[doc] = []
-        self._uploaded_rows[doc] = 0
         if self._update_listeners:
             # emit the demoting flush's novelty, then live-forward the
             # fallback doc's own update events
@@ -752,8 +703,6 @@ class BatchEngine:
 
     # -- device state management -------------------------------------------
 
-    _STATIC_COLS = _STATIC_COLS
-
     def _ensure_capacity(self, n_rows: int, n_segs: int) -> None:
         cap = _bucket(n_rows)
         seg_cap = _bucket(n_segs, 8)
@@ -803,92 +752,6 @@ class BatchEngine:
         self._flush_realloc_bytes += int(
             self._right.nbytes + self._deleted.nbytes + self._starts.nbytes
         )
-        # grow the resident statics device-side (pad, no host round trip).
-        # Allocation is lazy: the bulk-apply path never reads them on
-        # device, so an apply-only engine spends no HBM or transfer on
-        # statics at all (_ensure_statics allocates on first levels/seq
-        # dispatch).
-        if self._statics is not None:
-            old_statics = self._statics
-            self._statics = {}
-            for key, fill, dtype in self._STATIC_COLS:
-                self._statics[key] = jnp.pad(
-                    old_statics[key],
-                    ((0, 0), (0, self._cap - old_cap)),
-                    constant_values=fill,
-                )
-            self._flush_realloc_bytes += int(
-                sum(v.nbytes for v in self._statics.values())
-            )
-
-    def _ensure_statics(self) -> None:
-        if self._statics is not None:
-            return
-        b = self.n_docs
-        self._statics = {
-            key: self._put_b(np.full((b, self._cap + 1), fill, np.dtype(dtype)))
-            for key, fill, dtype in self._STATIC_COLS
-        }
-        self._flush_realloc_bytes += int(
-            sum(v.nbytes for v in self._statics.values())
-        )
-        # everything must (re-)upload into the fresh arrays
-        self._uploaded_rows = [0] * b
-
-    def _upload_statics(self, plans) -> None:
-        """Scatter this flush's statics delta (its own dispatch — the
-        levels/seq paths; the bulk path fuses the delta into
-        kernels.apply_plan2 instead)."""
-        self._ensure_statics()
-        packed = self._statics_delta(plans)
-        if packed is not None:
-            self._dispatch("statics", self._put_r(packed))
-
-    def _statics_delta(self, plans):
-        """This flush's NEW/changed rows as one packed [8, K] i32 block
-        (doc, row, six value columns; client_key bitcast).
-
-        A doc's immutable columns only change by appending rows — except
-        when a pre-split cuts an existing run (origin_row coverage moves to
-        the new fragment) or compaction renumbered the table, which both
-        force a full re-upload of that doc."""
-        doc_idx: list[np.ndarray] = []
-        row_idx: list[np.ndarray] = []
-        vals: dict[str, list[np.ndarray]] = {k: [] for k, _f, _d in self._STATIC_COLS}
-        for i, p in plans.items():
-            m = self.mirrors[i]
-            n = m.n_rows
-            start = 0 if len(p.splits) else self._uploaded_rows[i]
-            if n <= start:
-                continue
-            cols = m.static_columns(start)
-            doc_idx.append(np.full(n - start, i, np.int32))
-            row_idx.append(np.arange(start, n, dtype=np.int32))
-            for k in vals:
-                vals[k].append(cols[k])
-            self._uploaded_rows[i] = n
-        if not doc_idx:
-            return None
-        d = np.concatenate(doc_idx)
-        r = np.concatenate(row_idx)
-        # pad to a power-of-two bucket so the scatter compiles once per
-        # bucket, not once per delta size; padding lanes write the scratch
-        # row (index cap) of doc 0, whose contents are never read.  ONE
-        # packed [8, K] transfer: each transfer pays its own latency.
-        total = len(d)
-        padded = _bucket_lanes(total, 64)
-        packed = np.empty((2 + len(self._STATIC_COLS), padded), np.int32)
-        packed[0, :total] = d
-        packed[0, total:] = 0
-        packed[1, :total] = r
-        packed[1, total:] = self._cap
-        for j, (k, fill, dtype) in enumerate(self._STATIC_COLS):
-            v = np.concatenate(vals[k])
-            if dtype == "uint32":
-                v = v.astype(np.uint32).view(np.int32)
-            packed[2 + j, :total] = v
-            packed[2 + j, total:] = fill
-        return packed
 
     # -- compaction ---------------------------------------------------------
 
@@ -932,7 +795,6 @@ class BatchEngine:
             old_n = m.n_rows
             r, d, h = m.rebuild_compacted_self(gc)
             self._rows_at_compact[i] = len(r)
-            self._uploaded_rows[i] = 0  # renumbered: statics re-upload
             stats.append(
                 {"doc": i, "rows_before": old_n, "rows_after": len(r)}
             )
@@ -1048,7 +910,6 @@ class BatchEngine:
         self._ensure_capacity(max(1, len(r)), max(1, len(h)))
         self._pending_hydration[doc] = (r, d, h)
         self._rows_at_compact[doc] = len(r)
-        self._uploaded_rows[doc] = 0
         if len(r):
             self._active_docs.add(doc)
         return {"rows": len(r), "segs": len(h)}
@@ -1097,7 +958,6 @@ class BatchEngine:
         self.fallback.pop(doc, None)
         self._pending_hydration.pop(doc, None)
         self._update_log[doc] = []
-        self._uploaded_rows[doc] = 0
         self._rows_at_compact[doc] = 0
         self._active_docs.discard(doc)
         self._event_listeners.pop(doc, None)
@@ -1107,8 +967,7 @@ class BatchEngine:
             # the initial allocation, scratch column included): one
             # donated program writes three rows, the tables are not
             # copied.  Dispatched here, not at the next flush: the slot
-            # may be re-let as soon as this returns.  Statics re-upload
-            # from row 0 is already forced by _uploaded_rows above
+            # may be re-let as soon as this returns
             with self._phase_ctx("release.blank"):
                 # np.int32: a traced scalar to jit and to the kernel
                 # profiler alike (a Python int is a new signature a slot)
@@ -1130,7 +989,7 @@ class BatchEngine:
     def _finish_flush(self, metrics: dict) -> None:
         """The single exit point of every flush path: append to the flush
         ring (which serves last_flush_metrics) + update the registry.
-        Pipeline bookkeeping lands here so EVERY exit — bulk, levels/seq,
+        Pipeline bookkeeping lands here so EVERY exit — bulk,
         replay, and the empty flush — emits the full shared schema."""
         pl = self._pl
         metrics["t_pack_overlap_s"] = pl.t_pack_overlap_s
@@ -1163,10 +1022,6 @@ class BatchEngine:
             "deleted": int(self._deleted.nbytes),
             "starts": int(self._starts.nbytes),
         }
-        if self._statics is not None:
-            tables["statics"] = int(
-                sum(v.nbytes for v in self._statics.values())
-            )
         self.obs.device_memory(
             tables,
             next(iter(right.devices())).platform,
@@ -1202,22 +1057,14 @@ class BatchEngine:
         plan_fanout = 1  # docs co-planned by one whole-chunk planner call
         emitting = bool(self._update_listeners)
         observing = self._event_listeners
-        # kernel selection: "apply" (default, meshed or not) ships the
-        # planner's final link values in one conflict-free scatter;
-        # "levels"/"seq" run YATA on device (the sharded levels step
-        # serves YTPU_KERNEL=levels on a mesh)
-        mode = os.environ.get("YTPU_KERNEL")
-        if not mode:
-            mode = "apply"
-        want_levels = mode != "apply"
-        # bulk path + native planner: ONE ymx_prepare_many call plans every
-        # staged doc; levels/seq and the Python mirror keep the doc loop.
-        # Gate on planner availability, not any particular doc's mirror: a
-        # demoted doc 0 must not silently disable the fast path fleet-wide
-        use_batch = (
-            not want_levels
-            and native_plan_available()
-            and any(isinstance(m, NativeMirror) for m in self.mirrors)
+        # ONE device write path: the planner's final link values go up in
+        # one conflict-free scatter (_flush_bulk).  With the native planner
+        # ONE ymx_prepare_many call plans every staged doc; the Python
+        # mirror keeps the doc loop.  Gate on planner availability, not any
+        # particular doc's mirror: a demoted doc 0 must not silently
+        # disable the fast path fleet-wide
+        use_batch = native_plan_available() and any(
+            isinstance(m, NativeMirror) for m in self.mirrors
         )
         work: list = []  # batched path: (doc, mirror)
         with self._phase_ctx("plan"):
@@ -1254,7 +1101,7 @@ class BatchEngine:
                         pre_svs[i] = m.state_vector()
                     key = ent = None
                     if cache is not None:
-                        key = m.plan_key(want_levels)
+                        key = m.plan_key()
                         ent = cache.lookup(key)
                     t_d0 = time.perf_counter()
                     if ent is not None:
@@ -1293,7 +1140,7 @@ class BatchEngine:
                         t_plan_cold += time.perf_counter() - t_d0
                         continue
                     try:
-                        plans[i] = m.prepare_step(want_levels=want_levels)
+                        plans[i] = m.prepare_step()
                     except UnsupportedUpdate as e:
                         self._demote(i, pre_svs.get(i), reason=str(e))
                         demoted_now += 1
@@ -1337,9 +1184,7 @@ class BatchEngine:
                     plan_fanout = max(plan_fanout, co_planned)
                     for (i, m, key, token), sp in zip(chunk_cold, seg_plans):
                         try:
-                            plans[i] = m.prepare_step_finish(
-                                token, sp, want_levels
-                            )
+                            plans[i] = m.prepare_step_finish(token, sp)
                         except UnsupportedUpdate as e:
                             self._demote(i, pre_svs.get(i), reason=str(e))
                             demoted_now += 1
@@ -1366,7 +1211,7 @@ class BatchEngine:
                         continue
                     # leader demoted/failed before inserting: plan solo
                     try:
-                        plans[i] = m.prepare_step(want_levels=want_levels)
+                        plans[i] = m.prepare_step()
                     except UnsupportedUpdate as e:
                         self._demote(i, pre_svs.get(i), reason=str(e))
                         demoted_now += 1
@@ -1383,7 +1228,7 @@ class BatchEngine:
         t_plan = time.perf_counter()
         # ONE schema (obs.FLUSH_METRICS_SCHEMA) for every exit: each path
         # overwrites only the fields it measures, so the key set cannot
-        # drift between the apply/levels/seq/batched/empty-flush paths
+        # drift between the batched, per-doc and empty-flush paths
         metrics = new_flush_metrics(
             n_demoted=demoted_now,
             n_rolled_back=rolled_back,
@@ -1415,139 +1260,11 @@ class BatchEngine:
             metrics["t_total_s"] = time.perf_counter() - t_start
             self._finish_flush(metrics)
             return
-        if use_batch:
-            self._flush_bulk(
-                work, pre_svs, emitting, metrics, t_start,
-                observed=set(observing), native=True,
-            )
-            return
-        if mode == "apply":
-            self._flush_bulk(
-                sorted(plans.items()), pre_svs, emitting, metrics, t_start,
-                native=False,
-            )
-            return
-        with self._phase_ctx("pack"), self._pl.pack():
-            n_splits = _bucket(
-                max((len(p.splits) for p in plans.values()), default=0), 1
-            )
-            n_sched = _bucket(
-                max((len(p.sched) for p in plans.values()), default=0), 1
-            )
-            n_del = _bucket(
-                max((len(p.delete_rows) for p in plans.values()), default=0), 1
-            )
-            n_lv = _bucket(
-                max((p.n_levels for p in plans.values()), default=0), 1
-            )
-            w_lv = _bucket(
-                max((p.max_width for p in plans.values()), default=0), 1
-            )
-            max_rows = max((p.n_rows for p in plans.values()), default=0)
-            max_segs = max(
-                (self.mirrors[i].n_segs for i in plans), default=0
-            )
-            # reserve >= 2*w_lv spare row slots per doc: the level kernel's
-            # merged scatter uses two unique scratch lanes per schedule slot
-            self._ensure_capacity(max_rows + 2 * w_lv, max_segs)
-            b, cap = self.n_docs, self._cap
-
-            splits = np.full((b, n_splits, 2), NULL, np.int32)
-            sched = np.full((b, n_sched, 4), NULL, np.int32)
-            lv_sched = np.full((b, n_lv, w_lv, 8), NULL, np.int32)
-            dels = np.full((b, n_del), NULL, np.int32)
-            for i, p in plans.items():
-                if len(p.splits):
-                    splits[i, : len(p.splits)] = p.splits
-                if len(p.sched):
-                    sched[i, : len(p.sched)] = p.sched
-                if hasattr(p, "pack_into"):
-                    p.pack_into(lv_sched[i])
-                else:
-                    for lv, entries in enumerate(p.packed_levels()):
-                        if entries:
-                            lv_sched[i, lv, : len(entries)] = entries
-                if len(p.delete_rows):
-                    dels[i, : len(p.delete_rows)] = p.delete_rows
-
-            # EVERY doc needs its true row count here — masked scatter lanes
-            # land at scratch_base+lane even for docs with no work this
-            # flush, and must hit the padding region, not live rows
-            scratch_base = np.asarray(
-                [m.n_rows for m in self.mirrors], np.int32
-            )
-
-            self._upload_statics(plans)
-            statics = self._statics
-        t_pack = time.perf_counter()
-        with self._phase_ctx("dispatch"):
-            if mode == "seq":
-                self._metrics_dev = None  # no sharded counters this flush
-                self._dispatch(
-                    "seq", statics, self._put_b(splits),
-                    self._put_b(sched), self._put_b(dels),
-                )
-            else:
-                # blockwise over the level axis (the long-context analogue,
-                # SURVEY.md §5: long update logs are processed as fixed-size
-                # schedule tiles).  Levels are causally ordered and the
-                # device state persists between dispatches, so slicing by
-                # level prefix is exact: splits run only in the first block,
-                # deletes only in the last.  Bounds the padded [B, L, W, 8]
-                # transfer and device buffer no matter how long the log is —
-                # on the single-chip and the sharded (mesh) path alike.
-                block = max(
-                    1,
-                    int(os.environ.get("YTPU_BLOCK_LEVELS", "0"))
-                    or _block_levels(b, w_lv),
-                )
-                empty_splits = empty_dels = None
-                if n_lv > block:  # multi-block: cache the no-op inputs
-                    empty_splits = self._put_b(np.full((b, 1, 2), NULL, np.int32))
-                    empty_dels = self._put_b(np.full((b, 1), NULL, np.int32))
-                scratch_d = self._put_b(scratch_base)
-                self._metrics_dev = None
-                for c0 in range(0, n_lv, block):
-                    c1 = min(n_lv, c0 + block)
-                    self._dispatch(
-                        "levels",
-                        statics,
-                        self._put_b(splits) if c0 == 0 else empty_splits,
-                        self._put_b(lv_sched[:, c0:c1]),
-                        self._put_b(dels) if c1 == n_lv else empty_dels,
-                        scratch_d,
-                    )
-        t_dispatch = time.perf_counter()
-
-        with self._phase_ctx("emit"):
-            self._emit_phase(plans, pre_svs, emitting)
-        t_emit = time.perf_counter()
-
-        n_sched_entries = sum(len(p.sched8) for p in plans.values())
-        lv_slots = b * n_lv * w_lv
-        pending_docs = [i for i in plans if self.mirrors[i].has_pending()]
-        metrics.update({
-            "n_docs_flushed": sum(
-                1
-                for p in plans.values()
-                if len(p.sched8) or len(p.splits) or len(p.delete_rows)
-            ),
-            "n_rows_max": max_rows,
-            "n_sched_entries": n_sched_entries,
-            "n_levels": n_lv,
-            "level_width": w_lv,
-            # fraction of the padded [B, L, W] schedule that is real work
-            "schedule_occupancy": n_sched_entries / lv_slots if lv_slots else 0.0,
-            "n_pending_docs": len(pending_docs),
-            "pending_depth": sum(
-                self.mirrors[i].pending_depth() for i in pending_docs
-            ),
-            "t_pack_s": t_pack - t_plan,
-            "t_dispatch_s": t_dispatch - t_pack,
-            "t_emit_s": t_emit - t_dispatch,
-            "t_total_s": t_emit - t_start,
-        })
-        self._finish_flush(metrics)
+        self._flush_bulk(
+            work if use_batch else sorted(plans.items()),
+            pre_svs, emitting, metrics, t_start,
+            observed=set(observing), native=use_batch,
+        )
 
     def _emit_phase(self, plans, pre_svs, emitting, observed=None) -> None:
         """Post-dispatch host work shared by both dispatch paths: update-log
@@ -1588,19 +1305,14 @@ class BatchEngine:
     def _dispatch(self, kind, *args, slot=None):
         """THE one flush dispatch path (ISSUE 12): every device mutation of
         the resident tables — bulk lanes (per-doc python plans, native
-        batched plans, and cached-plan replay alike), the levels/seq YATA
-        step, the statics delta scatter, and whole-row rebuild scatters
-        (compaction, deferred hydration) — funnels through here, so the
-        pipeline bookkeeping (in-flight markers, staging-buffer fences,
-        sync A/B mode) and any future kernel change land exactly once.
+        batched plans, and cached-plan replay alike) and whole-row rebuild
+        scatters (compaction, deferred hydration) — funnels through here,
+        so the pipeline bookkeeping (in-flight markers, staging-buffer
+        fences, sync A/B mode) and any future kernel change land exactly
+        once.
 
         kinds:
           "lanes"   (lanes, key)                    bulk-apply scatter
-          "seq"     (statics, splits, sched, dels)  sequential YATA step
-          "levels"  (statics, splits, lv_block, dels, scratch)  one
-                    level-axis block (sharded or not; device metrics
-                    accumulate across blocks)
-          "statics" (packed,)                       resident-column delta
           "rows"    (idx, right, deleted, starts)   whole-row rebuild
 
         ``slot`` ties the dispatch to the staging buffer it consumes (the
@@ -1625,30 +1337,6 @@ class BatchEngine:
                 dyn = kernels.apply_plan2(
                     dyn, self._put_r(lanes[0]), k_dn, k_sp, k_h, k_d
                 )
-        elif kind == "seq":
-            statics, splits, sched, dels = args
-            dyn = kernels.batch_step(statics, dyn, splits, sched, dels)
-        elif kind == "levels":
-            statics, splits, lv_block, dels, scratch = args
-            largs = (statics, dyn, splits, lv_block, dels, scratch)
-            if self._sharded_step is not None:
-                # metrics stay device scalars (converting would block the
-                # async dispatch); accumulate across blocks
-                dyn, m = self._sharded_step(*largs)
-                self._metrics_dev = (
-                    m
-                    if self._metrics_dev is None
-                    else {k: self._metrics_dev[k] + m[k] for k in m}
-                )
-            else:
-                dyn = kernels.batch_step_levels(*largs)
-        elif kind == "statics":
-            (packed,) = args
-            self._statics = _scatter_statics(self._statics, packed)
-            self._pl.dispatched(
-                _done_token(self._statics["origin_row"]), slot
-            )
-            return
         elif kind == "rows":
             idx, new_right, new_deleted, new_starts = args
             dyn = kernels.scatter_rows(
@@ -1731,10 +1419,6 @@ class BatchEngine:
                 # shards, so the denominator must too or meshed runs report
                 # occupancy inflated by n_shards (ADVICE r4)
                 lanes_padded_tot += n_shards * sum(key)
-                # the apply path never reads the device statics; mark touched
-                # docs for full (re-)upload if a levels/seq flush ever runs
-                for t in chunk_ok:
-                    self._uploaded_rows[t[0]] = 0
                 work_ok.extend(chunk_ok)
             t2 = time.perf_counter()
             t_pack_acc += t2 - t1
@@ -1845,7 +1529,7 @@ class BatchEngine:
         groups: dict = {}  # key -> trailing same-key members
         if cache is not None:
             for i, m in chunk:
-                key = m.plan_key(False, want_sched)
+                key = m.plan_key(want_sched)
                 g = groups.get(key)
                 if g is not None:
                     # intra-chunk duplicate (broadcast fan-out):
@@ -1874,7 +1558,6 @@ class BatchEngine:
             )
             counts_all, rcs, staged_info = prepare_many(
                 [(i, m) for i, m, _k in cold],
-                want_levels=False,
                 want_sched=want_sched,
                 obs=self.obs,
             )
@@ -1934,8 +1617,7 @@ class BatchEngine:
                 acc.plan_threads, min(acc.cfg_threads, len(retry))
             )
             counts2, rcs2, staged2 = prepare_many(
-                retry, want_levels=False, want_sched=want_sched,
-                obs=self.obs,
+                retry, want_sched=want_sched, obs=self.obs,
             )
             for k, (i, m) in enumerate(retry):
                 try:

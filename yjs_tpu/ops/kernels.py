@@ -1,22 +1,18 @@
 """JAX device kernels for the batched CRDT engine.
 
 The reference integrates one Item at a time into a pointer-chased linked list
-(reference src/structs/Item.js:403-517).  Here the same YATA semantics run as
-a ``lax.scan`` over a *static* item table (the host pre-split pass guarantees
-no splits are needed mid-kernel), vmapped over the document batch: each
-sequential scan step integrates one item in every document of the batch, so
-the TPU's parallelism is over docs while the per-doc causal chain stays
-sequential — the parallelism split called out in SURVEY.md §7 ("concurrency
-across docs (vmap)").
+(reference src/structs/Item.js:403-517).  Here YATA runs on the host: the
+planner (``ops/native_mirror.py``, or ``ops/columns.py`` without a compiler)
+resolves every conflict and hands the device the FINAL link values, so a
+flush is one conflict-free scatter over the whole doc batch
+(``apply_plan2``; ``parallel.mesh.sharded_apply_plan`` on a mesh), and a
+compaction, a hydration or a release is one whole-row write
+(``scatter_rows``, ``blank_rows``).  The read side ranks document order from
+the right links (``list_ranks``) and answers state vectors and diffs as
+segment reductions.
 
-Set semantics without sets: the reference's ``itemsBeforeOrigin`` /
-``conflictingItems`` (Item.js:447-470) only ever grow between clears, so they
-are modelled with a per-row visit counter: a row is in ``itemsBeforeOrigin``
-iff ``visit[row] >= scan_base`` and in ``conflictingItems`` iff
-``visit[row] >= clear_mark``.  No O(N) clears, O(1) membership.
-
-All row arrays carry one extra trailing scratch row (index N) that absorbs
-masked scatter writes; its contents are never read meaningfully.
+All row arrays carry one extra trailing scratch row (index N); its contents
+are never read meaningfully.
 """
 
 from __future__ import annotations
@@ -30,342 +26,12 @@ import numpy as np
 from jax import lax
 
 from ..obs.prof import profiled
-from .columns import GATHER_SUCC
 
 NULL = -1
 
 
-def _upd(arr, idx, val, cond, dummy):
-    """Masked scatter: write ``val`` at ``idx`` when ``cond`` else write the
-    scratch row."""
-    safe_idx = jnp.where(cond, idx, dummy)
-    return arr.at[safe_idx].set(jnp.where(cond, val, arr[dummy]))
-
-
-def _ids_eq(s1, k1, s2, k2):
-    """compare_ids on (slot, clock) columns; NULL slot == null id."""
-    return (s1 == s2) & ((s1 == NULL) | (k1 == k2))
-
-
 # ---------------------------------------------------------------------------
-# per-doc step kernel (vmapped over the batch by `batch_step`)
-# ---------------------------------------------------------------------------
-
-
-def _doc_step(statics, dyn, splits, sched, delete_rows):
-    """Run one integration step for a single doc.
-
-    statics: dict of [N+1] columns (client_key u32, origin_slot/clock,
-        right_slot/clock, origin_row  i32)
-    dyn: (right_link[N+1], deleted[N+1], starts[S+1]) — starts holds each
-        segment's list head (root lists and per-map-key chains alike); no
-        left-link array: the head test is starts[seg]==row and document
-        order is ranked from right links alone
-    splits: [S, 2] i32 (orig_row, new_row), NULL-padded, right-to-left per
-        original row
-    sched: [M, 4] i32 (row, left_row, right_row, seg), NULL-padded, causal
-        order
-    delete_rows: [D] i32, NULL-padded
-    """
-    right_link, deleted, starts = dyn
-    n1 = right_link.shape[0]
-    dummy = n1 - 1
-
-    # -- split pre-pass: link surgery for host-computed run splits ----------
-    # (the device half of splitItem, reference src/structs/Item.js:84-120)
-    def split_body(carry, instr):
-        rl, dl = carry
-        orig, new = instr[0], instr[1]
-        valid = orig >= 0
-        safe_orig = jnp.where(valid, orig, dummy)
-        old_right = rl[safe_orig]
-        rl = _upd(rl, new, old_right, valid, dummy)
-        rl = _upd(rl, orig, new, valid, dummy)
-        dl = _upd(dl, new, dl[safe_orig], valid, dummy)
-        return (rl, dl), None
-
-    (right_link, deleted), _ = lax.scan(
-        split_body, (right_link, deleted), splits
-    )
-
-    # -- integration scan: one item per sequential step ---------------------
-    integrate_item = _make_integrate_item(statics, dummy)
-
-    def integ_body(carry, s):
-        carry = integrate_item(carry, s[0], s[1], s[2], s[3])
-        return carry, None
-
-    (right_link, starts), _ = lax.scan(
-        integ_body, (right_link, starts), sched
-    )
-
-    deleted = _apply_deletes(deleted, delete_rows, dummy)
-    return right_link, deleted, starts
-
-
-def _make_integrate_item(statics, dummy):
-    """The single-item YATA integrate (conflict scan + splice) as a carry
-    transformer — shared by the sequential path and the level path's
-    deferred (true-conflict) loop."""
-    client_key = statics["client_key"]
-    oslot = statics["origin_slot"]
-    oclock = statics["origin_clock"]
-    rslot = statics["right_slot"]
-    rclock = statics["right_clock"]
-    origin_row = statics["origin_row"]
-
-    def integrate_item(carry, k, left0, right0, seg):
-        rl, starts = carry
-        n1 = rl.shape[0]
-        s_dummy = starts.shape[0] - 1
-        safe_seg = jnp.where(seg >= 0, seg, s_dummy)
-        st = starts[safe_seg]  # this segment's list head
-        # per-scan conflict sets: fresh visit marks, so no cross-scan counter
-        visit = jnp.full((n1,), -1, jnp.int32)
-        counter = jnp.int32(0)
-        valid = k >= 0
-        safe_k = jnp.where(valid, k, dummy)
-        safe_l = jnp.where(left0 >= 0, left0, dummy)
-
-        # fast path, the negation of reference Item.js:432-434: skip the
-        # conflict scan when left is null and right is the current list head
-        # (st == right0), or when left.right is still exactly right
-        skip = jnp.where(
-            left0 == NULL,
-            (right0 != NULL) & (st == right0),
-            rl[safe_l] == right0,
-        )
-
-        scan_base = counter
-        o0 = jnp.where(
-            valid & ~skip,
-            jnp.where(left0 == NULL, st, rl[safe_l]),
-            NULL,
-        )
-
-        def cond_fn(cs):
-            o, _left, _clear, _cnt, _visit, done = cs
-            return (~done) & (o != NULL) & (o != right0)
-
-        def body_fn(cs):
-            o, left, clear, cnt, visit, done = cs
-            visit = visit.at[o].set(cnt)
-            cnt = cnt + 1
-            # case 1: same origin -> lower client id goes left
-            same_origin = _ids_eq(oslot[safe_k], oclock[safe_k], oslot[o], oclock[o])
-            c1_left = same_origin & (client_key[o] < client_key[safe_k])
-            c1_break = same_origin & ~c1_left & _ids_eq(
-                rslot[safe_k], rclock[safe_k], rslot[o], rclock[o]
-            )
-            # case 2: o's origin lies between this.origin and this
-            orow = origin_row[o]
-            has_origin = oslot[o] != NULL
-            safe_orow = jnp.where(has_origin, orow, dummy)
-            in_before = has_origin & (visit[safe_orow] >= scan_base)
-            c2 = ~same_origin & in_before
-            c2_left = c2 & ~(visit[safe_orow] >= clear)
-            # case 3: unrelated item -> done
-            c3_break = ~same_origin & ~in_before
-            take_left = c1_left | c2_left
-            left = jnp.where(take_left, o, left)
-            clear = jnp.where(take_left, cnt, clear)
-            done = c1_break | c3_break
-            o = jnp.where(done, o, rl[o])
-            return (o, left, clear, cnt, visit, done)
-
-        o, left, _clear, counter, visit, _done = lax.while_loop(
-            cond_fn,
-            body_fn,
-            (
-                o0.astype(jnp.int32),
-                left0.astype(jnp.int32),
-                scan_base.astype(jnp.int32),
-                counter.astype(jnp.int32),
-                visit,
-                jnp.bool_(False),
-            ),
-        )
-
-        # splice into the list (reference Item.js:473-489)
-        safe_left = jnp.where(left >= 0, left, dummy)
-        right2 = jnp.where(left == NULL, st, rl[safe_left])
-        rl = _upd(rl, left, k, valid & (left != NULL), dummy)
-        starts = _upd(starts, safe_seg, k, valid & (left == NULL), s_dummy)
-        rl = _upd(rl, k, right2, valid, dummy)
-        return (rl, starts)
-
-    return integrate_item
-
-
-def _apply_deletes(deleted, delete_rows, dummy):
-    # (reference DeleteSet.js readAndApplyDeleteSet tail)
-    valid_d = delete_rows >= 0
-    deleted = deleted.at[jnp.where(valid_d, delete_rows, dummy)].set(
-        jnp.where(valid_d, True, deleted[dummy])
-    )
-    return deleted
-
-
-def _doc_step_levels(statics, dyn, splits, lv_sched, delete_rows, scratch_base):
-    """Level-parallel integration for a single doc.
-
-    ``scratch_base`` is this doc's row count: rows beyond it are unused
-    padding, used as per-lane scratch so masked bulk scatters have UNIQUE
-    indices (duplicate scatter indices serialize on TPU).  The engine
-    guarantees >= W spare slots and masks phantom rows at export.
-
-    ``lv_sched`` is the 8-field schedule packed level-major, [L, W, 8]
-    NULL-padded rows of (row, left, right, check, succ, seg, fb_left,
-    fb_right); items in one
-    dependency level (host-assigned, see StepPlan.assign_levels) have
-    distinct splice gaps and already-placed deps, so every fast-path item
-    in a level splices in ONE vectorized pass; items sharing a gap are
-    pre-chained by the host (ascending client = YATA case-1 order,
-    reference Item.js:447-455) via the ``succ`` field, and only true
-    conflicts (stale pointers — concurrent edits at one position) fall
-    back to the sequential YATA scan.  Collapses the per-item lax.scan of
-    `_doc_step` (~#items steps) into ~#levels steps of width ~W.
-    """
-    right_link, deleted, starts = dyn
-    n1 = right_link.shape[0]
-    dummy = n1 - 1
-    s_dummy = starts.shape[0] - 1
-
-    # split pre-pass (identical to _doc_step)
-    def split_body(carry, instr):
-        rl, dl = carry
-        orig, new = instr[0], instr[1]
-        valid = orig >= 0
-        safe_orig = jnp.where(valid, orig, dummy)
-        old_right = rl[safe_orig]
-        rl = _upd(rl, new, old_right, valid, dummy)
-        rl = _upd(rl, orig, new, valid, dummy)
-        dl = _upd(dl, new, dl[safe_orig], valid, dummy)
-        return (rl, dl), None
-
-    (right_link, deleted), _ = lax.scan(
-        split_body, (right_link, deleted), splits
-    )
-
-    integrate_item = _make_integrate_item(statics, dummy)
-
-    def level_body(carry, lv):
-        rl, starts = carry
-        k = lv[:, 0]
-        l0 = lv[:, 1]  # left write target; NULL = head, NO_LEFT_WRITE = chained
-        r0 = lv[:, 2]
-        chk = lv[:, 3]  # shared gap left (NULL = head gap)
-        succ = lv[:, 4]  # next chain member, or GATHER_SUCC = old gap successor
-        seg = lv[:, 5]  # segment (root list / map-key chain) of the row
-        fb_l = lv[:, 6]  # the row's ORIGINAL YATA gap, for the deferred
-        fb_r = lv[:, 7]  # fallback (differs from chk/r0 on stitched chains)
-        w = k.shape[0]
-        mask = k >= 0
-        safe_chk = jnp.where(chk >= 0, chk, dummy)
-        safe_seg = jnp.where(seg >= 0, seg, s_dummy)
-        st = starts[safe_seg]  # per-lane segment head
-
-        # vectorized fast-path check across the level: the splice gap is
-        # intact iff the gap-left's successor is still exactly `right`
-        # (head gap: starts[seg] == r0 — covers the empty-segment r0==NULL
-        # case too).  All members of one chain share (chk, r0), so a chain
-        # is fast or deferred as a whole.
-        fast = mask & jnp.where(chk == NULL, st == r0, rl[safe_chk] == r0)
-
-        # bulk splice of all fast items (gaps are distinct by construction):
-        # ONE scatter for both writes (rl[l0]=k for chain heads and
-        # rl[k]=succ for every member; GATHER_SUCC resolves to r0 because
-        # fast means rl[chk]==r0).  masked lanes write to unique scratch
-        # slots — duplicate indices would serialize the scatter on TPU
-        lanes = scratch_base + jnp.arange(2 * w, dtype=jnp.int32)
-        succ_v = jnp.where(succ == GATHER_SUCC, r0, succ)
-        cond1 = fast & (l0 >= 0)
-        idx = jnp.concatenate([
-            jnp.where(cond1, l0, lanes[:w]),
-            jnp.where(fast, k, lanes[w:]),
-        ])
-        val = jnp.concatenate([
-            jnp.where(cond1, k, NULL),
-            jnp.where(fast, succ_v, NULL),
-        ])
-        rl = rl.at[idx].set(val, unique_indices=True)
-        # head writes: one segment head at most per (level, seg) by
-        # construction; masked lanes pile onto the scratch cell (junk)
-        starts = _upd(starts, seg, k, fast & (l0 == NULL), s_dummy)
-
-        # deferred: true conflicts run the sequential YATA scan one by one
-        # with the original YATA inputs (row, gap-left, right, seg); chain
-        # members are processed in ascending-client order (their index
-        # order), which the conflict scan keeps correct
-        pending = mask & ~fast
-
-        def defer_cond(cs):
-            pending, _carry = cs
-            return jnp.any(pending)
-
-        def defer_body(cs):
-            pending, carry = cs
-            j = jnp.argmax(pending)
-            carry = integrate_item(carry, k[j], fb_l[j], fb_r[j], seg[j])
-            return pending.at[j].set(False), carry
-
-        _, (rl, starts) = lax.while_loop(
-            defer_cond, defer_body, (pending, (rl, starts))
-        )
-        return (rl, starts), None
-
-    (right_link, starts), _ = lax.scan(
-        level_body,
-        (right_link, starts),
-        lv_sched,
-    )
-
-    deleted = _apply_deletes(deleted, delete_rows, dummy)
-    return right_link, deleted, starts
-
-
-@profiled("batch_step")
-@functools.partial(jax.jit, donate_argnums=(1,))
-def batch_step(statics, dyn, splits, sched, delete_rows):
-    """vmapped per-item integration step over the doc batch.
-
-    All arguments are dicts/tuples of arrays with a leading doc axis [B, ...].
-    """
-    return jax.vmap(_doc_step)(statics, dyn, splits, sched, delete_rows)
-
-
-@profiled("batch_step_levels")
-@functools.partial(jax.jit, donate_argnums=(1,))
-def batch_step_levels(statics, dyn, splits, lv_sched, delete_rows, scratch_base):
-    """vmapped level-parallel integration step (the default engine path).
-
-    lv_sched: [B, L, W, 8] level-major sched8 schedule, NULL-padded.
-    scratch_base: [B] i32 per-doc row count (see _doc_step_levels).
-    """
-    return jax.vmap(_doc_step_levels)(
-        statics, dyn, splits, lv_sched, delete_rows, scratch_base
-    )
-
-
-@profiled("batch_step_levels_shared")
-@functools.partial(jax.jit, donate_argnums=(1,))
-def batch_step_levels_shared(
-    statics, dyn, splits, lv_sched, delete_rows, scratch_base
-):
-    """Level-parallel step where ALL docs share one schedule + static table
-    (the broadcast-replay shape: one update fanned out to a whole batch).
-
-    statics/splits/lv_sched/delete_rows carry NO doc axis; vmap in_axes=None
-    lets XLA fuse the implicit broadcast, so HBM and the host->device link
-    hold ONE copy of the static columns instead of B.
-    """
-    return jax.vmap(
-        _doc_step_levels, in_axes=(None, 0, None, None, None, 0)
-    )(statics, dyn, splits, lv_sched, delete_rows, scratch_base)
-
-
-# ---------------------------------------------------------------------------
-# bulk apply: host-resolved final links in one scatter (the default path)
+# bulk apply: host-resolved final links in one scatter (the device write path)
 # ---------------------------------------------------------------------------
 
 

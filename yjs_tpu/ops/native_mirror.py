@@ -1,6 +1,6 @@
 """NativeMirror: DocMirror's interface served by the C++ plan core.
 
-The flush hot path (ingest -> prepare_step -> static_columns) runs entirely
+The flush hot path (ingest -> prepare_step -> lane pack) runs entirely
 inside yjs_tpu/native/plancore.cpp — the per-item Python interpreter cost
 that dominated the distinct-doc benchmark drops to one ctypes call per flush.  Everything *outside* the hot
 path — exports, wire encodes, event payloads — is served by lazily syncing
@@ -53,7 +53,6 @@ from . import plan_cache as _pc
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _i64p = ctypes.POINTER(ctypes.c_int64)
-_u32p = ctypes.POINTER(ctypes.c_uint32)
 
 
 def native_plan_available() -> bool:
@@ -94,15 +93,13 @@ def _p32(a: np.ndarray):
 class NativePlan:
     """Array-backed step plan (the C++ twin of :class:`StepPlan`).
 
-    ``splits``/``sched``/``sched8``/``delete_rows`` are numpy arrays (use
-    ``len()``, not truthiness); ``applied_ds`` is a plain list of tuples
-    for the encode path.  ``pack_into`` fills an engine-allocated
-    ``[L, W, 8]`` int32 block level-major (vectorized, no per-entry
-    Python)."""
+    ``splits``/``sched``/``delete_rows`` are numpy arrays (use ``len()``,
+    not truthiness); ``applied_ds`` is a plain list of tuples for the
+    encode path."""
 
     def __init__(self, lib, h, counts, mirror):
-        (self.n_rows, n_splits, n_sched, self._n_s8, self.n_levels,
-         self.max_width, n_del, self._n_ads) = (int(x) for x in counts[:8])
+        self.n_rows, n_splits, n_sched = (int(x) for x in counts[:3])
+        n_del, self._n_ads = int(counts[6]), int(counts[7])
         n_links, n_heads = int(counts[12]), int(counts[13])
         # full counts row retained for the plan cache (insert after a
         # cold per-doc prepare needs it)
@@ -128,7 +125,7 @@ class NativePlan:
             lib.ymx_plan_links(h, _p64(self.link_rows), _p64(self.link_vals))
         if n_heads:
             lib.ymx_plan_heads(h, _p64(self.head_segs), _p64(self.head_vals))
-        self._sched = self._sched8 = self._levels = self._applied = None
+        self._sched = self._applied = None
 
     def _fresh(self):
         if self._seq != self._mirror._plan_seq:
@@ -146,23 +143,6 @@ class NativePlan:
         return self._sched
 
     @property
-    def sched8(self):
-        if self._sched8 is None:
-            self._fresh()
-            self._sched8 = np.empty((self._n_s8, 8), np.int64)
-            self._levels = np.empty(self._n_s8, np.int64)
-            if self._n_s8:
-                self._lib.ymx_plan_sched8(
-                    self._h, _p64(self._sched8), _p64(self._levels)
-                )
-        return self._sched8
-
-    @property
-    def levels(self):
-        self.sched8
-        return self._levels
-
-    @property
     def applied_ds(self):
         if self._applied is None:
             self._fresh()
@@ -171,22 +151,6 @@ class NativePlan:
                 self._lib.ymx_plan_applied_ds(self._h, _p64(ads))
             self._applied = [tuple(row) for row in ads.tolist()]
         return self._applied
-
-    def pack_into(self, block: np.ndarray) -> None:
-        if not len(self.sched8):
-            return
-        lv = self.levels - 1
-        idx = np.argsort(lv, kind="stable")
-        sorted_lv = lv[idx]
-        starts = np.searchsorted(sorted_lv, np.arange(block.shape[0]))
-        pos = np.arange(len(idx)) - starts[sorted_lv]
-        block[sorted_lv, pos] = self.sched8[idx].astype(block.dtype)
-
-    def packed_levels(self):
-        out: list[list[tuple[int, ...]]] = [[] for _ in range(self.n_levels)]
-        for entry, lev in zip(self.sched8.tolist(), self.levels.tolist()):
-            out[lev - 1].append(tuple(entry))
-        return out
 
 
 def _empty_v2_update() -> bytes:
@@ -264,15 +228,14 @@ class NativeMirror:
             v2s[j] = 1 if v2 else 0
         return staged, ids, v2s
 
-    def plan_key(self, want_levels: bool, want_sched: bool = True):
+    def plan_key(self, want_sched: bool = True):
         """Plan-cache key for the staged work: kind + frontier + staged
-        content digest + plan-shape flags (the flags change the cloned
+        content digest + plan-shape flag (the flag changes the cloned
         ``plan`` member, not the integrated state)."""
         return (
             "n",
             self.plan_frontier,
             _pc.staged_digest(self._incoming),
-            bool(want_levels),
             bool(want_sched),
         )
 
@@ -338,18 +301,13 @@ class NativeMirror:
         """Wrap the core's current plan (valid until the next prepare)."""
         return NativePlan(self._lib, self._h, counts, self)
 
-    def prepare_step(self, want_levels: bool | None = None) -> NativePlan:
-        # default matches DocMirror: compute the full plan (level schedule
-        # included); the engine passes want_levels=False on the bulk path
-        if want_levels is None:
-            want_levels = True
+    def prepare_step(self) -> NativePlan:
         lib, h = self._lib, self._h
         _sync_plan_segment(lib)
         staged, ids, v2s = self._stage_bufs()
         counts = np.zeros(16, np.int64)
         rc = lib.ymx_prepare(
-            h, _p64(ids), _p64(v2s), len(staged), 1 if want_levels else 0,
-            _p64(counts),
+            h, _p64(ids), _p64(v2s), len(staged), _p64(counts)
         )
         self._finish_prepare(rc, staged, ids, counts)
         return NativePlan(lib, h, counts, self)
@@ -447,21 +405,6 @@ class NativeMirror:
                 for clock, ln in DocMirror._union_ranges(ranges)
             ]
         return ds
-
-    def static_columns(self, start: int = 0) -> dict[str, np.ndarray]:
-        lib, h = self._lib, self._h
-        n = self.n_rows - start
-        client_key = np.empty(n, np.uint32)
-        cols = {k: np.empty(n, np.int32) for k in
-                ("origin_slot", "origin_clock", "right_slot", "right_clock",
-                 "origin_row")}
-        lib.ymx_static_cols(
-            h, start, client_key.ctypes.data_as(_u32p),
-            _p32(cols["origin_slot"]), _p32(cols["origin_clock"]),
-            _p32(cols["right_slot"]), _p32(cols["right_clock"]),
-            _p32(cols["origin_row"]),
-        )
-        return {"client_key": client_key, **cols}
 
     # -- compaction ---------------------------------------------------------
 
@@ -773,8 +716,7 @@ class NativeMirror:
         return getattr(self.__dict__["_py"], name)
 
 
-def prepare_many(work, want_levels: bool = False, want_sched: bool = True,
-                 obs=None):
+def prepare_many(work, want_sched: bool = True, obs=None):
     """Batched ymx_prepare over many NativeMirrors in ONE native call.
 
     ``work`` is a list of ``(doc_idx, NativeMirror)``.  Returns
@@ -851,8 +793,7 @@ def prepare_many(work, want_levels: bool = False, want_sched: bool = True,
     ):
         lib.ymx_prepare_many(
             handles, n, _p64(buf_ofs), _p64(ids_flat), _p64(v2_flat),
-            1 if want_levels else 0, 1 if want_sched else 0, _p64(counts),
-            _p64(rcs),
+            1 if want_sched else 0, _p64(counts), _p64(rcs),
         )
     dt = time.perf_counter() - t0
     if obs is not None:

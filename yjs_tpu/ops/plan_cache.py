@@ -13,8 +13,8 @@ bit-identical post-plan state.  This module keys that fact:
   Nondeterministic events (rollback restore, plan errors that may leave
   the core mid-step) *poison* the frontier with a random nonce, so a
   stale mirror can never alias a cached entry;
-- the cache maps ``(kind, frontier, staged_digest, want_levels,
-  want_sched)`` to a snapshot of the post-prepare mirror state.  A hit
+- the cache maps ``(kind, frontier, staged_digest, want_sched)`` to a
+  snapshot of the post-prepare mirror state.  A hit
   replays the snapshot onto the probing doc (native: one
   ``ymx_clone_state`` deep copy; Python: a ``copy.deepcopy``) instead of
   re-planning — the resolved left/right-origin anchors, splice lists,

@@ -3,6 +3,5 @@
 from .mesh import (  # noqa: F401
     doc_mesh,
     shard_meshes,
-    sharded_batch_step,
     sharded_state_vectors,
 )

@@ -100,39 +100,6 @@ def shard_meshes(
     ]
 
 
-def sharded_batch_step(mesh: Mesh, axis: str = "docs"):
-    """The engine step sharded over the doc axis.
-
-    Returns a jitted fn with the signature of
-    :func:`yjs_tpu.ops.kernels.batch_step_levels` plus a replicated metrics
-    dict (psum over ICI) so every host sees global progress counters.
-    """
-    spec = P(axis)
-
-    def local_step(statics, dyn, splits, lv_sched, delete_rows, scratch_base):
-        out = jax.vmap(kernels._doc_step_levels)(
-            statics, dyn, splits, lv_sched, delete_rows, scratch_base
-        )
-        integrated = jnp.sum(lv_sched[..., 0] >= 0)
-        deleted = jnp.sum(delete_rows >= 0)
-        metrics = {
-            "integrated": lax.psum(integrated, axis),
-            "deleted": lax.psum(deleted, axis),
-        }
-        return out, metrics
-
-    sharded = shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(spec, spec, spec, spec, spec, spec),
-        out_specs=((spec, spec, spec), P()),
-    )
-    # donate the persistent dyn buffers like kernels.batch_step does
-    return profiled("sharded_batch_step")(
-        jax.jit(sharded, donate_argnums=(1,))
-    )
-
-
 def sharded_apply_plan(mesh: Mesh, axis: str, k_dn: int, k_sp: int,
                        k_h: int, k_d: int):
     """The bulk-apply flush sharded over the doc axis: each shard scatters
