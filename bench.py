@@ -523,8 +523,9 @@ def bench_planner(
     """detail.planner → BENCH_planner.json: plan-cache effectiveness
     (ISSUE 9).  Cold pass: ``YTPU_PLAN_CACHE=0``, ``reps`` fresh engines
     each plan the prepend-fragmented fixture from scratch.  Cached pass:
-    cache enabled and pre-warmed by one throwaway engine, so the same
-    ``reps`` engines serve every doc from the frontier-keyed cache.
+    cache enabled and pre-warmed by two throwaway engines (a key is
+    snapshotted at its second sighting), so the same ``reps`` engines
+    serve every doc from the frontier-keyed cache.
     Reports cold-vs-cached per-doc plan ms (p50/p99 across flushes), the
     cached-pass hit rate, and the Python planner's segment fast-path
     fraction on an interleaved trace."""
@@ -552,7 +553,8 @@ def bench_planner(
         cold = [one_flush() for _ in range(reps)]
         os.environ["YTPU_PLAN_CACHE"] = "1"
         plan_cache.reset_cache()
-        one_flush()  # populate the cache
+        one_flush()  # the key's first sighting: noted, no snapshot
+        one_flush()  # its second: populates the cache
         cached = [one_flush() for _ in range(reps)]
     finally:
         plan_cache.reset_cache()
@@ -654,7 +656,8 @@ def bench_planner_cold_unique(n_docs: int = 1024, n_ops: int = 1500) -> dict:
         os.environ["YTPU_PLAN_CACHE"] = "1" if cache_on else "0"
         try:
             plan_cache.reset_cache()
-            if prewarm:
+            # two passes: a key is snapshotted at its second sighting
+            for _ in range(2 if prewarm else 0):
                 w = BatchEngine(n_docs)
                 for i, u in enumerate(updates):
                     w.queue_update(i, u)

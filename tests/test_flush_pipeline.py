@@ -227,9 +227,13 @@ def test_cached_plan_adopted_after_donation_no_alias(native, monkeypatch):
     monkeypatch.setenv("YTPU_FLUSH_PIPELINE", "1")
     updates = make_trace("prepend", seed=6, n_ops=40)
     extra = make_trace("interleaved", seed=7, n_ops=40)
-    # leader populates the cache; every one of its dispatches donated
-    # the tables the cached plans were built against
+    # the cache snapshots a key at its second sighting: the first pass
+    # only notes the keys, the leader's pass populates the cache; every
+    # one of its dispatches donated the tables the cached plans were
+    # built against
+    run_engine(updates, 2, True, monkeypatch)
     s1, t1, _d, _k, leader = run_engine(updates, 2, True, monkeypatch)
+    assert len(plan_cache.get_cache()) > 0
     # leader keeps flushing OTHER traffic: the donated buffers are
     # freed and their memory recycled before the follower replays
     for j, u in enumerate(extra):
@@ -237,14 +241,28 @@ def test_cached_plan_adopted_after_donation_no_alias(native, monkeypatch):
         if (j + 1) % 5 == 0:
             leader.flush()
     leader.flush()
-    # follower replays the original trace purely from cache hits
+    # follower replays the original trace purely from cached entries:
+    # count the entries ``lookup`` itself hands out, since a same-key
+    # member cloned from its live in-chunk leader is a hit too
+    served = []
+    real_lookup = plan_cache.PlanCache.lookup
+
+    def spy(self, key):
+        ent = real_lookup(self, key)
+        if ent is not None:
+            served.append(key)
+        return ent
+
+    monkeypatch.setattr(plan_cache.PlanCache, "lookup", spy)
     s2, t2, _d2, _k2, follower = run_engine(updates, 2, True, monkeypatch)
     assert s2 == s1
     assert t2 == t1
     assert s2[0] == oracle_state(updates)
     m = follower.last_flush_metrics
-    if native:
-        assert m["plan_cache_hits"] > 0
+    assert m["plan_cache_hits"] == 2
+    assert m["plan_cache_misses"] == 0
+    # every flush of the follower replayed both docs through ``lookup``
+    assert len(served) == 2 * -(-len(updates) // 5)
 
 
 # -- the 20-seed pipeline on/off corpus (satellite 3) -------------------------

@@ -90,7 +90,7 @@ def run_engine(updates, n_docs, cache_on, monkeypatch, flush_every=5):
     deltas = {i: [] for i in range(n_docs)}
     eng.on_update(lambda i, u: deltas[i].append(u))
     sums = {"plan_cache_hits": 0, "plan_cache_misses": 0,
-            "plan_fastpath_structs": 0}
+            "plan_cache_admitted": 0, "plan_fastpath_structs": 0}
     keysets = set()
     for j, u in enumerate(updates):
         for i in range(n_docs):
@@ -131,27 +131,70 @@ def test_cache_on_off_byte_identical(shape, monkeypatch):
     assert keys_on == keys_off == {frozenset(FLUSH_METRICS_SCHEMA)}
 
 
-def test_cross_engine_replay_is_all_hits(monkeypatch):
-    """A second engine replaying the same trace is served entirely from
-    the cache and still converges byte-identically."""
-    monkeypatch.setenv("YTPU_PLAN_CACHE", "1")
+def use_planner(native, monkeypatch):
+    """Pin the planner a test runs on: the native core, or the Python
+    mirror planning doc by doc (no whole-chunk grouping of its own)."""
+    if native:
+        if not native_plan_available():
+            pytest.skip("native plan core unavailable")
+    else:
+        monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+        monkeypatch.setenv("YTPU_PLAN_SEGMENT", "np")
+
+
+PLANNERS = pytest.mark.parametrize(
+    "native", [True, False], ids=["native", "pymirror"]
+)
+
+
+@PLANNERS
+def test_cross_engine_replay_is_all_hits(native, monkeypatch):
+    """A key is snapshotted at its second sighting: the second engine to
+    replay a trace plans every flush cold and admits every plan, the
+    third is served entirely from the cache, and all converge
+    byte-identically."""
+    use_planner(native, monkeypatch)
     updates = make_trace("interleaved", seed=7)
-    s1, t1, _d, _s, _k = run_engine(updates, 2, True, monkeypatch)
-    s2, t2, _d, sums2, _k = run_engine(updates, 2, True, monkeypatch)
-    assert (s1, t1) == (s2, t2)
-    assert sums2["plan_cache_misses"] == 0
-    assert sums2["plan_cache_hits"] > 0
+    s1, t1, _d, sums1, _k = run_engine(updates, 1, True, monkeypatch)
+    assert sums1["plan_cache_hits"] == 0
+    assert sums1["plan_cache_admitted"] == 0
+    assert plan_cache.get_cache().stats()["entries"] == 0
+    s2, t2, _d, sums2, _k = run_engine(updates, 1, True, monkeypatch)
+    assert sums2["plan_cache_hits"] == 0
+    assert sums2["plan_cache_misses"] == sums1["plan_cache_misses"] > 0
+    assert sums2["plan_cache_admitted"] == sums2["plan_cache_misses"]
+    s3, t3, _d, sums3, _k = run_engine(updates, 1, True, monkeypatch)
+    assert (s1, t1) == (s2, t2) == (s3, t3)
+    assert sums3["plan_cache_misses"] == 0
+    assert sums3["plan_cache_admitted"] == 0
+    assert sums3["plan_cache_hits"] == sums1["plan_cache_misses"]
 
 
 def test_python_mirror_path_byte_identical(monkeypatch):
     monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
     updates = make_trace("interleaved", seed=13)
     plan_cache.reset_cache()
-    s_on, t_on, d_on, sums_on, _ = run_engine(updates, 2, True, monkeypatch)
+    s_on, t_on, d_on, sums_on, _ = run_engine(updates, 3, True, monkeypatch)
     plan_cache.reset_cache()
-    s_off, t_off, d_off, _s, _ = run_engine(updates, 2, False, monkeypatch)
+    s_off, t_off, d_off, _s, _ = run_engine(updates, 3, False, monkeypatch)
     assert (t_on, s_on, d_on) == (t_off, s_off, d_off)
+    # three docs of one history in a flush: whichever way the planner
+    # groups them, the third prober of a key is served
     assert sums_on["plan_cache_hits"] > 0
+
+
+@PLANNERS
+def test_three_replays_match_cache_off(native, monkeypatch):
+    """First sighting, admission and hit each produce what a cold plan
+    with the cache off produces: states, texts and emitted deltas."""
+    use_planner(native, monkeypatch)
+    updates = make_trace("storm", seed=17, n_ops=90)
+    on = [run_engine(updates, 2, True, monkeypatch)[:3] for _ in range(3)]
+    assert len(plan_cache.get_cache()) > 0
+    plan_cache.reset_cache()
+    off = [run_engine(updates, 2, False, monkeypatch)[:3] for _ in range(3)]
+    assert on == off
+    assert on[0] == on[1] == on[2]
 
 
 # -- frontier keying: a stale mirror can never alias --------------------------
@@ -396,6 +439,148 @@ def test_fastpath_structs_counted(monkeypatch):
 # -- cache mechanics ----------------------------------------------------------
 
 
+def one_keystroke_each(eng, docs, ch):
+    """Every room's own typist appends ``ch``: no two rooms ever share a
+    history, so no plan key is presented twice."""
+    for i, d in enumerate(docs):
+        sv = encode_state_vector(d)
+        t = d.get_text("text")
+        t.insert(len(t), ch)
+        eng.queue_update(i, encode_state_as_update(d, sv))
+    eng.flush()
+    return eng.last_flush_metrics
+
+
+def typists(n):
+    docs = []
+    for i in range(n):
+        d = Y.Doc(gc=False)
+        d.client_id = 500 + i
+        docs.append(d)
+    return docs
+
+
+def counter_value(name):
+    from yjs_tpu.obs import global_registry, registry_snapshot
+
+    snap = registry_snapshot(global_registry())
+    return sum(snap["counters"].get(name, {}).values())
+
+
+@PLANNERS
+def test_unique_keys_take_no_snapshot(native, monkeypatch):
+    """A pass of keys that never return (one typist a room) leaves the
+    cache empty and constructs no entry at all."""
+    use_planner(native, monkeypatch)
+    monkeypatch.setenv("YTPU_PLAN_CACHE", "1")
+    built = []
+    for cls in (plan_cache._NativeEntry, plan_cache._PyEntry):
+        real = cls.__init__
+
+        def spy(self, *a, _real=real, **kw):
+            built.append(type(self).__name__)
+            _real(self, *a, **kw)
+
+        monkeypatch.setattr(cls, "__init__", spy)
+    n = 6
+    eng = BatchEngine(n)
+    docs = typists(n)
+    for ch in "unique keys":
+        m = one_keystroke_each(eng, docs, ch)
+        assert m["plan_cache_misses"] == n
+        assert m["plan_cache_admitted"] == 0
+        assert m["plan_cache_hits"] == 0
+    assert built == []
+    st = plan_cache.get_cache().stats()
+    assert st["entries"] == 0 and st["bytes"] == 0
+    assert [eng.text(i) for i in range(n)] == ["unique keys"] * n
+
+
+@PLANNERS
+def test_second_sighting_admits_third_hits(native, monkeypatch):
+    """The same key planned cold twice leaves exactly one entry, and its
+    third prober is served from it."""
+    use_planner(native, monkeypatch)
+    monkeypatch.setenv("YTPU_PLAN_CACHE", "1")
+    d = Y.Doc(gc=False)
+    d.client_id = 9
+    d.get_text("text").insert(0, "twice cold, then served")
+    u = encode_state_as_update(d)
+    cache = plan_cache.get_cache()
+    seen = []
+    for _ in range(3):
+        eng = BatchEngine(1)
+        eng.queue_update(0, u)
+        eng.flush()
+        m = eng.last_flush_metrics
+        seen.append((
+            m["plan_cache_hits"], m["plan_cache_misses"],
+            m["plan_cache_admitted"], len(cache),
+        ))
+        assert eng.text(0) == "twice cold, then served"
+    assert seen == [(0, 1, 0, 0), (0, 1, 1, 1), (1, 0, 0, 1)]
+    # the entry took the key's place among the sightings
+    assert cache.stats()["sightings"] == 0
+
+
+def test_sightings_bounded_by_cap_and_forgotten_on_reset(monkeypatch):
+    monkeypatch.setenv("YTPU_PLAN_CACHE", "1")
+    monkeypatch.setenv("YTPU_PLAN_CACHE_CAP", "8")
+    plan_cache.reset_cache()
+    n = 5
+    eng = BatchEngine(n)
+    docs = typists(n)
+    for ch in "abcdef":  # 30 unique keys through a table of 8
+        one_keystroke_each(eng, docs, ch)
+        assert plan_cache.get_cache().stats()["sightings"] <= 8
+    cache = plan_cache.get_cache()
+    assert cache.stats() == {"entries": 0, "bytes": 0, "sightings": 8}
+    # a key pushed out of the table is a first sighting again
+    key = ("native", b"k" * 16, b"s" * 16, False)
+    assert cache.lookup(key) is None
+    for j in range(8):
+        assert cache.lookup(("native", bytes([j]) * 16, b"", False)) is None
+    assert cache.lookup(key) is None
+    assert cache.insert_py(key, None, None) is False
+    plan_cache.reset_cache()
+    assert plan_cache.get_cache().stats()["sightings"] == 0
+
+
+@PLANNERS
+def test_admission_counters_add_up_to_misses(native, monkeypatch):
+    """Every cold plan is either a first sighting or an admission: the
+    two process-global counters and the per-flush field account for
+    every miss, flush by flush."""
+    use_planner(native, monkeypatch)
+    monkeypatch.setenv("YTPU_PLAN_CACHE", "1")
+    names = (
+        "ytpu_plan_cache_first_sightings_total",
+        "ytpu_plan_cache_admissions_total",
+        "ytpu_plan_cache_misses_total",
+    )
+    updates = make_trace("interleaved", seed=23, n_ops=40)
+    tot = {"first": 0, "admitted": 0, "misses": 0}
+    for _ in range(3):
+        eng = BatchEngine(1)
+        for j, u in enumerate(updates):
+            eng.queue_update(0, u)
+            if (j + 1) % 4 == 0:
+                before = [counter_value(x) for x in names]
+                eng.flush()
+                first, adm, miss = (
+                    counter_value(x) - b for x, b in zip(names, before)
+                )
+                m = eng.last_flush_metrics
+                assert adm == m["plan_cache_admitted"]
+                assert miss == m["plan_cache_misses"]
+                assert first + adm == miss
+                tot["first"] += first
+                tot["admitted"] += adm
+                tot["misses"] += miss
+    assert tot["first"] == tot["admitted"] == 10
+    assert tot["misses"] == 20
+
+
 def test_cache_eviction_respects_caps(monkeypatch):
     monkeypatch.setenv("YTPU_PLAN_CACHE", "1")
     monkeypatch.setenv("YTPU_PLAN_CACHE_CAP", "4")
@@ -445,12 +630,22 @@ def test_plan_threads_reports_actual_width(monkeypatch):
     eng.flush()
     first = eng.last_flush_metrics["plan_threads"]
     assert 1 <= first <= 4  # one cold leader in a 4-doc chunk
-    eng2 = BatchEngine(4)
-    for i in range(4):
-        eng2.queue_update(i, u)
-    eng2.flush()
-    assert eng2.last_flush_metrics["plan_threads"] == 1  # all hits
-    assert eng2.last_flush_metrics["plan_cache_misses"] == 0
+    assert eng.last_flush_metrics["plan_cache_admitted"] == 0
+    metrics = []
+    for _ in range(2):
+        eng2 = BatchEngine(4)
+        for i in range(4):
+            eng2.queue_update(i, u)
+        eng2.flush()
+        metrics.append(eng2.last_flush_metrics)
+    second, third = metrics
+    # the key's second sighting: the leader plans cold again and is
+    # admitted, its three members clone it live
+    assert second["plan_cache_misses"] == second["plan_cache_admitted"] == 1
+    assert second["plan_cache_hits"] == 3
+    assert third["plan_threads"] == 1  # all hits
+    assert third["plan_cache_misses"] == 0
+    assert third["plan_cache_hits"] == 4
 
 
 def test_timer_split_is_consistent():

@@ -119,9 +119,12 @@ FLUSH_METRICS_SCHEMA: dict = {
     "plan_threads": 1,
     # frontier-keyed plan cache (ISSUE 9): probes served from cache /
     # planned cold this flush, and structs placed by the segment-sorted
-    # fast path instead of the sequential YATA walk
+    # fast path instead of the sequential YATA walk.  Of the cold plans,
+    # plan_cache_admitted left a snapshot (their key had been sighted
+    # before); the rest were first sightings (ISSUE 30)
     "plan_cache_hits": 0,
     "plan_cache_misses": 0,
+    "plan_cache_admitted": 0,
     "plan_fastpath_structs": 0,
     # device-authoritative segment planner (ISSUE 15): structs
     # integrated straight from device-computed ranks (fast set) vs

@@ -1052,7 +1052,7 @@ class BatchEngine:
         pre_svs: dict[int, dict[int, int]] = {}
         demoted_now = 0
         rolled_back = 0
-        cache_hits = cache_misses = 0
+        cache_hits = cache_misses = cache_admitted = 0
         t_plan_cached = t_plan_cold = 0.0
         plan_fanout = 1  # docs co-planned by one whole-chunk planner call
         emitting = bool(self._update_listeners)
@@ -1157,9 +1157,13 @@ class BatchEngine:
                         if key is not None:
                             cache_misses += 1
                             if isinstance(m, NativeMirror):
-                                cache.insert_native(key, m, plans[i].counts)
+                                cache_admitted += cache.insert_native(
+                                    key, m, plans[i].counts
+                                )
                             else:
-                                cache.insert_py(key, m, plans[i])
+                                cache_admitted += cache.insert_py(
+                                    key, m, plans[i]
+                                )
                     t_plan_cold += time.perf_counter() - t_d0
                 if chunk_cold:
                     t_d0 = time.perf_counter()
@@ -1197,7 +1201,9 @@ class BatchEngine:
                         else:
                             if key is not None:
                                 cache_misses += 1
-                                cache.insert_py(key, m, plans[i])
+                                cache_admitted += cache.insert_py(
+                                    key, m, plans[i]
+                                )
                     t_plan_cold += time.perf_counter() - t_d0
                 for i, m, key in chunk_dup:
                     t_d0 = time.perf_counter()
@@ -1223,7 +1229,7 @@ class BatchEngine:
                         rolled_back += 1
                     else:
                         cache_misses += 1
-                        cache.insert_py(key, m, plans[i])
+                        cache_admitted += cache.insert_py(key, m, plans[i])
                     t_plan_cold += time.perf_counter() - t_d0
         t_plan = time.perf_counter()
         # ONE schema (obs.FLUSH_METRICS_SCHEMA) for every exit: each path
@@ -1239,6 +1245,7 @@ class BatchEngine:
             t_plan_cold_s=t_plan_cold,
             plan_cache_hits=cache_hits,
             plan_cache_misses=cache_misses,
+            plan_cache_admitted=cache_admitted,
             plan_threads=plan_fanout,
             plan_fastpath_structs=sum(
                 getattr(p, "fastpath_structs", 0) or 0
@@ -1387,6 +1394,7 @@ class BatchEngine:
             plan_threads=1,
             cache_hits=0,
             cache_misses=0,
+            cache_admitted=0,
             t_cached=0.0,
             t_cold=0.0,
             demoted=metrics["n_demoted"],
@@ -1501,6 +1509,7 @@ class BatchEngine:
                 "t_plan_cold_s": acc.t_cold,
                 "plan_cache_hits": acc.cache_hits,
                 "plan_cache_misses": acc.cache_misses,
+                "plan_cache_admitted": acc.cache_admitted,
                 # widest worker pool any prepare batch in this flush
                 # actually used — min(configured width, docs in the
                 # batch); 1 when every doc was served from the plan cache
@@ -1603,8 +1612,11 @@ class BatchEngine:
                     if key is not None:
                         # post-prepare, pre-pack: the snapshot a
                         # future hit adopts before running the
-                        # pack/dispatch phases itself
-                        cache.insert_native(key, m, counts_all[k])
+                        # pack/dispatch phases itself (taken from a
+                        # key's second sighting on)
+                        acc.cache_admitted += cache.insert_native(
+                            key, m, counts_all[k]
+                        )
             acc.t_cold += time.perf_counter() - tc0
         if retry:
             # a leader's demote/isolate says nothing about its

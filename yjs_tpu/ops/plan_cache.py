@@ -25,6 +25,22 @@ Entries are immutable once inserted and never *become* wrong (the key is
 the full mutation history); eviction is pure memory policy (LRU over
 ``YTPU_PLAN_CACHE_CAP`` entries / ``YTPU_PLAN_CACHE_BYTES`` bytes).
 
+**Admission at the second sighting (ISSUE 30).**  A snapshot costs a deep
+copy of the whole mirror (80-500 us and 120-450 KB at 600-2,200 rows a
+room) and pays only if its key is probed again, which the cache can see
+in its own input: a ``lookup`` that misses notes the key in a bounded
+table of sightings (keys only, at most ``cap`` of them, oldest out), and
+``insert_native`` / ``insert_py`` take the snapshot only when that miss
+was not the key's first.  At a first sighting nothing is built: no
+handle, no clone, no copy of the pins, no eviction.  A workload whose
+keys never return (one typist a room: the key is the room's whole
+history) pays a dict probe a plan; one whose keys do return (one update
+broadcast over several chunks or flushes, a replica replaying a trace)
+plans a key cold twice and hits from the third prober on.  Same-key
+members of ONE native chunk never come here: they clone their live
+leader (``note_hits``).  ``clear`` forgets the sightings with the
+entries.
+
 Env knobs: ``YTPU_PLAN_CACHE=0`` disables probing and insertion
 entirely; ``YTPU_PLAN_CACHE_CAP`` (entries, default 4096),
 ``YTPU_PLAN_CACHE_BYTES`` (approx. host bytes, default 1 GiB),
@@ -34,6 +50,9 @@ entirely; ``YTPU_PLAN_CACHE_CAP`` (entries, default 4096),
 The metric families live on the process-global registry (the cache is
 process-global, like the kernel profiler): ``ytpu_plan_cache_hits_total``,
 ``ytpu_plan_cache_misses_total``,
+``ytpu_plan_cache_first_sightings_total`` /
+``ytpu_plan_cache_admissions_total`` (cold plans that left no snapshot /
+that left one),
 ``ytpu_plan_cache_invalidations_total{reason}``,
 ``ytpu_plan_fastpath_structs_total`` (structs placed by the segment-
 sorted fast path in ``ops/kernels.py`` / ``DocMirror.prepare_step``),
@@ -120,6 +139,15 @@ _HITS = _reg.counter(
 _MISSES = _reg.counter(
     "ytpu_plan_cache_misses_total",
     "Plan-cache probes that fell through to a cold plan",
+)
+_FIRST_SIGHTINGS = _reg.counter(
+    "ytpu_plan_cache_first_sightings_total",
+    "Cold plans that left no snapshot: their probe was the key's first "
+    "sighting",
+)
+_ADMISSIONS = _reg.counter(
+    "ytpu_plan_cache_admissions_total",
+    "Post-prepare snapshots taken: cold plans of a key sighted before",
 )
 _INVALIDATIONS = _reg.counter(
     "ytpu_plan_cache_invalidations_total",
@@ -275,6 +303,8 @@ class _PyEntry:
 class PlanCache:
     def __init__(self):
         self._d: OrderedDict = OrderedDict()
+        # keys that missed and hold no entry -> whether they missed again
+        self._seen: OrderedDict = OrderedDict()
         self._bytes = 0
         self.cap = int(os.environ.get("YTPU_PLAN_CACHE_CAP", "4096"))
         self.byte_cap = int(
@@ -291,10 +321,28 @@ class PlanCache:
         ent = self._d.get(key)
         if ent is None:
             _MISSES.inc()
+            seen = self._seen
+            if key in seen:
+                seen[key] = True
+            else:
+                seen[key] = False
+                if len(seen) > self.cap:
+                    seen.popitem(last=False)
             return None
         self._d.move_to_end(key)
         _HITS.inc()
         return ent
+
+    def _sighted_before(self, key) -> bool:
+        """Whether the cold plan being offered under ``key`` is worth a
+        snapshot: its probe was not the key's first.  The entry takes
+        the key's place in the sightings."""
+        if self._seen.get(key):
+            self._seen.pop(key, None)
+            _ADMISSIONS.inc()
+            return True
+        _FIRST_SIGHTINGS.inc()
+        return False
 
     def _admit(self, key, ent) -> None:
         if ent.nbytes > self.max_entry:
@@ -315,10 +363,13 @@ class PlanCache:
         _ENTRIES_G.set(len(self._d))
         _BYTES_G.set(self._bytes)
 
-    def insert_native(self, key, mirror, counts):
-        """Snapshot a NativeMirror's post-prepare state under ``key``.
+    def insert_native(self, key, mirror, counts) -> bool:
+        """Snapshot a NativeMirror's post-prepare state under ``key``,
+        unless this was the key's first sighting; says whether it did.
         ``mirror.plan_frontier`` has already been folded forward by
         ``_finish_prepare``, so it is the frontier a hit must adopt."""
+        if not self._sighted_before(key):
+            return False
         self._admit(
             key,
             _NativeEntry(
@@ -326,20 +377,29 @@ class PlanCache:
                 mirror.plan_frontier,
             ),
         )
+        return True
 
-    def insert_py(self, key, mirror, plan):
+    def insert_py(self, key, mirror, plan) -> bool:
+        if not self._sighted_before(key):
+            return False
         self._admit(key, _PyEntry(mirror, plan))
+        return True
 
     def clear(self):
         for ent in self._d.values():
             ent.close()
         self._d.clear()
+        self._seen.clear()
         self._bytes = 0
         _ENTRIES_G.set(0)
         _BYTES_G.set(0)
 
     def stats(self) -> dict:
-        return {"entries": len(self._d), "bytes": self._bytes}
+        return {
+            "entries": len(self._d),
+            "bytes": self._bytes,
+            "sightings": len(self._seen),
+        }
 
 
 _CACHE: PlanCache | None = None
