@@ -220,3 +220,308 @@ class TestBatchedSyncKernels:
             protocol.read_sync_message(Decoder(reply), Encoder(), d)
         for i, d in enumerate(clients):
             assert prov.text(f"r{i}") == d.get_text("text").to_string()
+
+
+# ---------------------------------------------------------------------------
+# The reconnect handshake against the CPU core (ISSUE 32): the reference
+# of a step 2 is ``Y.encode_state_as_update(doc, sv)`` on a ``Y.Doc`` fed
+# what the room was sent, and a client that is a ``Y.Doc`` holding a
+# stale prefix of it.  Rooms of the deployment's four kinds (the committed
+# traces; the prepend room at 3000 characters for the suite's time), each
+# with a seeded recent past of two typists.
+# ---------------------------------------------------------------------------
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent.parent
+if str(ROOT) not in sys.path:  # the fixtures' reader, as tests/bench does
+    sys.path.insert(0, str(ROOT))
+
+KINDS = ("distinct", "storm", "b4", "prepend")
+CLASSES = ("empty", "prefix", "current", "ahead", "unknown")
+TYPIST_A, TYPIST_B, NEWCOMER = 700_001, 700_002, 700_003
+SHARED, LATER = 8, 6  # entries both typists saw; entries B sent after A left
+
+
+def _frame_step1(sv_bytes: bytes) -> bytes:
+    from yjs_tpu.lib0 import encoding
+    from yjs_tpu.lib0.encoding import Encoder
+    from yjs_tpu.sync import protocol
+
+    enc = Encoder()
+    encoding.write_var_uint(enc, protocol.MESSAGE_YJS_SYNC_STEP_1)
+    encoding.write_var_uint8_array(enc, sv_bytes)
+    return enc.to_bytes()
+
+
+def _step2_payload(reply: bytes) -> bytes:
+    from yjs_tpu.lib0 import decoding
+    from yjs_tpu.lib0.decoding import Decoder
+    from yjs_tpu.sync import protocol
+
+    dec = Decoder(reply)
+    assert decoding.read_var_uint(dec) == protocol.MESSAGE_YJS_SYNC_STEP_2
+    return decoding.read_var_uint8_array(dec)
+
+
+def _sv(doc) -> dict:
+    return Y.decode_state_vector(Y.encode_state_vector(doc))
+
+
+def _replay(updates, client_id=None):
+    doc = Y.Doc(gc=False)
+    if client_id is not None:
+        doc.client_id = client_id
+    for u in updates:
+        Y.apply_update(doc, u)
+    return doc
+
+
+def _edit(doc, gen, n):
+    """``n`` transactions on ``doc``'s text, one update each."""
+    out = []
+    doc.on("update", lambda u, *_: out.append(u))
+    text = doc.get_text("text")
+    for _ in range(n):
+        ln = len(text)
+        if ln and gen.random() < 0.3:
+            text.delete(gen.randrange(ln), 1)
+        else:
+            text.insert(gen.randint(0, ln), gen.choice(["a", "bc", "d e", "🙂"]))
+    return out
+
+
+def _structs_by_client(update: bytes) -> dict:
+    """Per client of a v1 update: the clock its structs start at and the
+    elements they hold, read by the core's own struct reader."""
+    from yjs_tpu.coding import UpdateDecoderV1
+    from yjs_tpu.lib0.decoding import Decoder
+    from yjs_tpu.updates import read_clients_struct_refs
+
+    refs = read_clients_struct_refs(
+        UpdateDecoderV1(Decoder(update)), {}, Y.Doc(gc=False)
+    )
+    return {
+        client: (structs[0].id.clock, sum(s.length for s in structs))
+        for client, structs in refs.items() if structs
+    }
+
+
+def _base(kind: str, gen) -> bytes:
+    from benchmarks import deployment
+
+    if kind == "b4":
+        return (deployment.FIXTURES / "b4_trace.bin").read_bytes()
+    if kind == "prepend":  # prepend_frag_100000's shape, 3000 long
+        doc = Y.Doc(gc=False)
+        doc.client_id = 77
+        text = doc.get_text("text")
+        for _ in range(3000):
+            text.insert(0, gen.choice("abcdefgh "))
+        return Y.encode_state_as_update(doc)
+    return gen.choice(deployment.load_traces(f"{kind}_traces"))
+
+
+@pytest.fixture(scope="module")
+def handshake(tmp_path_factory):
+    """A provider with a WAL that holds one room of each kind: the
+    committed trace, then SHARED entries two typists made while both
+    were connected, then LATER entries B made after A had left.  ``sent``
+    is what the room was sent; ``a`` is A's own document, which went on
+    typing offline (``a_unsent``)."""
+    from yjs_tpu.persistence import WalConfig
+    from yjs_tpu.provider import TpuProvider
+
+    wal = tmp_path_factory.mktemp("handshake") / "wal"
+    prov = TpuProvider(
+        8, wal_dir=str(wal), wal_config=WalConfig(fsync="never")
+    )
+    heard = {}
+    prov.on_update(lambda guid, u: heard.setdefault(guid, []).append(u))
+    rooms = {}
+    for k, kind in enumerate(KINDS):
+        gen = random.Random(f"handshake:{kind}")
+        base = _base(kind, gen)
+        a, b = _replay([base], TYPIST_A), _replay([base], TYPIST_B)
+        sent = [base]
+        for turn in range(SHARED):
+            (u,) = _edit(a if turn % 2 else b, gen, 1)
+            Y.apply_update(b if turn % 2 else a, u)
+            sent.append(u)
+        a_unsent = _edit(a, gen, 3)
+        sent += _edit(b, gen, LATER)
+        guid = f"room/{kind}"
+        for u in sent:
+            assert prov.receive_update(guid, u)
+        rooms[kind] = {
+            "guid": guid, "sent": sent, "a": a, "a_unsent": a_unsent,
+            "oracle": _replay(sent), "gen": gen,
+        }
+    prov.flush()
+    yield prov, rooms, heard, wal
+    prov.close(checkpoint=False)
+
+
+def _client(room, cls):
+    """A client of class ``cls`` and the document that holds what it
+    must hold once it has applied its answer."""
+    sent, gen = room["sent"], random.Random(f"client:{cls}")
+    if cls == "empty":
+        return Y.Doc(gc=False), room["oracle"]
+    if cls == "prefix":
+        return _replay(sent[: 1 + gen.randint(1, SHARED + LATER - 1)]), room["oracle"]
+    if cls == "current":
+        return _replay(sent), room["oracle"]
+    if cls == "ahead":  # A: all it sent and more, and none of B's later entries
+        client = _replay([Y.encode_state_as_update(room["a"])], TYPIST_A)
+        return client, _replay(sent + room["a_unsent"])
+    # a newcomer that synced a prefix once and typed before it connected
+    client = _replay(sent[: 1 + SHARED], NEWCOMER)
+    typed = _edit(client, gen, 2)
+    return client, _replay(sent + typed)
+
+
+class TestReconnectHandshake:
+    @pytest.mark.parametrize("cls", CLASSES)
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_answer_is_the_gap_and_the_client_ends_where_the_oracle_is(
+        self, handshake, kind, cls
+    ):
+        prov, rooms, _heard, _wal = handshake
+        room = rooms[kind]
+        client, want = _client(room, cls)
+        session_sv, room_sv = _sv(client), _sv(room["oracle"])
+        frame = _frame_step1(Y.encode_state_vector(client))
+        (reply,) = prov.handle_sync_step1_batch([(room["guid"], frame)])
+        # (c) the batch's answer is handle_sync_message's, byte for byte
+        assert reply == prov.handle_sync_message(room["guid"], frame)
+        answer = _step2_payload(reply)
+        # (b) the gap and no more: per client, from the session's clock,
+        # as many elements as the room holds past it
+        assert _structs_by_client(answer) == {
+            c: (session_sv.get(c, 0), n - session_sv.get(c, 0))
+            for c, n in room_sv.items() if n > session_sv.get(c, 0)
+        }
+        assert _structs_by_client(answer) == _structs_by_client(
+            Y.encode_state_as_update(
+                room["oracle"], Y.encode_state_vector(client)
+            )
+        )
+        # (a) applied to the stale document: the oracle's state vector,
+        # text and canonical encoded state
+        Y.apply_update(client, answer)
+        assert _sv(client) == _sv(want)
+        assert client.get_text("text").to_string() == (
+            want.get_text("text").to_string()
+        )
+        assert Y.merge_updates([Y.encode_state_as_update(client)]) == (
+            Y.merge_updates([Y.encode_state_as_update(want)])
+        )
+        m = prov.last_sync_metrics
+        assert (m["n_requests"], m["n_bad"]) == (1, 0)
+        assert m["n_full"] == (cls == "empty")
+        assert m["reply_bytes"] == len(reply)
+        assert m["encode_buffer_bytes"] >= len(answer)
+
+    def test_a_batch_answers_every_room_as_one_at_a_time(self, handshake):
+        prov, rooms, _heard, _wal = handshake
+        msgs = [
+            (rooms[kind]["guid"],
+             _frame_step1(Y.encode_state_vector(_client(rooms[kind], cls)[0])))
+            for kind in KINDS for cls in CLASSES
+        ]
+        before = prov.engine.obs.registry.get(
+            "ytpu_provider_sync_step2_total"
+        ).value
+        replies = prov.handle_sync_step1_batch(msgs)
+        assert replies == [prov.handle_sync_message(g, f) for g, f in msgs]
+        m = prov.last_sync_metrics
+        assert (m["n_requests"], m["n_full"], m["n_bad"]) == (20, 4, 0)
+        assert m["reply_bytes"] == sum(map(len, replies))
+        assert m["t_decode_s"] > 0 and m["t_encode_s"] > 0
+        # 20 from the batch, 20 one at a time
+        assert prov.engine.obs.registry.get(
+            "ytpu_provider_sync_step2_total"
+        ).value == before + 40
+
+    def test_a_bad_frame_costs_its_own_answer_and_no_other(self, handshake):
+        """(d) ``handle_sync_message``'s contract, frame by frame."""
+        from yjs_tpu.sync import protocol
+
+        prov, rooms, _heard, _wal = handshake
+        guid = rooms["distinct"]["guid"]
+        good = _frame_step1(Y.encode_state_vector(Y.Doc(gc=False)))
+        update = rooms["distinct"]["sent"][1]
+        bad = {
+            "not step 1": bytes([protocol.MESSAGE_YJS_UPDATE, len(update)]) + update,
+            "no type": b"",
+            "truncated": good[:1] + b"\x09\x02",
+            "garbage state vector": _frame_step1(b"\xff\xff\xff\xff"),
+            "out of range": _frame_step1(b"\x01\x01" + b"\xff" * 9 + b"\x01"),
+        }
+        msgs = [(guid, good)]
+        for frame in bad.values():
+            msgs += [(guid, frame), (guid, good)]
+        queue, doc = prov.engine.dead_letters, prov.doc_id(guid)
+        letters = len(queue.list(doc=doc))
+        counted = prov.engine.obs.registry.get(
+            "ytpu_provider_sync_messages_total"
+        )
+        n_bad = counted.labels(type="bad").value
+        replies = prov.handle_sync_step1_batch(msgs)
+        want = prov.handle_sync_message(guid, good)
+        assert replies == [want] + [None, want] * len(bad)
+        assert prov.last_sync_metrics["n_bad"] == len(bad)
+        assert counted.labels(type="bad").value == n_bad + len(bad)
+        new = queue.list(doc=doc)[letters:]
+        assert [bytes(e.update) for e in new] == list(bad.values())
+        assert all(e.reason.startswith("bad-frame: ") for e in new)
+        # one at a time, the frames that are step 1 fail the same way
+        for name in ("no type", "truncated", "garbage state vector", "out of range"):
+            assert prov.handle_sync_message(guid, bad[name]) is None
+        assert not prov.engine.rollbacks and not prov.engine.fallback
+
+    def test_what_a_session_sends_back_is_an_update_like_any_other(
+        self, handshake
+    ):
+        """(e) the newcomer answers the server's step 1 with what it
+        typed while away: acknowledged, journaled, integrated, broadcast;
+        a second session of the room ends where the first did."""
+        from benchmarks.oracle import read_wal
+
+        prov, rooms, heard, wal = handshake
+        room = rooms["storm"]
+        guid = room["guid"]
+        first, _want = _client(room, "unknown")
+        second = _replay(room["sent"])
+        (reply,) = prov.handle_sync_step1_batch(
+            [(guid, _frame_step1(Y.encode_state_vector(first)))]
+        )
+        Y.apply_update(first, _step2_payload(reply))
+        # the server's own step 1, answered by the client's core
+        server_sv = _step2_payload(
+            bytes([1]) + prov.sync_step1(guid)[1:]
+        )
+        assert Y.decode_state_vector(server_sv) == _sv(room["oracle"])
+        back = Y.encode_state_as_update(first, server_sv)
+        assert set(_structs_by_client(back)) == {NEWCOMER}
+        n_heard = len(heard.get(guid, []))
+        assert prov.receive_update(guid, back) is True
+        prov.flush()
+        assert read_wal(wal)[guid][-1][-1] == back
+        assert prov.state_vector(guid) == _sv(first)
+        assert prov.text(guid) == first.get_text("text").to_string()
+        for u in heard[guid][n_heard:]:
+            Y.apply_update(second, u)
+        assert len(heard[guid]) > n_heard
+        assert _sv(second) == _sv(first)
+        assert Y.merge_updates([Y.encode_state_as_update(second)]) == (
+            Y.merge_updates([Y.encode_state_as_update(first)])
+        )
+        # and a third, that reconnects only now, is owed it as a gap
+        third = _replay(room["sent"])
+        (reply,) = prov.handle_sync_step1_batch(
+            [(guid, _frame_step1(Y.encode_state_vector(third)))]
+        )
+        assert set(_structs_by_client(_step2_payload(reply))) == {NEWCOMER}

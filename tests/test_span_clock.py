@@ -286,6 +286,48 @@ def test_tracker_without_a_tracer_spans_on_the_one_it_is_handed():
     ) == ["ytpu.slo.burn", "ytpu.slo.visible"]
 
 
+def test_sync_handshake_spans_on_both_clocks(tmp_path):
+    """``handle_sync_step1_batch`` and ``sync_step1`` open the spans of
+    ``obs.trace.SYNC_SPANS``: bare names, each inside its parent on one
+    thread, the batch's three in the ring and the per-connection one for
+    the profiler alone; a bad frame changes none of it."""
+    from yjs_tpu.obs.trace import SYNC_SPANS
+
+    prov = provider(tmp_path)
+    for u in keystrokes(12, 17):
+        assert prov.receive_update("room", u)
+    step1 = b"\x00\x01\x00"  # sync step 1 with an empty state vector
+    msgs = [("room", step1), ("room", b"\x00\x05\xff"), ("room", step1)]
+
+    def go():
+        replies = prov.handle_sync_step1_batch(msgs)
+        return replies, prov.sync_step1("room")
+
+    (replies, _frame), events = traced(tmp_path, go)
+    assert replies[1] is None and replies[0] == replies[2] is not None
+    sync = [e for e in events if e[2].startswith("ytpu.sync.")]
+    assert collections.Counter(e[2] for e in sync) == dict.fromkeys(SYNC_SPANS, 1)
+    assert len({(e[0], e[1]) for e in sync}) == 1  # one thread
+    at = {e[2]: (e[3], e[3] + e[4]) for e in sync}
+    for name, parent in SYNC_SPANS.items():
+        if parent is not None:
+            assert at[parent][0] <= at[name][0] and at[name][1] <= at[parent][1]
+    assert at["ytpu.sync.decode"][1] <= at["ytpu.sync.encode"][0]
+    # the flush at the batch's head is outside the batch's span
+    flushes = [e for e in events if e[2] == "ytpu.provider.flush"]
+    assert flushes and all(
+        e[3] + e[4] <= at["ytpu.sync.step1_batch"][0] for e in flushes
+    )
+    ring = collections.Counter(
+        e["name"] for e in prov.engine.obs.tracer.trace_events()
+        if e["ph"] == "X" and e["name"].startswith("ytpu.sync.")
+    )
+    assert ring == dict.fromkeys(set(SYNC_SPANS) - {"ytpu.sync.step1"}, 1)
+    m = prov.last_sync_metrics
+    assert (m["n_requests"], m["n_full"], m["n_bad"]) == (3, 2, 1)
+    prov.close(checkpoint=False)
+
+
 def test_importing_obs_does_not_load_jax():
     """The annotation is jax's, and is imported by the first Tracer:
     sessions, the lint and the CLIs import ``yjs_tpu.obs`` without it."""

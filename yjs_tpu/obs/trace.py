@@ -27,6 +27,17 @@ from collections import deque
 
 DEFAULT_MAX_EVENTS = 200_000
 
+# The sync handshake's spans, each with the span it opens inside (None:
+# its caller's).  ``ytpu.sync.step1`` is one a connection and goes to the
+# profiler alone.  tests/test_span_clock.py holds the program to these
+# names and the benchmark's ``sync_*`` readers read them.
+SYNC_SPANS = {
+    "ytpu.sync.step1_batch": None,
+    "ytpu.sync.decode": "ytpu.sync.step1_batch",
+    "ytpu.sync.encode": "ytpu.sync.step1_batch",
+    "ytpu.sync.step1": None,
+}
+
 
 class _Span:
     """One span on both clocks: the profiler's annotation around one

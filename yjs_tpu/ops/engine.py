@@ -352,6 +352,8 @@ class BatchEngine:
         # every flush live in obs.history; last_flush_metrics is the
         # compatibility view of the newest entry)
         self.obs = EngineObs()
+        # what the newest sync_step2_batch did (requests, buffer bytes, s)
+        self.last_sync_metrics: dict | None = None
         # resilience (ISSUE 2): per-doc failure isolation.  Strict mode
         # (YTPU_RESILIENCE_DISABLED=1) restores the pre-resilience
         # contract — integration failures raise out of flush()
@@ -2594,11 +2596,36 @@ class BatchEngine:
     def sync_step2_batch(
         self, requests: list[tuple[int, dict[int, int] | None]], v2: bool = False
     ) -> list[bytes]:
-        """Answer many sync-step-1 requests with ONE ``diff_mask_kernel``
-        dispatch: (doc, remote state vector) pairs in, diff updates out
-        (reference encodeStateAsUpdate, encoding.js:490-526, batched over
-        the doc axis).  Fallback docs are served by the CPU core."""
+        """Answer many sync-step-1 requests: (doc, remote state vector)
+        pairs in, diff updates out (reference encodeStateAsUpdate,
+        encoding.js:490-526), positionally.
+
+        The default path is the host's: a native mirror encodes its own
+        diff straight from the C++ columns, one ``ymx_encode_diff(_v2)``
+        call a request in a serial loop, each into a fresh buffer of
+        ``ymx_encode_bound`` bytes (the whole room's, whatever the diff
+        holds); no device round trip.  The ``diff_mask_kernel`` dispatch
+        (one for the whole batch, from columns copied out of the host
+        mirrors) serves what the native writer declines, Python-mirror
+        engines, and every request under ``YTPU_SYNC_DEVICE=1``.
+        Fallback docs are served by the CPU core.
+
+        The whole call is the ``ytpu.sync.encode`` span;
+        ``last_sync_metrics`` says what it did."""
+        t0 = time.perf_counter()
+        with self._phase_ctx("sync.encode"):
+            replies, buffer_bytes = self._sync_step2_batch(requests, v2)
+        self.last_sync_metrics = {
+            "n_requests": len(requests),
+            "encode_buffer_bytes": buffer_bytes,
+            "t_encode_s": time.perf_counter() - t0,
+        }
+        return replies
+
+    def _sync_step2_batch(self, requests, v2):
+        """The answers, and the bytes the native encodes allocated."""
         replies: list[bytes | None] = [None] * len(requests)
+        buffer_bytes = 0
         dev = [
             (j, i, sv) for j, (i, sv) in enumerate(requests) if i not in self.fallback
         ]
@@ -2613,10 +2640,6 @@ class BatchEngine:
                     write_state_vector(e, sv)
                     enc_sv = e.to_bytes()
                 replies[j] = self.encode_state_as_update(i, enc_sv, v2=v2)
-        # native mirrors answer straight from the C++ columns: one
-        # ymx_encode_diff(_v2) call per request, no device round trip (the
-        # device diff kernel still serves Python-mirror engines and can be
-        # forced with YTPU_SYNC_DEVICE=1)
         if not os.environ.get("YTPU_SYNC_DEVICE"):
             rest = []
             for j, i, sv in dev:
@@ -2627,6 +2650,7 @@ class BatchEngine:
                     rest.append((j, i, sv))
                 else:
                     replies[j] = u
+                    buffer_bytes += m.encode_buffer_bytes
             dev = rest
         if dev:
             docs = [i for _, i, _ in dev]
@@ -2651,7 +2675,7 @@ class BatchEngine:
                 replies[j] = self.mirrors[i].encode_masked_update(
                     needed[r], offset[r], v2=v2
                 )
-        return replies
+        return replies, buffer_bytes
 
     def encode_states_batched(
         self, docs: list[int], v2: bool = False

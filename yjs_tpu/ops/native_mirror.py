@@ -198,6 +198,8 @@ class NativeMirror:
         self._src_end2: list[int] = []
         self._src_count: list[int] = []
         self._src_v2: list[int] = []
+        # bytes the last encode_diff_update allocated for its output
+        self.encode_buffer_bytes = 0
 
     def __del__(self):
         lib = getattr(self, "_lib", None)
@@ -468,8 +470,10 @@ class NativeMirror:
             override = 1
         fn = lib.ymx_encode_diff_v2 if v2 else lib.ymx_encode_diff
         cap = int(lib.ymx_encode_bound(h))
+        self.encode_buffer_bytes = 0
         for _attempt in range(2):
             out = np.empty(cap, np.uint8)
+            self.encode_buffer_bytes += cap
             rc = int(
                 fn(
                     h, _p64(svc), _p64(svk), n_sv, _p64(ds), n_ds,

@@ -326,6 +326,9 @@ class TpuProvider:
         )
         # stats dict of the replay that built this provider (recover())
         self.last_recovery: dict | None = None
+        # what the newest handle_sync_step1_batch did, as the engine's
+        # last_flush_metrics is of a flush
+        self.last_sync_metrics: dict | None = None
         # per-peer session layer (ISSUE 5): sessions keyed by
         # (room guid, peer name); families register unconditionally so
         # exposition and the schema checker see the full surface
@@ -810,11 +813,15 @@ class TpuProvider:
 
     def sync_step1(self, guid: str) -> bytes:
         """Message announcing this doc's state vector (sync step 1)."""
-        enc = Encoder()
-        encoding.write_var_uint(enc, protocol.MESSAGE_YJS_SYNC_STEP_1)
-        encoding.write_var_uint8_array(enc, self.engine.encode_state_vector(self.doc_id(guid)))
-        self._m_step1.inc()
-        return enc.to_bytes()
+        # one a connection: the profiler's alone, as ytpu.slo.receive is
+        with self.engine.obs.tracer.span("ytpu.sync.step1", _ring=False):
+            enc = Encoder()
+            encoding.write_var_uint(enc, protocol.MESSAGE_YJS_SYNC_STEP_1)
+            encoding.write_var_uint8_array(
+                enc, self.engine.encode_state_vector(self.doc_id(guid))
+            )
+            self._m_step1.inc()
+            return enc.to_bytes()
 
     def handle_sync_message(self, guid: str, message: bytes) -> bytes | None:
         """Process one sync message for a doc; returns the reply, if any.
@@ -923,32 +930,79 @@ class TpuProvider:
 
     def handle_sync_step1_batch(
         self, messages: list[tuple[str, bytes]]
-    ) -> list[bytes]:
-        """Answer many concurrent sync-step-1 messages with ONE device
-        dispatch (the server's fan-in moment: N clients reconnect, N diffs
-        computed by one ``diff_mask_kernel`` call).  Returns the framed
-        step-2 reply per message."""
+    ) -> list[bytes | None]:
+        """Answer many sync-step-1 messages at once (the server's fan-in
+        moment: N clients reconnect): ONE ``flush()``, so that every
+        answer holds what was acknowledged before the call, then one
+        ``engine.sync_step2_batch``, which on the default path encodes
+        each diff from its room's native host mirror, one after another
+        (its docstring says when the device's ``diff_mask_kernel`` runs).
+        Returns the framed step-2 reply of each message, at its place.
+
+        Frame by frame the contract is ``handle_sync_message``'s: a frame
+        that is not step 1, or whose state vector does not decode, is
+        dead-lettered (``bad-frame: ...``), counted ``type="bad"`` and
+        answered with ``None``; it costs no other frame its answer.
+        ``last_sync_metrics`` says what the call did."""
         from .updates import decode_state_vector
 
         self.flush()
-        requests = []
-        for guid, message in messages:
-            dec = Decoder(message)
-            msg_type = decoding.read_var_uint(dec)
-            if msg_type != protocol.MESSAGE_YJS_SYNC_STEP_1:
-                raise ValueError("batch handler only accepts sync step 1")
-            remote_sv = decode_state_vector(decoding.read_var_uint8_array(dec))
-            requests.append((self.doc_id(guid), remote_sv))
-        updates = self.engine.sync_step2_batch(requests)
-        replies = []
-        for u in updates:
-            enc = Encoder()
-            encoding.write_var_uint(enc, protocol.MESSAGE_YJS_SYNC_STEP_2)
-            encoding.write_var_uint8_array(enc, u)
-            replies.append(enc.to_bytes())
-        self._m_sync_msgs.labels(type="step1").inc(len(messages))
-        self._m_step2.inc(len(replies))
-        self._m_step2_bytes.inc(sum(len(rep) for rep in replies))
+        tracer = self.engine.obs.tracer
+        replies: list[bytes | None] = [None] * len(messages)
+        requests, places = [], []
+        n_full = 0
+        with tracer.span("ytpu.sync.step1_batch"):
+            t0 = time.perf_counter()
+            with tracer.span("ytpu.sync.decode"):
+                for k, (guid, message) in enumerate(messages):
+                    doc = self.doc_id(guid)
+                    try:
+                        dec = Decoder(message)
+                        msg_type = decoding.read_var_uint(dec)
+                        if msg_type != protocol.MESSAGE_YJS_SYNC_STEP_1:
+                            raise ValueError(
+                                "batch handler only accepts sync step 1, "
+                                f"not type {msg_type}"
+                            )
+                        remote_sv = decode_state_vector(
+                            decoding.read_var_uint8_array(dec)
+                        )
+                        if any(c >> 63 or n >> 63 for c, n in remote_sv.items()):
+                            raise ValueError("state vector entry out of range")
+                    except Exception as e:
+                        self.engine._dead_letter(
+                            doc, message, False,
+                            f"bad-frame: {type(e).__name__}: {e}",
+                        )
+                        continue
+                    requests.append((doc, remote_sv))
+                    places.append(k)
+                    n_full += not remote_sv
+            t_decode = time.perf_counter() - t0
+            updates = self.engine.sync_step2_batch(requests)
+            reply_bytes = 0
+            for k, u in zip(places, updates):
+                enc = Encoder()
+                encoding.write_var_uint(enc, protocol.MESSAGE_YJS_SYNC_STEP_2)
+                encoding.write_var_uint8_array(enc, u)
+                replies[k] = reply = enc.to_bytes()
+                reply_bytes += len(reply)
+            n_bad = len(messages) - len(places)
+            self._m_sync_msgs.labels(type="step1").inc(len(places))
+            if n_bad:
+                self._m_sync_msgs.labels(type="bad").inc(n_bad)
+            self._m_step2.inc(len(places))
+            self._m_step2_bytes.inc(reply_bytes)
+            encoded = self.engine.last_sync_metrics
+            self.last_sync_metrics = {
+                "n_requests": len(messages),
+                "n_full": n_full,
+                "n_bad": n_bad,
+                "reply_bytes": reply_bytes,
+                "encode_buffer_bytes": encoded["encode_buffer_bytes"],
+                "t_decode_s": t_decode,
+                "t_encode_s": encoded["t_encode_s"],
+            }
         return replies
 
     # -- peer sessions (ISSUE 5) --------------------------------------------
