@@ -190,6 +190,14 @@ def load():
     lib.ymx_encode_diff_v2.restype = i64
     lib.ymx_encode_diff_v2.argtypes = [vp, i64p, i64p, i64, i64p, i64,
                                        ctypes.c_int, u8p, u64]
+    # one call a flush encodes every planned room's step update into the
+    # calling thread's arena; ymx_plan_seq numbers a mirror's plans
+    lib.ymx_encode_steps_many.restype = i64
+    lib.ymx_encode_steps_many.argtypes = [vpp, i64] + [i64p] * 9
+    lib.ymx_encode_arena.restype = vp
+    lib.ymx_encode_arena.argtypes = []
+    lib.ymx_plan_seq.restype = u64
+    lib.ymx_plan_seq.argtypes = [vp]
     lib.ymx_compact_self.restype = i64
     lib.ymx_compact_self.argtypes = [vp, ctypes.c_int, i32p, u8p, i32p, i64]
     # ymx_prepare_many's worker-pool width (surfaced as

@@ -181,6 +181,9 @@ struct Mirror {
   std::vector<std::unique_ptr<std::vector<uint8_t>>> owned;
 
   Plan plan;
+  // which prepare (or state clone) wrote `plan`: readers that hold a
+  // plan's number (counts[15]) can tell it has been overwritten
+  uint64_t plan_seq = 0;
   uint64_t gen = 0;
 
   // dedup epochs for Plan.dirty_links/dirty_heads (one bump per prepare);
@@ -1028,6 +1031,7 @@ struct Mirror {
       t0 = t1;
     };
     plan.clear();
+    plan_seq++;
     dirty_epoch++;
 
     // decode every staged update first (nothing merges on error; the doc
@@ -1849,6 +1853,11 @@ inline void build_diff_prep(Mirror* m, const int64_t* sv_clients,
                             int ds_override, DiffPrep* p) {
   size_t n_slots = m->client_of_slot.size();
   p->remote.assign(n_slots, 0);
+  p->dg_client.clear();  // a DiffPrep may be reused from room to room
+  p->dg_start.clear();
+  p->dg_len.clear();
+  p->d_clock.clear();
+  p->d_len.clear();
   for (int64_t i = 0; i < n_sv; i++) {
     auto it = m->slot_of_client.find(sv_clients[i]);
     if (it != m->slot_of_client.end())
@@ -2280,103 +2289,197 @@ extern "C" int64_t ytpu_encode_v1(
 namespace {
 
 
-// full-native sync encode: rows beyond a remote state vector, written
-// straight from the mirror state (reference encodeStateAsUpdate,
-// encoding.js:490-526 + writeClientsStructs :94-116).  Returns bytes
-// written, -7 when a selected row needs the Python spill path (V2-framed
-// embed/format/type payloads), <0 on writer errors.
-int64_t mirror_encode_diff(Mirror* m, const int64_t* sv_clients,
-                           const int64_t* sv_clocks, int64_t n_sv,
-                           const int64_t* ds_ranges, int64_t n_ds_override,
-                           int ds_override, uint8_t* out, uint64_t cap) {
+const uint8_t kNoBuf = 0;  // stands in for an empty buffer table or blob
+
+// what one V1 diff encode selects: the write groups and the 18 per-row
+// columns ytpu_encode_v1 reads, the DS section, the buffer table.  Kept
+// by the caller, so a call over many rooms allocates once.
+struct DiffCols {
   DiffPrep prep;
-  build_diff_prep(m, sv_clients, sv_clocks, n_sv, ds_ranges, n_ds_override,
-                  ds_override, &prep);
-  auto& remote = prep.remote;
-  // slots in descending client order ("heavily improves the conflict
-  // algorithm", encoding.js:112)
-  auto& slot_order = prep.slot_order;
-  // selected rows, flat in group order
   std::vector<int64_t> g_client, g_start, g_len;
   std::vector<int64_t> c_clock, c_len, c_ofs, c_oc, c_ok, c_rc, c_rk, c_ref;
   std::vector<int64_t> c_no, c_nl, c_so, c_sl, c_pc, c_pk;
   std::vector<int64_t> c_sk, c_sb, c_sofs, c_send;
+  std::vector<const uint8_t*> bptrs;
+  std::vector<uint64_t> blens;
+  // per slot, where the rows selected begin in its fragment index
+  std::vector<size_t> first_frag;
+  // no encode of the selection is longer than this
+  int64_t bound = 0;
+
+  std::array<std::vector<int64_t>*, 18> row_cols() {
+    return {{&c_clock, &c_len, &c_ofs, &c_oc, &c_ok, &c_rc, &c_rk, &c_ref,
+             &c_no, &c_nl, &c_so, &c_sl, &c_pc, &c_pk, &c_sk, &c_sb,
+             &c_sofs, &c_send}};
+  }
+};
+
+// rows beyond a remote state vector, selected from the mirror state in
+// write order (reference encodeStateAsUpdate, encoding.js:490-526 +
+// writeClientsStructs :94-116).  0, or -7 when a selected row needs the
+// Python spill path (V2-framed embed/format/type payloads).
+int64_t select_diff(Mirror* m, const int64_t* sv_clients,
+                    const int64_t* sv_clocks, int64_t n_sv,
+                    const int64_t* ds_ranges, int64_t n_ds_override,
+                    int ds_override, DiffCols* d) {
+  build_diff_prep(m, sv_clients, sv_clocks, n_sv, ds_ranges, n_ds_override,
+                  ds_override, &d->prep);
+  auto& remote = d->prep.remote;
+  // slots in descending client order ("heavily improves the conflict
+  // algorithm", encoding.js:112)
+  auto& slot_order = d->prep.slot_order;
+  // a slot's fragments are sorted by clock and do not overlap, so the
+  // rows that end beyond the remote clock are a suffix of its index:
+  // find where each begins, and the count of rows selected is known
+  // before a column is written
+  std::vector<size_t>& first = d->first_frag;
+  first.assign(remote.size(), 0);
+  size_t n_sel = 0;
+  for (size_t si : slot_order) {
+    const auto& fc = m->frag_clock[si];
+    const auto& fr = m->frag_row[si];
+    int64_t rem = remote[si];
+    size_t i = 0;
+    if (rem > 0) {
+      i = (size_t)(std::upper_bound(fc.begin(), fc.end(), rem) - fc.begin());
+      if (i > 0) {
+        int64_t r = fr[i - 1];
+        if (m->r_clock[r] + m->r_len[r] > rem) i--;
+      }
+    }
+    first[si] = i;
+    n_sel += fr.size() - i;
+  }
+  for (auto* v : d->row_cols()) {
+    v->clear();
+    v->reserve(n_sel);
+  }
+  d->g_client.clear();
+  d->g_start.clear();
+  d->g_len.clear();
+  int64_t bound = 32;
   for (size_t si : slot_order) {
     int64_t rem = remote[si];
-    size_t start = c_clock.size();
-    for (int64_t r : m->frag_row[si]) {
-      int64_t end = m->r_clock[r] + m->r_len[r];
-      if (end <= rem) continue;
+    size_t start = d->c_clock.size();
+    const auto& fr = m->frag_row[si];
+    for (size_t fi = first[si]; fi < fr.size(); fi++) {
+      int64_t r = fr[fi];
       const ContentDesc& c = m->r_c[(size_t)r];
       if (c.kind == kKindV2Lazy || c.kind == kKindSpill) return -7;
       int64_t off = std::max<int64_t>(0, rem - m->r_clock[r]);
-      c_clock.push_back(m->r_clock[r]);
-      c_len.push_back(m->r_len[r]);
-      c_ofs.push_back(off);
-      c_oc.push_back(m->r_oslot[r] == kNull
-                         ? kNull
-                         : m->client_of_slot[(size_t)m->r_oslot[r]]);
-      c_ok.push_back(m->r_oclock[r]);
-      c_rc.push_back(m->r_rslot[r] == kNull
-                         ? kNull
-                         : m->client_of_slot[(size_t)m->r_rslot[r]]);
-      c_rk.push_back(m->r_rclock[r]);
-      c_ref.push_back(m->r_ref[r]);
+      d->c_clock.push_back(m->r_clock[r]);
+      d->c_len.push_back(m->r_len[r]);
+      d->c_ofs.push_back(off);
+      d->c_oc.push_back(m->r_oslot[r] == kNull
+                            ? kNull
+                            : m->client_of_slot[(size_t)m->r_oslot[r]]);
+      d->c_ok.push_back(m->r_oclock[r]);
+      d->c_rc.push_back(m->r_rslot[r] == kNull
+                            ? kNull
+                            : m->client_of_slot[(size_t)m->r_rslot[r]]);
+      d->c_rk.push_back(m->r_rclock[r]);
+      d->c_ref.push_back(m->r_ref[r]);
       int64_t sg = m->r_seg[r];
       int64_t ni = sg == kNull ? kNull : m->seg_name_id[sg];
       int64_t sui = sg == kNull ? kNull : m->seg_sub_id[sg];
       int64_t pr = sg == kNull ? kNull : m->seg_parent[sg];
-      c_no.push_back(ni == kNull ? kNull : m->intern_ofs[(size_t)ni]);
-      c_nl.push_back(ni == kNull ? 0 : m->intern_len[(size_t)ni]);
-      c_so.push_back(sui == kNull ? kNull : m->intern_ofs[(size_t)sui]);
-      c_sl.push_back(sui == kNull ? 0 : m->intern_len[(size_t)sui]);
-      c_pc.push_back(
+      int64_t nl = ni == kNull ? 0 : m->intern_len[(size_t)ni];
+      int64_t sl = sui == kNull ? 0 : m->intern_len[(size_t)sui];
+      d->c_no.push_back(ni == kNull ? kNull : m->intern_ofs[(size_t)ni]);
+      d->c_nl.push_back(nl);
+      d->c_so.push_back(sui == kNull ? kNull : m->intern_ofs[(size_t)sui]);
+      d->c_sl.push_back(sl);
+      d->c_pc.push_back(
           pr == kNull ? kNull
                       : m->client_of_slot[(size_t)m->r_slot[(size_t)pr]]);
-      c_pk.push_back(pr == kNull ? 0 : m->r_clock[(size_t)pr]);
-      c_sk.push_back(m->r_is_gc[r] ? kSrcNone : c.kind);
-      c_sb.push_back(c.buf);
-      c_sofs.push_back(c.ofs);
-      c_send.push_back(c.end);
+      d->c_pk.push_back(pr == kNull ? 0 : m->r_clock[(size_t)pr]);
+      d->c_sk.push_back(m->r_is_gc[r] ? kSrcNone : c.kind);
+      d->c_sb.push_back(c.buf);
+      d->c_sofs.push_back(c.ofs);
+      d->c_send.push_back(c.end);
+      // info byte, four ids, parent info with both strings, the
+      // content's own length prefix and a cut surrogate's U+FFFD: 96
+      // covers them at ten bytes a varuint
+      bound += 96 + nl + sl +
+               ((c.end >= 0 && c.ofs >= 0) ? (c.end - c.ofs) : 16);
     }
-    if (c_clock.size() > start) {
-      g_client.push_back(m->client_of_slot[si]);
-      g_start.push_back((int64_t)start);
-      g_len.push_back((int64_t)(c_clock.size() - start));
+    if (d->c_clock.size() > start) {
+      d->g_client.push_back(m->client_of_slot[si]);
+      d->g_start.push_back((int64_t)start);
+      d->g_len.push_back((int64_t)(d->c_clock.size() - start));
+      bound += 32;
     }
   }
-  // DS section (built by build_diff_prep)
-  auto& dg_client = prep.dg_client;
-  auto& dg_start = prep.dg_start;
-  auto& dg_len = prep.dg_len;
-  auto& d_clock = prep.d_clock;
-  auto& d_len = prep.d_len;
-  std::vector<const uint8_t*> bptrs;
-  std::vector<uint64_t> blens;
+  d->bound = bound + 32 * (int64_t)d->prep.dg_client.size() +
+             24 * (int64_t)d->prep.d_clock.size();
+  d->bptrs.clear();
+  d->blens.clear();
+  d->bptrs.reserve(m->bufs.size() + 1);
+  d->blens.reserve(m->bufs.size() + 1);
   for (auto& [p, ln] : m->bufs) {
-    bptrs.push_back(p);
-    blens.push_back(ln);
+    d->bptrs.push_back(p);
+    d->blens.push_back(ln);
   }
-  static const uint8_t kNoBuf = 0;
-  if (bptrs.empty()) {
-    bptrs.push_back(&kNoBuf);
-    blens.push_back(0);
+  if (d->bptrs.empty()) {
+    d->bptrs.push_back(&kNoBuf);
+    d->blens.push_back(0);
   }
+  return 0;
+}
+
+// the selection written as one V1 update.  Returns bytes written, <0 on
+// writer errors (-2: `cap` was too small).
+int64_t write_diff(Mirror* m, DiffCols& d, uint8_t* out, uint64_t cap) {
   static const int64_t kZero = 0;
   auto dat = [](std::vector<int64_t>& v) {
     return v.empty() ? &kZero : v.data();
   };
+  auto& p = d.prep;
   return ytpu_encode_v1(
-      bptrs.data(), blens.data(), bptrs.size(),
-      dat(g_client), dat(g_start), dat(g_len), g_client.size(),
-      dat(c_clock), dat(c_len), dat(c_ofs),
-      dat(c_oc), dat(c_ok), dat(c_rc), dat(c_rk), dat(c_ref),
-      dat(c_no), dat(c_nl), dat(c_so), dat(c_sl), dat(c_pc), dat(c_pk),
-      dat(c_sk), dat(c_sb), dat(c_sofs), dat(c_send),
+      d.bptrs.data(), d.blens.data(), d.bptrs.size(),
+      dat(d.g_client), dat(d.g_start), dat(d.g_len), d.g_client.size(),
+      dat(d.c_clock), dat(d.c_len), dat(d.c_ofs),
+      dat(d.c_oc), dat(d.c_ok), dat(d.c_rc), dat(d.c_rk), dat(d.c_ref),
+      dat(d.c_no), dat(d.c_nl), dat(d.c_so), dat(d.c_sl), dat(d.c_pc),
+      dat(d.c_pk), dat(d.c_sk), dat(d.c_sb), dat(d.c_sofs), dat(d.c_send),
       m->strings.empty() ? &kNoBuf : m->strings.data(), m->strings.size(),
-      dat(dg_client), dat(dg_start), dat(dg_len), dg_client.size(),
-      dat(d_clock), dat(d_len), out, cap);
+      dat(p.dg_client), dat(p.dg_start), dat(p.dg_len), p.dg_client.size(),
+      dat(p.d_clock), dat(p.d_len), out, cap);
 }
+
+// full-native sync encode into a buffer of the caller's.  Returns bytes
+// written, -7 for the Python spill path, <0 on writer errors.
+int64_t mirror_encode_diff(Mirror* m, const int64_t* sv_clients,
+                           const int64_t* sv_clocks, int64_t n_sv,
+                           const int64_t* ds_ranges, int64_t n_ds_override,
+                           int ds_override, uint8_t* out, uint64_t cap) {
+  DiffCols d;
+  int64_t rc = select_diff(m, sv_clients, sv_clocks, n_sv, ds_ranges,
+                           n_ds_override, ds_override, &d);
+  if (rc != 0) return rc;
+  return write_diff(m, d, out, cap);
+}
+
+// the bytes of the last ymx_encode_steps_many this thread made: the
+// library keeps the buffer, so no room's encode allocates its own (and
+// none is zero-filled first, which a std::vector's resize would do)
+struct EncodeArena {
+  std::unique_ptr<uint8_t[]> p;
+  size_t len = 0, cap = 0;
+
+  // room for `n` more bytes at the end; what is there stays
+  uint8_t* tail(size_t n) {
+    if (len + n > cap) {
+      size_t grown = std::max(cap * 2, len + n);
+      std::unique_ptr<uint8_t[]> q(new uint8_t[grown]);
+      if (len) std::memcpy(q.get(), p.get(), len);
+      p = std::move(q);
+      cap = grown;
+    }
+    return p.get() + len;
+  }
+};
+thread_local EncodeArena g_encode_arena;
 
 }  // namespace
 
@@ -2423,10 +2526,11 @@ int64_t ymx_buf_len(void* h, int64_t idx) {
 }
 
 // run the flush pipeline over the staged updates (buf ids + v2 flags).
-// out_counts (int64[14]): n_rows, n_splits, n_sched, [3..5] reserved (0:
+// out_counts (int64[16]): n_rows, n_splits, n_sched, [3..5] reserved (0:
 // cached plans and the packer index this layout), n_delete_rows,
 // n_applied_ds, has_pending, pending_depth, n_slots, n_segs, n_links,
-// n_heads.  Returns 0 or an error code (<0).
+// n_heads, [14] 0 (ymx_prepare_many's dense-link flag), [15] the plan's
+// number (Mirror::plan_seq).  Returns 0 or an error code (<0).
 int ymx_prepare(void* h, const int64_t* buf_ids, const int64_t* v2_flags,
                 int64_t n_updates, int64_t* out_counts) {
   Mirror* m = static_cast<Mirror*>(h);
@@ -2446,6 +2550,8 @@ int ymx_prepare(void* h, const int64_t* buf_ids, const int64_t* v2_flags,
   out_counts[11] = m->n_segs();
   out_counts[12] = (int64_t)m->plan.link_rows.size();
   out_counts[13] = (int64_t)m->plan.head_segs.size();
+  out_counts[14] = 0;
+  out_counts[15] = (int64_t)m->plan_seq;
   return 0;
 }
 
@@ -2479,7 +2585,8 @@ void ymx_plan_segment_stats(int64_t* out) {
 
 // batched twin of ymx_prepare: one call plans EVERY staged doc, writing a
 // 16-wide counts row per doc ([0..13] = ymx_prepare's layout, [14] =
-// dense-link flag: link_rows == [0..n_rows)) and a per-doc rc.  Kills the
+// dense-link flag: link_rows == [0..n_rows), [15] = the plan's number)
+// and a per-doc rc.  Kills the
 // per-doc Python/ctypes round trip that dominated distinct-doc flushes.
 // Per-doc plans are independent (each touches only its own Mirror; the
 // only shared data are the const update bytes), so the loop fans out over
@@ -2519,7 +2626,7 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
              m->plan.link_rows.back() == k - 1)
                 ? 1
                 : 0;
-    c[15] = 0;
+    c[15] = (int64_t)m->plan_seq;
   };
   int nt = plan_pool_width();
   if (nt > (int)n_docs) nt = (int)n_docs;
@@ -2596,6 +2703,7 @@ int64_t ymx_clone_state(void* dst_h, void* src_h) {
   d->pending_ds = s->pending_ds;
 
   d->plan = s->plan;
+  d->plan_seq++;  // dst's own count: its previous plan is gone
   d->gen = s->gen;
   d->dl_mark = s->dl_mark;
   d->dh_mark = s->dh_mark;
@@ -3028,6 +3136,75 @@ int64_t ymx_encode_diff_v2(void* h, const int64_t* sv_clients,
   std::memcpy(out, bytes.data(), bytes.size());
   return (int64_t)bytes.size();
 }
+
+// the step updates of a flush, every planned room in one call (the
+// batched twin of ymx_encode_diff as the emit phase uses it; V1).  Room i
+// is encoded against the state vector sv_*[sv_ofs[i]..sv_ofs[i+1]) with
+// the DS section ds_mode[i] names:
+//   0  the applied delete set of the mirror's own current plan, read in
+//      place; plan_seq[i] is the number the plan was handed out under
+//      (counts[15]), and a mirror that has planned again since is
+//      refused with -8, not encoded
+//   1  the triples ds_triples[3*ds_ofs[i]..3*ds_ofs[i+1])
+//   2  the mirror's whole derived delete set (encodeStateAsUpdate, a sync
+//      step 2)
+// The bytes land back to back in this thread's arena (ymx_encode_arena),
+// room i at out_ofs[i]..out_ofs[i+1]; out_rc[i] is 0, -7 where the room
+// needs the Python writer, another negative on a writer error.  Every
+// byte is what ymx_encode_diff writes for the same room.  Returns the
+// arena's length.
+int64_t ymx_encode_steps_many(void** hs, int64_t n_docs,
+                              const int64_t* sv_ofs,
+                              const int64_t* sv_clients,
+                              const int64_t* sv_clocks,
+                              const int64_t* ds_mode,
+                              const int64_t* plan_seq, const int64_t* ds_ofs,
+                              const int64_t* ds_triples, int64_t* out_ofs,
+                              int64_t* out_rc) {
+  auto& arena = g_encode_arena;
+  // a cold load's arena (15 KB a room) is not kept for keystrokes
+  if (arena.cap > ((size_t)16 << 20) && arena.len * 4 < arena.cap) {
+    arena.p.reset();
+    arena.cap = 0;
+  }
+  arena.len = 0;
+  DiffCols d;
+  for (int64_t i = 0; i < n_docs; i++) {
+    Mirror* m = static_cast<Mirror*>(hs[i]);
+    out_ofs[i] = (int64_t)arena.len;
+    const int64_t* ds = nullptr;
+    int64_t n_ds = 0;
+    int override_ds = 1;
+    if (ds_mode[i] == 0) {
+      if ((int64_t)m->plan_seq != plan_seq[i]) {
+        out_rc[i] = -8;
+        continue;
+      }
+      ds = m->plan.applied_ds.empty() ? nullptr
+                                      : m->plan.applied_ds[0].data();
+      n_ds = (int64_t)m->plan.applied_ds.size();
+    } else if (ds_mode[i] == 1) {
+      ds = ds_triples + 3 * ds_ofs[i];
+      n_ds = ds_ofs[i + 1] - ds_ofs[i];
+    } else {
+      override_ds = 0;
+    }
+    int64_t lo = sv_ofs[i];
+    int64_t rc = select_diff(m, sv_clients + lo, sv_clocks + lo,
+                             sv_ofs[i + 1] - lo, ds, n_ds, override_ds, &d);
+    if (rc == 0) {
+      rc = write_diff(m, d, arena.tail((size_t)d.bound), (uint64_t)d.bound);
+      if (rc > 0) arena.len += (size_t)rc;
+    }
+    out_rc[i] = rc < 0 ? rc : 0;
+  }
+  out_ofs[n_docs] = (int64_t)arena.len;
+  return (int64_t)arena.len;
+}
+
+const uint8_t* ymx_encode_arena() { return g_encode_arena.p.get(); }
+
+uint64_t ymx_plan_seq(void* h) { return static_cast<Mirror*>(h)->plan_seq; }
 
 // compaction from the mirror's OWN list/deleted state — the flush
 // invariant keeps these equal to the device arrays, so no device

@@ -163,6 +163,14 @@ FLUSH_METRICS_SCHEMA: dict = {
     # bytes of device rows the releases since the previous flush blanked
     # in place (reset_doc: one whole row of each resident table a slot)
     "release_blanked_bytes": 0,
+    # the broadcast updates of this flush (0 when nobody listens for
+    # updates): rooms encoded by the one native call over all planned
+    # rooms (ymx_encode_steps_many), rooms that took the per-room
+    # encode_step_update (the Python planner's, and those whose payloads
+    # the native writer refuses), and the bytes handed to the listeners
+    "emit_batched": 0,
+    "emit_fallback": 0,
+    "emit_bytes": 0,
     # max device dispatches in flight at once (0 = no dispatch or
     # synchronous mode; the double-buffered staging pair bounds it)
     "pipeline_depth": 0,

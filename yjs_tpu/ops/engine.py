@@ -34,6 +34,8 @@ from . import plan_cache
 from . import segment_planner
 from .native_mirror import (
     NativeMirror,
+    NativePlan,
+    encode_steps_many,
     native_plan_available,
     pack_apply_lanes,
     plan_segment_stats,
@@ -1275,12 +1277,60 @@ class BatchEngine:
             observed=set(observing), native=use_batch,
         )
 
-    def _emit_phase(self, plans, pre_svs, emitting, observed=None) -> None:
+    def _encode_steps(self, plans, pre_svs, counts, metrics) -> list:
+        """The flush's broadcast updates as ``(doc, bytes | None)`` in
+        ``plans`` order.  Every room whose mirror is a NativeMirror is
+        encoded by ONE native call (``encode_steps_many``), its plan's
+        applied delete set read where the planner left it; a room the
+        native writer refuses (V2-framed or spilled payloads) and every
+        room of the Python planner take ``encode_step_update``, as all did
+        before.  Which is read off the room (its mirror's type, the
+        call's return code), as ``use_batch`` is for plans."""
+        batch = []
+        for i, p in plans.items():
+            m = self.mirrors[i]
+            if isinstance(m, NativeMirror) and (
+                p is None or isinstance(p, NativePlan)
+            ):
+                c = counts[i] if p is None else p.counts
+                batch.append((i, m, int(c[15])))
+        encoded: dict = {}
+        if batch:
+            updates, rcs = encode_steps_many(batch, pre_svs)
+            encoded = {
+                i: u
+                for (i, _m, _s), u, rc in zip(batch, updates, rcs.tolist())
+                if rc >= 0
+            }
+        out = []
+        n_bytes = 0
+        for i, p in plans.items():
+            if i in encoded:
+                u = encoded[i]
+            else:
+                m = self.mirrors[i]
+                if p is None:
+                    p = m.make_plan(counts[i])
+                u = m.encode_step_update(pre_svs[i], p)
+            if u is not None:
+                n_bytes += len(u)
+            out.append((i, u))
+        metrics["emit_batched"] = len(encoded)
+        metrics["emit_fallback"] = len(plans) - len(encoded)
+        metrics["emit_bytes"] = n_bytes
+        return out
+
+    def _emit_phase(
+        self, plans, pre_svs, emitting, metrics, observed=None, counts=None,
+    ) -> None:
         """Post-dispatch host work shared by both dispatch paths: update-log
         compaction + doc.on('update') novelty emission (overlaps the async
         device execution).  ``observed`` restricts event computation to a
         prepare-time listener snapshot (the batched path may not have
-        built plan.sched for docs unobserved at prepare)."""
+        built plan.sched for docs unobserved at prepare).  ``counts`` maps
+        a doc to its native plan's counts row where ``plans`` holds None
+        for it (the native path builds plan objects for observed docs
+        only)."""
         if self.health.tracked:
             # every doc that reached emit integrated cleanly this flush
             for i in plans:
@@ -1291,8 +1341,9 @@ class BatchEngine:
                 if len(self._update_log[i]) > 64 and not m.has_pending():
                     self._update_log[i] = [(m.encode_state_as_update(), False)]
         if emitting:
-            for i, p in plans.items():
-                u = self.mirrors[i].encode_step_update(pre_svs[i], p)
+            # encode all, then fan out in order: no listener runs between
+            # two rooms' encodes
+            for i, u in self._encode_steps(plans, pre_svs, counts, metrics):
                 if u is not None:
                     self._emit(i, u)
         if self._event_listeners:
@@ -1444,19 +1495,23 @@ class BatchEngine:
         with self._phase_ctx("emit"):
             if native:
                 # real plan objects only where the emit phase will read
-                # them: every doc when update listeners exist, observed
-                # docs for events; the log-compaction walk touches keys
-                # only.  The observed set is the PREPARE-TIME snapshot: a
+                # them: observed docs, for events (the batched encode
+                # reads a plan's delete set in the core, by its counts
+                # row); the log-compaction walk touches keys only.  The
+                # observed set is the PREPARE-TIME snapshot: a
                 # listener registered mid-flush (e.g. from an update
                 # callback) sees events from the next flush — plan.sched
                 # for this one may not have been built (want_sched gate)
                 plans = {
-                    i: (m.make_plan(c) if emitting or i in observed else None)
+                    i: (m.make_plan(c) if i in observed else None)
                     for i, m, c in work_ok
                 }
-                self._emit_phase(plans, pre_svs, emitting, observed=observed)
+                self._emit_phase(
+                    plans, pre_svs, emitting, metrics, observed=observed,
+                    counts={i: c for i, _m, c in work_ok},
+                )
             else:
-                self._emit_phase(dict(work_ok), pre_svs, emitting)
+                self._emit_phase(dict(work_ok), pre_svs, emitting, metrics)
         t_emit = time.perf_counter()
 
         if native:

@@ -94,8 +94,9 @@ class NativePlan:
     """Array-backed step plan (the C++ twin of :class:`StepPlan`).
 
     ``splits``/``sched``/``delete_rows`` are numpy arrays (use ``len()``,
-    not truthiness); ``applied_ds`` is a plain list of tuples for the
-    encode path."""
+    not truthiness); ``applied_ds`` is a plain list of tuples, fetched
+    when someone reads it (events, the per-room encode: the flush's
+    batched encode reads the ranges in place, ``encode_steps_many``)."""
 
     def __init__(self, lib, h, counts, mirror):
         self.n_rows, n_splits, n_sched = (int(x) for x in counts[:3])
@@ -108,7 +109,7 @@ class NativePlan:
         # staleness guard for lazy sections: the C++ plan buffers are
         # overwritten by the mirror's next prepare
         self._mirror = mirror
-        self._seq = mirror._plan_seq
+        self._seq = int(counts[15])
         self._n_sched = n_sched
         # hot-path sections fetched eagerly (the bulk apply + split count)
         self.splits = np.empty((n_splits, 2), np.int64)
@@ -149,7 +150,7 @@ class NativePlan:
             ads = np.empty((self._n_ads, 3), np.int64)
             if self._n_ads:
                 self._lib.ymx_plan_applied_ds(self._h, _p64(ads))
-            self._applied = [tuple(row) for row in ads.tolist()]
+            self._applied = list(map(tuple, ads.tolist()))
         return self._applied
 
 
@@ -186,7 +187,6 @@ class NativeMirror:
         # spill/encode paths realize through the descriptor columns
         self._py.realized_content = self.realized_content
         self._synced_gen = -1
-        self._plan_seq = 0
         # mirrors counts[8] of the last prepare: lets the engine skip the
         # per-doc ymx_has_pending call when binning flush work
         self._had_pending = False
@@ -211,6 +211,13 @@ class NativeMirror:
 
     def ingest(self, update: bytes, v2: bool = False) -> None:
         self._incoming.append((update, v2))
+
+    @property
+    def _plan_seq(self) -> int:
+        """The number of the core's current plan: every prepare and every
+        adopted snapshot overwrites the plan buffers and moves it.  A
+        plan's own number rides in its counts row (``counts[15]``)."""
+        return int(self._lib.ymx_plan_seq(self._h))
 
     def _stage_bufs(self):
         """Register the staged updates with the core; returns
@@ -250,7 +257,6 @@ class NativeMirror:
         mirror wrapped by the engine."""
         self._lib.ymx_clone_state(self._h, entry.h)
         self._incoming = []
-        self._plan_seq += 1
         self._had_pending = bool(entry.counts[8])
         # the clone's borrowed buffer pointers reference the source's
         # pinned update payloads; share the pins to keep them alive
@@ -258,7 +264,9 @@ class NativeMirror:
         self._realized.clear()
         self._synced_gen = -1  # force a full shadow rebuild on next _sync
         self.plan_frontier = entry.frontier_after
-        return np.array(entry.counts, np.int64, copy=True)
+        counts = np.array(entry.counts, np.int64, copy=True)
+        counts[15] = self._plan_seq  # the clone's number, not the source's
+        return counts
 
     def _finish_prepare(self, rc, staged, ids, counts) -> None:
         """Post-prepare bookkeeping shared by the per-doc and batched
@@ -266,7 +274,6 @@ class NativeMirror:
         lib, h = self._lib, self._h
         n_up = len(staged)
         self._incoming = []
-        self._plan_seq += 1
         self._had_pending = bool(counts[8])
         if rc != 0:
             # the core may have merged a prefix before failing — this
@@ -725,7 +732,8 @@ def prepare_many(work, want_sched: bool = True, obs=None):
 
     ``work`` is a list of ``(doc_idx, NativeMirror)``.  Returns
     ``(counts, rcs, staged_info)`` where ``counts`` is an ``(n, 16)``
-    int64 array (ymx_prepare layout + ``[14]`` = dense-link flag),
+    int64 array (ymx_prepare layout + ``[14]`` = dense-link flag;
+    ``[15]`` numbers the plan, see ``NativeMirror._plan_seq``),
     ``rcs`` the per-doc return codes, and ``staged_info`` the
     per-doc ``(staged, ids)`` needed by ``_finish_prepare``.
 
@@ -806,6 +814,76 @@ def prepare_many(work, want_sched: bool = True, obs=None):
 
     kernel_profiler().record_host_op("prepare_many", dt)
     return counts, rcs, staged_info
+
+
+def encode_steps_many(work, pre_svs):
+    """The step updates of many NativeMirrors from ONE native call
+    (``ymx_encode_steps_many``): what ``encode_step_update`` gives room by
+    room, byte for byte, V1.
+
+    ``work`` is a list of ``(doc_idx, NativeMirror, ds)``; ``pre_svs`` maps
+    ``doc_idx`` to the state vector the room's peers hold (missing or
+    empty: the whole room).  ``ds`` names the delete-set section: an
+    ``int`` is the number of the mirror's own current plan
+    (``counts[15]``), whose applied delete set the core then reads in
+    place; a sequence of ``(client, clock, len)`` triples is written as
+    given; ``None`` is the room's whole derived delete set (the form
+    ``encode_state_as_update`` and a sync step 2 send).
+
+    Returns ``(updates, rcs)``, both as long as ``work``.  ``rcs[k] < 0``
+    means the core wrote nothing for that room: ``-7`` a selected row
+    needs the Python writer (V2-framed or spilled payloads), ``-8`` the
+    mirror has planned again since the plan numbered ``ds``; the caller
+    takes such a room through ``encode_step_update``.  ``updates[k]`` is
+    ``None`` there, and where the update carries nothing (the V1 form of
+    that is two zero bytes)."""
+    n = len(work)
+    lib = work[0][1]._lib
+    handles = (ctypes.c_void_p * n)()
+    sv_ofs, ds_ofs, modes, seqs = [0], [0], [], []
+    svc: list[int] = []
+    svk: list[int] = []
+    triples: list = []
+    for k, (i, m, ds) in enumerate(work):
+        handles[k] = m._h
+        sv = pre_svs.get(i)
+        if sv:
+            svc.extend(sv.keys())
+            svk.extend(sv.values())
+        sv_ofs.append(len(svc))
+        mode = seq = 0
+        if ds is None:
+            mode = 2
+        elif isinstance(ds, int):
+            seq = ds
+        else:
+            mode = 1
+            triples.extend(ds)
+        modes.append(mode)
+        seqs.append(seq)
+        ds_ofs.append(len(triples))
+    flat = [
+        np.array(a, np.int64)
+        for a in (
+            sv_ofs, svc or [0], svk or [0], modes, seqs, ds_ofs,
+            triples or [0],
+        )
+    ]
+    out_ofs = np.zeros(n + 1, np.int64)
+    rcs = np.zeros(n, np.int64)
+    lib.ymx_encode_steps_many(
+        handles, n, *(_p64(a) for a in flat), _p64(out_ofs), _p64(rcs)
+    )
+    base = lib.ymx_encode_arena()
+    updates: list = [None] * n
+    ofs = out_ofs.tolist()
+    for k, rc in enumerate(rcs.tolist()):
+        if rc < 0:
+            continue
+        u = ctypes.string_at(base + ofs[k], ofs[k + 1] - ofs[k])
+        if u != b"\x00\x00":
+            updates[k] = u
+    return updates, rcs
 
 
 def pack_apply_lanes(work, doc_ids, b_loc, n_shards, widths, oob_r, oob_s,
