@@ -31,12 +31,15 @@ import yjs_tpu as Y
 ALPHABET = "abcdefghijklmnopqrstuvwxyz     eettaaoinshr"
 
 
-def generate(n_inserts: int = 182_000, n_deletes: int = 77_000, seed: int = 13):
+def generate(
+    n_inserts: int = 182_000, n_deletes: int = 77_000, seed: int = 13,
+    clients: tuple[int, int] = (101, 202),
+):
     gen = random.Random(seed)
     a = Y.Doc(gc=False)
-    a.client_id = 101
+    a.client_id = clients[0]
     b = Y.Doc(gc=False)
-    b.client_id = 202
+    b.client_id = clients[1]
 
     def sync():
         ua = Y.encode_state_as_update(a, Y.encode_state_vector(b))

@@ -557,3 +557,88 @@ def test_pool_width_engine_state_identical(monkeypatch):
     assert out1 == out4
     assert (l1 == l4).all()
     assert (d1 == d4).all()
+
+
+@pytest.mark.parametrize("want_sched", [True, False], ids=["sched", "no_sched"])
+def test_prepare_many_longest_first_is_index_order(monkeypatch, want_sched):
+    """``ymx_prepare_many`` hands its pool the call's long rooms first
+    (four times its mean staged bytes or more, longest first), then the
+    others in index order.  That is a permutation of the work index and
+    nothing else:
+    counts, return codes, plans and the updates encoded from them are
+    what index order (the serial path, one thread) gives, room by room;
+    and the pool's own clock comes back with them."""
+    import numpy as np
+
+    from yjs_tpu.ops.native_mirror import encode_steps_many, prepare_many
+
+    rng = random.Random(34)
+
+    def session(seed, n_ops):
+        docs = [Y.Doc(gc=False), Y.Doc(gc=False)]
+        for c, d in enumerate(docs):
+            d.client_id = 10 * (seed + 1) + c
+        for _ in range(n_ops):
+            d = rng.choice(docs)
+            t = d.get_text("text")
+            ln = len(t.to_string())
+            if ln and rng.random() < 0.3:
+                pos = rng.randrange(ln)
+                t.delete(pos, min(rng.randint(1, 4), ln - pos))
+            else:
+                t.insert(rng.randint(0, ln), rng.choice(["ab", "c ", "xyz"]))
+            if rng.random() < 0.1:
+                Y.apply_update(docs[1], Y.encode_state_as_update(docs[0]))
+                Y.apply_update(docs[0], Y.encode_state_as_update(docs[1]))
+        Y.apply_update(docs[0], Y.encode_state_as_update(docs[1]))
+        return Y.encode_state_as_update(docs[0])
+
+    # short rooms with the long ones last, as a deployment's slots hold
+    # them; one room brings two updates, one a malformed one
+    sizes = [20, 35, 10, 25, 30, 15, 40, 20, 25, 30, 15, 20, 35, 10, 25, 30]
+    sizes += [500, 900]
+    updates = [[session(s, n)] for s, n in enumerate(sizes)]
+    updates[1].append(session(100, 5))
+    updates[4] = [b"\x01\xff\xff\xff"]
+    # the last two are long by the pool's rule, and go first, the longer
+    # of them before the other; no other room is
+    staged = [sum(map(len, ups)) for ups in updates]
+    long = [4 * sum(staged) <= n * len(staged) for n in staged]
+    assert long == [False] * 16 + [True, True] and staged[-1] > staged[-2]
+
+    def plan(threads):
+        monkeypatch.setenv("YTPU_PLAN_THREADS", threads)
+        work = []
+        for i, ups in enumerate(updates):
+            m = NativeMirror("text")
+            for u in ups:
+                m.ingest(u)
+            work.append((i, m))
+        counts, rcs, staged, times = prepare_many(work, want_sched=want_sched)
+        plans, ok = [], []
+        for k, (i, m) in enumerate(work):
+            if rcs[k] != 0:
+                plans.append(None)
+                continue
+            m._finish_prepare(int(rcs[k]), staged[k][0], staged[k][1], counts[k])
+            p = m.make_plan(counts[k])
+            plans.append((
+                p.splits.tolist(), p.sched.tolist(),
+                sorted(p.delete_rows.tolist()), sorted(p.applied_ds),
+                p.link_rows.tolist(), p.link_vals.tolist(),
+                p.head_segs.tolist(), p.head_vals.tolist(),
+            ))
+            ok.append((i, m, int(counts[k][15])))
+        encoded, erc = encode_steps_many(ok, {})
+        # a plan's number counts its mirror's prepares: the same either way
+        return counts.copy(), rcs.tolist(), plans, encoded, erc.tolist(), times
+
+    c1, rc1, p1, e1, erc1, t1 = plan("1")
+    c4, rc4, p4, e4, erc4, t4 = plan("4")
+    assert rc1 == rc4 and rc1[4] != 0 and sum(map(bool, rc1)) == 1
+    np.testing.assert_array_equal(c1, c4)
+    assert p1 == p4 and e1 == e4 and erc1 == erc4
+    assert all(u is not None for u in e1)
+    for longest, total in (t1, t4):
+        # the longest room's prepare is one of the sum's terms
+        assert 0.0 < longest <= total

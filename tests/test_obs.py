@@ -405,3 +405,26 @@ def test_native_prepare_histograms_on_batched_path():
         return
     assert fam.count == 1
     assert fam.summary()["max"] == 2.0  # both docs planned in one call
+
+
+def test_the_pools_own_clock_rides_the_flush_metrics():
+    """``plan_room_max_s`` / ``plan_pool_s`` are measured inside
+    ``ymx_prepare_many``: the longest room's prepare and the sum over
+    rooms, 0 in a flush that planned nothing cold."""
+    from yjs_tpu.ops.native_mirror import native_plan_available
+
+    if not native_plan_available():
+        pytest.skip("native plan core unavailable")
+    eng = BatchEngine(3)
+    for i, word in enumerate(("one", "two", "three")):
+        eng.queue_update(i, _update(word))
+    eng.flush()
+    m = eng.last_flush_metrics
+    assert 0.0 < m["plan_room_max_s"] <= m["plan_pool_s"] <= 3 * m["plan_room_max_s"]
+    reg = eng.obs.registry
+    assert reg.get("ytpu_plan_pool_seconds_total").value == m["plan_pool_s"]
+    assert reg.get("ytpu_plan_room_max_seconds").value == m["plan_room_max_s"]
+    eng.flush()
+    m = eng.last_flush_metrics
+    assert m["plan_room_max_s"] == m["plan_pool_s"] == 0.0
+    assert reg.get("ytpu_plan_pool_seconds_total").value > 0.0

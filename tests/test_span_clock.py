@@ -344,3 +344,35 @@ def test_importing_obs_does_not_load_jax():
         text=True, timeout=120,
     )
     assert done.returncode == 0, done.stderr[-2000:]
+
+
+def test_staging_spans_open_once_a_block(tmp_path):
+    """A compaction stages its rooms in width classes, one block a class:
+    ``ytpu.compact.alloc``, ``.rebuild``, ``.put`` and ``.scatter`` each
+    open once a block, inside the flush's one ``ytpu.compact``, and
+    ``rows_staged_blocks`` counts the blocks."""
+    prov = provider(tmp_path)
+    prov.engine.compact_min_rows = 1 << 30  # no compaction but the one asked for
+    # a short room and one more than an octave wider: two width classes
+    last = {}
+    for guid, n, client in (("short", 30, 11), ("long", 200, 12)):
+        *typed, last[guid] = keystrokes(n, client)
+        for u in typed:
+            assert prov.receive_update(guid, u)
+    prov.flush()
+    eng = prov.engine
+    docs = [prov.doc_id("short"), prov.doc_id("long")]
+    assert eng.mirrors[docs[0]].n_rows <= 64 and eng.mirrors[docs[1]].n_rows > 128
+    before = len(eng.obs.tracer.trace_events())
+    eng.compact_min_rows = 8
+    for guid, u in last.items():  # the next keystrokes' flush compacts both
+        assert prov.receive_update(guid, u)
+    prov.flush()
+    assert len(eng.last_compaction) == 2
+    assert eng.last_flush_metrics["rows_staged_blocks"] == 2
+    ring = eng.obs.tracer.trace_events()[before:]
+    got = collections.Counter(e["name"] for e in ring if e["ph"] == "X")
+    assert got["ytpu.compact"] == 1
+    for name in ("alloc", "rebuild", "put", "scatter"):
+        assert got[f"ytpu.compact.{name}"] == 2
+    prov.close(checkpoint=False)

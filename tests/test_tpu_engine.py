@@ -774,6 +774,8 @@ class TestStagedWidth:
     N = 16
     WIDE = 3  # the room that sets the table width
     SMALL = [*range(WIDE), *range(WIDE + 1, N)]
+    LONG = {WIDE: 700}  # rooms prepended a character at a time: no row merges
+    CAP = 1024
 
     def _engines(self, mesh):
         eng, ref = _engine_pair(self.N, mesh)
@@ -791,13 +793,13 @@ class TestStagedWidth:
         for rnd in range(14):
             for i, d in enumerate(docs):
                 t = d.get_text("text")
-                if i != self.WIDE:
+                if i not in self.LONG:
                     for ch in f"r{rnd:02d}d{i % 5}, "[: 6 + i % 3]:
                         t.insert(len(t.to_string()), ch)
                     if rnd % 3 == 2:
                         t.delete(0, 4 + i % 3)
                 elif rnd == 0:
-                    for _ in range(700):
+                    for _ in range(self.LONG[i]):
                         t.insert(0, "x")
                 for e in engines:
                     for u in typed[i]:
@@ -812,7 +814,7 @@ class TestStagedWidth:
         return [np.asarray(t) for t in (eng._right, eng._deleted, eng._starts)]
 
     def _assert_same_tables(self, eng, ref):
-        assert eng._cap == ref._cap == 1024
+        assert eng._cap == ref._cap == self.CAP
         for got, want in zip(self._tables(eng), self._tables(ref)):
             assert got.shape == want.shape
             np.testing.assert_array_equal(got, want)
@@ -855,11 +857,14 @@ class TestStagedWidth:
         for i in (0, 1, self.WIDE, 9, self.N - 1):
             assert eng.text(i) == docs[i].get_text("text").to_string()
         if case == "hydrate":
-            # the flush reports the wide room's block of 16 and the four
-            # hydrated rooms' own
-            assert eng.last_flush_metrics["rows_staged_bytes"] == (
-                16 * (1024 * 5 + 8 * 4) + 4 * (64 * 5 + 8 * 4)
+            # the flush reports the fifteen small rooms' block, the wide
+            # room's own and the four hydrated rooms'
+            m = eng.last_flush_metrics
+            assert m["rows_staged_bytes"] == (
+                15 * (128 * 5 + 8 * 4) + (1024 * 5 + 8 * 4)
+                + 4 * (64 * 5 + 8 * 4)
             )
+            assert m["rows_staged_blocks"] == 3
 
     def test_staged_and_held_bytes_read_what_the_shapes_say(self):
         eng, _ref = self._engines(False)
@@ -887,8 +892,136 @@ class TestStagedWidth:
         assert reg.get("ytpu_flush_rows_held_bytes_total").value == (
             m["rows_held_bytes"]
         )
+        assert m["rows_staged_blocks"] == 2
+        assert reg.get("ytpu_flush_rows_staged_blocks_total").value == 2
         eng.flush()
         assert eng.last_flush_metrics["rows_staged_bytes"] == 0
+        assert eng.last_flush_metrics["rows_staged_blocks"] == 0
+
+
+def _stage_one_block(eng):
+    """The staging as it was before rooms were staged in width classes,
+    kept as the reference: one block for all of ``todo``, as wide as its
+    widest room."""
+    from yjs_tpu.ops.engine import _bucket
+
+    def one_block(todo, rebuild, n_rows, n_segs):
+        k = len(todo)
+        w = min(_bucket(max(n_rows)), eng._cap + 1)
+        ws = min(_bucket(max(n_segs), 8), eng._seg_cap + 1)
+        new_right = np.full((k, w), -1, np.int32)
+        new_deleted = np.zeros((k, w), bool)
+        new_starts = np.full((k, ws), -1, np.int32)
+        for j, i in enumerate(todo):
+            r, d, h = rebuild(i)
+            new_right[j, : len(r)] = r
+            new_deleted[j, : len(d)] = d
+            new_starts[j, : len(h)] = h
+        eng._dispatch(
+            "rows", eng._put_r(np.asarray(todo, np.int32)),
+            eng._put_r(new_right), eng._put_r(new_deleted),
+            eng._put_r(new_starts),
+        )
+
+    eng._scatter_rebuilt = one_block
+
+
+class TestWidthClasses:
+    """A ``todo`` of short, a few times longer and twenty times longer
+    rooms is staged in width classes (a class: the rooms of its widest
+    room's ``_bucket(rows)`` and of half of it), one block a class, and
+    must leave the three device tables, over their whole ``cap + 1``, as
+    the single block as wide as the widest room left them."""
+
+    N = TestStagedWidth.N
+    WIDE = TestStagedWidth.WIDE
+    MID = 10
+    NEAR = 12  # an octave under MID: shares MID's block
+    LONG = {WIDE: 1500, MID: 300, NEAR: 200}
+    CAP = 2048
+    _fragment = TestStagedWidth._fragment
+    _tables = staticmethod(TestStagedWidth._tables)
+    _assert_same_tables = TestStagedWidth._assert_same_tables
+
+    def _engines(self, mesh):
+        eng, ref = _engine_pair(self.N, mesh)
+        _stage_one_block(ref)
+        return eng, ref
+
+    @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "cpu_mesh"])
+    @pytest.mark.parametrize("case", ["compact", "hydrate"])
+    def test_width_classes_leave_the_tables_of_one_block(self, case, mesh):
+        eng, ref = self._engines(mesh)
+        docs = self._fragment((eng, ref))
+        todo = list(range(self.N))
+        before = {i: eng.mirrors[i].n_rows for i in todo}
+        assert before[self.WIDE] == 1500 and before[self.MID] == 300
+        assert before[self.NEAR] == 200
+        assert all(
+            64 < n <= 128 for i, n in before.items() if i not in self.LONG
+        )
+        for e in (eng, ref):
+            stats = e.compact_docs(todo)
+            # the classes are staged narrowest first; the stats stay in
+            # the order asked for
+            assert [s["doc"] for s in stats] == todo
+            assert [s["rows_before"] for s in stats] == list(before.values())
+        if case == "hydrate":
+            # park a room of each width, blank the slots, bring each back
+            # into another's: one pending hydration a class
+            moved = [1, self.MID, self.WIDE, 14]
+            for e in (eng, ref):
+                parked = [e.export_doc_columns(i) for i in moved]
+                for i in moved:
+                    e.reset_doc(i)
+                for i, m in zip(moved, reversed(parked)):
+                    e.hydrate_doc_columns(i, m)
+                e.flush()
+            docs[1], docs[14] = docs[14], docs[1]
+            docs[self.MID], docs[self.WIDE] = docs[self.WIDE], docs[self.MID]
+        self._assert_same_tables(eng, ref)
+        right, deleted, _starts = self._tables(eng)
+        for i in todo:
+            n = eng.mirrors[i].n_rows
+            assert (right[i, n:] == -1).all() and not deleted[i, n:].any()
+        for i in (0, 1, self.WIDE, self.MID, 14, self.N - 1):
+            assert eng.text(i) == docs[i].get_text("text").to_string()
+        m = eng.last_flush_metrics
+        if case == "hydrate":
+            # the compaction's three blocks (13 rooms 128 wide, the two
+            # of 300 and 200 rows 512 wide, one 2048) and the
+            # hydrations' three (two rooms 64 wide)
+            assert m["rows_staged_blocks"] == 6
+            assert m["rows_staged_bytes"] == sum(
+                k * (w * 5 + 8 * 4)
+                for k, w in ((13, 128), (2, 512), (1, 2048), (2, 64), (1, 512), (1, 2048))
+            )
+        else:
+            eng.flush()
+            m = eng.last_flush_metrics
+            assert m["rows_staged_blocks"] == 3
+            assert m["rows_staged_bytes"] == (
+                13 * (128 * 5 + 8 * 4) + 2 * (512 * 5 + 8 * 4) + (2048 * 5 + 8 * 4)
+            )
+
+    def test_one_class_is_one_block(self):
+        """Rooms of one width class (here an octave apart: 200 rows
+        beside about a hundred): exactly the block it was, one
+        ``scatter_rows`` of ``len(todo) x w``."""
+        eng, _ref = self._engines(False)
+        self._fragment((eng,))
+        eng.flush()
+        small = [i for i in range(self.N) if i not in (self.WIDE, self.MID)]
+        calls = []
+        dispatch = eng._dispatch
+        eng._dispatch = lambda kind, *a, **kw: (
+            calls.append((kind, *(x.shape for x in a))), dispatch(kind, *a, **kw)
+        )
+        eng.compact_docs(small)
+        assert calls == [("rows", (14,), (14, 256), (14, 256), (14, 8))]
+        eng._dispatch = dispatch
+        eng.flush()
+        assert eng.last_flush_metrics["rows_staged_blocks"] == 1
 
 
 def _reset_by_table_copies(eng):

@@ -135,7 +135,8 @@ def load():
     vpp = ctypes.POINTER(vp)
     lib.ymx_prepare_many.restype = None
     lib.ymx_prepare_many.argtypes = [vpp, i64, i64p, i64p, i64p,
-                                     ctypes.c_int, i64p, i64p]
+                                     ctypes.c_int, i64p, i64p,
+                                     ctypes.POINTER(ctypes.c_double)]
     for pack_name in ("ymx_pack_apply", "ymx_pack_apply16"):
         fn = getattr(lib, pack_name)
         fn.restype = None

@@ -2593,14 +2593,33 @@ void ymx_plan_segment_stats(int64_t* out) {
 // a worker pool on multi-core hosts — results are bit-identical at any
 // width because no doc reads another doc's state.  Callers must not pass
 // the same handle twice in one call.
+//
+// The pool takes the call's long docs first, longest first, then the
+// others in index order (a permutation of the work index; every output
+// lands at its doc's own i, so counts, rcs and plans are what index
+// order gives): a long document's plan runs behind the short ones'
+// instead of starting when the other threads have nothing left.  Long
+// is kLongDocFactor times the call's mean staged bytes or more; a call
+// of like docs keeps index order, which is the order their mirrors were
+// made in: sorted by size, like docs were planned in effect shuffled,
+// and a cold load's plan phase took half again as long on the chip's
+// host (PERF.md 6, PR 34).  out_times[0] is the longest single doc's
+// prepare and out_times[1] the sum over docs, in seconds: the pool's own
+// time, which the caller's wall clock around the call cannot tell apart.
+static const uint64_t kLongDocFactor = 4;
 void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
                       const int64_t* ids_flat, const int64_t* v2_flat,
-                      int want_sched, int64_t* out_counts, int64_t* out_rc) {
+                      int want_sched, int64_t* out_counts, int64_t* out_rc,
+                      double* out_times) {
+  using clk = std::chrono::steady_clock;
+  std::vector<double> took((size_t)n_docs, 0.0);
   auto plan_one = [&](int64_t i) {
+    clk::time_point t0 = clk::now();
     Mirror* m = static_cast<Mirror*>(hs[i]);
     int64_t lo = buf_ofs[i], hi = buf_ofs[i + 1];
     int rc = m->prepare(ids_flat + lo, v2_flat + lo, hi - lo,
                         want_sched != 0);
+    took[(size_t)i] = std::chrono::duration<double>(clk::now() - t0).count();
     out_rc[i] = rc;
     int64_t* c = out_counts + i * 16;
     if (rc != 0) {
@@ -2628,22 +2647,56 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
                 : 0;
     c[15] = (int64_t)m->plan_seq;
   };
+  auto report = [&] {
+    double longest = 0.0, sum = 0.0;
+    for (double t : took) {
+      sum += t;
+      if (t > longest) longest = t;
+    }
+    out_times[0] = longest;
+    out_times[1] = sum;
+  };
   int nt = plan_pool_width();
   if (nt > (int)n_docs) nt = (int)n_docs;
   if (nt <= 1) {
     for (int64_t i = 0; i < n_docs; i++) plan_one(i);
+    report();
     return;
   }
+  std::vector<uint64_t> staged((size_t)n_docs, 0);
+  for (int64_t i = 0; i < n_docs; i++) {
+    const Mirror* m = static_cast<const Mirror*>(hs[i]);
+    for (int64_t b = buf_ofs[i]; b < buf_ofs[i + 1]; b++) {
+      int64_t id = ids_flat[b];
+      if (id >= 0 && (size_t)id < m->bufs.size())
+        staged[(size_t)i] += m->buf_len(id);
+    }
+  }
+  uint64_t staged_total = 0;
+  for (uint64_t b : staged) staged_total += b;
+  std::vector<int64_t> order, rest;
+  order.reserve((size_t)n_docs);
+  for (int64_t i = 0; i < n_docs; i++) {
+    bool is_long = staged_total > 0 &&
+                   staged[(size_t)i] * (uint64_t)n_docs >=
+                       kLongDocFactor * staged_total;
+    (is_long ? order : rest).push_back(i);
+  }
+  std::stable_sort(order.begin(), order.end(), [&](int64_t a, int64_t b) {
+    return staged[(size_t)a] > staged[(size_t)b];
+  });
+  order.insert(order.end(), rest.begin(), rest.end());
   std::atomic<int64_t> next{0};
   std::vector<std::thread> pool;
   pool.reserve((size_t)nt);
   for (int t = 0; t < nt; t++)
     pool.emplace_back([&] {
-      for (int64_t i; (i = next.fetch_add(1, std::memory_order_relaxed)) <
+      for (int64_t k; (k = next.fetch_add(1, std::memory_order_relaxed)) <
                       n_docs;)
-        plan_one(i);
+        plan_one(order[(size_t)k]);
     });
   for (auto& th : pool) th.join();
+  report();
 }
 
 // deep state clone: dst becomes a bit-identical twin of src — same rows,

@@ -117,6 +117,13 @@ FLUSH_METRICS_SCHEMA: dict = {
     # co-planned by one whole-chunk segment-planner call (ISSUE 15).
     # 1 = fully serial per-doc planning.
     "plan_threads": 1,
+    # the native pool's own clock (ymx_prepare_many, summed over the
+    # flush's calls): the longest single room's prepare, and the sum
+    # over rooms.  plan_pool_s / (threads x the ytpu.plan.native span)
+    # is how evenly the pool was loaded; a plan_room_max_s near the
+    # span's length is one long room ending the phase alone
+    "plan_room_max_s": 0.0,
+    "plan_pool_s": 0.0,
     # frontier-keyed plan cache (ISSUE 9): probes served from cache /
     # planned cold this flush, and structs placed by the segment-sorted
     # fast path instead of the sequential YATA walk.  Of the cold plans,
@@ -157,9 +164,13 @@ FLUSH_METRICS_SCHEMA: dict = {
     # bytes the compactions and hydrations since the previous flush
     # staged for scatter_rows (host allocation = transfer = device
     # writes), and the bytes of rebuilt rows the rooms in those blocks
-    # hold: held / staged is how full a staged block is
+    # hold: held / staged is how full a staged block is.  Rooms are
+    # staged in width classes (a room's bucketed rows, or twice that),
+    # one block (one scatter_rows) a class: rows_staged_blocks counts
+    # them (0 where nothing was compacted)
     "rows_staged_bytes": 0,
     "rows_held_bytes": 0,
+    "rows_staged_blocks": 0,
     # bytes of device rows the releases since the previous flush blanked
     # in place (reset_doc: one whole row of each resident table a slot)
     "release_blanked_bytes": 0,
@@ -323,6 +334,18 @@ class EngineObs:
             "Docs planned per ymx_prepare_many call",
             unit="docs",
         )
+        self._plan_pool_seconds = r.counter(
+            "ytpu_plan_pool_seconds_total",
+            "Seconds the native planner's pool spent in prepare, summed "
+            "over rooms (the pool's own clock)",
+            unit="s",
+        )
+        self._plan_room_max_seconds = r.gauge(
+            "ytpu_plan_room_max_seconds",
+            "Longest single room's native prepare, last flush that "
+            "planned cold",
+            unit="s",
+        )
         self._rollbacks = r.counter(
             "ytpu_resilience_rollbacks_total",
             "Per-doc transactional flush rollbacks by reason",
@@ -421,6 +444,12 @@ class EngineObs:
             "staged bytes: how full a staged block is)",
             unit="bytes",
         )
+        self._flush_rows_staged_blocks = r.counter(
+            "ytpu_flush_rows_staged_blocks_total",
+            "Blocks staged for the row scatter: one a width class of "
+            "the rooms a flush compacts or hydrates",
+            unit="blocks",
+        )
         self._release_blanked_bytes = r.counter(
             "ytpu_release_blanked_bytes_total",
             "Bytes of device rows blanked in place by room releases "
@@ -464,6 +493,10 @@ class EngineObs:
         if metrics["rows_staged_bytes"]:
             self._flush_rows_staged_bytes.inc(metrics["rows_staged_bytes"])
             self._flush_rows_held_bytes.inc(metrics["rows_held_bytes"])
+            self._flush_rows_staged_blocks.inc(metrics["rows_staged_blocks"])
+        if metrics["plan_pool_s"]:
+            self._plan_pool_seconds.inc(metrics["plan_pool_s"])
+            self._plan_room_max_seconds.set(metrics["plan_room_max_s"])
         if metrics["release_blanked_bytes"]:
             self._release_blanked_bytes.inc(metrics["release_blanked_bytes"])
 

@@ -43,6 +43,7 @@ from .native_mirror import (
 )
 from . import kernels
 from .compile_cache import ensure_compile_cache
+from .host_heap import ensure_heap_kept
 
 
 def _native_plan_threads() -> int:
@@ -289,6 +290,7 @@ class BatchEngine:
     ):
         if policy not in ("auto", "cpu", "device"):
             raise ValueError(f"unknown policy {policy!r}")
+        ensure_heap_kept()
         if policy != "cpu":  # a CPU-served engine never touches JAX
             ensure_compile_cache()
         self.n_docs = n_docs
@@ -390,6 +392,7 @@ class BatchEngine:
         # full a staged block is)
         self._flush_rows_staged_bytes = 0
         self._flush_rows_held_bytes = 0
+        self._flush_rows_staged_blocks = 0
         # bytes of device rows the releases since the last flush's end
         # blanked (reset_doc: one whole row of each table a slot)
         self._release_blanked_bytes = 0
@@ -779,18 +782,20 @@ class BatchEngine:
 
     def _compact_rows(self, todo: list[int], gc: bool) -> list[dict]:
         """Rebuild ``todo``'s mirrors compacted and scatter the new rows
-        into the device tables; returns per-doc row stats.
+        into the device tables; returns per-doc row stats, in ``todo``'s
+        order.
 
         The mirror's host list/deleted state equals the device arrays by
         flush invariant (YTPU_EXPORT_DEVICE pins it), so merges are
         decided WITHOUT any device read-back; the device gets the
-        rebuilt rows in one write-only scatter, staged as wide as the
-        widest room is BEFORE its rebuild (``rows_before``): the cells a
-        room has written since its slot was last blanked are its first
+        rebuilt rows in write-only scatters, each room staged as wide as
+        it is BEFORE its rebuild (``rows_before``): the cells a room has
+        written since its slot was last blanked are its first
         ``rows_before``, so that width blanks the stale tail behind the
         shorter rebuilt table, and every cell beyond it is at fill
-        already."""
-        stats = []
+        already.  Rooms of one width share a block
+        (``_scatter_rebuilt``)."""
+        stats = {}
 
         def rebuild(i):
             # a fresh rebuild supersedes any still-pending hydration
@@ -799,59 +804,82 @@ class BatchEngine:
             old_n = m.n_rows
             r, d, h = m.rebuild_compacted_self(gc)
             self._rows_at_compact[i] = len(r)
-            stats.append(
-                {"doc": i, "rows_before": old_n, "rows_after": len(r)}
-            )
+            stats[i] = {"doc": i, "rows_before": old_n, "rows_after": len(r)}
             return r, d, h
 
         mirrors = [self.mirrors[i] for i in todo]
         self._scatter_rebuilt(
             todo, rebuild,
-            max(m.n_rows for m in mirrors), max(m.n_segs for m in mirrors),
+            [m.n_rows for m in mirrors], [m.n_segs for m in mirrors],
         )
-        return stats
+        return [stats[i] for i in todo]
 
-    def _scatter_rebuilt(self, todo, rebuild, n_rows: int, n_segs: int) -> None:
+    def _scatter_rebuilt(self, todo, rebuild, n_rows, n_segs) -> None:
         """Stage rebuilt rooms and scatter them into the device tables:
         the one staging path of compactions and hydrations.
 
         ``rebuild(doc)`` gives a doc of ``todo`` its ``(right, deleted,
-        heads)`` as ``rebuild_compacted_self`` does; ``n_rows`` and
-        ``n_segs`` bound the rows and list heads any of these slots has
-        held since it was last blanked.  The block is ``len(todo) x w``
-        with ``w = _bucket(n_rows)`` (the power-of-two rule of the table
-        widths, so the widths a process meets, and the ``scatter_rows``
-        programs it compiles, stay few), not the table's ``cap + 1``:
-        host allocation, transfer and device scatter scale with what the
-        rooms hold.  One wide room makes its block wide."""
+        heads)`` as ``rebuild_compacted_self`` does; ``n_rows[j]`` and
+        ``n_segs[j]`` bound the rows and list heads slot ``todo[j]`` has
+        held since it was last blanked.  The rooms are staged in width
+        classes, one block a class, ``len(class) x w``.  A room's own
+        width is ``_bucket(n_rows[j])`` (the power-of-two rule of the
+        table widths, never more than the table's ``cap + 1``); a class
+        is anchored at the widest room not yet in one and takes the
+        rooms of that width and of half of it, so a block is at most
+        twice as wide as any room in it, rooms that differ by a row
+        across a power of two still share one (the widths a process
+        meets, and the ``scatter_rows`` programs it compiles, stay few),
+        and a long room widens the block of no room but its like.  Each
+        block is allocated, rebuilt, put and scattered by itself,
+        narrowest first, its heads as wide as its own rooms need: host
+        allocation, transfer and device scatter scale with what each
+        room holds.  A room's block is at least as wide as the cells it
+        has written, whatever class the others are in, so the tables
+        come out as one block as wide as the widest room would leave
+        them."""
         span = self._phase_ctx
-        k = len(todo)
-        w = min(_bucket(n_rows), self._cap + 1)
-        ws = min(_bucket(n_segs, 8), self._seg_cap + 1)
-        with span("compact.alloc"):
-            new_right = np.full((k, w), NULL, np.int32)
-            new_deleted = np.zeros((k, w), bool)
-            new_starts = np.full((k, ws), NULL, np.int32)
-        held = 0
-        with span("compact.rebuild"):
-            for j, i in enumerate(todo):
-                r, d, h = rebuild(i)
-                new_right[j, : len(r)] = r
-                new_deleted[j, : len(d)] = d
-                new_starts[j, : len(h)] = h
-                held += r.nbytes + d.nbytes + h.nbytes
-        self._flush_rows_staged_bytes += (
-            new_right.nbytes + new_deleted.nbytes + new_starts.nbytes
-        )
-        self._flush_rows_held_bytes += held
-        with span("compact.put"):
-            rows = (
-                self._put_r(np.asarray(todo, np.int32)),
-                self._put_r(new_right), self._put_r(new_deleted),
-                self._put_r(new_starts),
+        own = [min(_bucket(n), self._cap + 1) for n in n_rows]
+        width_of: dict[int, int] = {}
+        for w in sorted(set(own), reverse=True):
+            # half an anchor's width joins it; anything else anchors
+            width_of[w] = 2 * w if width_of.get(2 * w) == 2 * w else w
+        classes: dict[int, list[int]] = {}
+        for j, w in enumerate(own):
+            classes.setdefault(width_of[w], []).append(j)
+        for w in sorted(classes):
+            members = classes[w]
+            docs = [todo[j] for j in members]
+            k = len(docs)
+            ws = min(
+                _bucket(max(n_segs[j] for j in members), 8),
+                self._seg_cap + 1,
             )
-        with span("compact.scatter"):
-            self._dispatch("rows", *rows)
+            with span("compact.alloc"):
+                new_right = np.full((k, w), NULL, np.int32)
+                new_deleted = np.zeros((k, w), bool)
+                new_starts = np.full((k, ws), NULL, np.int32)
+            held = 0
+            with span("compact.rebuild"):
+                for j, i in enumerate(docs):
+                    r, d, h = rebuild(i)
+                    new_right[j, : len(r)] = r
+                    new_deleted[j, : len(d)] = d
+                    new_starts[j, : len(h)] = h
+                    held += r.nbytes + d.nbytes + h.nbytes
+            self._flush_rows_staged_bytes += (
+                new_right.nbytes + new_deleted.nbytes + new_starts.nbytes
+            )
+            self._flush_rows_held_bytes += held
+            self._flush_rows_staged_blocks += 1
+            with span("compact.put"):
+                rows = (
+                    self._put_r(np.asarray(docs, np.int32)),
+                    self._put_r(new_right), self._put_r(new_deleted),
+                    self._put_r(new_starts),
+                )
+            with span("compact.scatter"):
+                self._dispatch("rows", *rows)
 
     def compact_docs(self, docs, gc: bool = True) -> list[dict]:
         """Forced tombstone/GC compaction of specific docs (the tier GC
@@ -925,8 +953,8 @@ class BatchEngine:
         when nothing is pending.
 
         ``reset_doc`` left each of these slots at fill (``hydrate_doc_
-        columns`` refuses any other), so the block is as wide as the
-        widest room it brings."""
+        columns`` refuses any other), so a room's block is as wide as
+        the room it brings."""
         if not self._pending_hydration:
             return
         pend = self._pending_hydration
@@ -941,8 +969,8 @@ class BatchEngine:
         with self._phase_ctx("compact"):
             self._scatter_rebuilt(
                 todo, pend.__getitem__,
-                max(len(r) for r, _d, _h in pend.values()),
-                max(len(h) for _r, _d, h in pend.values()),
+                [len(pend[i][0]) for i in todo],
+                [len(pend[i][2]) for i in todo],
             )
 
     def reset_doc(self, doc: int) -> None:
@@ -1007,7 +1035,9 @@ class BatchEngine:
         metrics["realloc_bytes"] = self._flush_realloc_bytes
         metrics["rows_staged_bytes"] = self._flush_rows_staged_bytes
         metrics["rows_held_bytes"] = self._flush_rows_held_bytes
+        metrics["rows_staged_blocks"] = self._flush_rows_staged_blocks
         self._flush_rows_staged_bytes = self._flush_rows_held_bytes = 0
+        self._flush_rows_staged_blocks = 0
         metrics["release_blanked_bytes"] = self._release_blanked_bytes
         self._release_blanked_bytes = 0
         self.obs.record_flush(metrics, row_capacity=self._cap)
@@ -1450,6 +1480,8 @@ class BatchEngine:
             cache_admitted=0,
             t_cached=0.0,
             t_cold=0.0,
+            room_max_s=0.0,
+            pool_s=0.0,
             demoted=metrics["n_demoted"],
             rolled_back=metrics["n_rolled_back"],
         )
@@ -1571,6 +1603,8 @@ class BatchEngine:
                 # actually used — min(configured width, docs in the
                 # batch); 1 when every doc was served from the plan cache
                 "plan_threads": acc.plan_threads,
+                "plan_room_max_s": acc.room_max_s,
+                "plan_pool_s": acc.pool_s,
             })
             seg_now = plan_segment_stats()
             metrics["plan_segment_fast"] = max(0, seg_now[0] - seg_base[0])
@@ -1622,11 +1656,13 @@ class BatchEngine:
             acc.plan_threads = max(
                 acc.plan_threads, min(acc.cfg_threads, len(cold))
             )
-            counts_all, rcs, staged_info = prepare_many(
+            counts_all, rcs, staged_info, pool_times = prepare_many(
                 [(i, m) for i, m, _k in cold],
                 want_sched=want_sched,
                 obs=self.obs,
             )
+            acc.room_max_s = max(acc.room_max_s, pool_times[0])
+            acc.pool_s += pool_times[1]
             for k, (i, m, key) in enumerate(cold):
                 try:
                     m._finish_prepare(
@@ -1685,9 +1721,11 @@ class BatchEngine:
             acc.plan_threads = max(
                 acc.plan_threads, min(acc.cfg_threads, len(retry))
             )
-            counts2, rcs2, staged2 = prepare_many(
+            counts2, rcs2, staged2, pool_times = prepare_many(
                 retry, want_sched=want_sched, obs=self.obs,
             )
+            acc.room_max_s = max(acc.room_max_s, pool_times[0])
+            acc.pool_s += pool_times[1]
             for k, (i, m) in enumerate(retry):
                 try:
                     m._finish_prepare(

@@ -731,11 +731,16 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     """Batched ymx_prepare over many NativeMirrors in ONE native call.
 
     ``work`` is a list of ``(doc_idx, NativeMirror)``.  Returns
-    ``(counts, rcs, staged_info)`` where ``counts`` is an ``(n, 16)``
-    int64 array (ymx_prepare layout + ``[14]`` = dense-link flag;
-    ``[15]`` numbers the plan, see ``NativeMirror._plan_seq``),
-    ``rcs`` the per-doc return codes, and ``staged_info`` the
-    per-doc ``(staged, ids)`` needed by ``_finish_prepare``.
+    ``(counts, rcs, staged_info, pool_times)`` where ``counts`` is an
+    ``(n, 16)`` int64 array (ymx_prepare layout + ``[14]`` = dense-link
+    flag; ``[15]`` numbers the plan, see ``NativeMirror._plan_seq``),
+    ``rcs`` the per-doc return codes, ``staged_info`` the per-doc
+    ``(staged, ids)`` needed by ``_finish_prepare``, and ``pool_times``
+    the pool's own clock: ``(longest single doc's prepare, sum over
+    docs)`` in seconds.  The pool takes the call's long docs first
+    (four times its mean staged bytes or more, longest first), then the
+    others in index order; every output is at its doc's index in
+    ``work``.
 
     ``obs`` (an :class:`yjs_tpu.obs.EngineObs`) records each call's wall
     time and doc count into the ``ytpu_native_prepare_many_*`` histograms
@@ -797,6 +802,7 @@ def prepare_many(work, want_sched: bool = True, obs=None):
         o += nb
     counts = np.zeros((n, 16), np.int64)
     rcs = np.zeros(n, np.int64)
+    times = np.zeros(2, np.float64)
     # the native call alone: what is left of ytpu.plan around it is
     # Python (this function's marshalling, the plan cache, finish)
     with (
@@ -806,6 +812,7 @@ def prepare_many(work, want_sched: bool = True, obs=None):
         lib.ymx_prepare_many(
             handles, n, _p64(buf_ofs), _p64(ids_flat), _p64(v2_flat),
             1 if want_sched else 0, _p64(counts), _p64(rcs),
+            times.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
         )
     dt = time.perf_counter() - t0
     if obs is not None:
@@ -813,7 +820,7 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     from ..obs.prof import kernel_profiler
 
     kernel_profiler().record_host_op("prepare_many", dt)
-    return counts, rcs, staged_info
+    return counts, rcs, staged_info, (float(times[0]), float(times[1]))
 
 
 def encode_steps_many(work, pre_svs):
