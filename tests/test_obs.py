@@ -428,3 +428,30 @@ def test_the_pools_own_clock_rides_the_flush_metrics():
     m = eng.last_flush_metrics
     assert m["plan_room_max_s"] == m["plan_pool_s"] == 0.0
     assert reg.get("ytpu_plan_pool_seconds_total").value > 0.0
+
+
+@pytest.mark.parametrize("planner", ["native", "python"])
+def test_what_a_flush_looked_at_rides_the_flush_metrics(monkeypatch, planner):
+    """``rooms_dirty`` / ``rooms_compact_looked`` are in the schema, in
+    the metrics of every exit of a flush (the empty one too) and in the
+    registry, and ``ytpu.compact.scan`` opens once a flush whatever the
+    sets hold."""
+    if planner == "python":
+        monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+    assert {"rooms_dirty", "rooms_compact_looked"} <= set(FLUSH_METRICS_SCHEMA)
+    eng = BatchEngine(8)
+    reg = eng.obs.registry
+    looked_at = []  # (rooms_dirty, rooms_compact_looked) a flush
+    for rooms in ((1, 4, 6), (4,), (), (), (0, 1, 2, 3, 4, 5, 6, 7)):
+        for i in rooms:
+            eng.queue_update(i, _update(f"room {i} of {len(rooms)}"))
+        eng.flush()
+        m = eng.last_flush_metrics
+        assert set(m) == set(FLUSH_METRICS_SCHEMA)
+        looked_at.append((m["rooms_dirty"], m["rooms_compact_looked"]))
+    # a look reads the rooms the flush before it planned
+    assert looked_at == [(3, 0), (1, 3), (0, 1), (0, 0), (8, 0)]
+    assert reg.get("ytpu_flush_rooms_dirty_total").value == 12
+    assert reg.get("ytpu_flush_rooms_compact_looked_total").value == 4
+    names = [e["name"] for e in eng.obs.tracer.trace_events()]
+    assert names.count("ytpu.compact.scan") == names.count("ytpu.flush") == 5

@@ -117,6 +117,12 @@ FLUSH_METRICS_SCHEMA: dict = {
     # co-planned by one whole-chunk segment-planner call (ISSUE 15).
     # 1 = fully serial per-doc planning.
     "plan_threads": 1,
+    # what a flush looked at: slots the plan phase visited (the engine's
+    # dirty set, fed by queue_update: over n_docs, the share of the
+    # slots a flush pays for) and slots whose n_rows the compaction
+    # look read (the rooms planned since the look before)
+    "rooms_dirty": 0,
+    "rooms_compact_looked": 0,
     # the native pool's own clock (ymx_prepare_many, summed over the
     # flush's calls): the longest single room's prepare, and the sum
     # over rooms.  plan_pool_s / (threads x the ytpu.plan.native span)
@@ -450,6 +456,18 @@ class EngineObs:
             "the rooms a flush compacts or hydrates",
             unit="blocks",
         )
+        self._flush_rooms_dirty = r.counter(
+            "ytpu_flush_rooms_dirty_total",
+            "Slots the plan phase visited: the rooms that took an "
+            "update since a flush last planned them, or that park structs",
+            unit="rooms",
+        )
+        self._flush_rooms_compact_looked = r.counter(
+            "ytpu_flush_rooms_compact_looked_total",
+            "Slots whose row count the compaction look read: the rooms "
+            "planned since the look before",
+            unit="rooms",
+        )
         self._release_blanked_bytes = r.counter(
             "ytpu_release_blanked_bytes_total",
             "Bytes of device rows blanked in place by room releases "
@@ -494,6 +512,8 @@ class EngineObs:
             self._flush_rows_staged_bytes.inc(metrics["rows_staged_bytes"])
             self._flush_rows_held_bytes.inc(metrics["rows_held_bytes"])
             self._flush_rows_staged_blocks.inc(metrics["rows_staged_blocks"])
+        self._flush_rooms_dirty.inc(metrics["rooms_dirty"])
+        self._flush_rooms_compact_looked.inc(metrics["rooms_compact_looked"])
         if metrics["plan_pool_s"]:
             self._plan_pool_seconds.inc(metrics["plan_pool_s"])
             self._plan_room_max_seconds.set(metrics["plan_room_max_s"])

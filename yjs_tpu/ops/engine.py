@@ -399,6 +399,15 @@ class BatchEngine:
         # slots that ever accepted traffic (cleared by reset_doc): feeds
         # the ytpu_prof_slot_occupancy gauge in O(1) per update
         self._active_docs: set[int] = set()
+        # rooms that may need something from the next flush: fed by
+        # queue_update (and hydrate_doc_columns, whose mirror may bring
+        # parked structs).  The plan phase visits these slots and no
+        # other; a room leaves when a flush took its _incoming and it
+        # holds no pending structs
+        self._dirty_docs: set[int] = set()
+        # rooms planned since the last compaction look: only their
+        # n_rows can have moved, so only they are read by the next look
+        self._compact_look: set[int] = set()
 
     # -- update ingestion ---------------------------------------------------
 
@@ -436,6 +445,7 @@ class BatchEngine:
         else:
             self._update_log[doc].append((update, v2))
             self.mirrors[doc].ingest(update, v2)
+            self._dirty_docs.add(doc)
         self._active_docs.add(doc)
         return True
 
@@ -570,6 +580,7 @@ class BatchEngine:
                 )
         self.fallback[doc] = fb
         self.mirrors[doc] = DocMirror(self.root_name)  # dead mirror
+        self._dirty_docs.discard(doc)
         plan_cache.note_invalidation("demote")
         self._update_log[doc] = []
         if self._update_listeners:
@@ -762,23 +773,39 @@ class BatchEngine:
 
     # -- compaction ---------------------------------------------------------
 
-    def _maybe_compact(self) -> None:
+    def _maybe_compact(self) -> int:
         """Amortized run-merge + GC: when a doc's table doubles since its
         last compaction, read back its links/deleted bits and rebuild the
         mirror + device state with adjacent runs merged (the engine-side
         analogue of the reference's per-transaction merge/GC passes,
         Transaction.js:165-238,299-332).  Keeps row count bounded by the
-        doc's true run structure instead of its edit history."""
+        doc's true run structure instead of its edit history.
+
+        The look reads ``n_rows`` of the rooms planned since the last
+        look (``_compact_look``) and of no other slot: a room's rows move
+        only in a flush that planned it, in its own rebuild and in
+        ``hydrate_doc_columns``, and the last two set
+        ``_rows_at_compact`` themselves.  So a room compacts at the head
+        of the first flush after the one that doubled it, whatever the
+        number of slots.  ``compact_min_rows`` is read at the time of the
+        look; one case differs from a scan of every slot: lowering it
+        under a room that was NOT planned since the last look does not
+        catch that room, which is looked at after its next update.
+        Returns the number of slots looked at (``rooms_compact_looked``)."""
         with self._phase_ctx("compact.scan"):
+            looked = sorted(self._compact_look)
+            self._compact_look.clear()
+            floor = self.compact_min_rows
             todo = [
                 i
-                for i, m in enumerate(self.mirrors)
+                for i in looked
                 if i not in self.fallback
-                and m.n_rows >= max(self.compact_min_rows, 2 * self._rows_at_compact[i])
+                and self.mirrors[i].n_rows
+                >= max(floor, 2 * self._rows_at_compact[i])
             ]
-        if not todo or self._right is None:
-            return
-        self.last_compaction = self._compact_rows(todo, self.gc)
+        if todo and self._right is not None:
+            self.last_compaction = self._compact_rows(todo, self.gc)
+        return len(looked)
 
     def _compact_rows(self, todo: list[int], gc: bool) -> list[dict]:
         """Rebuild ``todo``'s mirrors compacted and scatter the new rows
@@ -944,6 +971,8 @@ class BatchEngine:
         self._rows_at_compact[doc] = len(r)
         if len(r):
             self._active_docs.add(doc)
+        # the mirror may hold parked structs: the next flush looks
+        self._dirty_docs.add(doc)
         return {"rows": len(r), "segs": len(h)}
 
     def _apply_pending_hydrations(self) -> None:
@@ -992,6 +1021,8 @@ class BatchEngine:
         self._update_log[doc] = []
         self._rows_at_compact[doc] = 0
         self._active_docs.discard(doc)
+        self._dirty_docs.discard(doc)
+        self._compact_look.discard(doc)
         self._event_listeners.pop(doc, None)
         self.health.reset(doc)
         if self._right is not None:
@@ -1070,6 +1101,18 @@ class BatchEngine:
             self.health.tick()
 
     def _flush(self) -> None:
+        """One flush: compaction look, plan, pack, dispatch, emit.
+
+        A flush looks at the rooms that took an update and at no other
+        slot, so its fixed cost grows with the traffic and not with
+        ``n_docs``: the plan phase visits ``_dirty_docs`` (fed by
+        ``queue_update``; ``rooms_dirty`` in the metrics) in ascending
+        slot order, and the compaction look reads the rooms planned
+        since it last ran (``_maybe_compact``; ``rooms_compact_looked``).
+        A room leaves the dirty set when a flush has taken its staged
+        updates and it parks no struct; one that waits for a missing
+        struct is visited by every flush until the struct arrives, and
+        the rooms of a flush that raises stay for the retry."""
         t_start = time.perf_counter()
         # per-flush pipeline counters reset; the staging pair + in-flight
         # markers persist across flushes.  Sync (A/B) mode is re-read per
@@ -1080,7 +1123,7 @@ class BatchEngine:
         # integrates on top of the device link tables (pipeline stage 0)
         self._apply_pending_hydrations()
         with self._phase_ctx("compact"):
-            self._maybe_compact()
+            n_looked = self._maybe_compact()
         t_compact = time.perf_counter()
         plans = {}
         pre_svs: dict[int, dict[int, int]] = {}
@@ -1101,17 +1144,27 @@ class BatchEngine:
             isinstance(m, NativeMirror) for m in self.mirrors
         )
         work: list = []  # batched path: (doc, mirror)
+        # the slots this flush visits, in ascending order: work (and with
+        # it the chunks and their apply_plan2 lane keys) comes out as a
+        # walk over every slot would build it
+        dirty = sorted(self._dirty_docs)
         with self._phase_ctx("plan"):
             if use_batch:
-                for i, m in enumerate(self.mirrors):
-                    if i in self.fallback or not isinstance(m, NativeMirror):
+                for i in dirty:
+                    m = self.mirrors[i]
+                    if i in self.fallback:
+                        self._dirty_docs.discard(i)
                         continue
+                    if not isinstance(m, NativeMirror):
+                        continue  # the Python lane's room: kept for it
                     if not m._incoming and not m._had_pending:
+                        self._dirty_docs.discard(i)
                         continue  # idle doc: nothing to plan or emit
                     if emitting or i in observing:
                         pre_svs[i] = m.state_vector()
                     work.append((i, m))
                 plans = dict(work)  # presence for the empty-flush check
+                self._compact_look.update(plans)
             else:
                 cache = plan_cache.get_cache()
                 seg_mode = segment_planner.plan_segment_mode()
@@ -1126,11 +1179,14 @@ class BatchEngine:
                 # loop got this for free by inserting before the next
                 # lookup)
                 chunk_dup: list = []  # (doc, mirror, cache key)
-                for i, m in enumerate(self.mirrors):
-                    if i in self.fallback:
-                        continue
-                    if not m._incoming and not m.has_pending():
+                for i in dirty:
+                    m = self.mirrors[i]
+                    if i in self.fallback or (
+                        not m._incoming and not m.has_pending()
+                    ):
+                        self._dirty_docs.discard(i)
                         continue  # idle doc: nothing to plan, upload, or emit
+                    self._compact_look.add(i)
                     if emitting or i in observing:
                         pre_svs[i] = m.state_vector()
                     key = ent = None
@@ -1281,6 +1337,8 @@ class BatchEngine:
             plan_cache_misses=cache_misses,
             plan_cache_admitted=cache_admitted,
             plan_threads=plan_fanout,
+            rooms_dirty=len(dirty),
+            rooms_compact_looked=n_looked,
             plan_fastpath_structs=sum(
                 getattr(p, "fastpath_structs", 0) or 0
                 for p in plans.values()
@@ -1297,15 +1355,25 @@ class BatchEngine:
                 if p is not None and not isinstance(p, NativeMirror)
             ),
         )
-        if not plans:
+        if plans:
+            self._flush_bulk(
+                work if use_batch else sorted(plans.items()),
+                pre_svs, emitting, metrics, t_start,
+                observed=set(observing), native=use_batch,
+            )
+        else:
             metrics["t_total_s"] = time.perf_counter() - t_start
             self._finish_flush(metrics)
-            return
-        self._flush_bulk(
-            work if use_batch else sorted(plans.items()),
-            pre_svs, emitting, metrics, t_start,
-            observed=set(observing), native=use_batch,
-        )
+        # a planned room whose staged updates were taken and that parks
+        # no struct asks nothing more of a flush; one a listener has just
+        # fed, or one still waiting for a missing struct, stays.  (A
+        # flush that raised never gets here: its rooms stay too.)
+        for i in self._dirty_docs.intersection(plans):
+            m = self.mirrors[i]
+            if not m._incoming and not (
+                m._had_pending if use_batch else m.has_pending()
+            ):
+                self._dirty_docs.discard(i)
 
     def _encode_steps(self, plans, pre_svs, counts, metrics) -> list:
         """The flush's broadcast updates as ``(doc, bytes | None)`` in
