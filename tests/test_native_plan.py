@@ -566,11 +566,16 @@ def test_prepare_many_longest_first_is_index_order(monkeypatch, want_sched):
     others in index order.  That is a permutation of the work index and
     nothing else:
     counts, return codes, plans and the updates encoded from them are
-    what index order (the serial path, one thread) gives, room by room;
-    and the pool's own clock comes back with them."""
+    what index order (the serial path, one thread) gives, room by room,
+    and what the core gave before its laps were a clock (``PLANS_PR37``);
+    and the call's own clock comes back with them."""
+    import hashlib
+
     import numpy as np
 
-    from yjs_tpu.ops.native_mirror import encode_steps_many, prepare_many
+    from yjs_tpu.ops.native_mirror import (
+        PLAN_TIMES, encode_steps_many, prepare_many,
+    )
 
     rng = random.Random(34)
 
@@ -639,6 +644,48 @@ def test_prepare_many_longest_first_is_index_order(monkeypatch, want_sched):
     np.testing.assert_array_equal(c1, c4)
     assert p1 == p4 and e1 == e4 and erc1 == erc4
     assert all(u is not None for u in e1)
-    for longest, total in (t1, t4):
-        # the longest room's prepare is one of the sum's terms
-        assert 0.0 < longest <= total
+    digest = hashlib.blake2b(
+        repr((c1.tolist(), rc1, p1, e1, erc1)).encode(), digest_size=16
+    ).hexdigest()
+    assert digest == PLANS_PR37[want_sched]
+    phases = PLAN_TIMES[2:7]
+    for t in (t1, t4):
+        assert tuple(t) == PLAN_TIMES and all(v >= 0.0 for v in t.values())
+        # the longest room's prepare is one of the sum's terms, and the
+        # phases' laps lie inside their rooms' prepares
+        assert 0.0 < t["plan_room_max_s"] <= t["plan_pool_s"]
+        assert 0.0 < sum(t[k] for k in phases) <= t["plan_pool_s"]
+        assert all(t[k] > 0.0 for k in phases)
+    # the pool's own cost to the calling thread: none without a pool
+    assert t1["plan_pool_start_s"] == t1["plan_pool_join_s"] == 0.0
+    assert t4["plan_pool_start_s"] > 0.0 and t4["plan_pool_join_s"] > 0.0
+
+
+# blake2b-128 of the counts, return codes, plans and encoded updates of
+# test_prepare_many_longest_first_is_index_order, as the parent of the
+# change that timed the core's laps (PR 38) gave them, by want_sched
+PLANS_PR37 = {
+    True: "7a128842c85145f6d6c9d34b52ec3c5a",
+    False: "3b96490a50e2ec152779f9808ae84196",
+}
+
+
+def test_core_has_no_timing_switch_of_its_own(monkeypatch, capfd):
+    """The core's laps are a clock the flush reports
+    (``last_flush_metrics`` ``plan_scan_s`` ... ``plan_finalize_s``), not
+    a switch in the environment: with ``YMX_TIMING`` set, a prepare
+    through either entry writes nothing to stdout or stderr."""
+    from yjs_tpu.ops.native_mirror import prepare_many
+
+    monkeypatch.setenv("YMX_TIMING", "1")
+    doc = Y.Doc(gc=False)
+    doc.get_text("text").insert(0, "hello")
+    update = Y.encode_state_as_update(doc)
+    alone, batched = NativeMirror("text"), NativeMirror("text")
+    alone.ingest(update)
+    alone.prepare_step()
+    batched.ingest(update)
+    _counts, rcs, _staged, times = prepare_many([(0, batched)])
+    assert rcs.tolist() == [0] and times["plan_scan_s"] > 0.0
+    out, err = capfd.readouterr()
+    assert (out, err) == ("", "")

@@ -430,6 +430,40 @@ def test_the_pools_own_clock_rides_the_flush_metrics():
     assert reg.get("ytpu_plan_pool_seconds_total").value > 0.0
 
 
+@pytest.mark.parametrize("threads", ["1", "3"])
+def test_the_cores_laps_ride_the_flush_metrics(monkeypatch, threads):
+    """The native core's laps are a clock of the flush's: ``plan_pool_s``
+    by the phase of a room's prepare, summed over the flush's chunks,
+    and what the pool cost the flushing thread in starting and joining
+    its threads (0 when the call ran serially); every key 0 in a flush
+    that planned nothing cold, and no registry family for any."""
+    from yjs_tpu.ops.native_mirror import PLAN_TIMES, native_plan_available
+
+    if not native_plan_available():
+        pytest.skip("native plan core unavailable")
+    monkeypatch.setenv("YTPU_PLAN_THREADS", threads)
+    monkeypatch.setenv("YTPU_FLUSH_CHUNK", "2")  # two calls a flush
+    phases, pool = PLAN_TIMES[2:7], PLAN_TIMES[7:]
+    assert set(PLAN_TIMES) <= set(FLUSH_METRICS_SCHEMA)
+    eng = BatchEngine(4)
+    families = set(eng.obs.registry.names())
+    for i, word in enumerate(("one", "two", "three", "four")):
+        eng.queue_update(i, _update(word))
+    eng.flush()
+    m = eng.last_flush_metrics
+    assert all(m[k] > 0.0 for k in phases)
+    assert sum(m[k] for k in phases) <= m["plan_pool_s"]
+    if threads == "1":
+        assert m["plan_threads"] == 1 and all(m[k] == 0.0 for k in pool)
+    else:
+        assert m["plan_threads"] == 2 and all(m[k] > 0.0 for k in pool)
+    assert eng.obs.snapshot()["flush_history"][-1]["plan_scan_s"] == m["plan_scan_s"]
+    assert set(eng.obs.registry.names()) == families
+    eng.flush()
+    m = eng.last_flush_metrics
+    assert all(m[k] == 0.0 for k in PLAN_TIMES)
+
+
 @pytest.mark.parametrize("planner", ["native", "python"])
 def test_what_a_flush_looked_at_rides_the_flush_metrics(monkeypatch, planner):
     """``rooms_dirty`` / ``rooms_compact_looked`` are in the schema, in

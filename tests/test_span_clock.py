@@ -4,8 +4,9 @@ A small ``TpuProvider`` with a WAL takes updates and flushes, with a
 compaction and an update-log fold forced, under ``jax.profiler``.  What
 the benchmark's ``trace_reduce.read_xplane`` reads back holds every span
 of the program's contract (``tests/bench/data/spans_synthetic.json``,
-which the per-layer readers are held to as well) under its bare name,
-each child inside its parent on one thread.  With the profiler off the
+which the per-layer readers are held to as well, and the leaves' inner
+spans of ``obs.trace.LEAF_SPANS``) under its bare name, each child
+inside its parent on one thread.  With the profiler off the
 ring holds what it held before the two systems met; under
 ``YTPU_OBS_DISABLED=1`` it holds nothing and the profiler still sees
 every span.
@@ -23,7 +24,8 @@ if str(ROOT) not in sys.path:  # the benchmark's reader, as tests/bench does
     sys.path.insert(0, str(ROOT))
 
 import yjs_tpu as Y
-from yjs_tpu.admission import AdmissionConfig
+from yjs_tpu.admission import AdmissionConfig, AdmissionRejected
+from yjs_tpu.obs.trace import LEAF_SPANS
 from yjs_tpu.persistence import WalConfig
 from yjs_tpu.provider import TpuProvider
 
@@ -31,9 +33,12 @@ jax = pytest.importorskip("jax")
 
 from benchmarks import trace_reduce  # noqa: E402
 
-PARENTS = json.loads(
-    (ROOT / "tests" / "bench" / "data" / "spans_synthetic.json").read_text()
-)["parents"]
+PARENTS = {
+    **json.loads(
+        (ROOT / "tests" / "bench" / "data" / "spans_synthetic.json").read_text()
+    )["parents"],
+    **LEAF_SPANS,
+}
 # spans the ring held before this PR (the journal's record of an append
 # among them, now a span the journal opens itself), spans it holds from
 # this PR on (one a flush), and the per-update child it must never hold
@@ -42,12 +47,18 @@ RING_BEFORE = {
     "ytpu.compact", "ytpu.plan", "ytpu.pack", "ytpu.dispatch", "ytpu.emit",
     "ytpu.wal.append",
 }
-PROFILER_ONLY = {"ytpu.slo.receive"}
+PROFILER_ONLY = {"ytpu.slo.receive", "ytpu.wal.write"}
 PER_UPDATE = PROFILER_ONLY | {
     "ytpu.wal.append", "ytpu.provider.receive_update",
 }
-RING_NEW = set(PARENTS) - RING_BEFORE - PROFILER_ONLY
+# the plan phase's steps around the native call: once a flush that
+# plans (one chunk, one cold call), never once a room
+PLAN_STEPS = {n for n, p in LEAF_SPANS.items() if p == "ytpu.plan"}
+RING_NEW = set(PARENTS) - RING_BEFORE - PROFILER_ONLY - PLAN_STEPS - {
+    "ytpu.wal.fsync",
+}
 FLUSH_EVERY = 10
+FSYNC_EVERY = 16
 
 
 def keystrokes(n: int, client: int) -> list[bytes]:
@@ -80,10 +91,10 @@ def drive(prov, updates) -> int:
     return flushes + 1
 
 
-def provider(tmp_path, **kw):
+def provider(tmp_path, fsync="interval", **kw):
     prov = TpuProvider(
-        4, wal_dir=str(tmp_path / "wal"), wal_config=WalConfig(fsync="never"),
-        **kw,
+        4, wal_dir=str(tmp_path / "wal"),
+        wal_config=WalConfig(fsync=fsync, fsync_interval=FSYNC_EVERY), **kw,
     )
     prov.engine.compact_min_rows = 8
     prov.on_update(lambda guid, update: None)
@@ -133,9 +144,13 @@ def test_span_on_the_profilers_clock_inside_its_parent(run, name):
     assert mine, f"{name} is not in the device trace"
     if name in PER_UPDATE:
         assert len(mine) == run["updates"]
-    elif name in ("ytpu.provider.flush", "ytpu.flush", "ytpu.slo.visible",
-                  "ytpu.cost.on_flush", "ytpu.compact", "ytpu.emit",
-                  "ytpu.compact.scan", "ytpu.emit.fold"):
+    elif name == "ytpu.wal.fsync":
+        assert len(mine) == run["updates"] // FSYNC_EVERY
+    elif name in PLAN_STEPS or name in (
+        "ytpu.provider.flush", "ytpu.flush", "ytpu.slo.visible",
+        "ytpu.cost.on_flush", "ytpu.compact", "ytpu.emit",
+        "ytpu.compact.scan", "ytpu.emit.fold",
+    ):
         assert len(mine) == run["flushes"]
     parent = PARENTS[name]
     if parent is None:
@@ -169,18 +184,20 @@ def check_ring(ring, updates, flushes, name):
         assert got[(name, "s")] == got[(name, "f")] == updates
     elif name in ("ytpu.provider.receive_update", "ytpu.wal.append"):
         assert got[(name, "X")] == updates  # one an update, as before
-    elif name == "ytpu.slo.receive":
+    elif name in PROFILER_ONLY:
         assert (name, "X") not in got
+    elif name == "ytpu.wal.fsync":
+        assert got[(name, "X")] == updates // FSYNC_EVERY
     elif name in ("ytpu.plan", "ytpu.pack", "ytpu.dispatch"):
         assert got[(name, "X")] >= flushes  # one a chunk
-    elif name in RING_BEFORE or name in (
+    elif name in PLAN_STEPS or name in RING_BEFORE or name in (
         "ytpu.slo.visible", "ytpu.cost.on_flush", "ytpu.compact.scan",
         "ytpu.emit.fold",
     ):
         assert got[(name, "X")] == flushes
     else:
         assert name in RING_NEW and 1 <= got[(name, "X")] <= flushes + 1
-    assert {n for n, _ in got} == set(PARENTS) - {"ytpu.slo.receive"} | {
+    assert {n for n, _ in got} == set(PARENTS) - PROFILER_ONLY | {
         "ytpu.convergence"
     }
 
@@ -375,4 +392,184 @@ def test_staging_spans_open_once_a_block(tmp_path):
     assert got["ytpu.compact"] == 1
     for name in ("alloc", "rebuild", "put", "scatter"):
         assert got[f"ytpu.compact.{name}"] == 2
+    prov.close(checkpoint=False)
+
+
+def ring_spans(prov, since=0):
+    return [
+        e for e in prov.engine.obs.tracer.trace_events()[since:]
+        if e["ph"] == "X"
+    ]
+
+
+def inside(child, parents):
+    return any(
+        p["ts"] <= child["ts"]
+        and child["ts"] + child["dur"] <= p["ts"] + p["dur"]
+        for p in parents
+    )
+
+
+@pytest.mark.parametrize("policy", ["always", "interval", "never"])
+def test_fsync_span_opens_only_when_the_journal_fsyncs(tmp_path, policy):
+    """``ytpu.wal.fsync`` is the ``os.fsync`` the policy asks for and
+    nothing else: as many spans as ``ytpu_wal_fsyncs_total`` counted,
+    each inside the append that paid it; the record's write never
+    reaches the ring."""
+    prov = provider(tmp_path, fsync=policy)
+    counted = prov.wal.metrics.fsyncs.value
+    updates = keystrokes(40, 19)
+    fsyncs = {
+        "always": len(updates), "interval": len(updates) // FSYNC_EVERY,
+        "never": 0,
+    }[policy]
+    for u in updates:
+        assert prov.receive_update("room", u)
+    spans = ring_spans(prov)
+    appends = [e for e in spans if e["name"] == "ytpu.wal.append"]
+    synced = [e for e in spans if e["name"] == "ytpu.wal.fsync"]
+    assert len(appends) == len(updates) and len(synced) == fsyncs
+    assert prov.wal.metrics.fsyncs.value - counted == fsyncs
+    assert all(inside(e, appends) and "args" not in e for e in synced)
+    assert not [e for e in spans if e["name"] == "ytpu.wal.write"]
+    prov.close(checkpoint=False)
+
+
+class Recorded:
+    """A ``TraceAnnotation`` that writes its enter and exit into a
+    list: what the profiler would see, in order, without a profiler."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("open", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+        return False
+
+
+@pytest.mark.parametrize("branch", ["admit", "queue", "reject"])
+def test_receive_update_is_one_span_from_its_first_statement(
+    tmp_path, monkeypatch, branch
+):
+    """The admission gate, the trace context's mint and ``doc_id`` run
+    inside ``ytpu.provider.receive_update`` in the branch that
+    integrates and in the one that queues; an update the gate rejects
+    (its tenant's bucket and the queue are full) closes the span on its
+    way out and changes no state."""
+    prov = provider(tmp_path, admission_config=AdmissionConfig(
+        enabled=True, tenant_rate=0.0, tenant_burst=1, doc_rate=0.0,
+        doc_burst=1, queue_max=1,
+    ))
+    updates = keystrokes(3, 21)
+    sent = {"admit": 0, "queue": 1, "reject": 2}[branch]
+    for u in updates[:sent]:
+        assert prov.receive_update("t/room", u)
+    log = Recorded.log = []
+    monkeypatch.setattr(prov.engine.obs.tracer, "_annotation", Recorded)
+    for obj, attr in (
+        (prov.admission, "admit_update"), (prov, "_trace_ingress"),
+        (prov, "doc_id"),
+    ):
+        def wrapped(*a, _real=getattr(obj, attr), _attr=attr, **kw):
+            log.append(("call", _attr))
+            return _real(*a, **kw)
+        monkeypatch.setattr(obj, attr, wrapped)
+    before = (
+        len(prov.engine.obs.tracer), dict(prov._guids),
+        prov.wal.metrics.bytes.value, prov._m_updates_rx.value,
+        prov.admission.snapshot()["queued"], prov._dirty,
+    )
+    if branch == "reject":
+        with pytest.raises(AdmissionRejected):
+            prov.receive_update("t/other", updates[sent])
+        assert log == [
+            ("open", "ytpu.provider.receive_update"),
+            ("call", "admit_update"),
+            ("close", "ytpu.provider.receive_update"),
+        ]
+        # no slot, no journal record, no counter, nothing queued; the
+        # ring's record of the refused call is all that is new
+        assert (len(prov.engine.obs.tracer) - 1, *before[1:]) == before
+        assert ring_spans(prov)[-1]["args"] == {"guid": "t/other"}
+    else:
+        assert prov.receive_update("t/room", updates[sent])
+        assert log[0] == ("open", "ytpu.provider.receive_update")
+        assert log[-1] == ("close", "ytpu.provider.receive_update")
+        calls = [what for kind, what in log if kind == "call"]
+        assert calls == ["admit_update", "_trace_ingress"] + (
+            ["doc_id"] if branch == "admit" else []
+        )
+        mine = [
+            e for e in ring_spans(prov)[before[0]:]
+            if e["name"] == "ytpu.provider.receive_update"
+        ]
+        assert len(mine) == (1 if branch == "admit" else 0)
+    prov.close(checkpoint=False)
+
+
+@pytest.mark.parametrize("sample, traced", [("1", True), ("0", False)])
+def test_receive_span_takes_its_trace_after_it_opens(
+    tmp_path, monkeypatch, sample, traced
+):
+    """The ring record keeps ``guid`` and, for a sampled context, the
+    ``trace`` that ``scripts/check_trace.py`` follows to visibility."""
+    from yjs_tpu.obs.dist import mint_for_update
+
+    monkeypatch.setenv("YTPU_TRACE_SAMPLE", sample)
+    prov = provider(tmp_path)
+    (u,) = keystrokes(1, 23)
+    assert prov.receive_update("room", u)
+    (rec,) = [
+        e for e in ring_spans(prov)
+        if e["name"] == "ytpu.provider.receive_update"
+    ]
+    want = {"guid": "room"}
+    if traced:
+        want["trace"] = mint_for_update(u).trace_hex
+    assert rec["args"] == want
+    prov.close(checkpoint=False)
+
+
+@pytest.mark.parametrize("chunk, cache, native, want", [
+    # walk, keys, stage, native, finish
+    ("256", "1", True, (1, 1, 1, 1, 1)),
+    ("2", "1", True, (1, 3, 3, 3, 3)),
+    ("256", "0", True, (1, 0, 1, 1, 1)),
+    ("256", "1", False, (1, 0, 0, 0, 0)),
+])
+def test_plan_steps_open_once_a_chunk_never_once_a_room(
+    tmp_path, monkeypatch, chunk, cache, native, want
+):
+    """Six rooms in one flush: ``ytpu.plan.walk`` once, the steps around
+    the native call once a chunk, ``ytpu.plan.keys`` not at all with the
+    plan cache off; the Python planner's lane walks and plans in one
+    loop, and opens ``ytpu.plan.walk`` around it."""
+    monkeypatch.setenv("YTPU_FLUSH_CHUNK", chunk)
+    monkeypatch.setenv("YTPU_PLAN_CACHE", cache)
+    if not native:
+        monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+    prov = TpuProvider(8)
+    prov.on_update(lambda guid, update: None)
+    for k in range(6):
+        for u in keystrokes(4, 30 + k):
+            assert prov.receive_update(f"room{k}", u)
+    since = len(prov.engine.obs.tracer.trace_events())
+    prov.flush()
+    spans = ring_spans(prov, since)
+    plans = [e for e in spans if e["name"] == "ytpu.plan"]
+    got = collections.Counter(e["name"] for e in spans)
+    steps = ("walk", "keys", "stage", "native", "finish")
+    assert tuple(got[f"ytpu.plan.{s}"] for s in steps) == want
+    assert all(
+        inside(e, plans) for e in spans
+        if e["name"].startswith("ytpu.plan.")
+    )
+    m = prov.engine.last_flush_metrics
+    assert m["n_docs_flushed"] == 6 and m["rooms_dirty"] == 6
     prov.close(checkpoint=False)

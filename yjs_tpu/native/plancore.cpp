@@ -28,7 +28,6 @@
 
 #include <atomic>
 #include <chrono>
-#include <cstdio>
 #include <cstdlib>
 #include <thread>
 
@@ -1017,17 +1016,25 @@ struct Mirror {
 
   // ---- the flush pipeline (DocMirror.prepare_step twin) -----------------
 
+  // the phases a prepare's laps add into (seconds): ymx_prepare_many
+  // sums them over a call's rooms into out_times[2..6]
+  enum Lap { kLapScan, kLapMerge, kLapCuts, kLapRows, kLapFinalize, kNLaps };
+
   int prepare(const int64_t* buf_ids, const int64_t* v2_flags,
-              int64_t n_updates, bool want_sched = true) {
+              int64_t n_updates, bool want_sched = true,
+              double* laps = nullptr) {
     // nothing reads the sched section unless events are observed;
     // skipping it saves a 32-byte append per integrated row
-    const bool timing = std::getenv("YMX_TIMING") != nullptr;
-    auto t0 = std::chrono::steady_clock::now();
-    auto lap = [&](const char* what) {
-      if (!timing) return;
-      auto t1 = std::chrono::steady_clock::now();
-      std::fprintf(stderr, "[ymx] %-12s %8.1f us\n", what,
-                   std::chrono::duration<double, std::micro>(t1 - t0).count());
+    //
+    // laps (double[kNLaps], or none: no clock is read): each lap adds
+    // the seconds since the one before to its phase
+    using clk = std::chrono::steady_clock;
+    clk::time_point t0;
+    if (laps) t0 = clk::now();
+    auto lap = [&](Lap phase) {
+      if (!laps) return;
+      clk::time_point t1 = clk::now();
+      laps[phase] += std::chrono::duration<double>(t1 - t0).count();
       t0 = t1;
     };
     plan.clear();
@@ -1057,7 +1064,7 @@ struct Mirror {
       }
       for (auto& d : ds_new) ds_ranges.push_back(d);
     }
-    lap("scan");
+    lap(kLapScan);
     pending_ds.clear();
 
     // merge into per-client WORKING SETS of pointers (old pending refs
@@ -1121,7 +1128,7 @@ struct Mirror {
               [](const auto& a, const auto& b) { return a.first > b.first; });
     std::vector<size_t> q_head(clients_desc.size(), 0);
 
-    lap("merge");
+    lap(kLapMerge);
     // causal scheduling: per-client queue fixpoint, descending client order
     std::vector<PendRef*> sched;
     {
@@ -1217,7 +1224,7 @@ struct Mirror {
       }
     }
 
-    lap("fixpoint");
+    lap(kLapMerge);  // fixpoint
     // delete-set clamping against post-step state
     std::vector<std::array<int64_t, 3>> applicable;
     for (auto& [client, clock, ln] : ds_ranges) {
@@ -1230,7 +1237,7 @@ struct Mirror {
       }
     }
 
-    lap("ds-clamp");
+    lap(kLapCuts);  // ds-clamp
     // pre-split pass: every boundary this step needs (collected raw,
     // then sorted+deduped per client — matches Python's set semantics
     // without per-insert node allocation)
@@ -1299,7 +1306,7 @@ struct Mirror {
       need_start(client, clock);
       need_start(client, clock + ln);
     }
-    lap("cuts-collect");
+    lap(kLapCuts);  // cuts-collect
     for (auto& [client, ks] : cuts) {
       // mostly-ascending in practice (origins chain forward); skip the
       // sort when the scan produced them in order.  Clocks are small
@@ -1311,7 +1318,7 @@ struct Mirror {
       ks.erase(std::unique(ks.begin(), ks.end()), ks.end());
     }
 
-    lap("cuts");
+    lap(kLapCuts);
     // cuts inside existing rows: split + device link surgery
     size_t pre_split_marker = plan.splits.size();
     for (int64_t client : cut_clients) {
@@ -1335,7 +1342,7 @@ struct Mirror {
                 return a[1] > b[1];
               });
 
-    lap("pre-split");
+    lap(kLapCuts);  // pre-split
     // row assignment + pointer resolution, fragmenting each scheduled ref
     // by its client's cut set inline (same fragment order as the old
     // two-pass frag_sched build, without the fat-struct copy pass)
@@ -1518,7 +1525,7 @@ struct Mirror {
     if (seg_lookup_n)
       g_seg_lookup.fetch_add(seg_lookup_n, std::memory_order_relaxed);
 
-    lap("rows");
+    lap(kLapRows);
     // resolve delete ranges to row ids.  Ranges arrive grouped per
     // client (update DS sections are per-client), so a 1-entry slot memo
     // avoids a hash find per range; the memo must NOT create slots
@@ -1545,11 +1552,11 @@ struct Mirror {
       }
     }
 
-    lap("deletes");
+    lap(kLapRows);  // deletes
     // LWW: sorted seg order (delete order is consumer-order-independent)
     std::sort(touched_map_segs.begin(), touched_map_segs.end());
     lww_pass(touched_map_segs);
-    lap("lww");
+    lap(kLapRows);  // lww
     plan.n_rows = n_rows();
     // ascending row/seg order = the Python twin's `sorted(plan._dl)`.
     // When the dirty set is DENSE in the row range (bulk first flush),
@@ -1594,7 +1601,7 @@ struct Mirror {
       }
       pending.swap(new_pending);
     }
-    lap("finalize");
+    lap(kLapFinalize);
     gen++;
     return 0;
   }
@@ -2603,22 +2610,35 @@ void ymx_plan_segment_stats(int64_t* out) {
 // of like docs keeps index order, which is the order their mirrors were
 // made in: sorted by size, like docs were planned in effect shuffled,
 // and a cold load's plan phase took half again as long on the chip's
-// host (PERF.md 6, PR 34).  out_times[0] is the longest single doc's
-// prepare and out_times[1] the sum over docs, in seconds: the pool's own
-// time, which the caller's wall clock around the call cannot tell apart.
+// host (PERF.md 6, PR 34).
+//
+// out_times (double[kPlanTimes], seconds) is the call's own clock, which
+// the caller's wall clock around the call cannot tell apart: [0] the
+// longest single doc's prepare, [1] the sum over docs; [2..6] that sum
+// by phase (Mirror::Lap: scan, merge + fixpoint, the cuts from the
+// delete-set clamp to the pre-split, rows + deletes + LWW, finalize;
+// they leave out of [1] only the clock reads themselves); and what the
+// pool costs the calling thread, both 0 on the serial path: [7] from
+// the first std::thread constructed to the last one started, [8] from
+// the moment the last worker found the queue empty to the last join's
+// return (thread exit and the caller's wake-up).
 static const uint64_t kLongDocFactor = 4;
+static const int kPlanTimes = 2 + Mirror::kNLaps + 2;
 void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
                       const int64_t* ids_flat, const int64_t* v2_flat,
                       int want_sched, int64_t* out_counts, int64_t* out_rc,
                       double* out_times) {
   using clk = std::chrono::steady_clock;
   std::vector<double> took((size_t)n_docs, 0.0);
+  // a row of laps a doc, so that no two workers add into one sum
+  std::vector<double> laps((size_t)n_docs * Mirror::kNLaps, 0.0);
   auto plan_one = [&](int64_t i) {
     clk::time_point t0 = clk::now();
     Mirror* m = static_cast<Mirror*>(hs[i]);
     int64_t lo = buf_ofs[i], hi = buf_ofs[i + 1];
     int rc = m->prepare(ids_flat + lo, v2_flat + lo, hi - lo,
-                        want_sched != 0);
+                        want_sched != 0,
+                        laps.data() + (size_t)i * Mirror::kNLaps);
     took[(size_t)i] = std::chrono::duration<double>(clk::now() - t0).count();
     out_rc[i] = rc;
     int64_t* c = out_counts + i * 16;
@@ -2647,20 +2667,22 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
                 : 0;
     c[15] = (int64_t)m->plan_seq;
   };
-  auto report = [&] {
-    double longest = 0.0, sum = 0.0;
+  auto report = [&](double pool_start, double pool_join) {
+    for (int j = 0; j < kPlanTimes; j++) out_times[j] = 0.0;
     for (double t : took) {
-      sum += t;
-      if (t > longest) longest = t;
+      out_times[1] += t;
+      if (t > out_times[0]) out_times[0] = t;
     }
-    out_times[0] = longest;
-    out_times[1] = sum;
+    for (size_t k = 0; k < laps.size(); k++)
+      out_times[2 + k % Mirror::kNLaps] += laps[k];
+    out_times[2 + Mirror::kNLaps] = pool_start;
+    out_times[3 + Mirror::kNLaps] = pool_join;
   };
   int nt = plan_pool_width();
   if (nt > (int)n_docs) nt = (int)n_docs;
   if (nt <= 1) {
     for (int64_t i = 0; i < n_docs; i++) plan_one(i);
-    report();
+    report(0.0, 0.0);
     return;
   }
   std::vector<uint64_t> staged((size_t)n_docs, 0);
@@ -2689,14 +2711,24 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
   std::atomic<int64_t> next{0};
   std::vector<std::thread> pool;
   pool.reserve((size_t)nt);
+  // when each worker found the queue empty; read after its join
+  std::vector<clk::time_point> ended((size_t)nt);
+  clk::time_point t_start = clk::now();
   for (int t = 0; t < nt; t++)
-    pool.emplace_back([&] {
+    pool.emplace_back([&, t] {
       for (int64_t k; (k = next.fetch_add(1, std::memory_order_relaxed)) <
                       n_docs;)
         plan_one(order[(size_t)k]);
+      ended[(size_t)t] = clk::now();
     });
+  clk::time_point t_started = clk::now();
   for (auto& th : pool) th.join();
-  report();
+  clk::time_point t_joined = clk::now();
+  clk::time_point work_end = ended[0];
+  for (clk::time_point e : ended)
+    if (e > work_end) work_end = e;
+  report(std::chrono::duration<double>(t_started - t_start).count(),
+         std::chrono::duration<double>(t_joined - work_end).count());
 }
 
 // deep state clone: dst becomes a bit-identical twin of src — same rows,

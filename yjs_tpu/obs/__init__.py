@@ -130,6 +130,20 @@ FLUSH_METRICS_SCHEMA: dict = {
     # span's length is one long room ending the phase alone
     "plan_room_max_s": 0.0,
     "plan_pool_s": 0.0,
+    # plan_pool_s by the phase of a room's prepare (the core's laps:
+    # scan; merge + fixpoint; the cuts, delete-set clamp to pre-split;
+    # rows + deletes + LWW; finalize), and what the pool itself cost the
+    # flushing thread: constructing and starting its threads, and in
+    # join after the last worker found the queue empty (both 0 when the
+    # call ran serially).  The flush ring and /debug carry them; no
+    # registry family does
+    "plan_scan_s": 0.0,
+    "plan_merge_s": 0.0,
+    "plan_cuts_s": 0.0,
+    "plan_rows_s": 0.0,
+    "plan_finalize_s": 0.0,
+    "plan_pool_start_s": 0.0,
+    "plan_pool_join_s": 0.0,
     # frontier-keyed plan cache (ISSUE 9): probes served from cache /
     # planned cold this flush, and structs placed by the segment-sorted
     # fast path instead of the sequential YATA walk.  Of the cold plans,

@@ -19,6 +19,7 @@ tracer's trace explicitly.
 from __future__ import annotations
 
 import atexit
+import contextlib
 import json
 import os
 import threading
@@ -38,6 +39,34 @@ SYNC_SPANS = {
     "ytpu.sync.step1": None,
 }
 
+# The inner spans of the leaves the device idles under, each with the
+# span it opens inside.  The journal's: ``ytpu.wal.write`` is one a
+# record (the file's write and flush) and goes to the profiler alone;
+# ``ytpu.wal.fsync`` opens only around an fsync the policy asks for.
+# The plan phase's: each at most once a chunk, never once a room
+# (``walk``: the dirty rooms and their state vectors; ``keys``: plan
+# keys and cache probes, not opened with the cache off; ``stage``:
+# ``prepare_many``'s marshalling; ``finish``: ``_finish_prepare``,
+# clones and cache inserts), beside ``ytpu.plan.native``.
+# tests/test_span_clock.py holds the program to these names and the
+# benchmark's ``wal_write_share`` ... ``plan_finish_share`` read them.
+LEAF_SPANS = {
+    "ytpu.wal.write": "ytpu.wal.append",
+    "ytpu.wal.fsync": "ytpu.wal.append",
+    "ytpu.plan.walk": "ytpu.plan",
+    "ytpu.plan.keys": "ytpu.plan",
+    "ytpu.plan.stage": "ytpu.plan",
+    "ytpu.plan.finish": "ytpu.plan",
+}
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def no_span(name: str, _ring: bool = True, **args):
+    """``Tracer.span`` for code that was handed no tracer (a journal or
+    a ``prepare_many`` of a test's own): a context that records nothing."""
+    return _NO_SPAN
+
 
 class _Span:
     """One span on both clocks: the profiler's annotation around one
@@ -56,18 +85,29 @@ class _Span:
         self._t0 = time.perf_counter()
         return self
 
+    def note(self, **args) -> None:
+        """Arguments the ring record takes that the body learns only
+        after the span has opened."""
+        self._args = {**(self._args or {}), **args}
+
+    def unring(self) -> None:
+        """Keep this span out of the ring after all: the profiler's
+        annotation still closes at the body's end."""
+        self._tracer = None
+
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
         tr = self._tracer
-        tr._events.append((
-            self._name,
-            "X",
-            (self._t0 - tr._t0) * 1e6,
-            (t1 - self._t0) * 1e6,
-            threading.get_ident(),
-            self._args,
-            None,
-        ))
+        if tr is not None:
+            tr._events.append((
+                self._name,
+                "X",
+                (self._t0 - tr._t0) * 1e6,
+                (t1 - self._t0) * 1e6,
+                threading.get_ident(),
+                self._args,
+                None,
+            ))
         self._ann.__exit__(exc_type, exc, tb)
         return False
 
@@ -104,7 +144,10 @@ class Tracer:
         captured, enabled tracer or not; ``args`` go to the ring.
         ``_ring=False`` keeps a per-update child span out of the ring,
         which already takes that update's span, flow start and journal
-        record (costs measured: PERF.md 6, PR 25)."""
+        record (costs measured: PERF.md 6, PR 25).  What ``with ... as``
+        binds is a ``_Span`` exactly when the tracer is enabled and
+        ``_ring`` is left on: its body may still ``note`` arguments or
+        ``unring`` it."""
         ann = self._annotation(name)
         if not (_ring and self.enabled):
             return ann

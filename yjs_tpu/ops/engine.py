@@ -33,6 +33,7 @@ from .columns import NULL, DocMirror, UnsupportedUpdate
 from . import plan_cache
 from . import segment_planner
 from .native_mirror import (
+    PLAN_TIMES,
     NativeMirror,
     NativePlan,
     encode_steps_many,
@@ -51,6 +52,16 @@ def _native_plan_threads() -> int:
     planner is unavailable or the host has a single core)."""
     lib = native.load()
     return int(lib.ymx_plan_threads()) if lib is not None else 1
+
+
+def _add_plan_times(total: dict, call: dict) -> None:
+    """One ymx_prepare_many call's clock (``PLAN_TIMES``) into a flush's:
+    the longest room's prepare is a maximum, the others are sums."""
+    for key, t in call.items():
+        if key == "plan_room_max_s":
+            total[key] = max(total[key], t)
+        else:
+            total[key] += t
 
 
 def make_mirror(root_name: str):
@@ -1150,19 +1161,20 @@ class BatchEngine:
         dirty = sorted(self._dirty_docs)
         with self._phase_ctx("plan"):
             if use_batch:
-                for i in dirty:
-                    m = self.mirrors[i]
-                    if i in self.fallback:
-                        self._dirty_docs.discard(i)
-                        continue
-                    if not isinstance(m, NativeMirror):
-                        continue  # the Python lane's room: kept for it
-                    if not m._incoming and not m._had_pending:
-                        self._dirty_docs.discard(i)
-                        continue  # idle doc: nothing to plan or emit
-                    if emitting or i in observing:
-                        pre_svs[i] = m.state_vector()
-                    work.append((i, m))
+                with self._phase_ctx("plan.walk"):
+                    for i in dirty:
+                        m = self.mirrors[i]
+                        if i in self.fallback:
+                            self._dirty_docs.discard(i)
+                            continue
+                        if not isinstance(m, NativeMirror):
+                            continue  # the Python lane's room: kept for it
+                        if not m._incoming and not m._had_pending:
+                            self._dirty_docs.discard(i)
+                            continue  # idle doc: nothing to plan or emit
+                        if emitting or i in observing:
+                            pre_svs[i] = m.state_vector()
+                        work.append((i, m))
                 plans = dict(work)  # presence for the empty-flush check
                 self._compact_look.update(plans)
             else:
@@ -1179,82 +1191,83 @@ class BatchEngine:
                 # loop got this for free by inserting before the next
                 # lookup)
                 chunk_dup: list = []  # (doc, mirror, cache key)
-                for i in dirty:
-                    m = self.mirrors[i]
-                    if i in self.fallback or (
-                        not m._incoming and not m.has_pending()
-                    ):
-                        self._dirty_docs.discard(i)
-                        continue  # idle doc: nothing to plan, upload, or emit
-                    self._compact_look.add(i)
-                    if emitting or i in observing:
-                        pre_svs[i] = m.state_vector()
-                    key = ent = None
-                    if cache is not None:
-                        key = m.plan_key()
-                        ent = cache.lookup(key)
-                    t_d0 = time.perf_counter()
-                    if ent is not None:
-                        # hit: replay the cached post-prepare snapshot
-                        # onto this mirror instead of re-planning
-                        if isinstance(m, NativeMirror):
-                            plans[i] = m.make_plan(m.adopt_cached(ent))
-                        else:
-                            m2, plans[i] = ent.clone()
-                            # keep the mirror's object identity (engine
-                            # internals and tests may hold references)
-                            m.__dict__.clear()
-                            m.__dict__.update(m2.__dict__)
-                        cache_hits += 1
-                        t_plan_cached += time.perf_counter() - t_d0
-                        continue
-                    if seg_mode == "device" and type(m) is DocMirror:
-                        if key is not None and key in chunk_keys:
-                            chunk_dup.append((i, m, key))
+                with self._phase_ctx("plan.walk"):
+                    for i in dirty:
+                        m = self.mirrors[i]
+                        if i in self.fallback or (
+                            not m._incoming and not m.has_pending()
+                        ):
+                            self._dirty_docs.discard(i)
+                            continue  # idle doc: nothing to plan, upload, or emit
+                        self._compact_look.add(i)
+                        if emitting or i in observing:
+                            pre_svs[i] = m.state_vector()
+                        key = ent = None
+                        if cache is not None:
+                            key = m.plan_key()
+                            ent = cache.lookup(key)
+                        t_d0 = time.perf_counter()
+                        if ent is not None:
+                            # hit: replay the cached post-prepare snapshot
+                            # onto this mirror instead of re-planning
+                            if isinstance(m, NativeMirror):
+                                plans[i] = m.make_plan(m.adopt_cached(ent))
+                            else:
+                                m2, plans[i] = ent.clone()
+                                # keep the mirror's object identity (engine
+                                # internals and tests may hold references)
+                                m.__dict__.clear()
+                                m.__dict__.update(m2.__dict__)
+                            cache_hits += 1
+                            t_plan_cached += time.perf_counter() - t_d0
+                            continue
+                        if seg_mode == "device" and type(m) is DocMirror:
+                            if key is not None and key in chunk_keys:
+                                chunk_dup.append((i, m, key))
+                                continue
+                            try:
+                                token = m.prepare_step_begin()
+                            except UnsupportedUpdate as e:
+                                self._demote(i, pre_svs.get(i), reason=str(e))
+                                demoted_now += 1
+                            except Exception as e:
+                                if self._strict:
+                                    raise
+                                self._isolate_failure(i, e, pre_svs.get(i))
+                                demoted_now += 1
+                                rolled_back += 1
+                            else:
+                                chunk_cold.append((i, m, key, token))
+                                if key is not None:
+                                    chunk_keys.add(key)
+                            t_plan_cold += time.perf_counter() - t_d0
                             continue
                         try:
-                            token = m.prepare_step_begin()
+                            plans[i] = m.prepare_step()
                         except UnsupportedUpdate as e:
                             self._demote(i, pre_svs.get(i), reason=str(e))
                             demoted_now += 1
                         except Exception as e:
+                            # malformed bytes (or any integration fault):
+                            # roll back and contain THIS doc; the rest of
+                            # the batch flushes normally
                             if self._strict:
                                 raise
                             self._isolate_failure(i, e, pre_svs.get(i))
                             demoted_now += 1
                             rolled_back += 1
                         else:
-                            chunk_cold.append((i, m, key, token))
                             if key is not None:
-                                chunk_keys.add(key)
+                                cache_misses += 1
+                                if isinstance(m, NativeMirror):
+                                    cache_admitted += cache.insert_native(
+                                        key, m, plans[i].counts
+                                    )
+                                else:
+                                    cache_admitted += cache.insert_py(
+                                        key, m, plans[i]
+                                    )
                         t_plan_cold += time.perf_counter() - t_d0
-                        continue
-                    try:
-                        plans[i] = m.prepare_step()
-                    except UnsupportedUpdate as e:
-                        self._demote(i, pre_svs.get(i), reason=str(e))
-                        demoted_now += 1
-                    except Exception as e:
-                        # malformed bytes (or any integration fault):
-                        # roll back and contain THIS doc; the rest of
-                        # the batch flushes normally
-                        if self._strict:
-                            raise
-                        self._isolate_failure(i, e, pre_svs.get(i))
-                        demoted_now += 1
-                        rolled_back += 1
-                    else:
-                        if key is not None:
-                            cache_misses += 1
-                            if isinstance(m, NativeMirror):
-                                cache_admitted += cache.insert_native(
-                                    key, m, plans[i].counts
-                                )
-                            else:
-                                cache_admitted += cache.insert_py(
-                                    key, m, plans[i]
-                                )
-                    t_plan_cold += time.perf_counter() - t_d0
                 if chunk_cold:
                     t_d0 = time.perf_counter()
                     try:
@@ -1548,8 +1561,8 @@ class BatchEngine:
             cache_admitted=0,
             t_cached=0.0,
             t_cold=0.0,
-            room_max_s=0.0,
-            pool_s=0.0,
+            # ymx_prepare_many's own clock, over the flush's calls
+            times=dict.fromkeys(PLAN_TIMES, 0.0),
             demoted=metrics["n_demoted"],
             rolled_back=metrics["n_rolled_back"],
         )
@@ -1671,8 +1684,7 @@ class BatchEngine:
                 # actually used — min(configured width, docs in the
                 # batch); 1 when every doc was served from the plan cache
                 "plan_threads": acc.plan_threads,
-                "plan_room_max_s": acc.room_max_s,
-                "plan_pool_s": acc.pool_s,
+                **acc.times,
             })
             seg_now = plan_segment_stats()
             metrics["plan_segment_fast"] = max(0, seg_now[0] - seg_base[0])
@@ -1696,20 +1708,21 @@ class BatchEngine:
         cold: list = []    # (doc, mirror, key) — group leaders
         groups: dict = {}  # key -> trailing same-key members
         if cache is not None:
-            for i, m in chunk:
-                key = m.plan_key(want_sched)
-                g = groups.get(key)
-                if g is not None:
-                    # intra-chunk duplicate (broadcast fan-out):
-                    # cloned from the leader after it plans
-                    g.append((i, m))
-                    continue
-                ent = cache.lookup(key)
-                if ent is not None:
-                    hits.append((i, m, ent))
-                else:
-                    groups[key] = []
-                    cold.append((i, m, key))
+            with self._phase_ctx("plan.keys"):
+                for i, m in chunk:
+                    key = m.plan_key(want_sched)
+                    g = groups.get(key)
+                    if g is not None:
+                        # intra-chunk duplicate (broadcast fan-out):
+                        # cloned from the leader after it plans
+                        g.append((i, m))
+                        continue
+                    ent = cache.lookup(key)
+                    if ent is not None:
+                        hits.append((i, m, ent))
+                    else:
+                        groups[key] = []
+                        cold.append((i, m, key))
         else:
             cold = [(i, m, None) for i, m in chunk]
         th0 = time.perf_counter()
@@ -1729,55 +1742,55 @@ class BatchEngine:
                 want_sched=want_sched,
                 obs=self.obs,
             )
-            acc.room_max_s = max(acc.room_max_s, pool_times[0])
-            acc.pool_s += pool_times[1]
-            for k, (i, m, key) in enumerate(cold):
-                try:
-                    m._finish_prepare(
-                        int(rcs[k]), staged_info[k][0],
-                        staged_info[k][1], counts_all[k],
-                    )
-                except UnsupportedUpdate as e:
-                    self._demote(i, pre_svs.get(i), reason=str(e))
-                    acc.demoted += 1
-                    retry.extend(groups.get(key, ()))
-                except Exception as e:
-                    if self._strict:
-                        raise
-                    self._isolate_failure(i, e, pre_svs.get(i))
-                    acc.demoted += 1
-                    acc.rolled_back += 1
-                    retry.extend(groups.get(key, ()))
-                else:
-                    chunk_ok.append((i, m, counts_all[k]))
-                    members = groups.get(key)
-                    if members:
-                        # identical frontier + staged bytes plan
-                        # identically: clone the leader's live
-                        # post-prepare state instead of
-                        # re-walking each member
-                        th1 = time.perf_counter()
-                        src = SimpleNamespace(
-                            h=m._h,
-                            counts=counts_all[k],
-                            pins=m._py_bufs,
-                            frontier_after=m.plan_frontier,
+            _add_plan_times(acc.times, pool_times)
+            with self._phase_ctx("plan.finish"):
+                for k, (i, m, key) in enumerate(cold):
+                    try:
+                        m._finish_prepare(
+                            int(rcs[k]), staged_info[k][0],
+                            staged_info[k][1], counts_all[k],
                         )
-                        for j, mj in members:
-                            chunk_ok.append(
-                                (j, mj, mj.adopt_cached(src))
+                    except UnsupportedUpdate as e:
+                        self._demote(i, pre_svs.get(i), reason=str(e))
+                        acc.demoted += 1
+                        retry.extend(groups.get(key, ()))
+                    except Exception as e:
+                        if self._strict:
+                            raise
+                        self._isolate_failure(i, e, pre_svs.get(i))
+                        acc.demoted += 1
+                        acc.rolled_back += 1
+                        retry.extend(groups.get(key, ()))
+                    else:
+                        chunk_ok.append((i, m, counts_all[k]))
+                        members = groups.get(key)
+                        if members:
+                            # identical frontier + staged bytes plan
+                            # identically: clone the leader's live
+                            # post-prepare state instead of
+                            # re-walking each member
+                            th1 = time.perf_counter()
+                            src = SimpleNamespace(
+                                h=m._h,
+                                counts=counts_all[k],
+                                pins=m._py_bufs,
+                                frontier_after=m.plan_frontier,
                             )
-                        acc.cache_hits += len(members)
-                        plan_cache.note_hits(len(members))
-                        acc.t_cached += time.perf_counter() - th1
-                    if key is not None:
-                        # post-prepare, pre-pack: the snapshot a
-                        # future hit adopts before running the
-                        # pack/dispatch phases itself (taken from a
-                        # key's second sighting on)
-                        acc.cache_admitted += cache.insert_native(
-                            key, m, counts_all[k]
-                        )
+                            for j, mj in members:
+                                chunk_ok.append(
+                                    (j, mj, mj.adopt_cached(src))
+                                )
+                            acc.cache_hits += len(members)
+                            plan_cache.note_hits(len(members))
+                            acc.t_cached += time.perf_counter() - th1
+                        if key is not None:
+                            # post-prepare, pre-pack: the snapshot a
+                            # future hit adopts before running the
+                            # pack/dispatch phases itself (taken from a
+                            # key's second sighting on)
+                            acc.cache_admitted += cache.insert_native(
+                                key, m, counts_all[k]
+                            )
             acc.t_cold += time.perf_counter() - tc0
         if retry:
             # a leader's demote/isolate says nothing about its
@@ -1792,25 +1805,25 @@ class BatchEngine:
             counts2, rcs2, staged2, pool_times = prepare_many(
                 retry, want_sched=want_sched, obs=self.obs,
             )
-            acc.room_max_s = max(acc.room_max_s, pool_times[0])
-            acc.pool_s += pool_times[1]
-            for k, (i, m) in enumerate(retry):
-                try:
-                    m._finish_prepare(
-                        int(rcs2[k]), staged2[k][0], staged2[k][1],
-                        counts2[k],
-                    )
-                except UnsupportedUpdate as e:
-                    self._demote(i, pre_svs.get(i), reason=str(e))
-                    acc.demoted += 1
-                except Exception as e:
-                    if self._strict:
-                        raise
-                    self._isolate_failure(i, e, pre_svs.get(i))
-                    acc.demoted += 1
-                    acc.rolled_back += 1
-                else:
-                    chunk_ok.append((i, m, counts2[k]))
+            _add_plan_times(acc.times, pool_times)
+            with self._phase_ctx("plan.finish"):
+                for k, (i, m) in enumerate(retry):
+                    try:
+                        m._finish_prepare(
+                            int(rcs2[k]), staged2[k][0], staged2[k][1],
+                            counts2[k],
+                        )
+                    except UnsupportedUpdate as e:
+                        self._demote(i, pre_svs.get(i), reason=str(e))
+                        acc.demoted += 1
+                    except Exception as e:
+                        if self._strict:
+                            raise
+                        self._isolate_failure(i, e, pre_svs.get(i))
+                        acc.demoted += 1
+                        acc.rolled_back += 1
+                    else:
+                        chunk_ok.append((i, m, counts2[k]))
             acc.t_cold += time.perf_counter() - tc0
         # hit/leader/member completion order is cache-dependent;
         # pack and emit must see the same doc order either way

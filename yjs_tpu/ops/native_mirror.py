@@ -20,7 +20,6 @@ Scope fallbacks keep semantics identical to the Python mirror:
 
 from __future__ import annotations
 
-import contextlib
 import ctypes
 import os
 import time
@@ -30,6 +29,7 @@ import numpy as np
 from ..lib0.decoding import Decoder
 from ..lib0 import decoding
 from ..lib0.u16 import utf8_decode_u16
+from ..obs.trace import no_span
 from ..native import (
     SRC_ANYS,
     SRC_DELETED,
@@ -727,6 +727,19 @@ class NativeMirror:
         return getattr(self.__dict__["_py"], name)
 
 
+# ymx_prepare_many's out_times in order, under the flush-metric keys a
+# flush reports them as (seconds): the longest single doc's prepare, the
+# sum over docs, that sum by phase (scan; merge + fixpoint; the cuts;
+# rows + deletes + LWW; finalize), and what the pool cost the calling
+# thread in starting its threads and in joining them once the work had
+# ended (both 0 on the serial path)
+PLAN_TIMES = (
+    "plan_room_max_s", "plan_pool_s",
+    "plan_scan_s", "plan_merge_s", "plan_cuts_s", "plan_rows_s",
+    "plan_finalize_s", "plan_pool_start_s", "plan_pool_join_s",
+)
+
+
 def prepare_many(work, want_sched: bool = True, obs=None):
     """Batched ymx_prepare over many NativeMirrors in ONE native call.
 
@@ -736,8 +749,8 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     flag; ``[15]`` numbers the plan, see ``NativeMirror._plan_seq``),
     ``rcs`` the per-doc return codes, ``staged_info`` the per-doc
     ``(staged, ids)`` needed by ``_finish_prepare``, and ``pool_times``
-    the pool's own clock: ``(longest single doc's prepare, sum over
-    docs)`` in seconds.  The pool takes the call's long docs first
+    the call's own clock, a dict of seconds under ``PLAN_TIMES``' keys.
+    The pool takes the call's long docs first
     (four times its mean staged bytes or more, longest first), then the
     others in index order; every output is at its doc's index in
     ``work``.
@@ -745,8 +758,9 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     ``obs`` (an :class:`yjs_tpu.obs.EngineObs`) records each call's wall
     time and doc count into the ``ytpu_native_prepare_many_*`` histograms
     — the planner-pool visibility the engine's per-flush timers cannot
-    give once flushes span multiple chunks — and puts the one native
-    call under the ``ytpu.plan.native`` span.
+    give once flushes span multiple chunks — and puts the marshalling
+    under the ``ytpu.plan.stage`` span and the one native call under
+    ``ytpu.plan.native``.
 
     ``want_sched=False`` skips building each plan's sched section
     (``NativePlan.sched`` then reads back empty) — ONLY safe when no
@@ -756,59 +770,58 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     Replaces the per-doc ctypes round trip.
     """
     t0 = time.perf_counter()
+    span = obs.tracer.span if obs is not None else no_span
     n = len(work)
     lib = work[0][1]._lib
-    _sync_plan_segment(lib)
-    handles = (ctypes.c_void_p * n)()
-    buf_ofs = np.zeros(n + 1, np.int64)
-    # batched staging: ONE native call registers every staged buffer.
-    # The c_char_p array extracts each bytes object's pointer in C
-    # (no per-buffer numpy view); the bytes stay pinned via _py_bufs.
-    all_bytes: list[bytes] = []
-    v2_list: list[int] = []
-    buf_hs = []
-    for k, (_i, m) in enumerate(work):
-        staged = m._incoming
-        buf_ofs[k + 1] = buf_ofs[k] + len(staged)
-        for u, v2 in staged:
-            all_bytes.append(u)
-            v2_list.append(1 if v2 else 0)
-            buf_hs.append(m._h)
-        handles[k] = m._h
-    nb_tot = len(all_bytes)
-    ids_flat = np.zeros(max(1, nb_tot), np.int64)
-    v2_flat = np.asarray(v2_list or [0], np.int64)
-    if nb_tot:
-        ptrs = (ctypes.c_char_p * nb_tot)(*all_bytes)
-        lens = np.fromiter(
-            (len(u) for u in all_bytes), np.uint64, nb_tot
-        )
-        bhs = (ctypes.c_void_p * nb_tot)(*buf_hs)
-        lib.ymx_add_bufs_many(
-            bhs, ptrs,
-            lens.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-            nb_tot,
-            _p64(ids_flat),
-        )
-    staged_info = []
-    o = 0
-    for k, (_i, m) in enumerate(work):
-        staged = m._incoming
-        nb = len(staged)
-        ids = ids_flat[o : o + nb]
-        for j, (u, _v2) in enumerate(staged):
-            m._py_bufs[int(ids[j])] = (u, None)
-        staged_info.append((staged, np.asarray(ids, np.int64)))
-        o += nb
-    counts = np.zeros((n, 16), np.int64)
-    rcs = np.zeros(n, np.int64)
-    times = np.zeros(2, np.float64)
-    # the native call alone: what is left of ytpu.plan around it is
-    # Python (this function's marshalling, the plan cache, finish)
-    with (
-        obs.tracer.span("ytpu.plan.native") if obs is not None
-        else contextlib.nullcontext()
-    ):
+    with span("ytpu.plan.stage"):
+        _sync_plan_segment(lib)
+        handles = (ctypes.c_void_p * n)()
+        buf_ofs = np.zeros(n + 1, np.int64)
+        # batched staging: ONE native call registers every staged buffer.
+        # The c_char_p array extracts each bytes object's pointer in C
+        # (no per-buffer numpy view); the bytes stay pinned via _py_bufs.
+        all_bytes: list[bytes] = []
+        v2_list: list[int] = []
+        buf_hs = []
+        for k, (_i, m) in enumerate(work):
+            staged = m._incoming
+            buf_ofs[k + 1] = buf_ofs[k] + len(staged)
+            for u, v2 in staged:
+                all_bytes.append(u)
+                v2_list.append(1 if v2 else 0)
+                buf_hs.append(m._h)
+            handles[k] = m._h
+        nb_tot = len(all_bytes)
+        ids_flat = np.zeros(max(1, nb_tot), np.int64)
+        v2_flat = np.asarray(v2_list or [0], np.int64)
+        if nb_tot:
+            ptrs = (ctypes.c_char_p * nb_tot)(*all_bytes)
+            lens = np.fromiter(
+                (len(u) for u in all_bytes), np.uint64, nb_tot
+            )
+            bhs = (ctypes.c_void_p * nb_tot)(*buf_hs)
+            lib.ymx_add_bufs_many(
+                bhs, ptrs,
+                lens.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+                nb_tot,
+                _p64(ids_flat),
+            )
+        staged_info = []
+        o = 0
+        for k, (_i, m) in enumerate(work):
+            staged = m._incoming
+            nb = len(staged)
+            ids = ids_flat[o : o + nb]
+            for j, (u, _v2) in enumerate(staged):
+                m._py_bufs[int(ids[j])] = (u, None)
+            staged_info.append((staged, np.asarray(ids, np.int64)))
+            o += nb
+        counts = np.zeros((n, 16), np.int64)
+        rcs = np.zeros(n, np.int64)
+        times = np.zeros(len(PLAN_TIMES), np.float64)
+    # the native call alone: what is left of ytpu.plan around the two
+    # is the engine's (the walk, the plan cache, finish)
+    with span("ytpu.plan.native"):
         lib.ymx_prepare_many(
             handles, n, _p64(buf_ofs), _p64(ids_flat), _p64(v2_flat),
             1 if want_sched else 0, _p64(counts), _p64(rcs),
@@ -820,7 +833,7 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     from ..obs.prof import kernel_profiler
 
     kernel_profiler().record_host_op("prepare_many", dt)
-    return counts, rcs, staged_info, (float(times[0]), float(times[1]))
+    return counts, rcs, staged_info, dict(zip(PLAN_TIMES, times.tolist()))
 
 
 def encode_steps_many(work, pre_svs):

@@ -32,13 +32,13 @@ Env knobs (constructor args win over env):
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import re
 import time
 from pathlib import Path
 
+from ..obs.trace import no_span
 from .records import (
     KIND_DLQ,
     KIND_NAMES,
@@ -225,7 +225,7 @@ class WriteAheadLog:
         self.metrics = metrics if metrics is not None else WalMetrics(None)
         # optional host tracer (yjs_tpu.obs.Tracer): journal latency
         # becomes a span inside the provider's receive/flush timeline
-        self._tracer = tracer
+        self._span = tracer.span if tracer is not None else no_span
         existing = list_segments(self.dir)
         ckpts = list_checkpoints(self.dir)
         self._next_index = max(
@@ -279,23 +279,24 @@ class WriteAheadLog:
         # every append of every kind is one span, on the profiler's
         # clock and (tracer enabled) in the ring: the journal's latency
         # inside the provider's receive/flush timeline
-        span = (
-            self._tracer.span("ytpu.wal.append", kind=KIND_NAMES[kind])
-            if self._tracer is not None
-            else contextlib.nullcontext()
-        )
-        with span:
+        # inside it the record's write (one a record: profiler only, the
+        # ring has the append) and the fsync the policy asks for, so that
+        # the append's own time is the encode, the counters, a roll
+        span = self._span
+        with span("ytpu.wal.append", kind=KIND_NAMES[kind]):
             t0 = time.perf_counter()
             rec = encode_record(kind, guid, payload, v2)
             if self._f is None or self._size >= self.config.segment_bytes:
                 self._seal()
                 self._open_next()
             offset = self._size
-            self._f.write(rec)
-            # flush to the OS on every append: in-process readers (tests,
-            # the crash harness) must see exactly what a crashed process
-            # would leave behind — fsync is the only policy-gated cost
-            self._f.flush()
+            with span("ytpu.wal.write", _ring=False):
+                self._f.write(rec)
+                # flush to the OS on every append: in-process readers
+                # (tests, the crash harness) must see exactly what a
+                # crashed process would leave behind — fsync is the only
+                # policy-gated cost
+                self._f.flush()
             self._size += len(rec)
             self._appends += 1
             self.metrics.records.labels(kind=KIND_NAMES[kind]).inc()
@@ -305,7 +306,8 @@ class WriteAheadLog:
                 cfg.fsync == "interval"
                 and self._appends % cfg.fsync_interval == 0
             ):
-                os.fsync(self._f.fileno())
+                with span("ytpu.wal.fsync"):
+                    os.fsync(self._f.fileno())
                 self.metrics.fsyncs.inc()
             self.metrics.append_seconds.observe(time.perf_counter() - t0)
         return (self._path, offset, len(rec))

@@ -551,16 +551,19 @@ class TpuProvider:
         changes — internal traffic (migration, failover, recovery)
         bypasses the gate with ``internal=True``."""
         adm = self.admission
-        verdict = "admit"
-        if adm.enabled and not internal:
-            # gate BEFORE doc_id: a rejected writer must not allocate a
-            # slot, and a queued update takes its slot at drain time
-            verdict = adm.admit_update(self, guid, len(update))
-        ctx = self._trace_ingress(update)
-        span = self.engine.obs.tracer.span
-        if verdict == "queue":
-            # profiler only: the ring never held this branch
-            with span("ytpu.provider.receive_update", _ring=False):
+        tracer = self.engine.obs.tracer
+        # the whole call is one span, the gate, the trace context's mint
+        # and doc_id included (a released room's slot is re-let there)
+        with tracer.span("ytpu.provider.receive_update", guid=guid) as sp:
+            verdict = "admit"
+            if adm.enabled and not internal:
+                # gate BEFORE doc_id: a rejected writer must not allocate
+                # a slot, and a queued update takes its slot at drain time
+                verdict = adm.admit_update(self, guid, len(update))
+            ctx = self._trace_ingress(update)
+            if verdict == "queue":
+                if tracer.enabled:
+                    sp.unring()  # profiler only: the ring never held this branch
                 if self.wal is not None:
                     # journaled at ENQUEUE: the queue is host memory, and
                     # zero acked-update loss must hold across a crash.  SLO
@@ -577,33 +580,34 @@ class TpuProvider:
                     self, guid, bytes(update), v2, undoable, None, trace=ctx
                 )
                 return True
-        doc = self.doc_id(guid)
-        with obs_dist.use_context(ctx), span(
-            "ytpu.provider.receive_update", guid=guid,
-            **({"trace": ctx.trace_hex} if ctx.sampled else {}),
-        ):
-            # profiler only: the stamp's flow arrow is its ring record
-            with span("ytpu.slo.receive", _ring=False):
-                key = self.slo.receive(update, v2=v2, guid=guid, trace=ctx)
-            if self.wal is not None:
-                # journal BEFORE integrating (write-ahead): a crash between
-                # append and flush replays the update; the reverse order
-                # could integrate state the log never saw
-                self.wal.append(KIND_UPDATE, guid, update, v2=v2)
-                self.cost.wal_bytes(guid, len(update))
-            accepted = self.engine.queue_update(doc, update, v2=v2)
-            self._m_updates_rx.inc()
-            self._m_ingress_bytes.inc(len(update))
-            if not accepted:
-                self.slo.rejected(key)
-                return False
-            self.slo.integrated(key)
-            self.cost.staged(guid, len(update))
-            self._dirty = True
-            ru = self._undo.get(guid)
-            if ru is not None:
-                ru.apply_update(update, tracked=undoable, v2=v2)
-            return True
+            doc = self.doc_id(guid)
+            if ctx.sampled and tracer.enabled:
+                sp.note(trace=ctx.trace_hex)
+            with obs_dist.use_context(ctx):
+                # profiler only: the stamp's flow arrow is its ring record
+                with tracer.span("ytpu.slo.receive", _ring=False):
+                    key = self.slo.receive(
+                        update, v2=v2, guid=guid, trace=ctx
+                    )
+                if self.wal is not None:
+                    # journal BEFORE integrating (write-ahead): a crash
+                    # between append and flush replays the update; the
+                    # reverse order could integrate state the log never saw
+                    self.wal.append(KIND_UPDATE, guid, update, v2=v2)
+                    self.cost.wal_bytes(guid, len(update))
+                accepted = self.engine.queue_update(doc, update, v2=v2)
+                self._m_updates_rx.inc()
+                self._m_ingress_bytes.inc(len(update))
+                if not accepted:
+                    self.slo.rejected(key)
+                    return False
+                self.slo.integrated(key)
+                self.cost.staged(guid, len(update))
+                self._dirty = True
+                ru = self._undo.get(guid)
+                if ru is not None:
+                    ru.apply_update(update, tracked=undoable, v2=v2)
+                return True
 
     def _integrate_admitted(
         self, guid: str, update: bytes, v2: bool, undoable: bool, slo_key
