@@ -18,6 +18,7 @@ from yjs_tpu.ops.columns import DocMirror
 from yjs_tpu.ops.native_mirror import (
     NativeMirror,
     NativePlan,
+    encode_diffs_many,
     encode_steps_many,
     native_plan_available,
 )
@@ -492,3 +493,221 @@ def test_applied_ds_is_built_on_first_read():
     assert ads and all(type(t) is tuple and len(t) == 3 for t in ads)
     assert plan.applied_ds is ads
     assert len(ads) == int(plan.counts[7])
+
+
+# -- a handshake's answers (mode 2, one state vector a request) ---------------
+
+DIFF_KINDS = ("typed", "storm", "deletes in the gap", "empty")
+
+
+def _diff_room(kind: str):
+    """A loaded NativeMirror and the state vectors of its sessions: one
+    that reloads (None) and one that says so ({}), stale ones that hold a
+    prefix, a current one, and an offline typist (a client the room has
+    never heard of, and more of a known client than the room holds)."""
+    m = NativeMirror("text")
+    if kind == "empty":
+        return m, [None, {}, {41: 7}]
+    if kind == "deletes in the gap":
+        d = Y.Doc(gc=False)
+        d.client_id = 77
+        t = d.get_text("text")
+        t.insert(0, "a room that is only ever erased from now on")
+        ups = [Y.encode_state_as_update(d)]
+        for pos in (3, 9, 20):
+            sv = Y.encode_state_vector(d)
+            t.delete(pos, 4)
+            ups.append(Y.encode_state_as_update(d, sv))
+    else:
+        ups = session("storm" if kind == "storm" else "deleting", 11, 60)
+    stale = []
+    for j, u in enumerate(ups):
+        m.ingest(u)
+        m.prepare_step()
+        if j in (0, len(ups) // 2, len(ups) - 2):
+            stale.append(m.state_vector())
+    now = m.state_vector()
+    some = next(iter(now))
+    typist = dict(now)
+    typist[some] += 5
+    typist[999_999] = 3
+    return m, [None, {}, *stale, now, dict(now), typist]
+
+
+@pytest.mark.parametrize("kind", DIFF_KINDS)
+def test_diffs_many_equal_encode_diff_update(kind):
+    """Every answer of the batched call is ``encode_diff_update``'s of the
+    same request, byte for byte, the sessions of one room side by side in
+    one call and again beside another room's."""
+    m, svs = _diff_room(kind)
+    other, other_svs = _diff_room("typed")
+    requests = [(m, sv) for sv in svs]
+    mixed = requests + [(other, sv) for sv in other_svs]
+    random.Random(kind).shuffle(mixed)
+    for reqs in (requests, mixed):
+        want = [mm.encode_diff_update(sv) for mm, sv in reqs]
+        got, arena_bytes = encode_diffs_many(reqs)
+        assert got == want
+        assert arena_bytes == sum(map(len, want))
+    answers = dict.fromkeys(
+        m.encode_diff_update(sv) for sv in svs
+    )
+    if kind == "empty":
+        # nothing to send is sent: two zero bytes, not None
+        assert list(answers) == [b"\x00\x00"]
+    elif kind == "deletes in the gap":
+        # erasing moves no clock: every session that holds the text is
+        # owed the delete set and no struct
+        assert len(answers) == 2
+        assert all(u[-1] != 0 for u in answers)  # a delete set in each
+    else:
+        assert len(answers) >= 4
+
+
+def test_diffs_many_refuses_a_v2_framed_room_alone():
+    plain, svs = _diff_room("typed")
+    rich = NativeMirror("text")
+    for u in session("nested", 2, 40, v2=True):
+        rich.ingest(u, True)
+    rich.prepare_step()
+    assert rich.encode_diff_update(None) is None
+    got, arena_bytes = encode_diffs_many(
+        [(plain, svs[0]), (rich, None), (plain, svs[-1]), (rich, {})]
+    )
+    assert got == [
+        plain.encode_diff_update(svs[0]), None,
+        plain.encode_diff_update(svs[-1]), None,
+    ]
+    assert arena_bytes == len(got[0]) + len(got[2])
+    assert encode_diffs_many([]) == ([], 0)
+
+
+def _sv_bytes(sv):
+    from yjs_tpu.coding import DSEncoderV1
+    from yjs_tpu.updates import write_state_vector
+
+    if not sv:
+        return None
+    e = DSEncoderV1()
+    write_state_vector(e, sv)
+    return e.to_bytes()
+
+
+def _handshake_engine():
+    """Six rooms: four native ones of the kinds above, one fed V2-framed
+    formats (the core refuses it: -7), one served by the CPU core; and a
+    tick's requests, several sessions a room, shuffled."""
+    eng = BatchEngine(6)
+    sessions = [
+        session("deleting", 11, 60), session("storm", 11, 60),
+        session("map", 3, 30), [],
+        session("nested", 2, 40, v2=True), session("distinct", 4, 30),
+    ]
+    eng._cpu_serve(5)
+    for i, ups in enumerate(sessions):
+        for u in ups:
+            eng.queue_update(i, u, v2=(i == 4))
+    eng.flush()
+    assert set(eng.fallback) == {5}
+    requests = []
+    for i in range(6):
+        now = eng.state_vector(i)
+        half = {c: n // 2 for c, n in now.items() if n // 2}
+        requests += [(i, None), (i, now), (i, half), (i, {**now, 999_999: 3})]
+    random.Random(6).shuffle(requests)
+    return eng, requests
+
+
+def test_sync_step2_batch_is_one_native_call_and_counts_fallbacks(monkeypatch):
+    calls = _count_native_calls(monkeypatch)
+    eng, requests = _handshake_engine()
+    del calls[:]
+    replies = eng.sync_step2_batch(requests)
+    m = eng.last_sync_metrics
+    # one call over the 20 requests of the five native rooms.  The V2-framed
+    # room is refused where the answer selects a row (its reload and its
+    # stale session); those two and the fallback doc's four go one by one
+    assert calls == [20]
+    refused = [
+        sv for i, sv in requests
+        if i == 4 and eng.mirrors[4].encode_diff_update(sv) is None
+    ]
+    assert len(refused) == 2
+    assert (m["n_requests"], m["encode_batched"], m["encode_fallback"]) == (
+        24, 18, 6
+    )
+    assert m["encode_buffer_bytes"] >= sum(
+        len(u) for (i, _sv), u in zip(requests, replies) if i < 4
+    )
+    for (i, sv), u in zip(requests, replies):
+        if i < 4:
+            assert u == eng.mirrors[i].encode_diff_update(sv)
+        assert u == eng.encode_state_as_update(i, _sv_bytes(sv))
+    # with nothing refused and no fallback doc, nothing falls back
+    native = [r for r in requests if r[0] < 4]
+    assert eng.sync_step2_batch(native) == [
+        u for r, u in zip(requests, replies) if r[0] < 4
+    ]
+    m = eng.last_sync_metrics
+    assert (m["encode_batched"], m["encode_fallback"]) == (16, 0)
+    assert m["encode_buffer_bytes"] == sum(
+        len(u) for r, u in zip(requests, replies) if r[0] < 4
+    )
+
+
+@pytest.mark.parametrize("how", ["v2", "device", "python-mirror"])
+def test_other_paths_answer_as_before_and_count_as_fallbacks(monkeypatch, how):
+    """``v2=True``, ``YTPU_SYNC_DEVICE=1`` and Python-mirror engines never
+    reach the batched call; their answers are the single-request path's."""
+    if how == "python-mirror":
+        monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+    eng, requests = _handshake_engine()
+    if how == "python-mirror":
+        assert not any(isinstance(m, NativeMirror) for m in eng.mirrors)
+    calls = _count_native_calls(monkeypatch)
+    if how == "device":
+        monkeypatch.setenv("YTPU_SYNC_DEVICE", "1")
+    v2 = how == "v2"
+    replies = eng.sync_step2_batch(requests, v2=v2)
+    m = eng.last_sync_metrics
+    assert calls == []
+    assert (m["encode_batched"], m["encode_fallback"]) == (0, len(requests))
+    for (i, sv), u in zip(requests, replies):
+        assert u == eng.encode_state_as_update(i, _sv_bytes(sv), v2=v2)
+
+
+def test_encode_states_batched_over_more_rooms_than_a_slice(monkeypatch):
+    """A checkpoint's whole rooms go through in slices: each slice's
+    answers are copied out before the next reuses the arena, and come
+    back in the order asked."""
+    from yjs_tpu.ops import native_mirror
+
+    monkeypatch.setattr(native_mirror, "_DIFF_SLICE", 4)
+    calls = _count_native_calls(monkeypatch)
+    n = 11
+    eng = BatchEngine(n)
+    sessions = [session(KINDS[i % 3], 20 + i, 25) for i in range(n)]
+    for i, ups in enumerate(sessions):
+        for u in ups:
+            eng.queue_update(i, u)
+    eng.flush()
+    del calls[:]
+    order = list(range(n))
+    random.Random(3).shuffle(order)
+    states = eng.encode_states_batched(order)
+    assert calls == [4, 4, 3]
+    m = eng.last_sync_metrics
+    assert (m["encode_batched"], m["encode_fallback"]) == (n, 0)
+    assert len(set(states)) == n
+    for i, u in zip(order, states):
+        assert u == eng.mirrors[i].encode_diff_update(None)
+        ref, got = Y.Doc(gc=False), Y.Doc(gc=False)
+        for s in sessions[i]:
+            Y.apply_update(ref, s)
+        Y.apply_update(got, u)
+        assert got.get_text("text").to_string() == (
+            ref.get_text("text").to_string()
+        )
+        assert Y.decode_state_vector(Y.encode_state_vector(got)) == (
+            Y.decode_state_vector(Y.encode_state_vector(ref))
+        )

@@ -525,3 +525,132 @@ class TestReconnectHandshake:
             [(guid, _frame_step1(Y.encode_state_vector(third)))]
         )
         assert set(_structs_by_client(_step2_payload(reply))) == {NEWCOMER}
+
+
+# ---------------------------------------------------------------------------
+# Step 1 from the core's own state vector (ISSUE 39): a server that only
+# announces state vectors reads no Python shadow and builds none.
+# ---------------------------------------------------------------------------
+
+from yjs_tpu.ops.columns import DocMirror
+from yjs_tpu.ops.native_mirror import NativeMirror, native_plan_available
+
+native_only = pytest.mark.skipif(
+    not native_plan_available(), reason="native plan core unavailable"
+)
+
+
+def _typed(client_id, text, base=None):
+    doc = _replay([base] if base else [], client_id)
+    doc.get_text("text").insert(0, text)
+    return doc
+
+
+def _step1_updates(case):
+    if case == "no client":
+        return []
+    a = _typed(5, "hello")
+    if case == "one client":
+        return [Y.encode_state_as_update(a)]
+    b = _typed(1 << 40, "B", Y.encode_state_as_update(a))
+    c = _typed(300, "a third", Y.encode_state_as_update(b))
+    if case == "several clients":
+        return [Y.encode_state_as_update(c)]
+    # heard of and not held: client 9's second entry arrives without its
+    # first and is parked, so its state in the room is 0 and stays out
+    d = _typed(9, "X", Y.encode_state_as_update(a))
+    sv = Y.encode_state_vector(d)
+    d.get_text("text").insert(0, "Y")
+    return [Y.encode_state_as_update(a), Y.encode_state_as_update(d, sv)]
+
+
+@native_only
+class TestStep1FromTheCore:
+    @pytest.mark.parametrize(
+        "case",
+        ["no client", "one client", "several clients", "a client at state 0"],
+    )
+    def test_bytes_are_the_shadows(self, case):
+        m = NativeMirror("text")
+        for u in _step1_updates(case):
+            m.ingest(u)
+        m.prepare_step()
+        assert m.has_pending() == (case == "a client at state 0")
+        got = m.encode_state_vector()
+        assert m._synced_gen == -1  # the shadow was never built
+        m._sync()
+        assert got == DocMirror.encode_state_vector(m._py)
+        assert Y.decode_state_vector(got) == m.state_vector()
+        assert len(m.state_vector()) == {
+            "no client": 0, "one client": 1, "several clients": 3,
+            "a client at state 0": 1,
+        }[case]
+
+    def test_a_state_vector_longer_than_the_first_buffer(self):
+        m = NativeMirror("text")
+        base = None
+        for k in range(40):  # 40 clients of 8-byte ids: over 256 bytes
+            doc = _typed((1 << 52) + k, "x", base)
+            base = Y.encode_state_as_update(doc)
+        m.ingest(base)
+        m.prepare_step()
+        got = m.encode_state_vector()
+        assert len(got) > 256 and len(Y.decode_state_vector(got)) == 40
+        m._sync()
+        assert got == DocMirror.encode_state_vector(m._py)
+
+    def test_the_handshake_builds_no_shadow(self, monkeypatch):
+        """``sync_step1``, ``handle_sync_step1_batch`` and a session
+        host's ``state_vector`` on native rooms: ``_sync()`` never runs
+        and ``_synced_gen`` stays where it was."""
+        from yjs_tpu.provider import TpuProvider, _ProviderSessionHost
+
+        prov = TpuProvider(4)
+        docs = {}
+        for k, guid in enumerate(("r/a", "r/b", "r/c")):
+            docs[guid] = d = _typed(40 + k, f"room {guid} " * (k + 1))
+            d.get_text("text").delete(1, 2)
+            prov.receive_update(guid, Y.encode_state_as_update(d))
+        prov.flush()
+        mirrors = [prov.engine.mirrors[prov.doc_id(g)] for g in docs]
+        assert all(isinstance(m, NativeMirror) for m in mirrors)
+        gens = [m._synced_gen for m in mirrors]
+        ran = []
+        real = NativeMirror._sync
+        monkeypatch.setattr(
+            NativeMirror, "_sync", lambda self: ran.append(self) or real(self)
+        )
+        step1 = {g: prov.sync_step1(g) for g in docs}
+        # more typing moves every room's generation; the next flush is the
+        # batch handler's own
+        for g, d in docs.items():
+            sv = Y.encode_state_vector(d)
+            d.get_text("text").insert(0, "more ")
+            prov.receive_update(g, Y.encode_state_as_update(d, sv))
+        msgs = [
+            (g, _frame_step1(sv))
+            for g in docs
+            for sv in (b"\x00", Y.encode_state_vector(docs[g]))
+        ]
+        replies = prov.handle_sync_step1_batch(msgs)
+        m = prov.last_sync_metrics
+        assert (m["encode_batched"], m["encode_fallback"]) == (len(msgs), 0)
+        assert m["encode_buffer_bytes"] == sum(
+            len(_step2_payload(r)) for r in replies
+        )
+        hosted = {
+            g: _ProviderSessionHost(prov, g, "peer").state_vector()
+            for g in docs
+        }
+        assert not ran
+        assert [m._synced_gen for m in mirrors] == gens
+        monkeypatch.setattr(NativeMirror, "_sync", real)
+        for (g, d), mirror in zip(docs.items(), mirrors):
+            sv = _step2_payload(bytes([1]) + prov.sync_step1(g)[1:])
+            assert sv == hosted[g]
+            assert Y.decode_state_vector(sv) == _sv(d)
+            mirror._sync()
+            assert sv == DocMirror.encode_state_vector(mirror._py)
+            assert step1[g] != prov.sync_step1(g)  # it had typed since
+        for (g, frame), reply in zip(msgs, replies):
+            assert reply == prov.handle_sync_message(g, frame)

@@ -183,6 +183,8 @@ def load():
     lib.ymx_rows.argtypes = [vp, i64] + [i64p] * 21
     lib.ymx_copy_bytes.restype = ctypes.c_int
     lib.ymx_copy_bytes.argtypes = [vp, i64, i64, i64, u8p]
+    lib.ymx_encode_state_vector.restype = i64
+    lib.ymx_encode_state_vector.argtypes = [vp, ctypes.c_char_p, u64]
     lib.ymx_encode_bound.restype = i64
     lib.ymx_encode_bound.argtypes = [vp]
     lib.ymx_encode_diff.restype = i64

@@ -3053,6 +3053,25 @@ void ymx_state(void* h, int64_t* out) {
   for (int64_t s : m->state) *out++ = s;
 }
 
+// the state vector as a sync step 1 carries it (writeStateVector,
+// encoding.js:565-573): the count of clients whose state is past 0, then
+// (client, clock) varuints in slot order.  Returns the bytes it needs,
+// and writes them only where `cap` holds them (the caller asks again).
+int64_t ymx_encode_state_vector(void* h, uint8_t* out, uint64_t cap) {
+  Mirror* m = static_cast<Mirror*>(h);
+  VecW w;
+  uint64_t live = 0;
+  for (int64_t s : m->state) live += s > 0;
+  w.varuint(live);
+  for (size_t i = 0; i < m->state.size(); i++)
+    if (m->state[i] > 0) {
+      w.varuint((uint64_t)m->client_of_slot[i]);
+      w.varuint((uint64_t)m->state[i]);
+    }
+  if (w.b.size() <= cap) std::memcpy(out, w.b.data(), w.b.size());
+  return (int64_t)w.b.size();
+}
+
 void ymx_segs(void* h, int64_t* name_ofs, int64_t* name_len,
               int64_t* sub_ofs, int64_t* sub_len, int64_t* parent_row) {
   Mirror* m = static_cast<Mirror*>(h);
@@ -3233,6 +3252,9 @@ int64_t ymx_encode_diff_v2(void* h, const int64_t* sv_clients,
 //   1  the triples ds_triples[3*ds_ofs[i]..3*ds_ofs[i+1])
 //   2  the mirror's whole derived delete set (encodeStateAsUpdate, a sync
 //      step 2)
+// A work item has its own sv range and no mode writes the mirror, so one
+// handle may appear any number of times: a tick's handshakes hold several
+// sessions of one room, each with its own state vector.
 // The bytes land back to back in this thread's arena (ymx_encode_arena),
 // room i at out_ofs[i]..out_ofs[i+1]; out_rc[i] is 0, -7 where the room
 // needs the Python writer, another negative on a writer error.  Every

@@ -36,6 +36,7 @@ from .native_mirror import (
     PLAN_TIMES,
     NativeMirror,
     NativePlan,
+    encode_diffs_many,
     encode_steps_many,
     native_plan_available,
     pack_apply_lanes,
@@ -2774,32 +2775,45 @@ class BatchEngine:
         pairs in, diff updates out (reference encodeStateAsUpdate,
         encoding.js:490-526), positionally.
 
-        The default path is the host's: a native mirror encodes its own
-        diff straight from the C++ columns, one ``ymx_encode_diff(_v2)``
-        call a request in a serial loop, each into a fresh buffer of
-        ``ymx_encode_bound`` bytes (the whole room's, whatever the diff
-        holds); no device round trip.  The ``diff_mask_kernel`` dispatch
-        (one for the whole batch, from columns copied out of the host
-        mirrors) serves what the native writer declines, Python-mirror
+        The default path is the host's and never leaves the native
+        mirrors: every V1 request whose room's mirror is a NativeMirror
+        goes to ``encode_diffs_many``, ONE ``ymx_encode_steps_many`` call
+        a slice of 256 requests, which encodes each diff straight from the
+        C++ columns into one arena sized by the diffs' own bounds; the
+        replies are cut out of it.  A request that call refuses (V2-framed
+        or spilled payloads) and every ``v2=True`` request take the
+        mirror's own ``encode_diff_update``, one native call and one
+        buffer of the room's whole bound each.  The ``diff_mask_kernel``
+        dispatch (one for the whole batch, from columns copied out of the
+        host mirrors) serves what that declines too, Python-mirror
         engines, and every request under ``YTPU_SYNC_DEVICE=1``.
         Fallback docs are served by the CPU core.
 
         The whole call is the ``ytpu.sync.encode`` span;
-        ``last_sync_metrics`` says what it did."""
+        ``last_sync_metrics`` says what it did: ``encode_batched`` requests
+        the batched call answered, ``encode_fallback`` requests that took
+        any other path, ``encode_buffer_bytes`` the arena bytes the calls
+        wrote plus what the fallbacks' own buffers held (no less than the
+        answers' bytes)."""
         t0 = time.perf_counter()
         with self._phase_ctx("sync.encode"):
-            replies, buffer_bytes = self._sync_step2_batch(requests, v2)
+            replies, buffer_bytes, n_batched = self._sync_step2_batch(
+                requests, v2
+            )
         self.last_sync_metrics = {
             "n_requests": len(requests),
+            "encode_batched": n_batched,
+            "encode_fallback": len(requests) - n_batched,
             "encode_buffer_bytes": buffer_bytes,
             "t_encode_s": time.perf_counter() - t0,
         }
         return replies
 
     def _sync_step2_batch(self, requests, v2):
-        """The answers, and the bytes the native encodes allocated."""
+        """The answers, the bytes their encodes wrote or allocated, and
+        how many of them the batched native call gave."""
         replies: list[bytes | None] = [None] * len(requests)
-        buffer_bytes = 0
+        buffer_bytes = n_batched = 0
         dev = [
             (j, i, sv) for j, (i, sv) in enumerate(requests) if i not in self.fallback
         ]
@@ -2815,9 +2829,27 @@ class BatchEngine:
                     enc_sv = e.to_bytes()
                 replies[j] = self.encode_state_as_update(i, enc_sv, v2=v2)
         if not os.environ.get("YTPU_SYNC_DEVICE"):
+            mirrors = self.mirrors
+            batch, one_by_one = [], []
+            for r in dev:
+                if not v2 and isinstance(mirrors[r[1]], NativeMirror):
+                    batch.append(r)
+                else:
+                    one_by_one.append(r)
+            if batch:
+                updates, arena_bytes = encode_diffs_many(
+                    [(mirrors[i], sv) for _j, i, sv in batch]
+                )
+                buffer_bytes += arena_bytes
+                for r, u in zip(batch, updates):
+                    if u is None:
+                        one_by_one.append(r)
+                    else:
+                        replies[r[0]] = u
+                        n_batched += 1
             rest = []
-            for j, i, sv in dev:
-                m = self.mirrors[i]
+            for j, i, sv in one_by_one:
+                m = mirrors[i]
                 enc = getattr(m, "encode_diff_update", None)
                 u = enc(sv, v2=v2) if enc is not None else None
                 if u is None:
@@ -2849,7 +2881,7 @@ class BatchEngine:
                 replies[j] = self.mirrors[i].encode_masked_update(
                     needed[r], offset[r], v2=v2
                 )
-        return replies, buffer_bytes
+        return replies, buffer_bytes, n_batched
 
     def encode_states_batched(
         self, docs: list[int], v2: bool = False

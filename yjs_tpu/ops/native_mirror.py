@@ -385,6 +385,19 @@ class NativeMirror:
             int(c): int(s) for c, s in zip(clients, state) if s > 0
         }
 
+    def encode_state_vector(self) -> bytes:
+        """The state vector as a sync step 1 carries it, written by the
+        core from its own slots (``DocMirror.state_vector``'s order and
+        filter, so the shadow's bytes); the shadow is neither read nor
+        rebuilt."""
+        cap = 256
+        while True:
+            out = ctypes.create_string_buffer(cap)
+            n = int(self._lib.ymx_encode_state_vector(self._h, out, cap))
+            if n <= cap:
+                return out.raw[:n]
+            cap = n
+
     def delete_set(self):
         """The doc's derived DeleteSet straight from the core — a cheap
         Snapshot capture (no shadow sync, no device I/O); the DocMirror
@@ -836,6 +849,27 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     return counts, rcs, staged_info, dict(zip(PLAN_TIMES, times.tolist()))
 
 
+def _encode_many(lib, handles, n, sv_ofs, svc, svk, modes, seqs, ds_ofs,
+                 triples):
+    """The one ``ymx_encode_steps_many`` call both callers below make:
+    ``(arena, ofs, rcs)``, room ``k``'s bytes at ``arena + ofs[k]`` up to
+    ``arena + ofs[k + 1]`` wherever ``rcs[k] >= 0``.  The arena is the
+    calling thread's and its next call overwrites it."""
+    flat = [
+        np.array(a, np.int64)
+        for a in (
+            sv_ofs, svc or [0], svk or [0], modes, seqs, ds_ofs,
+            triples or [0],
+        )
+    ]
+    out_ofs = np.zeros(n + 1, np.int64)
+    rcs = np.zeros(n, np.int64)
+    lib.ymx_encode_steps_many(
+        handles, n, *(_p64(a) for a in flat), _p64(out_ofs), _p64(rcs)
+    )
+    return lib.ymx_encode_arena(), out_ofs.tolist(), rcs
+
+
 def encode_steps_many(work, pre_svs):
     """The step updates of many NativeMirrors from ONE native call
     (``ymx_encode_steps_many``): what ``encode_step_update`` gives room by
@@ -848,7 +882,8 @@ def encode_steps_many(work, pre_svs):
     (``counts[15]``), whose applied delete set the core then reads in
     place; a sequence of ``(client, clock, len)`` triples is written as
     given; ``None`` is the room's whole derived delete set (the form
-    ``encode_state_as_update`` and a sync step 2 send).
+    ``encode_state_as_update`` sends; a handshake's many answers go
+    through ``encode_diffs_many``, which keys nothing by room).
 
     Returns ``(updates, rcs)``, both as long as ``work``.  ``rcs[k] < 0``
     means the core wrote nothing for that room: ``-7`` a selected row
@@ -882,21 +917,10 @@ def encode_steps_many(work, pre_svs):
         modes.append(mode)
         seqs.append(seq)
         ds_ofs.append(len(triples))
-    flat = [
-        np.array(a, np.int64)
-        for a in (
-            sv_ofs, svc or [0], svk or [0], modes, seqs, ds_ofs,
-            triples or [0],
-        )
-    ]
-    out_ofs = np.zeros(n + 1, np.int64)
-    rcs = np.zeros(n, np.int64)
-    lib.ymx_encode_steps_many(
-        handles, n, *(_p64(a) for a in flat), _p64(out_ofs), _p64(rcs)
+    base, ofs, rcs = _encode_many(
+        lib, handles, n, sv_ofs, svc, svk, modes, seqs, ds_ofs, triples
     )
-    base = lib.ymx_encode_arena()
     updates: list = [None] * n
-    ofs = out_ofs.tolist()
     for k, rc in enumerate(rcs.tolist()):
         if rc < 0:
             continue
@@ -904,6 +928,60 @@ def encode_steps_many(work, pre_svs):
         if u != b"\x00\x00":
             updates[k] = u
     return updates, rcs
+
+
+# requests one ymx_encode_steps_many call answers: a tick of handshakes.
+# A checkpoint's 4096 whole rooms in one arena would be 60 MB and more;
+# sliced, the arena stays what a tick needs and the core's own release
+# rule sees it as it sees a flush's
+_DIFF_SLICE = 256
+
+
+def encode_diffs_many(requests):
+    """The answers to many sync step 1 from ONE native call a slice of
+    ``_DIFF_SLICE`` requests: what ``encode_diff_update(sv)`` gives
+    request by request, byte for byte, V1 (``ymx_encode_steps_many`` with
+    every room's whole derived delete set, ``encodeStateAsUpdate``).
+
+    ``requests`` is a list of ``(NativeMirror, state vector)``, one state
+    vector a REQUEST: several sessions of one room ask with different
+    ones, so nothing is keyed by room, and a mirror may appear any number
+    of times (the core only reads it).  Missing or empty: the whole room.
+
+    Returns ``(updates, arena_bytes)``.  ``updates[k]`` is ``None`` where
+    the core wrote nothing (its ``rc < 0``: ``-7`` a selected row needs
+    the Python writer) and the caller asks ``encode_diff_update``; an
+    answer that carries nothing is its two zero bytes, which a step 2
+    sends.  ``arena_bytes`` is what the calls wrote: the bytes of
+    ``updates`` and no more."""
+    updates: list = []
+    arena_bytes = 0
+    lib = requests[0][0]._lib if requests else None
+    for lo in range(0, len(requests), _DIFF_SLICE):
+        part = requests[lo : lo + _DIFF_SLICE]
+        n = len(part)
+        handles = (ctypes.c_void_p * n)()
+        sv_ofs = [0]
+        svc: list[int] = []
+        svk: list[int] = []
+        for k, (m, sv) in enumerate(part):
+            handles[k] = m._h
+            if sv:
+                svc.extend(sv.keys())
+                svk.extend(sv.values())
+            sv_ofs.append(len(svc))
+        base, ofs, rcs = _encode_many(
+            lib, handles, n, sv_ofs, svc, svk, [2] * n, [0] * n,
+            [0] * (n + 1), None,
+        )
+        arena_bytes += ofs[n]
+        # copies, made before this thread's next encode reuses the arena
+        updates.extend(
+            None if rc < 0
+            else ctypes.string_at(base + ofs[k], ofs[k + 1] - ofs[k])
+            for k, rc in enumerate(rcs.tolist())
+        )
+    return updates, arena_bytes
 
 
 def pack_apply_lanes(work, doc_ids, b_loc, n_shards, widths, oob_r, oob_s,

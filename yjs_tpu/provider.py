@@ -939,8 +939,9 @@ class TpuProvider:
         moment: N clients reconnect): ONE ``flush()``, so that every
         answer holds what was acknowledged before the call, then one
         ``engine.sync_step2_batch``, which on the default path encodes
-        each diff from its room's native host mirror, one after another
-        (its docstring says when the device's ``diff_mask_kernel`` runs).
+        every diff from its room's native host mirror in one native call
+        into one arena (its docstring says which requests take another
+        path, and when the device's ``diff_mask_kernel`` runs).
         Returns the framed step-2 reply of each message, at its place.
 
         Frame by frame the contract is ``handle_sync_message``'s: a frame
@@ -1003,6 +1004,8 @@ class TpuProvider:
                 "n_full": n_full,
                 "n_bad": n_bad,
                 "reply_bytes": reply_bytes,
+                "encode_batched": encoded["encode_batched"],
+                "encode_fallback": encoded["encode_fallback"],
                 "encode_buffer_bytes": encoded["encode_buffer_bytes"],
                 "t_decode_s": t_decode,
                 "t_encode_s": encoded["t_encode_s"],
