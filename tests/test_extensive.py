@@ -147,6 +147,14 @@ def _engine_fuzz(gen: random.Random, n_ops: int, mesh=None) -> None:
     engine (incremental flushes, so splits/pending paths see deep histories),
     checked against the CPU core oracle at the end.
 
+    PR 40: format ops on a root text and XML ops (elements, their texts
+    with marks, attributes, node deletion) under a root fragment ride the
+    same streams; the engine's own formatting clean-ups (what it
+    broadcasts after a flush, ``_format_cleanup``) go back to the docs
+    as a peer's would, everything goes round until nobody has anything
+    new to say, and the rooms are then held to the docs' deltas and XML
+    strings as well.
+
     r5: updates fan out to FOUR engine rooms (docs 0..3, each receiving an
     independent random prefix), and YTPU_FLUSH_CHUNK=2 forces every flush
     through the chunked plan/transfer-overlap path; random engine
@@ -167,6 +175,40 @@ def _engine_fuzz(gen: random.Random, n_ops: int, mesh=None) -> None:
 
     n_rooms = n_clients  # one engine room per client stream
     eng = BatchEngine(8 if mesh is not None else n_rooms, mesh=mesh)
+    cleaned: list = []  # what the engine broadcast: its clean-ups among it
+    eng.on_update(lambda _room, u: cleaned.append(u))
+
+    def xml_op(d):
+        frag = d.get_xml_fragment("xml")
+        els = [e for e in frag.to_array() if isinstance(e, Y.YXmlElement)]
+        r = gen.random()
+        if not els or r < 0.15:
+            el = Y.YXmlElement(gen.choice(["paragraph", "heading"]))
+            t = Y.YXmlText()
+            t.insert(0, gen.choice(["some words ", "more of them "]))
+            el.insert(0, [t])
+            frag.insert(gen.randint(0, len(els)), [el])
+            return
+        el = gen.choice(els)
+        kids = el.to_array()
+        t = kids[0] if kids else None
+        if r < 0.2 and len(els) > 3:
+            frag.delete(frag.to_array().index(el), 1)
+        elif r < 0.35:
+            el.set_attribute(gen.choice(["level", "align"]), gen.randrange(4))
+        elif t is None:
+            return
+        elif r < 0.65:
+            t.insert(gen.randint(0, t.length), gen.choice(["ab", "c ", "xyz"]))
+        elif r < 0.8 and t.length:
+            pos = gen.randrange(t.length)
+            t.delete(pos, min(gen.randint(1, 3), t.length - pos))
+        elif t.length:
+            pos = gen.randrange(t.length)
+            t.format(
+                pos, min(gen.randint(1, 6), t.length - pos),
+                {gen.choice(["strong", "em"]): gen.choice([{}, None, {}])},
+            )
     # prefix of upds[i] already queued to engine room i
     delivered = [0] * n_clients
     flush_every = max(40, n_ops // 200)
@@ -182,7 +224,23 @@ def _engine_fuzz(gen: random.Random, n_ops: int, mesh=None) -> None:
         i = gen.randrange(n_clients)
         d = docs[i]
         op = gen.random()
-        if op < 0.5:
+        if op < 0.08:
+            xml_op(d)
+        elif op < 0.12:
+            # a root text of its own: "text" is the undo managers' scope
+            # (an undone format and a peer's clean-up of it are another
+            # matter), and a format that lands inside a surrogate pair
+            # splits it in the doc that asked and in no other
+            t = d.get_text("rich")
+            if t.length < 8 or gen.random() < 0.4:
+                t.insert(gen.randint(0, t.length), gen.choice(["ab ", "cde", "f"]))
+            else:
+                pos = gen.randrange(t.length)
+                t.format(
+                    pos, min(gen.randint(1, 5), t.length - pos),
+                    {"bold": gen.choice([True, None]), "c": gen.choice(["r", None])},
+                )
+        elif op < 0.5:
             t = d.get_text(gen.choice(["text", "notes"]))
             ln = len(t.to_string())
             if gen.random() < 0.65 or ln == 0:
@@ -229,25 +287,42 @@ def _engine_fuzz(gen: random.Random, n_ops: int, mesh=None) -> None:
                     c: v for c, v in snap.sv.items() if v > 0
                 } == eng.state_vector(room)
 
-    # quiesce: everyone sees everything, every engine room included
-    all_updates = [u for us in upds for u in us]
-    gen.shuffle(all_updates)
-    for d in docs:
-        for u in all_updates:
-            Y.apply_update(d, u)
-    for room in range(n_rooms):
-        for u in all_updates:
-            eng.queue_update(room, u)
-    eng.flush()
+    # quiesce: everyone sees everything, every engine room included; a
+    # doc that hears a remote transaction may clean its formatting, and
+    # so may the engine: round again until nobody says anything new
+    told = 0
+    for _ in range(20):
+        all_updates = [u for us in upds for u in us] + cleaned
+        if len(all_updates) == told:
+            break
+        told = len(all_updates)
+        gen.shuffle(all_updates)
+        for d in docs:
+            for u in all_updates:
+                Y.apply_update(d, u)
+        for room in range(n_rooms):
+            for u in all_updates:
+                eng.queue_update(room, u)
+        eng.flush()
+    else:
+        raise AssertionError("the clean-ups never went quiet")
 
     ref = docs[0]
     for other in docs[1:]:
         for name in ("text", "notes"):
             assert other.get_text(name).to_string() == ref.get_text(name).to_string()
         assert other.get_map("map").to_json() == ref.get_map("map").to_json()
+        assert other.get_text("rich").to_delta() == ref.get_text("rich").to_delta()
+        assert (
+            other.get_xml_fragment("xml").to_string()
+            == ref.get_xml_fragment("xml").to_string()
+        )
+    assert "<" in ref.get_xml_fragment("xml").to_string()
     for room in range(n_rooms):
         for name in ("text", "notes"):
             assert eng.text(room, name) == ref.get_text(name).to_string()
+        assert eng.to_delta(room, "rich") == ref.get_text("rich").to_delta()
+        assert eng.xml_string(room, "xml") == ref.get_xml_fragment("xml").to_string()
         assert eng.map_json(room, "map") == ref.get_map("map").to_json()
         assert eng.state_vector(room) == {
             c: v for c, v in Y.get_state_vector(ref.store).items() if v > 0

@@ -25,7 +25,7 @@ if str(ROOT) not in sys.path:  # the benchmark's reader, as tests/bench does
 
 import yjs_tpu as Y
 from yjs_tpu.admission import AdmissionConfig, AdmissionRejected
-from yjs_tpu.obs.trace import LEAF_SPANS
+from yjs_tpu.obs.trace import FORMAT_SPANS, LEAF_SPANS
 from yjs_tpu.persistence import WalConfig
 from yjs_tpu.provider import TpuProvider
 
@@ -38,6 +38,8 @@ PARENTS = {
         (ROOT / "tests" / "bench" / "data" / "spans_synthetic.json").read_text()
     )["parents"],
     **LEAF_SPANS,
+    # the look of the formatting clean-up, once a flush (PR 40)
+    **FORMAT_SPANS,
 }
 # spans the ring held before this PR (the journal's record of an append
 # among them, now a span the journal opens itself), spans it holds from
@@ -53,7 +55,9 @@ PER_UPDATE = PROFILER_ONLY | {
 }
 # the plan phase's steps around the native call: once a flush that
 # plans (one chunk, one cold call), never once a room
-PLAN_STEPS = {n for n, p in LEAF_SPANS.items() if p == "ytpu.plan"}
+PLAN_STEPS = {
+    n for n, p in {**LEAF_SPANS, **FORMAT_SPANS}.items() if p == "ytpu.plan"
+}
 RING_NEW = set(PARENTS) - RING_BEFORE - PROFILER_ONLY - PLAN_STEPS - {
     "ytpu.wal.fsync",
 }

@@ -1178,8 +1178,8 @@ def test_a_mesh_reuses_a_program_whose_lanes_cover_the_chunk():
     """On a mesh a lane width is the widest shard's sum: the same rooms
     re-let into other slots wander over a bucket's edge.  A program the
     mesh already has that covers the chunk at no more than a quarter
-    more lanes is used before another is issued; one device keeps
-    its exact keys."""
+    more lanes is used before another is issued; one device keeps a
+    bulk load's exact key, and covers a served flush's (PR 40)."""
     from yjs_tpu.parallel import doc_mesh
 
     def prepended(client, n):
@@ -1213,7 +1213,19 @@ def test_a_mesh_reuses_a_program_whose_lanes_cover_the_chunk():
     one = BatchEngine(8)
     one.queue_update(0, Y.encode_state_as_update(first))
     one.flush()
-    assert one._covering_key((192, 64, 8, 64)) == (192, 64, 8, 64)
+    assert one._mesh_keys == {(256, 64, 8, 64)}  # a new width: a power of two
+    # a served flush's key wanders in four widths at once: an issued key
+    # that covers it at 512 lanes more, or a quarter, is issued again
+    for served in ((192, 64, 8, 64), (64, 64, 8, 64), (112, 64, 8, 64)):
+        assert one._covering_key(served) == (256, 64, 8, 64)
+    # not covered: its own, each width the next power of two
+    assert one._covering_key((208, 64, 9, 64)) == (256, 64, 16, 64)
+    assert one._covering_key((272, 72, 8, 64)) == (512, 128, 8, 64)
+    assert one._covering_key((260, 64, 8, 64)) == (512, 128, 8, 64)
+    # a bulk load's rooms give the same key every time: kept as it is
+    bulk = (8192, 64, 8, 64)
+    assert one._covering_key(bulk) == bulk and bulk not in one._mesh_keys
+    assert one._covering_key((8000, 64, 8, 64)) == (8000, 64, 8, 64)
 
 
 class TestChunkedFlushStress:

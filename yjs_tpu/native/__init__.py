@@ -201,6 +201,10 @@ def load():
     lib.ymx_encode_arena.argtypes = []
     lib.ymx_plan_seq.restype = u64
     lib.ymx_plan_seq.argtypes = [vp]
+    # the formatting clean-up after a remote transaction, for the room's
+    # current plan: (client, clock, length) triples, -1 to fall back
+    lib.ymx_format_cleanup.restype = i64
+    lib.ymx_format_cleanup.argtypes = [vp, i64, i64p, i64, i64p]
     lib.ymx_compact_self.restype = i64
     lib.ymx_compact_self.argtypes = [vp, ctypes.c_int, i32p, u8p, i32p, i64]
     # ymx_prepare_many's worker-pool width (surfaced as

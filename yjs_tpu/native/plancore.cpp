@@ -107,9 +107,24 @@ struct Plan {
   // sorts, matching the Python twin's `sorted(plan._dl)`.
   std::vector<int64_t> dirty_links, dirty_heads;
   std::vector<int64_t> link_rows, link_vals, head_segs, head_vals;
+  // what this step planned, by kind (counts[3..5], plan_kind_counts):
+  // rows it added, and of those the rows whose parent is a type item,
+  // format rows, rows under a parentSub and type rows; segments before
+  // it; map entries it deleted (a later writer's delete set, or the
+  // last-writer-wins pass); format rows it deleted
+  int64_t rows_new = 0, rows_nested = 0, rows_format = 0;
+  int64_t rows_attr = 0, rows_type = 0, segs_before = 0;
+  int64_t lww_overwritten = 0, format_deleted = 0;
+  // rows this step deleted (delete_rows also carries the fragments it
+  // split off rows that earlier steps deleted)
+  std::vector<int64_t> fresh_deletes;
 
   void clear() {
+    fresh_deletes.clear();
     n_rows = 0;
+    rows_new = rows_nested = rows_format = 0;
+    rows_attr = rows_type = segs_before = 0;
+    lww_overwritten = format_deleted = 0;
     splits.clear();
     sched.clear();
     delete_rows.clear();
@@ -677,10 +692,15 @@ struct Mirror {
     if (r_host_deleted[row] || r_is_gc[row]) return;
     r_host_deleted[row] = 1;
     plan.delete_rows.push_back(row);
+    plan.fresh_deletes.push_back(row);
+    if (r_ref[row] == 6) plan.format_deleted++;
     note_deleted(r_slot[row], r_clock[row], r_len[row]);
     plan.applied_ds.push_back({{row_client(row), r_clock[row], r_len[row]}});
     int64_t sg = r_seg[row];
-    if (sg != kNull && seg_is_map(sg)) r_lww_deleted[row] = 1;
+    if (sg != kNull && seg_is_map(sg)) {
+      r_lww_deleted[row] = 1;
+      plan.lww_overwritten++;
+    }
     if (r_ref[row] == 7) {
       auto it = segs_of_parent.find(row);
       if (it != segs_of_parent.end()) {
@@ -702,6 +722,125 @@ struct Mirror {
       for (int64_t r : it->second)
         if (r != tail && !r_lww_deleted[r]) delete_row(r);
     }
+  }
+
+  // ---- formatting clean-up (ops/engine.py _cleanup_room is the twin) -----
+
+  // a format row's key and JSON value, where its payload is V1-framed
+  bool format_of(int64_t row, std::string* key, std::string* value) const {
+    const ContentDesc& c = r_c[row];
+    if (c.kind != kKindFramed || c.buf < 0) return false;
+    Reader r{buf_ptr(c.buf), (uint64_t)c.end, (uint64_t)c.ofs, false};
+    uint64_t o, b;
+    r.var_string(&o, &b);
+    if (r.fail) return false;
+    key->assign(reinterpret_cast<const char*>(r.buf + o), (size_t)b);
+    r.var_string(&o, &b);
+    if (r.fail) return false;
+    value->assign(reinterpret_cast<const char*>(r.buf + o), (size_t)b);
+    return true;
+  }
+
+  // the type ref of a ContentType row, or -1
+  int64_t type_ref_of(int64_t row) const {
+    const ContentDesc& c = r_c[row];
+    if (c.kind == kKindV2Lazy) return c.count;
+    if (c.kind != kKindFramed || c.buf < 0) return -1;
+    Reader r{buf_ptr(c.buf), (uint64_t)c.end, (uint64_t)c.ofs, false};
+    uint64_t t = r.varuint();
+    return r.fail ? -1 : (int64_t)t;
+  }
+
+  struct HeldAttr {
+    int64_t row;        // the format row that set it
+    std::string value;  // its JSON text
+  };
+
+  // JS `held || null` === the value of the format row `row`: an object
+  // is itself alone, a primitive equals its like (JSON text)
+  static bool attr_strict_eq(const HeldAttr* held, int64_t row,
+                             const std::string& value) {
+    static const std::string kNullText = "null";
+    bool falsy = held == nullptr || held->value == "null" ||
+                 held->value == "false" || held->value == "0" ||
+                 held->value == "-0" || held->value == "\"\"";
+    const std::string& a = falsy ? kNullText : held->value;
+    bool a_obj = a[0] == '{' || a[0] == '[';
+    bool v_obj = !value.empty() && (value[0] == '{' || value[0] == '[');
+    if (a_obj || v_obj) return !falsy && held->row == row;
+    return a == value;
+  }
+
+  // What a Y.Doc deletes from its texts after a remote transaction
+  // (YText._callObserver -> cleanupYTextFormatting), for the step the
+  // current plan is of; `rows_before`: the rows held before that step.
+  // Writes (client, clock, length) a format row to delete and returns
+  // their number; -1 where a payload is not V1-framed or `cap` is too
+  // small (the caller then walks in Python).
+  int64_t format_cleanup(int64_t rows_before, int64_t* out, int64_t cap,
+                         int64_t* n_texts) {
+    *n_texts = 0;
+    std::vector<int64_t> changed, lost;
+    bool brought = false;
+    for (int64_t r = rows_before; r < n_rows(); r++) {
+      if (r_ref[r] == 6 && !r_host_deleted[r] && !r_is_gc[r]) brought = true;
+      if (r_seg[r] != kNull) changed.push_back(r_seg[r]);
+    }
+    for (int64_t r : plan.fresh_deletes) {
+      int64_t sg = r_seg[r];
+      if (sg == kNull) continue;
+      changed.push_back(sg);
+      if (r_ref[r] == 6) lost.push_back(sg);
+    }
+    std::sort(changed.begin(), changed.end());
+    changed.erase(std::unique(changed.begin(), changed.end()), changed.end());
+    std::sort(lost.begin(), lost.end());
+    int64_t n_out = 0;
+    std::string key, value;
+    for (int64_t sg : changed) {
+      int64_t parent = seg_parent[sg];
+      if (seg_sub_id[sg] != kNull || parent == kNull) continue;
+      if (!brought && !std::binary_search(lost.begin(), lost.end(), sg))
+        continue;
+      if (parent >= rows_before || r_host_deleted[parent] ||
+          r_ref[parent] != 7)
+        continue;
+      int64_t tref = type_ref_of(parent);
+      if (tref < 0) return -1;
+      if (tref != 2 && tref != 6) continue;  // YText, YXmlText
+      (*n_texts)++;
+      std::map<std::string, HeldAttr> attrs, start_attrs;
+      std::vector<int64_t> gap;  // live format rows since the last string
+      for (int64_t r = head_of_seg[sg]; r != kNull; r = list_next[r]) {
+        if (r_host_deleted[r]) continue;
+        if (r_ref[r] == 6) {
+          if (!format_of(r, &key, &value)) return -1;
+          gap.push_back(r);
+          if (value == "null") attrs.erase(key);
+          else attrs[key] = HeldAttr{r, value};
+        } else if (r_ref[r] == 4 || r_ref[r] == 5) {
+          for (int64_t f : gap) {
+            format_of(f, &key, &value);
+            auto e = attrs.find(key);
+            auto b = start_attrs.find(key);
+            bool eq_end = attr_strict_eq(
+                e == attrs.end() ? nullptr : &e->second, f, value);
+            bool eq_start = attr_strict_eq(
+                b == start_attrs.end() ? nullptr : &b->second, f, value);
+            if (!eq_end || eq_start) {
+              if (n_out >= cap) return -1;
+              out[3 * n_out] = row_client(f);
+              out[3 * n_out + 1] = r_clock[f];
+              out[3 * n_out + 2] = r_len[f];
+              n_out++;
+            }
+          }
+          gap.clear();
+          start_attrs = attrs;
+        }
+      }
+    }
+    return n_out;
   }
 
   // ---- wire scan (decode_update_refs twin) ------------------------------
@@ -1038,6 +1177,7 @@ struct Mirror {
       t0 = t1;
     };
     plan.clear();
+    plan.segs_before = n_segs();
     plan_seq++;
     dirty_epoch++;
 
@@ -1441,6 +1581,11 @@ struct Mirror {
       em_last_clock = ref.clock;
       em_last_len = ref.length;
       if (want_sched) plan.sched.push_back({{row, left_row, right_row, sg}});
+      plan.rows_new++;
+      if (seg_parent[sg] != kNull) plan.rows_nested++;
+      if (seg_is_map(sg)) plan.rows_attr++;
+      if (ref.ref == 6) plan.rows_format++;
+      if (ref.ref == 7) plan.rows_type++;
       int64_t actual_left = list_insert(sg, row, left_row, right_row);
       if (seg_is_map(sg)) {
         auto& chain = map_chain[sg];
@@ -2538,6 +2683,17 @@ int64_t ymx_buf_len(void* h, int64_t idx) {
 // n_applied_ds, has_pending, pending_depth, n_slots, n_segs, n_links,
 // n_heads, [14] 0 (ymx_prepare_many's dense-link flag), [15] the plan's
 // number (Mirror::plan_seq).  Returns 0 or an error code (<0).
+// counts[3..5]: the step's rows by kind, 21 bits a field (a field that
+// would not fit reads its largest value)
+static void plan_kind_counts(const Mirror* m, int64_t* c) {
+  auto f = [](int64_t v) { return v < 0 ? 0 : (v > 0x1FFFFF ? 0x1FFFFF : v); };
+  const Plan& p = m->plan;
+  c[3] = f(p.rows_type) | (f(p.format_deleted) << 21);
+  c[4] = f(p.rows_new) | (f(p.rows_nested) << 21) | (f(p.rows_format) << 42);
+  c[5] = f(p.rows_attr) | (f(m->n_segs() - p.segs_before) << 21) |
+         (f(p.lww_overwritten) << 42);
+}
+
 int ymx_prepare(void* h, const int64_t* buf_ids, const int64_t* v2_flags,
                 int64_t n_updates, int64_t* out_counts) {
   Mirror* m = static_cast<Mirror*>(h);
@@ -2548,7 +2704,7 @@ int ymx_prepare(void* h, const int64_t* buf_ids, const int64_t* v2_flags,
   out_counts[0] = m->plan.n_rows;
   out_counts[1] = (int64_t)m->plan.splits.size();
   out_counts[2] = (int64_t)m->plan.sched.size();
-  out_counts[3] = out_counts[4] = out_counts[5] = 0;
+  plan_kind_counts(m, out_counts);
   out_counts[6] = (int64_t)m->plan.delete_rows.size();
   out_counts[7] = (int64_t)m->plan.applied_ds.size();
   out_counts[8] = (m->pending.empty() && m->pending_ds.empty()) ? 0 : 1;
@@ -2651,7 +2807,7 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
     c[0] = m->plan.n_rows;
     c[1] = (int64_t)m->plan.splits.size();
     c[2] = (int64_t)m->plan.sched.size();
-    c[3] = c[4] = c[5] = 0;
+    plan_kind_counts(m, c);
     c[6] = (int64_t)m->plan.delete_rows.size();
     c[7] = (int64_t)m->plan.applied_ds.size();
     c[8] = (m->pending.empty() && m->pending_ds.empty()) ? 0 : 1;
@@ -3312,6 +3468,12 @@ int64_t ymx_encode_steps_many(void** hs, int64_t n_docs,
 const uint8_t* ymx_encode_arena() { return g_encode_arena.p.get(); }
 
 uint64_t ymx_plan_seq(void* h) { return static_cast<Mirror*>(h)->plan_seq; }
+
+int64_t ymx_format_cleanup(void* h, int64_t rows_before, int64_t* out,
+                           int64_t cap, int64_t* n_texts) {
+  return static_cast<Mirror*>(h)->format_cleanup(rows_before, out, cap,
+                                                 n_texts);
+}
 
 // compaction from the mirror's OWN list/deleted state — the flush
 // invariant keeps these equal to the device arrays, so no device

@@ -205,6 +205,31 @@ FLUSH_METRICS_SCHEMA: dict = {
     # max device dispatches in flight at once (0 = no dispatch or
     # synchronous mode; the double-buffered staging pair bounds it)
     "pipeline_depth": 0,
+    # what the planner integrated, by kind: rows it added, and of those
+    # the rows whose parent is a type item (a nested type's children),
+    # ContentFormat rows, rows under a parentSub (a map entry, an XML
+    # attribute) and ContentType rows; segments the flush created (a
+    # nested type's list, a map key's chain); map entries the flush
+    # deleted (overwritten by a later writer, whose own delete set names
+    # the old value, or by the last-writer-wins pass; or removed);
+    # format rows the flush deleted
+    "rows_planned": 0,
+    "rows_nested": 0,
+    "rows_format": 0,
+    "rows_attr": 0,
+    "rows_type": 0,
+    "segs_created": 0,
+    "lww_overwritten": 0,
+    "format_deleted": 0,
+    # format items the clean-up after a remote transaction deleted
+    # (engine._format_cleanup: what a Y.Doc's YText._callObserver
+    # deletes), and the texts it walked
+    "format_cleanup_deleted": 0,
+    "format_cleanup_texts": 0,
+    # the widest planned room's segments, beside the width of the list
+    # heads' table (starts is [n_docs, seg_cap + 1])
+    "n_segs_max": 0,
+    "seg_cap": 0,
 }
 
 FLUSH_PHASES = ("compact", "plan", "pack", "dispatch", "emit")
@@ -476,6 +501,36 @@ class EngineObs:
             "update since a flush last planned them, or that park structs",
             unit="rooms",
         )
+        self._flush_rows_by_kind = {
+            kind: r.counter(
+                "ytpu_flush_rows_by_kind_total",
+                "Rows the planner integrated, by kind: every row "
+                "(planned), children of a nested type (nested), format "
+                "items (format), entries under a parentSub (attr), type "
+                "items (type)",
+                labelnames=("kind",),
+            ).labels(kind=kind)
+            for kind in ("planned", "nested", "format", "attr", "type")
+        }
+        self._flush_segs_created = r.counter(
+            "ytpu_flush_segments_created_total",
+            "Segments flushes created: a nested type's list, a map "
+            "key's chain",
+        )
+        self._flush_lww_overwritten = r.counter(
+            "ytpu_flush_lww_overwritten_total",
+            "Map entries flushes deleted: overwritten by a later writer "
+            "(last writer wins) or removed",
+        )
+        self._flush_format_cleanup_deleted = r.counter(
+            "ytpu_flush_format_cleanup_deleted_total",
+            "Format items deleted by the clean-up after a remote "
+            "transaction (what a Y.Doc's YText._callObserver deletes)",
+        )
+        self._segment_capacity = r.gauge(
+            "ytpu_engine_segment_capacity",
+            "Per-doc device list-head capacity after last flush",
+        )
         self._flush_rooms_compact_looked = r.counter(
             "ytpu_flush_rooms_compact_looked_total",
             "Slots whose row count the compaction look read: the rooms "
@@ -528,6 +583,16 @@ class EngineObs:
             self._flush_rows_staged_blocks.inc(metrics["rows_staged_blocks"])
         self._flush_rooms_dirty.inc(metrics["rooms_dirty"])
         self._flush_rooms_compact_looked.inc(metrics["rooms_compact_looked"])
+        if metrics["rows_planned"]:
+            for kind, child in self._flush_rows_by_kind.items():
+                child.inc(metrics[f"rows_{kind}"])
+            self._flush_segs_created.inc(metrics["segs_created"])
+            self._flush_lww_overwritten.inc(metrics["lww_overwritten"])
+        if metrics["format_cleanup_deleted"]:
+            self._flush_format_cleanup_deleted.inc(
+                metrics["format_cleanup_deleted"]
+            )
+        self._segment_capacity.set(metrics["seg_cap"])
         if metrics["plan_pool_s"]:
             self._plan_pool_seconds.inc(metrics["plan_pool_s"])
             self._plan_room_max_seconds.set(metrics["plan_room_max_s"])

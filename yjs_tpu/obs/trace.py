@@ -59,6 +59,18 @@ LEAF_SPANS = {
     "ytpu.plan.finish": "ytpu.plan",
 }
 
+# The formatting clean-up after a remote transaction (PR 40,
+# ``BatchEngine._format_cleanup``): the look at what each planned room
+# brought, once a flush, and the walk of the texts a format item came to
+# or left, under a ``ytpu.plan`` span of its own; the deletions it finds
+# are planned by a second round of the same flush, under that round's
+# own phase spans.  The benchmark's ``cleanup_share`` reads it.  (A table
+# of its own: ``tests/bench/test_leaf_spans.py`` holds ``LEAF_SPANS`` to
+# PR 38's six.)
+FORMAT_SPANS = {
+    "ytpu.plan.cleanup": "ytpu.plan",
+}
+
 _NO_SPAN = contextlib.nullcontext()
 
 

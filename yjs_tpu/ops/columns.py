@@ -452,6 +452,10 @@ class StepPlan:
     # structs placed by the segment-sorted conflict-free fast path
     # instead of the sequential YATA walk (ISSUE 9 accounting)
     fastpath_structs: int = 0
+    # segments the mirror had before this step, and map entries the
+    # step deleted (a later writer's delete set, or the LWW pass)
+    segs_before: int = 0
+    lww_overwritten: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -918,6 +922,7 @@ class DocMirror:
         sg = self.row_seg[row]
         if sg != NULL and self.seg_is_map(sg):
             self._lww_deleted.add(row)
+            plan.lww_overwritten += 1
         if self.row_content_ref[row] == 7:
             for cs in self._segs_of_parent.get(row, ()):
                 for child in list(self._rows_of_seg.get(cs, ())):
@@ -1186,7 +1191,7 @@ class DocMirror:
             need_start(client, clock)
             need_start(client, clock + ln)
 
-        plan = StepPlan(n_rows=0)
+        plan = StepPlan(n_rows=0, segs_before=self.n_segs)
         plan._dl = set()  # rows whose list_next changed this step
         plan._dh = set()  # segs whose head changed this step
 

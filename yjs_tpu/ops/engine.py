@@ -13,6 +13,7 @@ Provider gating seam.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from types import SimpleNamespace
@@ -91,6 +92,191 @@ def visible_text(mirror, rows, deleted) -> str:
         else:
             out.append("".join(str(x) for x in getattr(content, "arr", [])))
     return from_u16("".join(out))
+
+
+# one device: the widest lane key that counts as a served flush's, and
+# the padding such a flush accepts before it has a new program compiled
+# (engine._covering_key)
+_SERVED_LANES = 4096
+_SERVED_PAD = 512
+# one device: the rooms, and their width, that one staged block of a
+# served process's compactions holds (engine._scatter_rebuilt)
+_SERVED_ROOMS = 8
+_SERVED_WIDTH = 4096
+
+_KIND_KEYS = (
+    "rows_planned", "rows_nested", "rows_format", "rows_attr", "rows_type",
+    "segs_created", "lww_overwritten", "format_deleted",
+)
+_KIND_BITS = 21  # plancore.cpp plan_kind_counts: three fields a word
+
+
+def _kind_counts(counts: np.ndarray) -> dict:
+    """What each native plan of ``counts`` integrated, by kind
+    (``counts[:, 3:6]`` as ``plan_kind_counts`` packs them)."""
+    mask = (1 << _KIND_BITS) - 1
+    c3, c4, c5 = counts[:, 3], counts[:, 4], counts[:, 5]
+    return {
+        "rows_planned": c4 & mask,
+        "rows_nested": (c4 >> _KIND_BITS) & mask,
+        "rows_format": (c4 >> 2 * _KIND_BITS) & mask,
+        "rows_attr": c5 & mask,
+        "rows_type": c3 & mask,
+        "segs_created": (c5 >> _KIND_BITS) & mask,
+        "lww_overwritten": (c5 >> 2 * _KIND_BITS) & mask,
+        "format_deleted": (c3 >> _KIND_BITS) & mask,
+    }
+
+
+def _kind_counts_py(m, p) -> dict:
+    """The same of one Python plan, from the mirror's columns."""
+    rows = [int(s[0]) for s in p.sched]
+    info, seg, ref = m.seg_info, m.row_seg, m.row_content_ref
+    segs = [info[seg[r]] for r in rows if seg[r] != NULL]
+    return {
+        "rows_planned": len(rows),
+        "rows_nested": sum(1 for _n, _s, parent in segs if parent != NULL),
+        "rows_format": sum(1 for r in rows if ref[r] == 6),
+        "rows_attr": sum(1 for _n, sub, _p in segs if sub is not None),
+        "rows_type": sum(1 for r in rows if ref[r] == 7),
+        "segs_created": m.n_segs - p.segs_before,
+        "lww_overwritten": p.lww_overwritten,
+        "format_deleted": sum(1 for r in p.delete_rows if ref[int(r)] == 6),
+    }
+
+
+# of a flush's metrics, those that are a level and not a sum: the second
+# round of a flush (engine._finish_flush) keeps the larger
+_FLUSH_LEVELS = (
+    "n_rows_max", "n_levels", "level_width", "schedule_occupancy",
+    "n_fallback_docs", "n_pending_docs", "pending_depth", "plan_threads",
+    "plan_room_max_s", "pipeline_depth", "n_segs_max", "seg_cap",
+)
+
+
+def _add_flush_round(metrics: dict, first: dict) -> None:
+    """Fold the first round's metrics of a flush into its second's."""
+    for key, value in first.items():
+        if key in _FLUSH_LEVELS:
+            metrics[key] = max(metrics[key], value)
+        elif key == "flush_donated":
+            metrics[key] = int(
+                (metrics[key] or first[key])
+                and not (metrics["realloc_bytes"] + first["realloc_bytes"])
+            )
+        else:
+            metrics[key] += value
+
+
+def _delete_only_update(ids) -> bytes:
+    """A V1 update of no struct and the delete set ``ids``
+    (``(client, clock, length)``, any order)."""
+    from ..coding import DSEncoderV1
+    from ..core import (
+        add_to_delete_set, create_delete_set, sort_and_merge_delete_set,
+        write_delete_set,
+    )
+    from ..lib0.encoding import write_var_uint
+
+    ds = create_delete_set()
+    for client, clock, length in ids:
+        add_to_delete_set(ds, client, clock, length)
+    sort_and_merge_delete_set(ds)
+    encoder = DSEncoderV1()
+    write_var_uint(encoder.rest_encoder, 0)  # no client's structs
+    write_delete_set(encoder, ds)
+    return encoder.to_bytes()
+
+
+def _cleanup_room(m, rows_before: int, plan):
+    """``(ids, texts)``: the format items ``(client, clock, length)``
+    that ``BatchEngine._format_cleanup`` deletes in one room after the
+    flush that ``plan`` is of, and the texts it walked.  ``rows_before``
+    is the mirror's row count before that flush."""
+    from .events import _coverage, _deleted_now
+
+    info, row_seg, ref = m.seg_info, m.row_seg, m.row_content_ref
+    dead = m._host_deleted_rows
+    client_of, slot, clock = m.client_of_slot, m.row_slot, m.row_clock
+    # the types the flush changed: it added an item of theirs (a row it
+    # split is in a segment it added to or deleted from) ...
+    changed, brought = set(), False
+    for r in range(rows_before, m.n_rows):
+        if ref[r] == 6 and r not in dead:
+            brought = True
+        if row_seg[r] != NULL:
+            changed.add(int(row_seg[r]))
+    # ... or deleted one (fragments of rows deleted by earlier flushes
+    # ride in delete_rows too: the flush's own delete set tells them)
+    cov = _coverage(plan.applied_ds)
+    lost_format = set()
+    for r in plan.delete_rows:
+        r = int(r)
+        sg = int(row_seg[r])
+        if sg == NULL or not _deleted_now(cov, client_of[slot[r]], clock[r]):
+            continue
+        changed.add(sg)
+        if ref[r] == 6:
+            lost_format.add(sg)
+    ids, texts = [], 0
+    for sg in sorted(changed):
+        _name, sub, parent = info[sg]
+        if sub is not None or parent == NULL or not (
+            brought or sg in lost_format
+        ):
+            continue
+        parent = int(parent)
+        if parent >= rows_before or parent in dead:
+            continue  # a type of this flush's own, or one it deleted
+        kind = type(getattr(m.realized_content(parent), "type", None))
+        if kind.__name__ not in ("YText", "YXmlText"):
+            continue
+        texts += 1
+        ids += [
+            (client_of[slot[r]], int(clock[r]), int(m.row_len[r]))
+            for r in _cleanup_text(m, sg)
+        ]
+    return ids, texts
+
+
+def _cleanup_text(m, seg: int) -> list[int]:
+    """``cleanupYTextFormatting`` (reference YText.js:412-437) over one
+    text's rows in list order: the format rows to delete.  An attribute
+    value that is an object equals itself alone, as in the reference
+    (JS ``===``): a row's realized content is one object while this
+    runs."""
+    from ..types.ytext import _js_strict_eq, _or_null, update_current_attributes
+
+    dead, nxt, ref = m._host_deleted_rows, m.list_next, m.row_content_ref
+    order = []
+    r = m.head_of_seg[seg]
+    while r != NULL:
+        order.append(int(r))
+        r = nxt[int(r)]
+    formats: dict[int, object] = {}
+    doomed: list[int] = []
+    start, start_attrs, attrs = 0, {}, {}
+    for end, r in enumerate(order):
+        if r in dead:
+            continue
+        if ref[r] == 6:
+            formats[r] = content = m.realized_content(r)
+            update_current_attributes(attrs, content)
+        elif ref[r] in (4, 5):
+            for at in order[start:end]:
+                content = formats.get(at)
+                if content is not None and (
+                    not _js_strict_eq(
+                        _or_null(attrs.get(content.key)), content.value
+                    )
+                    or _js_strict_eq(
+                        _or_null(start_attrs.get(content.key)), content.value
+                    )
+                ):
+                    doomed.append(at)
+            formats.clear()
+            start, start_attrs = end, dict(attrs)
+    return doomed
 
 
 def _bucket(n: int, minimum: int = 64) -> int:
@@ -337,7 +523,7 @@ class BatchEngine:
         self._sharded_sv: dict[int, object] = {}
         # cached sharded bulk-apply callables keyed by lane bucket shape
         self._sharded_apply: dict[tuple, object] = {}
-        # lane keys the packer has issued on a mesh (_covering_key)
+        # lane keys the packer has issued and may issue again (_covering_key)
         self._mesh_keys: set[tuple] = set()
         # explicit placement: a meshed engine pins EVERY host->device
         # transfer to the mesh's devices so it can never touch the default
@@ -405,6 +591,15 @@ class BatchEngine:
         self._flush_rows_staged_bytes = 0
         self._flush_rows_held_bytes = 0
         self._flush_rows_staged_blocks = 0
+        # rooms whose last plan brought or deleted a format item, each
+        # with the rows it held before that plan: what _format_cleanup
+        # looks at; and the first round's metrics of a flush whose
+        # clean-up planned a second one
+        self._cleanup_gate: list[tuple] = []
+        self._flush_carry: dict | None = None
+        # the device's rows of one room, read back once for an export
+        # that walks many of its segments: (doc, right, deleted, starts)
+        self._export_rows: tuple | None = None
         # bytes of device rows the releases since the last flush's end
         # blanked (reset_doc: one whole row of each table a slot)
         self._release_blanked_bytes = 0
@@ -766,22 +961,74 @@ class BatchEngine:
             self._right = fresh((b, self._cap + 1), NULL, jnp.int32)
             self._deleted = fresh((b, self._cap + 1), False, jnp.bool_)
             self._starts = fresh((b, self._seg_cap + 1), NULL, jnp.int32)
+            grown = (self._right, self._deleted, self._starts)
         else:
-            # old scratch column (index old_cap) resets to padding
-            self._right = grow(
-                self._right, old_cap, self._cap + 1, NULL, jnp.int32
-            )
-            self._deleted = grow(
-                self._deleted, old_cap, self._cap + 1, False, jnp.bool_
-            )
-            self._starts = grow(
-                self._starts, old_seg, self._seg_cap + 1, NULL, jnp.int32
-            )
+            # the table whose width changed, and no other: a room that
+            # gains segments widens the list heads (a few KB a room), not
+            # the rows; old scratch column (index old_cap) resets to
+            # padding
+            grown = ()
+            if self._cap != old_cap:
+                self._right = grow(
+                    self._right, old_cap, self._cap + 1, NULL, jnp.int32
+                )
+                self._deleted = grow(
+                    self._deleted, old_cap, self._cap + 1, False, jnp.bool_
+                )
+                grown += (self._right, self._deleted)
+            if self._seg_cap != old_seg:
+                self._starts = grow(
+                    self._starts, old_seg, self._seg_cap + 1, NULL, jnp.int32
+                )
+                grown += (self._starts,)
         # donation bookkeeping: a grown table is a fresh allocation, so
         # this flush cannot have updated device state purely in place
-        self._flush_realloc_bytes += int(
-            self._right.nbytes + self._deleted.nbytes + self._starts.nbytes
-        )
+        self._flush_realloc_bytes += sum(int(t.nbytes) for t in grown)
+
+    # -- formatting clean-up --------------------------------------------------
+
+    def _format_cleanup(self, metrics: dict) -> int:
+        """What a ``Y.Doc`` does to its texts after a remote transaction
+        (reference ``YText._callObserver``, YText.js:803-856;
+        ``types/ytext.py``), for the rooms this flush planned: a flush
+        is the engine's transaction.  A text is looked at if it is a
+        nested ``Y.Text`` / ``Y.XmlText`` that existed before the flush
+        and lives after it (a root's kind is not on the wire, so a root
+        text is not cleaned, as a ``Y.Doc`` that never called
+        ``get_text`` on it does not clean it), the flush added or
+        deleted an item of it, and the flush brought a live format item
+        (anywhere in the room) or deleted one of the text's.  Such a
+        text takes ``cleanupYTextFormatting``: between two live strings
+        every format item goes that the attributes in force after the
+        gap do not owe to it, or that repeats what was in force before
+        it.  (The reference's other branch, the contextless gap
+        clean-up, reads the clean-up transaction's own, empty delete
+        set and deletes nothing.)
+
+        The deletions are queued as one delete-only update a room, which
+        the flush's second round plans, dispatches and broadcasts like a
+        peer's; they are in no journal (a recovery that replays the
+        journal cleans again).  Only rooms whose plan says it brought or
+        deleted a format item are looked at (``_cleanup_gate``).
+        Returns the rooms an update was queued for."""
+        gate, self._cleanup_gate = self._cleanup_gate, []
+        queued = 0
+        for doc, rows_before, plan in gate:
+            if doc in self.fallback:
+                continue
+            m = self.mirrors[doc]
+            found = None
+            if isinstance(plan, np.ndarray):  # a native plan's counts row
+                found = m.format_cleanup(rows_before)
+                if found is None:
+                    plan = m.make_plan(plan)
+            ids, n_texts = found or _cleanup_room(m, rows_before, plan)
+            metrics["format_cleanup_texts"] += n_texts
+            if ids:
+                metrics["format_cleanup_deleted"] += len(ids)
+                if self.queue_update(doc, _delete_only_update(ids)):
+                    queued += 1
+        return queued
 
     # -- compaction ---------------------------------------------------------
 
@@ -876,16 +1123,33 @@ class BatchEngine:
         room holds.  A room's block is at least as wide as the cells it
         has written, whatever class the others are in, so the tables
         come out as one block as wide as the widest room would leave
-        them."""
+        them.
+
+        One device, a handful of short rooms (``_SERVED_ROOMS`` at most,
+        none wider than ``_SERVED_WIDTH``: the amortized compactions of a
+        served process, a room now and then as it doubles): one block of
+        exactly that shape, its spare rows aimed past the last slot,
+        where the scatter drops them, its heads as wide as the table's.
+        A block's shape is a ``scatter_rows`` program, and which rooms
+        double in one flush, and how long they are, is the traffic's: a
+        process would go on meeting new shapes for as long as it serves
+        (some hundred KB staged where some dozen would do, against
+        seconds of compiling)."""
         span = self._phase_ctx
         own = [min(_bucket(n), self._cap + 1) for n in n_rows]
+        served = (
+            self.mesh is None and len(todo) <= _SERVED_ROOMS
+            and max(own) <= _SERVED_WIDTH <= self._cap
+        )
         width_of: dict[int, int] = {}
         for w in sorted(set(own), reverse=True):
             # half an anchor's width joins it; anything else anchors
             width_of[w] = 2 * w if width_of.get(2 * w) == 2 * w else w
         classes: dict[int, list[int]] = {}
         for j, w in enumerate(own):
-            classes.setdefault(width_of[w], []).append(j)
+            classes.setdefault(
+                _SERVED_WIDTH if served else width_of[w], []
+            ).append(j)
         for w in sorted(classes):
             members = classes[w]
             docs = [todo[j] for j in members]
@@ -894,6 +1158,8 @@ class BatchEngine:
                 _bucket(max(n_segs[j] for j in members), 8),
                 self._seg_cap + 1,
             )
+            if served:
+                k, ws = _SERVED_ROOMS, self._seg_cap + 1
             with span("compact.alloc"):
                 new_right = np.full((k, w), NULL, np.int32)
                 new_deleted = np.zeros((k, w), bool)
@@ -912,6 +1178,8 @@ class BatchEngine:
             self._flush_rows_held_bytes += held
             self._flush_rows_staged_blocks += 1
             with span("compact.put"):
+                # a served block's spare rows: past the last slot
+                docs = docs + [self.n_docs] * (k - len(docs))
                 rows = (
                     self._put_r(np.asarray(docs, np.int32)),
                     self._put_r(new_right), self._put_r(new_deleted),
@@ -1083,6 +1351,24 @@ class BatchEngine:
         self._flush_rows_staged_blocks = 0
         metrics["release_blanked_bytes"] = self._release_blanked_bytes
         self._release_blanked_bytes = 0
+        metrics["seg_cap"] = self._seg_cap
+        carry, self._flush_carry = self._flush_carry, None
+        if carry is not None:
+            # the second round of a flush whose clean-up deleted format
+            # items: one record a flush, the rounds' counts and seconds
+            # added up
+            self._cleanup_gate = []
+            _add_flush_round(metrics, carry)
+        else:
+            with self._phase_ctx("plan"), self._phase_ctx("plan.cleanup"):
+                queued = self._format_cleanup(metrics)
+            if queued:
+                self._flush_carry = metrics
+                try:
+                    self._flush()
+                finally:  # a round that raised has not taken it
+                    self._flush_carry = None
+                return
         self.obs.record_flush(metrics, row_capacity=self._cap)
         if self.obs.enabled:
             self._record_device_memory()
@@ -1641,7 +1927,37 @@ class BatchEngine:
             pending_mask = counts[:, 8] == 1
             n_pending = int(pending_mask.sum())
             pending_depth = int(counts[pending_mask, 9].sum())
+            kinds = _kind_counts(counts)
+            n_segs_max = int(counts[:, 11].max(initial=0))
+            rows_before = counts[:, 0] - kinds["rows_planned"] - counts[:, 1]
+            self._cleanup_gate = [
+                (work_ok[k][0], int(rows_before[k]), work_ok[k][2])
+                for k in np.flatnonzero(
+                    (kinds["rows_format"] > 0) | (kinds["format_deleted"] > 0)
+                )
+            ]
+            kinds = {k: int(v.sum()) for k, v in kinds.items()}
         else:
+            kinds = dict.fromkeys(_KIND_KEYS, 0)
+            n_segs_max = 0
+            self._cleanup_gate = []
+            for i, p in work_ok:
+                m = self.mirrors[i]
+                if isinstance(p, NativePlan):  # a native room in this lane
+                    one = {
+                        k: int(v[0])
+                        for k, v in _kind_counts(p.counts[None]).items()
+                    }
+                    held = p.counts
+                else:
+                    one, held = _kind_counts_py(m, p), p
+                for k, v in one.items():
+                    kinds[k] += v
+                n_segs_max = max(n_segs_max, m.n_segs)
+                if one["rows_format"] or one["format_deleted"]:
+                    self._cleanup_gate.append((
+                        i, p.n_rows - one["rows_planned"] - len(p.splits), held
+                    ))
             n_flushed = sum(
                 1
                 for _, p in work_ok
@@ -1672,6 +1988,8 @@ class BatchEngine:
             "t_dispatch_s": t_disp_acc,
             "t_emit_s": t_emit - t_dispatch,
             "t_total_s": t_emit - t_start,
+            "n_segs_max": n_segs_max,
+            **kinds,
         })
         if native:
             metrics.update({
@@ -1832,21 +2150,44 @@ class BatchEngine:
         return chunk_ok
 
     def _covering_key(self, key):
-        """The lane widths a chunk is packed and dispatched at.  On a
-        mesh a width is the widest shard's sum, and which rooms share a
-        shard changes whenever freed slots are re-let, so the same rooms
-        wander over a bucket's edge from one flush to the next: a key
-        this packer has issued before whose lanes cover the chunk's, at
-        no more than a quarter more of them (two steps of
-        ``_bucket_lanes``), is issued again before a new one, which the
-        dispatcher would have to compile (seconds on a chip against
-        microseconds of padding).  One device: the key as it is, since
-        there the same rooms always give the same key."""
-        if self.mesh is None or key in self._mesh_keys:
+        """The lane widths a chunk is packed and dispatched at: a key
+        this packer has issued before whose lanes cover the chunk's is
+        issued again before a new one, which the dispatcher would have
+        to compile (seconds on a chip against microseconds of padding).
+
+        On a mesh a width is the widest shard's sum, and which rooms
+        share a shard changes whenever freed slots are re-let, so the
+        same rooms wander over a bucket's edge from one flush to the
+        next: an issued key covers at no more than a quarter more lanes
+        (two steps of ``_bucket_lanes``).  On one device the same rooms
+        always give the same key, so a bulk load keeps its exact key;
+        but a served flush (``_SERVED_LANES`` lanes or fewer: some dozens
+        of rooms' keystrokes) wanders too, in each of its four widths by
+        itself (links with the characters typed, list heads with the
+        nodes split, deletes with the backspaces), and the product of
+        their buckets is more programs than a process should meet: there
+        a width that has to be new is a power of two, and an issued key
+        covers at a quarter or ``_SERVED_PAD`` lanes more, whichever is
+        more."""
+        if key in self._mesh_keys:
             return key
+        lanes = sum(key)
+        if self.mesh is None:
+            if lanes > _SERVED_LANES:
+                return key
+            # a new width is a power of two: the widest flush a process
+            # has met keeps being outdone by a lane or two for as long
+            # as it runs, and every such record would be a program
+            key = tuple(_bucket(k, 8) for k in key)
+            if key in self._mesh_keys:
+                return key
+            lanes = sum(key)
+        most = 5 * lanes // 4
+        if self.mesh is None:
+            most = max(most, lanes + _SERVED_PAD)
         fits = [
             k for k in self._mesh_keys
-            if all(a >= b for a, b in zip(k, key)) and 4 * sum(k) <= 5 * sum(key)
+            if all(a >= b for a, b in zip(k, key)) and sum(k) <= most
         ]
         if fits:
             return min(fits, key=sum)
@@ -2063,6 +2404,19 @@ class BatchEngine:
             return np.asarray(rows_l, np.int64), np.asarray(dele_l, bool)
         if self._right is None:
             return np.zeros(0, np.int64), np.zeros(0, bool)
+        held = self._export_rows
+        if held is not None and held[0] == doc:
+            # a whole room's export (a tree of many segments): the
+            # room's rows came back once, and each segment is walked
+            # from its head along the device's own links
+            _doc, right, deleted, starts = held
+            rows_l = []
+            r = int(starts[seg]) if seg < len(starts) else NULL
+            while r != NULL and len(rows_l) <= len(right):
+                rows_l.append(r)
+                r = int(right[r])
+            rows = np.asarray(rows_l, np.int64)
+            return rows, deleted[rows]
         self._apply_pending_hydrations()  # device read-back must see them
         valid_host = np.zeros(self._right.shape[1], bool)
         n = m.n_rows
@@ -2490,10 +2844,35 @@ class BatchEngine:
         pack_str()
         return ops
 
+    @contextlib.contextmanager
+    def _room_rows(self, doc: int):
+        """While it is open, ``_order`` walks ``doc``'s segments out of
+        one read-back of the room's device rows (``export_from_device``
+        only; the host path reads no device)."""
+        if (
+            not self.export_from_device or self._right is None
+            or doc in self.fallback or self._export_rows is not None
+        ):
+            yield
+            return
+        self._apply_pending_hydrations()
+        self._export_rows = (
+            doc, np.asarray(self._right[doc]),
+            np.asarray(self._deleted[doc]), np.asarray(self._starts[doc]),
+        )
+        try:
+            yield
+        finally:
+            self._export_rows = None
+
     def xml_string(self, doc: int, name: str | None = None) -> str:
         """Serialize a root XML fragment from the mirror (reference
         YXmlFragment/YXmlElement/YXmlText toString — sorted attributes,
         nested formatting tags), no CPU-doc replay."""
+        with self._room_rows(doc):
+            return self._xml_string(doc, name)
+
+    def _xml_string(self, doc: int, name: str | None) -> str:
         name = name or self.root_name
         fb = self.fallback.get(doc)
         if fb is not None:

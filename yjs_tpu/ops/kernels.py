@@ -162,7 +162,9 @@ def scatter_rows(right, deleted, starts, idx, new_right, new_deleted,
     the same donation contract as the flush dispatch kernels (ISSUE 12)."""
 
     def put(table, block):
-        return table.at[idx, : block.shape[1]].set(block)
+        # a row aimed past the last doc is dropped (a padded block's
+        # spare rows, BatchEngine._scatter_rebuilt)
+        return table.at[idx, : block.shape[1]].set(block, mode="drop")
 
     return (
         put(right, new_right),

@@ -321,6 +321,24 @@ class NativeMirror:
         self._finish_prepare(rc, staged, ids, counts)
         return NativePlan(lib, h, counts, self)
 
+    def format_cleanup(self, rows_before: int):
+        """``(ids, texts)`` as ``engine._cleanup_room`` gives them, from
+        the core's own rows (no Python shadow is built), for the step
+        the current plan is of; None where the core cannot say (a format
+        item that came V2-framed): the caller walks the shadow."""
+        cap = 256
+        while True:
+            out = np.empty((cap, 3), np.int64)
+            texts = np.zeros(1, np.int64)
+            n = int(self._lib.ymx_format_cleanup(
+                self._h, rows_before, _p64(out), cap, _p64(texts)
+            ))
+            if n >= 0:
+                return list(map(tuple, out[:n].tolist())), int(texts[0])
+            if cap >= self.n_rows:
+                return None
+            cap = self.n_rows
+
     def content_gen(self) -> int:
         """Monotonic change counter (the C++ core's ``gen``): bumps on
         every integrated mutation AND at the end of every prepare, so

@@ -1244,9 +1244,11 @@ class TpuProvider:
 
     # -- state accessors ----------------------------------------------------
 
-    def text(self, guid: str) -> str:
+    def text(self, guid: str, name: str | None = None) -> str:
+        """The room's root text ``name`` (the provider's ``root_name``
+        where none is given, as for every accessor below)."""
         self.flush()
-        return self.engine.text(self.doc_id(guid))
+        return self.engine.text(self.doc_id(guid), name)
 
     def to_delta(
         self,
@@ -1254,6 +1256,7 @@ class TpuProvider:
         snapshot=None,
         prev_snapshot=None,
         compute_ychange=None,
+        name: str | None = None,
     ) -> list:
         """Attributed rich-text delta of the room's root text (reference
         YText.toDelta) — served from the mirror, no CPU replay.  With
@@ -1262,6 +1265,7 @@ class TpuProvider:
         self.flush()
         return self.engine.to_delta(
             self.doc_id(guid),
+            name=name,
             snapshot=snapshot,
             prev_snapshot=prev_snapshot,
             compute_ychange=compute_ychange,
@@ -1347,11 +1351,24 @@ class TpuProvider:
             self.doc_id(guid), rpos
         )
 
-    def xml_string(self, guid: str) -> str:
-        """XML serialization of the room's root fragment (reference
-        YXmlFragment.toString) — served from the mirror."""
+    def xml_string(self, guid: str, name: str | None = None) -> str:
+        """XML serialization of the room's root fragment ``name``
+        (reference YXmlFragment.toString) — served from the mirror.  A
+        y-prosemirror room's is ``xml_string(guid, "prosemirror")``."""
         self.flush()
-        return self.engine.xml_string(self.doc_id(guid))
+        return self.engine.xml_string(self.doc_id(guid), name)
+
+    def map_json(self, guid: str, name: str | None = None) -> dict:
+        """The room's root map ``name`` as JSON (reference YMap.toJSON),
+        nested types included — served from the mirror."""
+        self.flush()
+        return self.engine.map_json(self.doc_id(guid), name)
+
+    def to_json(self, guid: str, name: str | None = None):
+        """The room's root array ``name`` as JSON (reference
+        YArray.toJSON), nested types included — served from the mirror."""
+        self.flush()
+        return self.engine.to_json(self.doc_id(guid), name)
 
     def state_vector(self, guid: str) -> dict[int, int]:
         self.flush()
