@@ -186,8 +186,20 @@ def test_plan_threads_reports_native_pool_width(monkeypatch):
         pytest.skip("native plancore unavailable")
     monkeypatch.setenv("YTPU_PLAN_THREADS", "3")
     m = _distinct_doc_engine(4, monkeypatch)
-    # min(configured pool width, cold docs in the batch)
-    assert m["plan_threads"] == 3
+    # four traces of twelve edits are reckoned under a millisecond of
+    # work: the flushing thread plans them alone and wakes nobody
+    assert (m["plan_threads"], m["plan_pool_woken"]) == (1, 0)
+    # four pasted pages are worth more threads than the width allows:
+    # min(configured width, cold docs in the batch, the work's worth)
+    eng = BatchEngine(4)
+    for i in range(4):
+        doc = Y.Doc(gc=False)
+        doc.client_id = 300 + i
+        doc.get_text("text").insert(0, "a pasted page " * 3000)
+        eng.queue_update(i, Y.encode_state_as_update(doc))
+    eng.flush()
+    m = eng.last_flush_metrics
+    assert (m["plan_threads"], m["plan_pool_woken"]) == (3, 2)
 
 
 def test_steady_state_flush_donates(monkeypatch):

@@ -574,7 +574,7 @@ def test_prepare_many_longest_first_is_index_order(monkeypatch, want_sched):
     import numpy as np
 
     from yjs_tpu.ops.native_mirror import (
-        PLAN_TIMES, encode_steps_many, prepare_many,
+        PLAN_POOL_COUNTS, PLAN_TIMES, encode_steps_many, prepare_many,
     )
 
     rng = random.Random(34)
@@ -655,15 +655,20 @@ def test_prepare_many_longest_first_is_index_order(monkeypatch, want_sched):
     assert digest == PLANS_PR37[want_sched]
     phases = PLAN_TIMES[2:7]
     for t in (t1, t4):
-        assert tuple(t) == PLAN_TIMES and all(v >= 0.0 for v in t.values())
+        assert tuple(t) == PLAN_TIMES + PLAN_POOL_COUNTS
+        assert all(v >= 0.0 for v in t.values())
         # the longest room's prepare is one of the sum's terms, and the
         # phases' laps lie inside their rooms' prepares
         assert 0.0 < t["plan_room_max_s"] <= t["plan_pool_s"]
         assert 0.0 < sum(t[k] for k in phases) <= t["plan_pool_s"]
         assert all(t[k] > 0.0 for k in phases)
-    # the pool's own cost to the calling thread: none without a pool
-    assert t1["plan_pool_start_s"] == t1["plan_pool_join_s"] == 0.0
-    assert t4["plan_pool_start_s"] > 0.0 and t4["plan_pool_join_s"] > 0.0
+    # the pool's own cost to the calling thread: none without a pool,
+    # and these eighteen rooms (20 KB staged) are reckoned under a
+    # millisecond, so a width of 4 wakes nobody for them either (calls
+    # that do: test_pool_gives_what_one_thread_gives_call_after_call)
+    for t in (t1, t4):
+        assert t["plan_pool_start_s"] == t["plan_pool_join_s"] == 0.0
+        assert [t[k] for k in PLAN_POOL_COUNTS] == [1, 0, 0]
 
 
 # blake2b-128 of the counts, return codes, plans and encoded updates of
@@ -694,3 +699,286 @@ def test_core_has_no_timing_switch_of_its_own(monkeypatch, capfd):
     assert rcs.tolist() == [0] and times["plan_scan_s"] > 0.0
     out, err = capfd.readouterr()
     assert (out, err) == ("", "")
+
+
+# -- the pool that outlives the call (PR 41) ---------------------------------
+
+POOL_CALL_SIZES = (1, 2, 12, 72, 300)
+POOL_ROUNDS = 10  # a call a size a round: 50 consecutive calls
+
+
+@pytest.fixture(scope="module")
+def pool_traffic():
+    """Eight short writing sessions and a long one, each as the update
+    that loads it and ``POOL_ROUNDS - 1`` keystrokes after it.  A load
+    is a pasted page and some edits: the core reckons a call's work from
+    its rooms and staged bytes, and a call reckoned under a millisecond
+    wakes nobody."""
+    gen = random.Random(41)
+
+    def session(client, n_ops, pasted=1500):
+        doc = Y.Doc(gc=False)
+        doc.client_id = client
+        text = doc.get_text("text")
+        text.insert(0, "".join(gen.choice("abcdef ") for _ in range(pasted)))
+
+        def edit():
+            ln = len(text.to_string())
+            if ln and gen.random() < 0.3:
+                pos = gen.randrange(ln)
+                text.delete(pos, min(gen.randint(1, 3), ln - pos))
+            else:
+                text.insert(gen.randint(0, ln), gen.choice(["a", "bc", "d "]))
+
+        for _ in range(n_ops):
+            edit()
+        updates = [Y.encode_state_as_update(doc)]
+        for _ in range(POOL_ROUNDS - 1):
+            sv = Y.encode_state_vector(doc)
+            edit()
+            updates.append(Y.encode_state_as_update(doc, sv))
+        return updates
+
+    return {
+        "short": [session(500 + k, 20 + 5 * k) for k in range(8)],
+        "long": session(600, 400, pasted=60000),
+    }
+
+
+def _pool_calls(traffic, threads, monkeypatch):
+    """``POOL_ROUNDS`` rounds of one ``prepare_many`` call a size of
+    ``POOL_CALL_SIZES`` over mirrors that live through the rounds (a
+    group's last room is the long one); a digest of each call's counts,
+    return codes and plans, and each call's pool counts."""
+    import hashlib
+
+    from yjs_tpu.ops.native_mirror import PLAN_POOL_COUNTS, prepare_many
+
+    monkeypatch.setenv("YTPU_PLAN_THREADS", threads)
+    groups = {
+        n: [(j, NativeMirror("text")) for j in range(n)]
+        for n in POOL_CALL_SIZES
+    }
+    digests, pools = [], []
+    for r in range(POOL_ROUNDS):
+        for n, work in groups.items():
+            for j, m in work:
+                long = n > 1 and j == n - 1
+                ups = traffic["long"] if long else traffic["short"][j % 8]
+                m.ingest(ups[r])
+            counts, rcs, staged, times = prepare_many(work)
+            plans = []
+            for k, (_j, m) in enumerate(work):
+                m._finish_prepare(
+                    int(rcs[k]), staged[k][0], staged[k][1], counts[k]
+                )
+                p = m.make_plan(counts[k])
+                plans.append((
+                    p.splits.tolist(), p.sched.tolist(),
+                    p.delete_rows.tolist(), p.applied_ds,
+                    p.link_rows.tolist(), p.link_vals.tolist(),
+                    p.head_segs.tolist(), p.head_vals.tolist(),
+                ))
+            digests.append(hashlib.blake2b(
+                repr((counts.tolist(), rcs.tolist(), plans)).encode(),
+                digest_size=16,
+            ).hexdigest())
+            pools.append([times[k] for k in PLAN_POOL_COUNTS])
+    return digests, pools
+
+
+@pytest.fixture(scope="module")
+def pool_serial_digests(pool_traffic):
+    mp = pytest.MonkeyPatch()
+    try:
+        digests, pools = _pool_calls(pool_traffic, "1", mp)
+    finally:
+        mp.undo()
+    # the serial branch: one thread, nobody woken, nothing constructed
+    assert pools == [[1, 0, 0]] * len(digests)
+    return digests
+
+
+@pytest.mark.parametrize("threads", ["2", "4", "13"])
+def test_pool_gives_what_one_thread_gives_call_after_call(
+    monkeypatch, pool_traffic, pool_serial_digests, threads
+):
+    """Fifty consecutive calls of 1, 2, 12, 72 and 300 rooms on workers
+    that live through them, one long room among the short ones of each:
+    counts, return codes and plans are the serial branch's, call by
+    call, at every width; and a call plans on no more threads than the
+    width or its rooms."""
+    digests, pools = _pool_calls(pool_traffic, threads, monkeypatch)
+    assert digests == pool_serial_digests
+    sizes = POOL_CALL_SIZES * POOL_ROUNDS
+    for n, (used, woken, _made) in zip(sizes, pools):
+        assert 1 <= used <= min(int(threads), n) and woken == used - 1
+    # the widest calls hold work for every thread the width allows
+    assert max(p[0] for p in pools) == int(threads)
+
+
+POOL_PROCESS = r"""
+import json, os, sys, warnings
+import numpy as np
+import yjs_tpu as Y
+from yjs_tpu.ops.native_mirror import NativeMirror, prepare_many
+
+def update(client):
+    doc = Y.Doc(gc=False)
+    doc.client_id = client
+    doc.get_text("text").insert(0, "a room of the pool's test " * 40)
+    return Y.encode_state_as_update(doc)
+
+UPDATES = [update(700 + k) for k in range(8)]
+
+def call(threads, n=300):
+    os.environ["YTPU_PLAN_THREADS"] = threads
+    work = [(j, NativeMirror("text")) for j in range(n)]
+    for j, m in work:
+        m.ingest(UPDATES[j % 8])
+    counts, rcs, _staged, times = prepare_many(work)
+    return counts.tolist(), rcs.tolist(), [
+        times[k] for k in ("plan_threads", "plan_pool_woken", "plan_pool_started")
+    ]
+
+out = {"calls": []}
+serial = call("1")
+for threads in ("2", "2", "4", "4", "2", "13", "13", "4"):
+    counts, rcs, pool = call(threads)
+    out["calls"].append([int(threads), pool, (counts, rcs) == serial[:2]])
+r, w = os.pipe()
+with warnings.catch_warnings():
+    # the pool's parked workers are the threads Python warns of
+    warnings.simplefilter("ignore", DeprecationWarning)
+    pid = os.fork()
+if pid == 0:
+    os.close(r)
+    counts, rcs, pool = call("4")
+    again = call("4")[2]
+    os.write(w, json.dumps([pool, again, (counts, rcs) == serial[:2]]).encode())
+    os._exit(0)
+os.close(w)
+out["child"] = json.loads(os.read(r, 1 << 16).decode())
+out["child_status"] = os.waitpid(pid, 0)[1]
+out["after_fork"] = call("4")[2]
+print(json.dumps(out))
+# and now exit, with the workers parked
+"""
+
+
+@pytest.fixture(scope="module")
+def pool_process():
+    """One process of its own, so that the pool starts empty: calls of
+    300 rooms at widths 2, 2, 4, 4, 2, 13, 13, 4; then a fork whose
+    child plans twice at width 4; then the exit with workers parked."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    if not hasattr(os, "fork"):
+        pytest.skip("no os.fork here")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": root}
+    p = subprocess.run(
+        [sys.executable, "-c", POOL_PROCESS], env=env, cwd=root,
+        capture_output=True, text=True, timeout=300,
+    )
+    out = json.loads(p.stdout) if p.returncode == 0 and p.stdout else None
+    return p.returncode, out, p.stderr
+
+
+def test_pool_constructs_its_threads_once(pool_process):
+    """``plan_pool_started`` is what a call had to construct: the
+    width's workers in the first call of a width wider than any before,
+    0 in every call after it, a narrower width's among them."""
+    rc, out, err = pool_process
+    assert rc == 0, err
+    assert [c[:2] for c in out["calls"]] == [
+        [2, [2, 1, 1]], [2, [2, 1, 0]],
+        [4, [4, 3, 2]], [4, [4, 3, 0]],
+        [2, [2, 1, 0]],
+        [13, [13, 12, 9]], [13, [13, 12, 0]],
+        [4, [4, 3, 0]],
+    ]
+    assert all(same for _t, _pool, same in out["calls"])
+    assert out["after_fork"] == [4, 3, 0]
+
+
+def test_pool_lets_the_process_exit_with_workers_parked(pool_process):
+    """Twelve workers are parked when the interpreter exits: exit code
+    0, and nothing on stderr (a joinable ``std::thread`` destroyed at
+    exit would call ``std::terminate``)."""
+    rc, out, err = pool_process
+    assert (rc, err) == (0, "") and out is not None
+
+
+def test_pool_is_made_anew_in_a_forked_child(pool_process):
+    """A forked child holds the parent's pool and none of its threads:
+    its first call constructs workers of its own, its second none, and
+    its plans are the serial branch's."""
+    rc, out, err = pool_process
+    assert rc == 0, err
+    first, again, same = out["child"]
+    assert first == [4, 3, 3] and again == [4, 3, 0] and same
+    assert out["child_status"] == 0
+
+
+def test_pool_serves_one_call_at_a_time(monkeypatch):
+    """Two Python threads flush an engine each at once (``ctypes``
+    releases the GIL around the native call): the call that finds the
+    pool taken plans on its own thread, both engines hold what a
+    ``Y.Doc`` holds, and neither waits for the other."""
+    import sys
+    import threading
+
+    from yjs_tpu.ops import BatchEngine
+
+    monkeypatch.setenv("YTPU_PLAN_THREADS", "4")
+    monkeypatch.setenv("YTPU_PLAN_CACHE", "0")
+    n_rooms, rounds = 48, 12
+    gen = random.Random(7)
+
+    def typist(client):
+        doc = Y.Doc(gc=False)
+        doc.client_id = client
+        text = doc.get_text("text")
+        updates = []
+        for _ in range(rounds):
+            sv = Y.encode_state_vector(doc)
+            for _ in range(30):
+                ln = len(text.to_string())
+                text.insert(gen.randint(0, ln), gen.choice(["ab", "c ", "xyz"]))
+            updates.append(Y.encode_state_as_update(doc, sv))
+        return updates, text.to_string()
+
+    typists = [typist(800 + k) for k in range(8)]
+    engines = [BatchEngine(n_rooms), BatchEngine(n_rooms)]
+    failed = []
+
+    def flusher(k):
+        eng = engines[k]
+        try:
+            for r in range(rounds):
+                for i in range(n_rooms):
+                    eng.queue_update(i, typists[i % 8][0][r])
+                eng.flush()
+        except BaseException as e:  # noqa: BLE001 - reported by the test
+            failed.append(e)
+            raise
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=flusher, args=(k,)) for k in (0, 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    assert not failed
+    for eng in engines:
+        for i in range(n_rooms):
+            assert eng.text(i) == typists[i % 8][1]

@@ -434,34 +434,52 @@ def test_the_pools_own_clock_rides_the_flush_metrics():
 def test_the_cores_laps_ride_the_flush_metrics(monkeypatch, threads):
     """The native core's laps are a clock of the flush's: ``plan_pool_s``
     by the phase of a room's prepare, summed over the flush's chunks,
-    and what the pool cost the flushing thread in starting and joining
-    its threads (0 when the call ran serially); every key 0 in a flush
-    that planned nothing cold, and no registry family for any."""
-    from yjs_tpu.ops.native_mirror import PLAN_TIMES, native_plan_available
+    and what the pool cost the flushing thread in handing it the call
+    and in waiting for its last worker (0 on the serial branch), with
+    the workers its calls woke and the threads they had to construct;
+    every key 0 in a flush that planned nothing cold, and no registry
+    family for any but the pool's two counters."""
+    from yjs_tpu.ops.native_mirror import (
+        PLAN_POOL_COUNTS, PLAN_TIMES, native_plan_available,
+    )
 
     if not native_plan_available():
         pytest.skip("native plan core unavailable")
     monkeypatch.setenv("YTPU_PLAN_THREADS", threads)
     monkeypatch.setenv("YTPU_FLUSH_CHUNK", "2")  # two calls a flush
     phases, pool = PLAN_TIMES[2:7], PLAN_TIMES[7:]
-    assert set(PLAN_TIMES) <= set(FLUSH_METRICS_SCHEMA)
+    assert set(PLAN_TIMES + PLAN_POOL_COUNTS) <= set(FLUSH_METRICS_SCHEMA)
     eng = BatchEngine(4)
     families = set(eng.obs.registry.names())
+    assert {
+        "ytpu_plan_pool_threads_started_total", "ytpu_plan_pool_wakeups_total",
+    } <= families
+    # a pasted page a room: the core reckons a call's work from its
+    # rooms and staged bytes, and wakes nobody for under a millisecond
     for i, word in enumerate(("one", "two", "three", "four")):
-        eng.queue_update(i, _update(word))
+        eng.queue_update(i, _update(word * 10000))
     eng.flush()
     m = eng.last_flush_metrics
     assert all(m[k] > 0.0 for k in phases)
     assert sum(m[k] for k in phases) <= m["plan_pool_s"]
+    woken = eng.obs.registry.get("ytpu_plan_pool_wakeups_total")
+    started = eng.obs.registry.get("ytpu_plan_pool_threads_started_total")
     if threads == "1":
         assert m["plan_threads"] == 1 and all(m[k] == 0.0 for k in pool)
+        assert m["plan_pool_woken"] == m["plan_pool_started"] == 0
     else:
+        # two rooms a call: the flushing thread and one worker, twice
         assert m["plan_threads"] == 2 and all(m[k] > 0.0 for k in pool)
+        assert m["plan_pool_woken"] == 2 and m["plan_pool_started"] <= 1
+    assert woken.value == m["plan_pool_woken"]
+    assert started.value == m["plan_pool_started"]
     assert eng.obs.snapshot()["flush_history"][-1]["plan_scan_s"] == m["plan_scan_s"]
     assert set(eng.obs.registry.names()) == families
     eng.flush()
     m = eng.last_flush_metrics
     assert all(m[k] == 0.0 for k in PLAN_TIMES)
+    assert m["plan_threads"] == 1
+    assert m["plan_pool_woken"] == m["plan_pool_started"] == 0
 
 
 @pytest.mark.parametrize("planner", ["native", "python"])

@@ -136,7 +136,7 @@ def load():
     lib.ymx_prepare_many.restype = None
     lib.ymx_prepare_many.argtypes = [vpp, i64, i64p, i64p, i64p,
                                      ctypes.c_int, i64p, i64p,
-                                     ctypes.POINTER(ctypes.c_double)]
+                                     ctypes.POINTER(ctypes.c_double), i64p]
     for pack_name in ("ymx_pack_apply", "ymx_pack_apply16"):
         fn = getattr(lib, pack_name)
         fn.restype = None
@@ -207,8 +207,8 @@ def load():
     lib.ymx_format_cleanup.argtypes = [vp, i64, i64p, i64, i64p]
     lib.ymx_compact_self.restype = i64
     lib.ymx_compact_self.argtypes = [vp, ctypes.c_int, i32p, u8p, i32p, i64]
-    # ymx_prepare_many's worker-pool width (surfaced as
-    # last_flush_metrics["plan_threads"])
+    # the most threads a ymx_prepare_many call may plan on (the width a
+    # flush used is last_flush_metrics["plan_threads"], the call's own)
     lib.ymx_plan_threads.restype = ctypes.c_int
     lib.ymx_plan_threads.argtypes = []
     # one ctypes crossing registers every staged buffer of a flush

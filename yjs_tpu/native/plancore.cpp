@@ -26,9 +26,14 @@
 
 #include "wire.h"
 
+#include <unistd.h>
+
 #include <atomic>
 #include <chrono>
+#include <condition_variable>
 #include <cstdlib>
+#include <functional>
+#include <mutex>
 #include <thread>
 
 #include <algorithm>
@@ -2718,10 +2723,10 @@ int ymx_prepare(void* h, const int64_t* buf_ids, const int64_t* v2_flags,
   return 0;
 }
 
-// planner worker-pool width: YTPU_PLAN_THREADS wins, else the hardware
-// concurrency of the host (1 on this build image — the pool then takes
-// the serial path with zero thread overhead; real multi-core hosts fan
-// the per-doc plans out)
+// the most threads one ymx_prepare_many call may plan on, the caller
+// included: YTPU_PLAN_THREADS wins, else the hardware concurrency of the
+// host.  Read every call (tests change it within a process); 1 is the
+// serial branch, which never touches the pool
 static int plan_pool_width() {
   const char* e = std::getenv("YTPU_PLAN_THREADS");
   if (e && *e) {
@@ -2746,6 +2751,95 @@ void ymx_plan_segment_stats(int64_t* out) {
   out[1] = g_seg_lookup.load(std::memory_order_relaxed);
 }
 
+// The planner's workers, kept between calls.  One pool a process, made
+// by the first call that wants a worker, grown by a later call that
+// wants more, and never destroyed: its threads are detached and the
+// pool is leaked, so a process that exits with workers parked destroys
+// no joinable std::thread (std::terminate) and waits for nobody.
+// Between calls every worker is blocked on work_cv; none spins (the
+// flushing thread's ingest runs on the same shared cores).
+//
+// One call owns the pool at a time (`taken`); a caller that finds it
+// taken plans on its own thread.  A forked child holds this object and
+// none of its threads: acquire() sees another pid and makes the child a
+// pool of its own (the parent's is left as it is: its mutex may be held
+// by a thread the child does not have).
+struct PlanPool {
+  std::mutex mu;
+  std::condition_variable work_cv;  // the workers, for a ticket
+  std::condition_variable done_cv;  // the owning call, for active == 0
+  // all below under mu
+  const std::function<void(int)>* job = nullptr;
+  int tickets = 0;    // wakes of the current call that nobody has taken
+  int next_slot = 0;  // the job's argument for the next ticket's taker
+  int active = 0;     // tickets not yet brought back, taken or not
+  int n_workers = 0;
+  std::atomic<bool> taken{false};
+  const pid_t pid = getpid();
+
+  // the process's pool if no other call holds it, else null
+  static PlanPool* acquire() {
+    static std::atomic<PlanPool*> the_pool{nullptr};
+    PlanPool* p = the_pool.load(std::memory_order_acquire);
+    if (p == nullptr || p->pid != getpid()) {
+      PlanPool* made = new PlanPool;
+      if (the_pool.compare_exchange_strong(p, made,
+                                           std::memory_order_acq_rel))
+        p = made;
+      else
+        delete made;  // another thread's came first, and is p now
+    }
+    return p->taken.exchange(true, std::memory_order_acquire) ? nullptr : p;
+  }
+  void release() { taken.store(false, std::memory_order_release); }
+
+  // f(0) on the calling thread and f(1), f(2), ... on the workers that
+  // take one of `want` tickets, one each; returns when every ticket
+  // taken has been brought back.  A ticket nobody has taken by the time
+  // the caller's own f(0) returns is withdrawn: the caller waits for
+  // workers inside f, never for one that is still waking.  Returns the
+  // threads it had to construct (0 once the pool is as wide as its
+  // widest call); *handed is when the caller turned to f(0).
+  int run(int want, const std::function<void(int)>& f,
+          std::chrono::steady_clock::time_point* handed) {
+    int made = 0;
+    {
+      std::lock_guard<std::mutex> lk(mu);
+      for (; n_workers < want; n_workers++, made++)
+        std::thread([this] { work(); }).detach();
+      job = &f;
+      tickets = active = want;
+      next_slot = 1;
+    }
+    if (want == n_workers)
+      work_cv.notify_all();
+    else
+      for (int k = 0; k < want; k++) work_cv.notify_one();
+    *handed = std::chrono::steady_clock::now();
+    f(0);
+    std::unique_lock<std::mutex> lk(mu);
+    active -= tickets;
+    tickets = 0;
+    done_cv.wait(lk, [this] { return active == 0; });
+    job = nullptr;
+    return made;
+  }
+
+  void work() {
+    std::unique_lock<std::mutex> lk(mu);
+    for (;;) {
+      work_cv.wait(lk, [this] { return tickets > 0; });
+      tickets--;
+      const std::function<void(int)>* f = job;
+      int slot = next_slot++;
+      lk.unlock();
+      (*f)(slot);
+      lk.lock();
+      if (--active == 0) done_cv.notify_one();
+    }
+  }
+};
+
 // batched twin of ymx_prepare: one call plans EVERY staged doc, writing a
 // 16-wide counts row per doc ([0..13] = ymx_prepare's layout, [14] =
 // dense-link flag: link_rows == [0..n_rows), [15] = the plan's number)
@@ -2753,11 +2847,12 @@ void ymx_plan_segment_stats(int64_t* out) {
 // per-doc Python/ctypes round trip that dominated distinct-doc flushes.
 // Per-doc plans are independent (each touches only its own Mirror; the
 // only shared data are the const update bytes), so the loop fans out over
-// a worker pool on multi-core hosts — results are bit-identical at any
+// the worker pool on multi-core hosts — results are bit-identical at any
 // width because no doc reads another doc's state.  Callers must not pass
 // the same handle twice in one call.
 //
-// The pool takes the call's long docs first, longest first, then the
+// The calling thread and the workers it wakes take docs from one queue:
+// the call's long docs first, longest first, then the
 // others in index order (a permutation of the work index; every output
 // lands at its doc's own i, so counts, rcs and plans are what index
 // order gives): a long document's plan runs behind the short ones'
@@ -2768,22 +2863,40 @@ void ymx_plan_segment_stats(int64_t* out) {
 // and a cold load's plan phase took half again as long on the chip's
 // host (PERF.md 6, PR 34).
 //
+// How many threads a call plans on is read from the call: one, the
+// caller among them, for each kThreadWorthNs that its docs are reckoned
+// to take (kDocNs a doc and kByteNs a staged byte: a keystroke's prepare
+// takes 6.5 us and a 15.7 KB room's 0.31 ms on the chip's host), and no
+// more than YTPU_PLAN_THREADS or docs.  A wake costs the caller 14 us
+// and a doc planned on a core that did not stage it twice its time, so
+// a flood's 72 keystrokes (0.48 ms on one thread, 0.38-0.47 on 2 to 13)
+// and a storm's 11 rooms (0.10 ms, 0.17-0.22) wake nobody, and a cold
+// load's 256 rooms (80 ms) every worker (PERF.md 6, PR 41).  A call
+// reckoned under two threads' worth, a call of one doc, a width of 1
+// and a call that finds the pool taken by another thread's call all
+// take the serial branch.
+//
 // out_times (double[kPlanTimes], seconds) is the call's own clock, which
 // the caller's wall clock around the call cannot tell apart: [0] the
 // longest single doc's prepare, [1] the sum over docs; [2..6] that sum
 // by phase (Mirror::Lap: scan, merge + fixpoint, the cuts from the
 // delete-set clamp to the pre-split, rows + deletes + LWW, finalize;
 // they leave out of [1] only the clock reads themselves); and what the
-// pool costs the calling thread, both 0 on the serial path: [7] from
-// the first std::thread constructed to the last one started, [8] from
-// the moment the last worker found the queue empty to the last join's
-// return (thread exit and the caller's wake-up).
+// pool costs the calling thread, both 0 on the serial branch: [7]
+// handing the call to the pool (the job published and its workers
+// woken; threads constructed only where the pool grows), [8] from the
+// moment the last thread found the queue empty to the caller running
+// again (nothing where the caller was that thread).
+// out_pool (int64[3]) counts: [0] the threads the call planned on, the
+// caller included, [1] the workers it woke, [2] the threads it
+// constructed (0 in every call once the pool is as wide as its widest).
 static const uint64_t kLongDocFactor = 4;
+static const uint64_t kDocNs = 6000, kByteNs = 20, kThreadWorthNs = 500000;
 static const int kPlanTimes = 2 + Mirror::kNLaps + 2;
 void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
                       const int64_t* ids_flat, const int64_t* v2_flat,
                       int want_sched, int64_t* out_counts, int64_t* out_rc,
-                      double* out_times) {
+                      double* out_times, int64_t* out_pool) {
   using clk = std::chrono::steady_clock;
   std::vector<double> took((size_t)n_docs, 0.0);
   // a row of laps a doc, so that no two workers add into one sum
@@ -2823,7 +2936,8 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
                 : 0;
     c[15] = (int64_t)m->plan_seq;
   };
-  auto report = [&](double pool_start, double pool_join) {
+  auto report = [&](double pool_start, double pool_join, int threads,
+                    int made) {
     for (int j = 0; j < kPlanTimes; j++) out_times[j] = 0.0;
     for (double t : took) {
       out_times[1] += t;
@@ -2833,25 +2947,34 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
       out_times[2 + k % Mirror::kNLaps] += laps[k];
     out_times[2 + Mirror::kNLaps] = pool_start;
     out_times[3 + Mirror::kNLaps] = pool_join;
+    out_pool[0] = threads;
+    out_pool[1] = threads - 1;
+    out_pool[2] = made;
   };
-  int nt = plan_pool_width();
-  if (nt > (int)n_docs) nt = (int)n_docs;
-  if (nt <= 1) {
+  int64_t nt = std::min<int64_t>(plan_pool_width(), n_docs);
+  std::vector<uint64_t> staged;
+  uint64_t staged_total = 0;
+  if (nt > 1) {
+    staged.assign((size_t)n_docs, 0);
+    for (int64_t i = 0; i < n_docs; i++) {
+      const Mirror* m = static_cast<const Mirror*>(hs[i]);
+      for (int64_t b = buf_ofs[i]; b < buf_ofs[i + 1]; b++) {
+        int64_t id = ids_flat[b];
+        if (id >= 0 && (size_t)id < m->bufs.size())
+          staged[(size_t)i] += m->buf_len(id);
+      }
+      staged_total += staged[(size_t)i];
+    }
+    nt = std::min<int64_t>(
+        nt, (int64_t)(((uint64_t)n_docs * kDocNs + staged_total * kByteNs) /
+                      kThreadWorthNs));
+  }
+  PlanPool* pool = nt > 1 ? PlanPool::acquire() : nullptr;
+  if (pool == nullptr) {
     for (int64_t i = 0; i < n_docs; i++) plan_one(i);
-    report(0.0, 0.0);
+    report(0.0, 0.0, 1, 0);
     return;
   }
-  std::vector<uint64_t> staged((size_t)n_docs, 0);
-  for (int64_t i = 0; i < n_docs; i++) {
-    const Mirror* m = static_cast<const Mirror*>(hs[i]);
-    for (int64_t b = buf_ofs[i]; b < buf_ofs[i + 1]; b++) {
-      int64_t id = ids_flat[b];
-      if (id >= 0 && (size_t)id < m->bufs.size())
-        staged[(size_t)i] += m->buf_len(id);
-    }
-  }
-  uint64_t staged_total = 0;
-  for (uint64_t b : staged) staged_total += b;
   std::vector<int64_t> order, rest;
   order.reserve((size_t)n_docs);
   for (int64_t i = 0; i < n_docs; i++) {
@@ -2865,26 +2988,23 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
   });
   order.insert(order.end(), rest.begin(), rest.end());
   std::atomic<int64_t> next{0};
-  std::vector<std::thread> pool;
-  pool.reserve((size_t)nt);
-  // when each worker found the queue empty; read after its join
+  // when each thread found the queue empty; a slot nobody took keeps
+  // the clock's epoch
   std::vector<clk::time_point> ended((size_t)nt);
-  clk::time_point t_start = clk::now();
-  for (int t = 0; t < nt; t++)
-    pool.emplace_back([&, t] {
-      for (int64_t k; (k = next.fetch_add(1, std::memory_order_relaxed)) <
-                      n_docs;)
-        plan_one(order[(size_t)k]);
-      ended[(size_t)t] = clk::now();
-    });
-  clk::time_point t_started = clk::now();
-  for (auto& th : pool) th.join();
-  clk::time_point t_joined = clk::now();
-  clk::time_point work_end = ended[0];
-  for (clk::time_point e : ended)
-    if (e > work_end) work_end = e;
-  report(std::chrono::duration<double>(t_started - t_start).count(),
-         std::chrono::duration<double>(t_joined - work_end).count());
+  std::function<void(int)> drain = [&](int slot) {
+    for (int64_t k;
+         (k = next.fetch_add(1, std::memory_order_relaxed)) < n_docs;)
+      plan_one(order[(size_t)k]);
+    ended[(size_t)slot] = clk::now();
+  };
+  clk::time_point t_call = clk::now(), t_handed;
+  int made = pool->run((int)nt - 1, drain, &t_handed);
+  clk::time_point t_back = clk::now();
+  pool->release();
+  clk::time_point work_end = *std::max_element(ended.begin(), ended.end());
+  report(std::chrono::duration<double>(t_handed - t_call).count(),
+         std::chrono::duration<double>(t_back - work_end).count(), (int)nt,
+         made);
 }
 
 // deep state clone: dst becomes a bit-identical twin of src — same rows,

@@ -110,13 +110,19 @@ FLUSH_METRICS_SCHEMA: dict = {
     "schedule_occupancy": 0.0,
     "n_pending_docs": 0,
     "pending_depth": 0,
-    # planner fan-out this flush actually used: the native planner's
-    # worker-pool width (min(pool width, docs in the batch);
-    # YTPU_PLAN_THREADS overrides the pool), or — on the Python path
-    # under YTPU_PLAN_SEGMENT=device — the number of cold docs
+    # planner fan-out this flush actually used: the most threads one
+    # native call planned on, the caller included (the core's own rule:
+    # a thread for each share of the work the call is reckoned to hold,
+    # at most YTPU_PLAN_THREADS and the call's docs), or — on the Python
+    # path under YTPU_PLAN_SEGMENT=device — the number of cold docs
     # co-planned by one whole-chunk segment-planner call (ISSUE 15).
     # 1 = fully serial per-doc planning.
     "plan_threads": 1,
+    # the native planner's pool outlives the call: workers the flush's
+    # calls woke, and threads they had to construct (0 in every flush
+    # once the pool is as wide as its widest call)
+    "plan_pool_woken": 0,
+    "plan_pool_started": 0,
     # what a flush looked at: slots the plan phase visited (the engine's
     # dirty set, fed by queue_update: over n_docs, the share of the
     # slots a flush pays for) and slots whose n_rows the compaction
@@ -133,8 +139,10 @@ FLUSH_METRICS_SCHEMA: dict = {
     # plan_pool_s by the phase of a room's prepare (the core's laps:
     # scan; merge + fixpoint; the cuts, delete-set clamp to pre-split;
     # rows + deletes + LWW; finalize), and what the pool itself cost the
-    # flushing thread: constructing and starting its threads, and in
-    # join after the last worker found the queue empty (both 0 when the
+    # flushing thread: handing the call to its workers (the job
+    # published and they woken; threads constructed only where the pool
+    # grows), and the wait from the last thread's finding the queue
+    # empty to the flushing thread's running again (both 0 when the
     # call ran serially).  The flush ring and /debug carry them; no
     # registry family does
     "plan_scan_s": 0.0,
@@ -338,7 +346,9 @@ class EngineObs:
             unit="ratio",
         )
         self._plan_threads = r.gauge(
-            "ytpu_engine_plan_threads", "Native planner worker-pool width"
+            "ytpu_engine_plan_threads",
+            "Most threads one native planner call of the last flush "
+            "planned on, the flushing thread included",
         )
         self._row_capacity = r.gauge(
             "ytpu_engine_row_capacity",
@@ -384,6 +394,18 @@ class EngineObs:
             "Seconds the native planner's pool spent in prepare, summed "
             "over rooms (the pool's own clock)",
             unit="s",
+        )
+        self._plan_pool_threads_started = r.counter(
+            "ytpu_plan_pool_threads_started_total",
+            "Threads the native planner's pool constructed: it keeps "
+            "them between calls, so this stops at its widest call",
+            unit="threads",
+        )
+        self._plan_pool_wakeups = r.counter(
+            "ytpu_plan_pool_wakeups_total",
+            "Workers the native planner's calls woke (a call plans on "
+            "the flushing thread too)",
+            unit="wakeups",
         )
         self._plan_room_max_seconds = r.gauge(
             "ytpu_plan_room_max_seconds",
@@ -596,6 +618,8 @@ class EngineObs:
         if metrics["plan_pool_s"]:
             self._plan_pool_seconds.inc(metrics["plan_pool_s"])
             self._plan_room_max_seconds.set(metrics["plan_room_max_s"])
+            self._plan_pool_threads_started.inc(metrics["plan_pool_started"])
+            self._plan_pool_wakeups.inc(metrics["plan_pool_woken"])
         if metrics["release_blanked_bytes"]:
             self._release_blanked_bytes.inc(metrics["release_blanked_bytes"])
 

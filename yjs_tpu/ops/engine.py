@@ -24,7 +24,6 @@ import numpy as np
 
 from ..core import Doc
 from ..lib0.u16 import from_u16
-from .. import native
 from ..obs import EngineObs, new_flush_metrics
 from ..obs.prof import profiled
 from ..resilience import DeadLetterQueue, HealthTracker
@@ -34,6 +33,7 @@ from .columns import NULL, DocMirror, UnsupportedUpdate
 from . import plan_cache
 from . import segment_planner
 from .native_mirror import (
+    PLAN_POOL_COUNTS,
     PLAN_TIMES,
     NativeMirror,
     NativePlan,
@@ -49,18 +49,12 @@ from .compile_cache import ensure_compile_cache
 from .host_heap import ensure_heap_kept
 
 
-def _native_plan_threads() -> int:
-    """Worker-pool width ymx_prepare_many fans out to (1 when the native
-    planner is unavailable or the host has a single core)."""
-    lib = native.load()
-    return int(lib.ymx_plan_threads()) if lib is not None else 1
-
-
 def _add_plan_times(total: dict, call: dict) -> None:
-    """One ymx_prepare_many call's clock (``PLAN_TIMES``) into a flush's:
-    the longest room's prepare is a maximum, the others are sums."""
+    """One ymx_prepare_many call's clock and counts (``PLAN_TIMES``,
+    ``PLAN_POOL_COUNTS``) into a flush's: the longest room's prepare and
+    the threads a call planned on are maxima, the others are sums."""
     for key, t in call.items():
-        if key == "plan_room_max_s":
+        if key in ("plan_room_max_s", "plan_threads"):
             total[key] = max(total[key], t)
         else:
             total[key] += t
@@ -1841,15 +1835,19 @@ class BatchEngine:
             cache=plan_cache.get_cache() if native else None,
             # events read plan.sched; skip building it otherwise
             want_sched=bool(self._event_listeners),
-            cfg_threads=_native_plan_threads() if native else 1,
-            plan_threads=1,
             cache_hits=0,
             cache_misses=0,
             cache_admitted=0,
             t_cached=0.0,
             t_cold=0.0,
-            # ymx_prepare_many's own clock, over the flush's calls
-            times=dict.fromkeys(PLAN_TIMES, 0.0),
+            # ymx_prepare_many's own clock and counts, over the flush's
+            # calls: a flush that planned nothing cold planned on one
+            # thread and woke nobody
+            times={
+                **dict.fromkeys(PLAN_TIMES, 0.0),
+                **dict.fromkeys(PLAN_POOL_COUNTS, 0),
+                "plan_threads": 1,
+            },
             demoted=metrics["n_demoted"],
             rolled_back=metrics["n_rolled_back"],
         )
@@ -1999,10 +1997,10 @@ class BatchEngine:
                 "plan_cache_hits": acc.cache_hits,
                 "plan_cache_misses": acc.cache_misses,
                 "plan_cache_admitted": acc.cache_admitted,
-                # widest worker pool any prepare batch in this flush
-                # actually used — min(configured width, docs in the
-                # batch); 1 when every doc was served from the plan cache
-                "plan_threads": acc.plan_threads,
+                # plan_threads among them: the most threads one native
+                # call of this flush planned on, the caller included (the
+                # core's own rule, plancore.cpp); 1 when every doc was
+                # served from the plan cache
                 **acc.times,
             })
             seg_now = plan_segment_stats()
@@ -2053,9 +2051,6 @@ class BatchEngine:
         if cold:
             tc0 = time.perf_counter()
             acc.cache_misses += len(cold)
-            acc.plan_threads = max(
-                acc.plan_threads, min(acc.cfg_threads, len(cold))
-            )
             counts_all, rcs, staged_info, pool_times = prepare_many(
                 [(i, m) for i, m, _k in cold],
                 want_sched=want_sched,
@@ -2118,9 +2113,6 @@ class BatchEngine:
             tc0 = time.perf_counter()
             acc.cache_misses += len(retry)
             plan_cache.note_misses(len(retry))
-            acc.plan_threads = max(
-                acc.plan_threads, min(acc.cfg_threads, len(retry))
-            )
             counts2, rcs2, staged2, pool_times = prepare_many(
                 retry, want_sched=want_sched, obs=self.obs,
             )

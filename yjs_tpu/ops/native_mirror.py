@@ -762,13 +762,17 @@ class NativeMirror:
 # flush reports them as (seconds): the longest single doc's prepare, the
 # sum over docs, that sum by phase (scan; merge + fixpoint; the cuts;
 # rows + deletes + LWW; finalize), and what the pool cost the calling
-# thread in starting its threads and in joining them once the work had
-# ended (both 0 on the serial path)
+# thread: handing the call to its workers, and the wait from the last
+# thread's finding the queue empty to the caller's running again (both
+# 0 on the serial branch)
 PLAN_TIMES = (
     "plan_room_max_s", "plan_pool_s",
     "plan_scan_s", "plan_merge_s", "plan_cuts_s", "plan_rows_s",
     "plan_finalize_s", "plan_pool_start_s", "plan_pool_join_s",
 )
+# its out_pool, likewise: the threads the call planned on (the caller
+# included), the workers it woke, the threads it had to construct
+PLAN_POOL_COUNTS = ("plan_threads", "plan_pool_woken", "plan_pool_started")
 
 
 def prepare_many(work, want_sched: bool = True, obs=None):
@@ -780,7 +784,8 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     flag; ``[15]`` numbers the plan, see ``NativeMirror._plan_seq``),
     ``rcs`` the per-doc return codes, ``staged_info`` the per-doc
     ``(staged, ids)`` needed by ``_finish_prepare``, and ``pool_times``
-    the call's own clock, a dict of seconds under ``PLAN_TIMES``' keys.
+    the call's own clock and counts, a dict of seconds under
+    ``PLAN_TIMES``' keys and of integers under ``PLAN_POOL_COUNTS``'.
     The pool takes the call's long docs first
     (four times its mean staged bytes or more, longest first), then the
     others in index order; every output is at its doc's index in
@@ -850,6 +855,7 @@ def prepare_many(work, want_sched: bool = True, obs=None):
         counts = np.zeros((n, 16), np.int64)
         rcs = np.zeros(n, np.int64)
         times = np.zeros(len(PLAN_TIMES), np.float64)
+        pool = np.zeros(len(PLAN_POOL_COUNTS), np.int64)
     # the native call alone: what is left of ytpu.plan around the two
     # is the engine's (the walk, the plan cache, finish)
     with span("ytpu.plan.native"):
@@ -857,6 +863,7 @@ def prepare_many(work, want_sched: bool = True, obs=None):
             handles, n, _p64(buf_ofs), _p64(ids_flat), _p64(v2_flat),
             1 if want_sched else 0, _p64(counts), _p64(rcs),
             times.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            _p64(pool),
         )
     dt = time.perf_counter() - t0
     if obs is not None:
@@ -864,7 +871,9 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     from ..obs.prof import kernel_profiler
 
     kernel_profiler().record_host_op("prepare_many", dt)
-    return counts, rcs, staged_info, dict(zip(PLAN_TIMES, times.tolist()))
+    pool_times = dict(zip(PLAN_TIMES, times.tolist()))
+    pool_times.update(zip(PLAN_POOL_COUNTS, pool.tolist()))
+    return counts, rcs, staged_info, pool_times
 
 
 def _encode_many(lib, handles, n, sv_ofs, svc, svk, modes, seqs, ds_ofs,
