@@ -581,6 +581,51 @@ def test_env_config_and_validation(tmp_path, monkeypatch):
     assert list_segments(tmp_path / "envwal")
 
 
+@pytest.mark.parametrize("planner", ["native", "python"])
+def test_recover_on_the_device_backend_merges_a_long_tail(
+    tmp_path, monkeypatch, planner
+):
+    """One room's log of 200 updates comes back through
+    ``backend="device"`` in ONE flush (a whole tail merged in one plan)
+    equal to a ``Y.Doc`` fed the updates one by one."""
+    if planner == "python":
+        monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+    doc = Y.Doc(gc=False)
+    doc.client_id = 77
+    updates: list[bytes] = []
+    doc.on("update", lambda u, *_: updates.append(u))
+    text = doc.get_text("text")
+    k = 0
+    while len(updates) < 200:  # a keystroke an update, a backspace in seven
+        if k % 7 == 6:
+            text.delete(k // 7, 1)
+        else:
+            at = len(str(text))
+            text.insert(at // 2 if k % 3 else at, "ab"[k % 2])
+        k += 1
+    prov = TpuProvider(2, backend="device", wal_dir=tmp_path / "wal")
+    for u in updates:
+        assert prov.receive_update("room", u)
+        prov.flush()
+    prov.wal.abandon()  # killed: no close(), no checkpoint
+    new = TpuProvider.recover(tmp_path / "wal", n_docs=2, backend="device")
+    r = new.last_recovery
+    assert (r["records_applied"], r["records_max_a_room"]) == (len(updates),) * 2
+    assert (r["outcome"], r["dead_lettered"]) == ("clean", 0)
+    oracle = Y.Doc(gc=False)
+    for u in updates:
+        Y.apply_update(oracle, u)
+    assert new.text("room") == oracle.get_text("text").to_string() == str(text)
+    assert new.state_vector("room") == Y.decode_state_vector(
+        Y.encode_state_vector(oracle)
+    )
+    assert canonical(new, "room") == Y.merge_updates(
+        [Y.encode_state_as_update(oracle)]
+    )
+    assert not new.engine.fallback and not new.engine.demotions
+    new.close(checkpoint=False)
+
+
 def test_wal_metric_families_always_registered():
     prov = TpuProvider(1, backend="cpu")  # no WAL attached
     names = set(prov.engine.obs.registry.names())
@@ -596,6 +641,8 @@ def test_wal_metric_families_always_registered():
         "ytpu_wal_torn_tail_truncations_total",
         "ytpu_wal_corrupt_records_total",
         "ytpu_wal_replay_seconds",
+        "ytpu_wal_replay_bytes_total",
+        "ytpu_wal_replay_phase_seconds_total",
         "ytpu_provider_docs_evicted_total",
     }
     assert expected <= names

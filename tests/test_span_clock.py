@@ -25,7 +25,7 @@ if str(ROOT) not in sys.path:  # the benchmark's reader, as tests/bench does
 
 import yjs_tpu as Y
 from yjs_tpu.admission import AdmissionConfig, AdmissionRejected
-from yjs_tpu.obs.trace import FORMAT_SPANS, LEAF_SPANS
+from yjs_tpu.obs.trace import FORMAT_SPANS, LEAF_SPANS, RECOVER_SPANS
 from yjs_tpu.persistence import WalConfig
 from yjs_tpu.provider import TpuProvider
 
@@ -347,6 +347,83 @@ def test_sync_handshake_spans_on_both_clocks(tmp_path):
     m = prov.last_sync_metrics
     assert (m["n_requests"], m["n_full"], m["n_bad"]) == (3, 2, 1)
     prov.close(checkpoint=False)
+
+
+@pytest.fixture(scope="module")
+def recovery(tmp_path_factory):
+    """A log of three segments and a torn last record, recovered under
+    the profiler through ``backend="device"``."""
+    import shutil
+
+    from yjs_tpu.persistence.records import KIND_UPDATE, encode_record
+
+    tmp = tmp_path_factory.mktemp("recover_spans")
+    prov = TpuProvider(
+        4, wal_dir=str(tmp / "wal"),
+        wal_config=WalConfig(
+            fsync="interval", fsync_interval=FSYNC_EVERY, segment_bytes=1024
+        ),
+    )
+    updates = keystrokes(70, 19)
+    drive(prov, updates)
+    crashed = tmp / "crashed"
+    shutil.copytree(tmp / "wal", crashed)  # no close(): the process died
+    files = sorted(crashed.glob("wal-*.log"))
+    with open(files[-1], "ab") as f:
+        torn = encode_record(KIND_UPDATE, "room", updates[0])
+        f.write(torn[: len(torn) // 2])
+
+    def go():
+        return TpuProvider.recover(str(crashed), n_docs=4, backend="device")
+
+    new, events = traced(tmp, go)
+    stats = dict(new.last_recovery)
+    ring = new.engine.obs.tracer.trace_events()
+    same = new.text("room") == prov.text("room")
+    new.close(checkpoint=False)
+    prov.close(checkpoint=False)
+    return {
+        "events": events, "ring": ring, "files": len(files), "stats": stats,
+        "records": len(updates), "same": same,
+    }
+
+
+def test_a_recovery_counts_its_files_bytes_and_phases(recovery):
+    s = recovery["stats"]
+    assert recovery["same"] and recovery["files"] >= 3
+    assert (s["files"], s["records_applied"], s["torn_truncations"]) == (
+        recovery["files"], recovery["records"], 1
+    )
+    assert s["records_max_a_room"] == recovery["records"]
+    assert s["bytes_read"] > 14 * recovery["records"]
+    phases = [s[f"t_{p}_s"] for p in ("construct", "read", "validate", "queue", "flush")]
+    assert all(t > 0 for t in phases)
+    assert sum(phases[1:]) <= s["duration_s"] + 1e-6
+
+
+@pytest.mark.parametrize("name", sorted(RECOVER_SPANS))
+def test_recovery_span_once_a_recovery_or_once_a_file(recovery, name):
+    """``obs.trace.RECOVER_SPANS``: bare names on the profiler's clock,
+    each inside its parent on one thread; the recovery and its
+    construction once, the three passes once a file, none once a
+    record; the ring is the new provider's and holds the passes."""
+    mine = [e for e in recovery["events"] if e[2] == name]
+    once = name in ("ytpu.recover", "ytpu.recover.construct")
+    assert len(mine) == (1 if once else recovery["files"]) < recovery["records"]
+    parent = RECOVER_SPANS[name]
+    ring = [e for e in recovery["ring"] if e["name"] == name and e["ph"] == "X"]
+    assert len(ring) == (0 if once else recovery["files"])
+    if parent is None:
+        # the closing flush is inside the recovery, with its own spans
+        (a, b) = (mine[0][3], mine[0][3] + mine[0][4])
+        flushes = [e for e in recovery["events"] if e[2] == "ytpu.flush"]
+        assert flushes and all(a <= e[3] and e[3] + e[4] <= b for e in flushes)
+        return
+    (around,) = [e for e in recovery["events"] if e[2] == parent]
+    assert (around[0], around[1]) == (mine[0][0], mine[0][1])  # one thread
+    assert all(
+        around[3] <= e[3] and e[3] + e[4] <= around[3] + around[4] for e in mine
+    )
 
 
 def test_importing_obs_does_not_load_jax():

@@ -71,6 +71,25 @@ FORMAT_SPANS = {
     "ytpu.plan.cleanup": "ytpu.plan",
 }
 
+# A recovery (``TpuProvider.recover``, ``persistence.replay_wal``), each
+# with the span it opens inside.  ``ytpu.recover`` and its ``construct``
+# (the provider's construction and the WAL's opening) are once a
+# recovery and go to the profiler alone: the ring is the new provider's,
+# which does not exist when they open.  ``read`` (a file's read, record
+# decode and CRC), ``validate`` (``validate_update`` a record) and
+# ``queue`` (``doc_id``, ``queue_update``, releases and the other record
+# kinds, in the log's order) are once a file, never once a record; the
+# closing flush opens its own spans.  tests/test_span_clock.py holds the
+# program to these names and the benchmark's ``recover_*_share`` read
+# them.
+RECOVER_SPANS = {
+    "ytpu.recover": None,
+    "ytpu.recover.construct": "ytpu.recover",
+    "ytpu.recover.read": "ytpu.recover",
+    "ytpu.recover.validate": "ytpu.recover",
+    "ytpu.recover.queue": "ytpu.recover",
+}
+
 _NO_SPAN = contextlib.nullcontext()
 
 

@@ -119,6 +119,7 @@ class WalMetrics:
             self.recoveries = self.replayed = noop
             self.torn = self.corrupt = self.replay_seconds = noop
             self.append_seconds = self.overflow = noop
+            self.replay_bytes = self.replay_phase_seconds = noop
             return
         self.records = registry.counter(
             "ytpu_wal_records_appended_total",
@@ -172,6 +173,19 @@ class WalMetrics:
             "ytpu_wal_replay_seconds",
             "Wall time of one recovery replay (snapshot + tail)",
             unit="s",
+        )
+        self.replay_bytes = registry.counter(
+            "ytpu_wal_replay_bytes_total",
+            "Bytes of checkpoint and segment files read by recovery "
+            "replays",
+            unit="bytes",
+        )
+        self.replay_phase_seconds = registry.counter(
+            "ytpu_wal_replay_phase_seconds_total",
+            "Wall time of recoveries by phase (construct / read / "
+            "validate / queue / flush)",
+            unit="s",
+            labelnames=("phase",),
         )
         self.append_seconds = registry.histogram(
             "ytpu_wal_append_seconds",

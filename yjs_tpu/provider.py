@@ -1804,28 +1804,41 @@ class TpuProvider:
         history).  ``n_docs=None`` sizes the fleet from the distinct
         guids in the log.  The replay stats land in
         ``provider.last_recovery``."""
+        from .obs.trace import Tracer
         from .persistence import count_guids, replay_wal
 
-        if n_docs is None:
-            n_docs = max(1, count_guids(path))
-        prov = cls(
-            n_docs,
-            root_name=root_name,
-            mesh=mesh,
-            gc=gc,
-            backend=backend,
-            wal_dir=path,
-            wal_config=wal_config,
-            tier_config=tier_config,
-            admission_config=admission_config,
-        )
-        prov.recovering = True
-        try:
-            prov.last_recovery = replay_wal(
-                prov, path, exclude_from=prov.wal.first_index
-            )
-        finally:
-            prov.recovering = False
+        # the provider's own tracer does not exist until the provider
+        # does: the two spans around its construction go to the
+        # profiler alone (obs/trace.py RECOVER_SPANS)
+        span = Tracer(enabled=False).span
+        with span("ytpu.recover"):
+            with span("ytpu.recover.construct"):
+                t0 = time.perf_counter()
+                if n_docs is None:
+                    n_docs = max(1, count_guids(path))
+                prov = cls(
+                    n_docs,
+                    root_name=root_name,
+                    mesh=mesh,
+                    gc=gc,
+                    backend=backend,
+                    wal_dir=path,
+                    wal_config=wal_config,
+                    tier_config=tier_config,
+                    admission_config=admission_config,
+                )
+                t_construct = time.perf_counter() - t0
+            prov.recovering = True
+            try:
+                prov.last_recovery = replay_wal(
+                    prov, path, exclude_from=prov.wal.first_index
+                )
+            finally:
+                prov.recovering = False
+            prov.last_recovery["t_construct_s"] = t_construct
+            prov._wal_metrics.replay_phase_seconds.labels(
+                phase="construct"
+            ).inc(t_construct)
         return prov
 
 
