@@ -145,3 +145,66 @@ def test_crash_recover_reconverges_bytewise(backend, crash_seed, tmp_path):
     assert victim.text(ROOM) == ref.text(ROOM)
     assert victim.state_vector(ROOM) == ref.state_vector(ROOM)
     assert canonical(victim) == canonical(ref)
+
+
+def _invalid_record_log(tmp_path, two_files: bool):
+    """A log of three update records for one room, all with a good CRC:
+    a valid one, one that does not decode, a valid one after it."""
+    from yjs_tpu.persistence import KIND_UPDATE, SEG_HEADER, encode_record
+
+    a = client_updates(seed=7, n_ops=2, n_clients=1)
+    assert len(a) == 2
+    other = Y.Doc(gc=False)
+    other.client_id = 2000
+    other.get_text("text").insert(0, "never applied")
+    bad = Y.encode_state_as_update(other)[:-3]
+    payloads = [a[0], bad, a[1]]
+    files = [payloads[:2], payloads[2:]] if two_files else [payloads]
+    for i, group in enumerate(files):
+        (tmp_path / f"wal-{i:08d}.log").write_bytes(
+            SEG_HEADER
+            + b"".join(encode_record(KIND_UPDATE, ROOM, p) for p in group)
+        )
+    ref = Y.Doc(gc=False)
+    for u in a:
+        Y.apply_update(ref, u)
+    return bad, len(files), ref
+
+
+@pytest.mark.parametrize("native_core", (True, False))
+@pytest.mark.parametrize("two_files", (False, True))
+def test_invalid_record_is_one_dead_letter(
+    two_files, native_core, tmp_path, monkeypatch
+):
+    """Recovery's refusals read as before the validate pass became one
+    native call a file: an undecodable record with a good CRC is ONE
+    dead letter with ``validate_update``'s words, the valid records
+    around it apply in the log's order, and ``last_recovery`` counts
+    who gave each verdict: with ``YTPU_NO_NATIVE`` the same stats
+    through the fallback."""
+    from yjs_tpu import native
+
+    if not native_core:
+        monkeypatch.setenv("YTPU_NO_NATIVE", "1")
+        for name in ("_lib", "_error"):
+            monkeypatch.setattr(native, name, None)
+        monkeypatch.setattr(native, "_tried", False)
+    elif native.load() is None:
+        pytest.skip(f"no native core: {native.load_error()}")
+    bad, n_files, ref = _invalid_record_log(tmp_path, two_files)
+    prov = TpuProvider.recover(tmp_path, n_docs=2, backend="cpu")
+    s = prov.last_recovery
+    assert (s["files"], s["records_applied"], s["dead_lettered"]) == (
+        n_files, 2, 1
+    )
+    assert (s["corrupt_records"], s["torn_truncations"]) == (0, 0)
+    assert (s["validated_native"], s["validated_fallback"]) == (
+        (2, 1) if native_core else (0, 3)
+    )
+    (letter,) = prov.dead_letters()
+    assert letter["reason"] == (
+        "wal-invalid: InvalidUpdate: ValueError: unexpected end of array"
+    )
+    assert (letter["bytes"], letter["v2"]) == (len(bad), False)
+    assert prov.text(ROOM) == ref.get_text("text").to_string()
+    assert prov.state_vector(ROOM) == Y.get_state_vector(ref.store)

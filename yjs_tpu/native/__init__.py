@@ -108,6 +108,10 @@ def load():
     lib.ytpu_count_v2.argtypes = [u8p, ctypes.c_uint64, u64p, u64p]
     lib.ytpu_decode_v2.restype = ctypes.c_int
     lib.ytpu_decode_v2.argtypes = [u8p, ctypes.c_uint64] + [i64p] * 22
+    lib.ytpu_validate_many.restype = None
+    lib.ytpu_validate_many.argtypes = [
+        ctypes.c_char_p, u64p, u8p, ctypes.c_uint64, i64p,
+    ]
     lib.ytpu_encode_v1.restype = ctypes.c_int64
     lib.ytpu_encode_v1.argtypes = (
         [ctypes.POINTER(u8p), u64p, ctypes.c_uint64]      # bufs
@@ -394,3 +398,26 @@ def decode_v2_columns(update: bytes):
     if rc != 0:
         raise NativeDecodeError(f"v2 decode pass failed: {rc}")
     return cols, ds
+
+
+def validate_many(updates: list[bytes], v2s) -> np.ndarray | None:
+    """The count pass over every update in one native call: a row
+    ``[rc, structs, ds_ranges, clients]`` an update, ``rc`` 0 where the
+    structural walk accepts it (``transcode.cpp`` ``ytpu_validate_many``
+    gives the other codes).  None when the library is unavailable."""
+    lib = load()
+    if lib is None:
+        return None
+    n = len(updates)
+    ofs = np.zeros(n + 1, np.uint64)
+    np.cumsum(np.fromiter(map(len, updates), np.uint64, n), out=ofs[1:])
+    flags = np.fromiter(v2s, np.uint8, n)
+    out = np.empty((n, 4), np.int64)
+    lib.ytpu_validate_many(
+        b"".join(updates),
+        ofs.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+        flags.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        n,
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+    )
+    return out

@@ -13,10 +13,42 @@
 // Two-pass C ABI: ytpu_count_v1/v2 sizes the outputs, ytpu_decode_v1/v2
 // fills caller-allocated arrays.  All columns are int64 with -1 as the null
 // sentinel.  Returns 0 on success, a negative error code otherwise.
+// ytpu_validate_many is the count pass alone over many updates: the
+// whole of what a validation needs.
+
+#include <algorithm>
 
 #include "wire.h"
 
 using namespace ytpu_wire;
+
+// The count pass: one structural walk of an update with null outputs.
+// Fails on any truncation or overflow and refuses trailing bytes; `stats`
+// is the validating walk's (ytpu_validate_many), null for a decode's.
+static int count_v1(const uint8_t* buf, uint64_t len, uint64_t* n_structs,
+                    uint64_t* n_ds, ScanStats* stats) {
+  Reader r{buf, len, 0, false};
+  *n_structs = parse_structs(&r, nullptr, stats);
+  if (r.fail) return -1;
+  *n_ds = parse_ds(&r, nullptr, nullptr, nullptr);
+  if (r.fail) return -2;
+  if (r.pos != len) return -3;  // trailing garbage
+  return 0;
+}
+
+static int count_v2(const uint8_t* buf, uint64_t len, uint64_t* n_structs,
+                    uint64_t* n_ds, ScanStats* stats) {
+  V2Streams v;
+  if (!v.init(buf, len)) return -1;
+  int err = 0;
+  *n_structs = parse_structs_v2(&v, nullptr, &err, stats);
+  if (err != 0) return err;
+  *n_ds = parse_ds_v2(&v.rest, nullptr, nullptr, nullptr);
+  if (v.rest.fail) return -2;
+  if (v.rest.pos != len) return -3;  // trailing garbage
+  return 0;
+}
+
 extern "C" {
 
 // Returns bytes written into `out`, or a negative error code.
@@ -148,13 +180,7 @@ int64_t ytpu_encode_v1(
 
 int ytpu_count_v1(const uint8_t* buf, uint64_t len,
                   uint64_t* n_structs, uint64_t* n_ds) {
-  Reader r{buf, len, 0, false};
-  *n_structs = parse_structs(&r, nullptr);
-  if (r.fail) return -1;
-  *n_ds = parse_ds(&r, nullptr, nullptr, nullptr);
-  if (r.fail) return -2;
-  if (r.pos != len) return -3;  // trailing garbage
-  return 0;
+  return count_v1(buf, len, n_structs, n_ds, nullptr);
 }
 
 int ytpu_decode_v1(const uint8_t* buf, uint64_t len,
@@ -183,15 +209,7 @@ int ytpu_decode_v1(const uint8_t* buf, uint64_t len,
 
 int ytpu_count_v2(const uint8_t* buf, uint64_t len,
                   uint64_t* n_structs, uint64_t* n_ds) {
-  V2Streams v;
-  if (!v.init(buf, len)) return -1;
-  int err = 0;
-  *n_structs = parse_structs_v2(&v, nullptr, &err);
-  if (err != 0) return err;
-  *n_ds = parse_ds_v2(&v.rest, nullptr, nullptr, nullptr);
-  if (v.rest.fail) return -2;
-  if (v.rest.pos != len) return -3;  // trailing garbage
-  return 0;
+  return count_v2(buf, len, n_structs, n_ds, nullptr);
 }
 
 int ytpu_decode_v2(const uint8_t* buf, uint64_t len,
@@ -221,6 +239,36 @@ int ytpu_decode_v2(const uint8_t* buf, uint64_t len,
   parse_ds_v2(&v.rest, ds_client, ds_clock, ds_len);
   if (v.rest.fail) return -2;
   return 0;
+}
+
+// What `validate_update` asks of n updates in one call: update i is
+// bytes [ofs[i], ofs[i+1]) of `joined`.  out[4 i ..]: the count pass's
+// return code for it, or -5 where the walk passed and a root name or
+// parentSub is not strict UTF-8 (the Python side decodes those two
+// eagerly); then structs, delete ranges and distinct clients that brought
+// a struct.  Any code but 0 leaves the verdict to the caller's slow path.
+void ytpu_validate_many(const uint8_t* joined, const uint64_t* ofs,
+                        const uint8_t* v2, uint64_t n, int64_t* out) {
+  ScanStats stats;
+  for (uint64_t i = 0; i < n; i++, out += 4) {
+    stats.clients.clear();
+    stats.loose_utf8 = false;
+    const uint8_t* buf = joined + ofs[i];
+    uint64_t len = ofs[i + 1] - ofs[i];
+    uint64_t n_structs = 0, n_ds = 0;
+    int rc = v2[i] ? count_v2(buf, len, &n_structs, &n_ds, &stats)
+                   : count_v1(buf, len, &n_structs, &n_ds, &stats);
+    if (rc == 0 && stats.loose_utf8) rc = -5;
+    auto& c = stats.clients;
+    if (c.size() > 1) {
+      std::sort(c.begin(), c.end());
+      c.erase(std::unique(c.begin(), c.end()), c.end());
+    }
+    out[0] = rc;
+    out[1] = (int64_t)n_structs;
+    out[2] = (int64_t)n_ds;
+    out[3] = (int64_t)c.size();
+  }
 }
 
 }  // extern "C"

@@ -146,6 +146,43 @@ struct Reader {
 
 constexpr uint8_t kBit6 = 0x20, kBit7 = 0x40, kBit8 = 0x80, kBits5 = 0x1f;
 
+// UTF-8 as Python's strict ``bytes.decode("utf-8")`` takes it (RFC 3629:
+// no overlong form, no surrogate, nothing above U+10FFFF).  `utf16_len`
+// and `StringDec::read` above are looser: they count units and leave the
+// second byte's range to whoever decodes the string.
+inline bool utf8_strict(const uint8_t* p, uint64_t n) {
+  for (uint64_t i = 0; i < n; ) {
+    uint8_t b = p[i];
+    if (b < 0x80) { i++; continue; }
+    uint64_t k;
+    uint8_t lo = 0x80, hi = 0xBF;  // the second byte's range
+    if (b < 0xC2) return false;
+    else if (b < 0xE0) k = 2;
+    else if (b < 0xF0) { k = 3; if (b == 0xE0) lo = 0xA0; if (b == 0xED) hi = 0x9F; }
+    else if (b < 0xF5) { k = 4; if (b == 0xF0) lo = 0x90; if (b == 0xF4) hi = 0x8F; }
+    else return false;
+    if (k > n - i) return false;
+    if (p[i + 1] < lo || p[i + 1] > hi) return false;
+    for (uint64_t j = 2; j < k; j++) {
+      if ((p[i + j] & 0xC0) != 0x80) return false;
+    }
+    i += k;
+  }
+  return true;
+}
+
+// What a validating walk keeps beside the counts (transcode.cpp
+// ytpu_validate_many): the verdict of `validate_update` rests on the two
+// strings the Python side decodes eagerly, and its summary names the
+// distinct clients that brought a struct.
+struct ScanStats {
+  std::vector<uint64_t> clients;  // one entry a client group with a struct
+  bool loose_utf8 = false;        // a root name or parentSub fails utf8_strict
+  void parent_string(const uint8_t* buf, int64_t ofs, int64_t end) {
+    if (!utf8_strict(buf + ofs, (uint64_t)(end - ofs))) loose_utf8 = true;
+  }
+};
+
 // ---------------------------------------------------------------------------
 // V2: lib0 stream decoders over sub-ranges of the update buffer
 // (mirrors yjs_tpu/lib0/decoding.py RleDecoder / UintOptRleDecoder /
@@ -332,8 +369,10 @@ struct StructOut2 {
   int64_t *content_count;                 // element count / type_ref
 };
 
-// Parse the V2 struct section.  When out == nullptr, only counts.
-inline uint64_t parse_structs_v2(V2Streams* v, StructOut2* out, int* err) {
+// Parse the V2 struct section.  When out == nullptr, only counts; a
+// validating walk passes `stats`.
+inline uint64_t parse_structs_v2(V2Streams* v, StructOut2* out, int* err,
+                                 ScanStats* stats = nullptr) {
   uint64_t idx = 0;
   Reader* rest = &v->rest;
   uint64_t n_updates = rest->varuint();
@@ -341,6 +380,7 @@ inline uint64_t parse_structs_v2(V2Streams* v, StructOut2* out, int* err) {
     uint64_t n_structs = rest->varuint();
     int64_t client = v->client.read();
     uint64_t clock = rest->varuint();
+    if (stats != nullptr && n_structs > 0) stats->clients.push_back((uint64_t)client);
     for (uint64_t s = 0; s < n_structs; s++) {
       if (v->any_fail()) { *err = -1; return idx; }
       uint8_t info = (uint8_t)v->info.read();
@@ -359,6 +399,10 @@ inline uint64_t parse_structs_v2(V2Streams* v, StructOut2* out, int* err) {
             pic = v->client.read(); pik = v->left_clock.read();
           }
           if (info & kBit6) v->str.read(&pso, &pse);
+          if (stats != nullptr && !v->str.failed()) {
+            if (pno >= 0) stats->parent_string(v->str.buf, pno, pne);
+            if (pso >= 0) stats->parent_string(v->str.buf, pso, pse);
+          }
         }
         switch (ref) {
           case 1: length = v->len.read(); break;            // ContentDeleted
@@ -480,15 +524,18 @@ struct StructOut {
   int64_t *content_ofs, *content_end;
 };
 
-// Parse the struct section.  When out == nullptr, only counts.
+// Parse the struct section.  When out == nullptr, only counts; a
+// validating walk passes `stats`.
 // Returns the number of structs, or sets r->fail.
-inline uint64_t parse_structs(Reader* r, StructOut* out) {
+inline uint64_t parse_structs(Reader* r, StructOut* out,
+                              ScanStats* stats = nullptr) {
   uint64_t idx = 0;
   uint64_t n_updates = r->varuint();
   for (uint64_t u = 0; u < n_updates && !r->fail; u++) {
     uint64_t n_structs = r->varuint();
     uint64_t client = r->varuint();
     uint64_t clock = r->varuint();
+    if (stats != nullptr && n_structs > 0) stats->clients.push_back(client);
     for (uint64_t s = 0; s < n_structs && !r->fail; s++) {
       uint8_t info = r->u8();
       uint8_t ref = info & kBits5;
@@ -508,6 +555,10 @@ inline uint64_t parse_structs(Reader* r, StructOut* out) {
           if (info & kBit6) {
             uint64_t o, b; r->var_string(&o, &b);
             pso = (int64_t)o; psl = (int64_t)b;
+          }
+          if (stats != nullptr && !r->fail) {
+            if (pno >= 0) stats->parent_string(r->buf, pno, pno + pnl);
+            if (pso >= 0) stats->parent_string(r->buf, pso, pso + psl);
           }
         }
         c_ofs = r->pos;

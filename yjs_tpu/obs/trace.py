@@ -76,7 +76,8 @@ FORMAT_SPANS = {
 # (the provider's construction and the WAL's opening) are once a
 # recovery and go to the profiler alone: the ring is the new provider's,
 # which does not exist when they open.  ``read`` (a file's read, record
-# decode and CRC), ``validate`` (``validate_update`` a record) and
+# decode and CRC), ``validate`` (``validate_updates`` of a file's
+# update and snapshot records: one native structural walk) and
 # ``queue`` (``doc_id``, ``queue_update``, releases and the other record
 # kinds, in the log's order) are once a file, never once a record; the
 # closing flush opens its own spans.  tests/test_span_clock.py holds the

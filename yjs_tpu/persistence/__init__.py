@@ -11,7 +11,9 @@ Three pieces, all zero-dependency host-side code:
 - :mod:`recovery` — ``replay_wal`` / ``TpuProvider.recover``:
   snapshot-then-tail replay tolerating torn tails (truncate at the
   first bad checksum on the final segment) and mid-log corruption
-  (``validate_update`` → dead-letter queue, resync, continue).
+  (``validate_update`` → dead-letter queue, resync, continue); a
+  file's update and snapshot records are validated in one native call
+  (``validate_updates``), an undecodable one dead-lettered in its place.
 
 Env knobs: ``YTPU_WAL_DIR`` (journal every provider constructed without
 an explicit ``wal_dir``), ``YTPU_WAL_SEGMENT_BYTES`` (rotation

@@ -429,20 +429,82 @@ def validate_update(update: bytes, v2: bool = False) -> dict:
     so bytes that pass here decode on both the CPU core and the mirror
     planner.  Returns a summary ``{"clients", "structs", "ds_ranges",
     "bytes"}``; raises :class:`InvalidUpdate` on malformed input.
+    :func:`validate_updates` of one.
     """
+    (verdict,) = validate_updates((update,), (v2,))
+    if isinstance(verdict, InvalidUpdate):
+        raise verdict
+    return verdict
+
+
+def validate_updates(updates, v2s, tally: dict | None = None) -> list:
+    """:func:`validate_update` of every update, in order: its summary,
+    or the :class:`InvalidUpdate` it would have raised.
+
+    One native call walks them all (``native.validate_many``: the
+    scanner's count pass, which builds nothing).  What that walk does
+    not accept (malformed bytes, a root name or ``parentSub`` that is
+    not strict UTF-8, a legacy V2 payload kind, no native core) goes to
+    ``decode_update_refs``, whose pure-Python decoder arbitrates, so the
+    verdict on every input is that decoder's.  ``tally``, if given,
+    counts both ways under ``validated_native`` and
+    ``validated_fallback``.
+    """
+    from .native import validate_many
+
+    n = len(updates)
+    if all(type(u) is bytes and u for u in updates):
+        # a log file's records: nothing to refuse unwalked or to copy
+        out: list = [None] * n
+        walk, bufs = range(n), updates
+    else:
+        out = [_not_an_update(u) for u in updates]
+        walk = [i for i, refusal in enumerate(out) if refusal is None]
+        bufs = [bytes(updates[i]) for i in walk]
+        v2s = [v2s[i] for i in walk]
+    rows = validate_many(bufs, v2s) if bufs else None
+    if rows is None:
+        slow = range(len(bufs))
+    else:
+        slow = rows[:, 0].nonzero()[0].tolist()
+        structs, ds_ranges, clients = rows[:, 1:].T.tolist()
+        for i, s, d, c, buf in zip(walk, structs, ds_ranges, clients, bufs):
+            out[i] = {
+                "clients": c, "structs": s, "ds_ranges": d, "bytes": len(buf),
+            }
+    for j in slow:
+        out[walk[j]] = _validate_decoded(bufs[j], bool(v2s[j]))
+    if tally is not None:
+        tally["validated_native"] = (
+            tally.get("validated_native", 0) + len(bufs) - len(slow)
+        )
+        tally["validated_fallback"] = (
+            tally.get("validated_fallback", 0) + len(slow)
+        )
+    return out
+
+
+def _not_an_update(update) -> InvalidUpdate | None:
+    """The refusal of what needs no walk: no bytes, or none at all."""
     if not isinstance(update, (bytes, bytearray, memoryview)):
-        raise InvalidUpdate(f"not a bytes payload: {type(update).__name__}")
-    update = bytes(update)
+        return InvalidUpdate(f"not a bytes payload: {type(update).__name__}")
     if not update:
-        raise InvalidUpdate("empty update")
-    # the doc-free ref scanner is the same decoder the flush planner runs
-    # (native columnar scan with pure-Python arbitration on failure)
+        return InvalidUpdate("empty update")
+    return None
+
+
+def _validate_decoded(update: bytes, v2: bool):
+    """The slow path of :func:`validate_updates`: the doc-free ref
+    scanner the Python planner runs (native columnar scan, pure-Python
+    arbitration on failure), its refs counted and thrown away."""
     from .ops.columns import decode_update_refs
 
     try:
         refs, ds = decode_update_refs(update, v2)
     except Exception as e:
-        raise InvalidUpdate(f"{type(e).__name__}: {e}") from e
+        err = InvalidUpdate(f"{type(e).__name__}: {e}")
+        err.__cause__ = e
+        return err
     return {
         "clients": len(refs),
         "structs": sum(len(rs) for rs in refs.values()),
