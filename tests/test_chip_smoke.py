@@ -140,8 +140,10 @@ def test_staging_fence_waits_on_real_completion(monkeypatch):
     monkeypatch.setattr(engine_mod._FlushPipeline, "acquire", acquire)
     eng = BatchEngine(4)
     pl = eng._pl
-    docs = [_edits(6, seed) for seed in range(4)]
-    for r in range(6):
+    # the first round loads empty slots: row blocks, staged anew each
+    # time, which acquire no slot; the six after it ride the lanes
+    docs = [_edits(7, seed) for seed in range(4)]
+    for r in range(7):
         for i in range(4):
             eng.queue_update(i, docs[i][r])
         eng.flush()  # 4 dispatches, each donating the previous tables

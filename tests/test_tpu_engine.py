@@ -1182,37 +1182,42 @@ def test_a_mesh_reuses_a_program_whose_lanes_cover_the_chunk():
     bulk load's exact key, and covers a served flush's (PR 40)."""
     from yjs_tpu.parallel import doc_mesh
 
-    def prepended(client, n):
+    def grown(eng, slot, client, n):
+        """A room of ``n`` rows whose dense links ride the lanes: it
+        holds its first row when the others arrive, each typed right
+        behind that one, so the plan rewrites every link the room has.
+        (Loaded whole into an empty slot it would go up as a row block
+        and meet no lane key: tests/test_row_load.py.)"""
         d = make_doc(client)
-        for _ in range(n):
-            d.get_text("text").insert(0, "x")
+        t = d.get_text("text")
+        t.insert(0, "x")
+        eng.queue_update(slot, Y.encode_state_as_update(d))
+        eng.flush()
+        sv = Y.encode_state_vector(d)
+        for _ in range(n - 1):
+            t.insert(1, "x")
+        eng.queue_update(slot, Y.encode_state_as_update(d, sv))
+        eng.flush()
         return d
 
     eng = BatchEngine(8, mesh=doc_mesh(4, backend="cpu"))
-    first = prepended(1, 200)
-    eng.queue_update(0, Y.encode_state_as_update(first))
-    eng.flush()
+    grown(eng, 0, 1, 200)
     (key,) = eng._sharded_apply
     assert key[0] == 208  # 200 dense link writes, bucketed
     # the room comes back a little smaller and on another chip's block
     eng.reset_doc(0)
-    second = prepended(2, 180)
-    eng.queue_update(5, Y.encode_state_as_update(second))
-    eng.flush()
+    second = grown(eng, 5, 2, 180)
     assert set(eng._sharded_apply) == {key}
     assert_engine_matches(eng, second, idx=5)
     assert eng._covering_key((192, 64, 8, 64)) == key
     # too narrow for the padding to be worth it, or wider: its own
     for other in ((64, 64, 8, 64), (224, 64, 8, 64), (208, 64, 8, 128)):
         assert eng._covering_key(other) == other
-    third = prepended(3, 40)
-    eng.queue_update(2, Y.encode_state_as_update(third))
-    eng.flush()
+    third = grown(eng, 2, 3, 40)
     assert set(eng._sharded_apply) == {key, (64, 64, 8, 64)}
     assert_engine_matches(eng, third, idx=2)
     one = BatchEngine(8)
-    one.queue_update(0, Y.encode_state_as_update(first))
-    one.flush()
+    grown(one, 0, 1, 200)
     assert one._mesh_keys == {(256, 64, 8, 64)}  # a new width: a power of two
     # a served flush's key wanders in four widths at once: an issued key
     # that covers it at 512 lanes more, or a quarter, is issued again
@@ -1354,7 +1359,12 @@ class TestLaneBucketing:
 
         def mk_updates(n_docs, ops, seed0):
             # two-client conflict texture: realistic fragmentation so the
-            # lane demand is real work, not floor padding
+            # lane demand is real work, not floor padding.  Each room is
+            # a pair: its first keystroke, flushed by itself, and the
+            # whole room behind it, so that the room holds a row when
+            # the rest arrives and rides the lanes (loaded whole into an
+            # empty slot it goes up as a row block and packs no lane:
+            # tests/test_row_load.py)
             outs = []
             for k in range(n_docs):
                 gen = random.Random(seed0 + k)
@@ -1369,6 +1379,9 @@ class TestLaneBucketing:
                     Y.apply_update(b, ua)
                     Y.apply_update(a, ub)
 
+                a.get_text("text").insert(0, "ab")
+                first = Y.encode_state_as_update(a)
+                sync()
                 for i in range(ops + gen.randint(0, ops // 20)):
                     d = a if gen.random() < 0.5 else b
                     t = d.get_text("text")
@@ -1381,13 +1394,18 @@ class TestLaneBucketing:
                     if gen.random() < 0.2:
                         sync()
                 sync()
-                outs.append(Y.encode_state_as_update(a))
+                outs.append((first, Y.encode_state_as_update(a)))
             return outs
 
+        def load(eng, rooms):
+            for part in (0, 1):
+                for i, pair in enumerate(rooms):
+                    eng.queue_update(i, pair[part])
+                eng.flush()
+
         eng = BatchEngine(32)
-        for i, u in enumerate(mk_updates(32, 120, 5000)):
-            eng.queue_update(i, u)
-        eng.flush()
+        load(eng, mk_updates(32, 120, 5000))
+        assert eng.last_flush_metrics["rooms_row_loaded"] == 0
         occ = eng.last_flush_metrics["schedule_occupancy"]
         # >=0.90 at this 32-doc scale (the fixed 64/64/8/64 minimum-width
         # floors are ~5% of demand here)
@@ -1407,9 +1425,7 @@ class TestLaneBucketing:
             for run, seed0 in enumerate(range(6000, 6600, 100)):
                 e1 = BatchEngine(32)
                 ops = 120 + (run % 3) * 4  # ±~5% demand wobble per run
-                for i, u in enumerate(mk_updates(32, ops, seed0)):
-                    e1.queue_update(i, u)
-                e1.flush()
+                load(e1, mk_updates(32, ops, seed0))
         finally:
             engine_mod.pack_apply_lanes = orig
         assert len(widths) >= 6

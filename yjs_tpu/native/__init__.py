@@ -147,6 +147,10 @@ def load():
         fn.argtypes = [vpp, i64p, i64, i64, i64, i64, i64, i64, i64,
                        ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
                        vp, i64p]
+    for pack_name in ("ymx_pack_rows", "ymx_pack_rows16"):
+        fn = getattr(lib, pack_name)
+        fn.restype = i64
+        fn.argtypes = [vpp, i64p, i64, i64, i64, ctypes.c_int32, vp, vp, vp]
     for name, args in [
         ("ymx_plan_splits", [vp, i64p]),
         ("ymx_plan_sched", [vp, i64p]),

@@ -120,12 +120,16 @@ struct Plan {
   int64_t rows_new = 0, rows_nested = 0, rows_format = 0;
   int64_t rows_attr = 0, rows_type = 0, segs_before = 0;
   int64_t lww_overwritten = 0, format_deleted = 0;
+  // the mirror held no row before this step: with dense links, every
+  // cell the room has ever written is in this plan (plan_shape)
+  bool from_empty = false;
   // rows this step deleted (delete_rows also carries the fragments it
   // split off rows that earlier steps deleted)
   std::vector<int64_t> fresh_deletes;
 
   void clear() {
     fresh_deletes.clear();
+    from_empty = false;
     n_rows = 0;
     rows_new = rows_nested = rows_format = 0;
     rows_attr = rows_type = segs_before = 0;
@@ -1183,6 +1187,7 @@ struct Mirror {
     };
     plan.clear();
     plan.segs_before = n_segs();
+    plan.from_empty = n_rows() == 0;
     plan_seq++;
     dirty_epoch++;
 
@@ -2686,10 +2691,21 @@ int64_t ymx_buf_len(void* h, int64_t idx) {
 // out_counts (int64[16]): n_rows, n_splits, n_sched, [3..5] reserved (0:
 // cached plans and the packer index this layout), n_delete_rows,
 // n_applied_ds, has_pending, pending_depth, n_slots, n_segs, n_links,
-// n_heads, [14] 0 (ymx_prepare_many's dense-link flag), [15] the plan's
-// number (Mirror::plan_seq).  Returns 0 or an error code (<0).
+// n_heads, [14] the step's shape (plan_shape), [15] the plan's number
+// (Mirror::plan_seq).  Returns 0 or an error code (<0).
 // counts[3..5]: the step's rows by kind, 21 bits a field (a field that
 // would not fit reads its largest value)
+// counts[14]: bit 0, the step's links are dense (link_rows == [0..n_rows):
+// the packer ships values only); bit 1, the mirror held no row before the
+// step.  3 is a room loaded whole into an empty slot: the engine writes
+// it to the device as a row (ymx_pack_rows)
+static int64_t plan_shape(const Mirror* m) {
+  const Plan& p = m->plan;
+  int64_t k = (int64_t)p.link_rows.size();
+  int64_t dense = k > 0 && k == p.n_rows && p.link_rows.back() == k - 1;
+  return dense | ((int64_t)p.from_empty << 1);
+}
+
 static void plan_kind_counts(const Mirror* m, int64_t* c) {
   auto f = [](int64_t v) { return v < 0 ? 0 : (v > 0x1FFFFF ? 0x1FFFFF : v); };
   const Plan& p = m->plan;
@@ -2718,7 +2734,7 @@ int ymx_prepare(void* h, const int64_t* buf_ids, const int64_t* v2_flags,
   out_counts[11] = m->n_segs();
   out_counts[12] = (int64_t)m->plan.link_rows.size();
   out_counts[13] = (int64_t)m->plan.head_segs.size();
-  out_counts[14] = 0;
+  out_counts[14] = plan_shape(m);
   out_counts[15] = (int64_t)m->plan_seq;
   return 0;
 }
@@ -2841,9 +2857,7 @@ struct PlanPool {
 };
 
 // batched twin of ymx_prepare: one call plans EVERY staged doc, writing a
-// 16-wide counts row per doc ([0..13] = ymx_prepare's layout, [14] =
-// dense-link flag: link_rows == [0..n_rows), [15] = the plan's number)
-// and a per-doc rc.  Kills the
+// 16-wide counts row per doc (ymx_prepare's layout) and a per-doc rc.  Kills the
 // per-doc Python/ctypes round trip that dominated distinct-doc flushes.
 // Per-doc plans are independent (each touches only its own Mirror; the
 // only shared data are the const update bytes), so the loop fans out over
@@ -2929,11 +2943,7 @@ void ymx_prepare_many(void** hs, int64_t n_docs, const int64_t* buf_ofs,
     c[11] = m->n_segs();
     c[12] = (int64_t)m->plan.link_rows.size();
     c[13] = (int64_t)m->plan.head_segs.size();
-    int64_t k = c[12];
-    c[14] = (k > 0 && k == m->plan.n_rows &&
-             m->plan.link_rows.back() == k - 1)
-                ? 1
-                : 0;
+    c[14] = plan_shape(m);
     c[15] = (int64_t)m->plan_seq;
   };
   auto report = [&](double pool_start, double pool_join, int threads,
@@ -3245,6 +3255,71 @@ void ymx_pack_apply16(void** hs, const int64_t* doc_ids, int64_t n_plans,
   pack_apply_t<int16_t>(hs, doc_ids, n_plans, b_loc, n_shards, k_dn, k_sp,
                         k_h, k_d, (int16_t)oob_r, (int16_t)oob_s,
                         (int16_t)null_val, lanes, stats);
+}
+
+}  // extern "C"
+
+// the row form of a room loaded whole into an empty slot (plan_shape 3):
+// plan i becomes row pos[i] of a staged block, as wide as the tables'
+// cells it covers: `right` holds its links (dense: link_vals in row
+// order) and null_val beyond them, `deleted` its tombstones, `starts`
+// its list heads and null_val elsewhere.  Every cell of a row is written;
+// rows of the block no plan is given stay as the caller left them.
+// Returns the writes that fell outside a row (0 unless the caller sized
+// the block wrong; nothing is written for them).
+template <typename T>
+static int64_t pack_rows_t(void** hs, const int64_t* pos, int64_t n_plans,
+                           int64_t w, int64_t ws, T null_val, T* right,
+                           uint8_t* deleted, T* starts) {
+  int64_t outside = 0;
+  for (int64_t pi = 0; pi < n_plans; pi++) {
+    const Plan& p = static_cast<Mirror*>(hs[pi])->plan;
+    T* r = right + pos[pi] * w;
+    uint8_t* d = deleted + pos[pi] * w;
+    T* s = starts + pos[pi] * ws;
+    int64_t k = (int64_t)p.link_vals.size();
+    if (k > w) {
+      outside += k - w;
+      k = w;
+    }
+    for (int64_t j = 0; j < k; j++) r[j] = (T)p.link_vals[(size_t)j];
+    for (int64_t j = k; j < w; j++) r[j] = null_val;
+    std::memset(d, 0, (size_t)w);
+    for (int64_t row : p.delete_rows) {
+      if (row < 0 || row >= w) {
+        outside++;
+        continue;
+      }
+      d[row] = 1;
+    }
+    for (int64_t j = 0; j < ws; j++) s[j] = null_val;
+    for (size_t j = 0; j < p.head_segs.size(); j++) {
+      int64_t sg = p.head_segs[j];
+      if (sg < 0 || sg >= ws) {
+        outside++;
+        continue;
+      }
+      s[sg] = (T)p.head_vals[j];
+    }
+  }
+  return outside;
+}
+
+extern "C" {
+
+int64_t ymx_pack_rows(void** hs, const int64_t* pos, int64_t n_plans,
+                      int64_t w, int64_t ws, int32_t null_val, int32_t* right,
+                      uint8_t* deleted, int32_t* starts) {
+  return pack_rows_t<int32_t>(hs, pos, n_plans, w, ws, null_val, right,
+                              deleted, starts);
+}
+
+// int16 twin: a block no wider than 32767 holds no row index beyond it
+int64_t ymx_pack_rows16(void** hs, const int64_t* pos, int64_t n_plans,
+                        int64_t w, int64_t ws, int32_t null_val,
+                        int16_t* right, uint8_t* deleted, int16_t* starts) {
+  return pack_rows_t<int16_t>(hs, pos, n_plans, w, ws, (int16_t)null_val,
+                              right, deleted, starts);
 }
 
 void ymx_plan_links(void* h, int64_t* rows, int64_t* vals) {

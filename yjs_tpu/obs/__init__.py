@@ -189,6 +189,13 @@ FLUSH_METRICS_SCHEMA: dict = {
     # previous flush); realloc_bytes is the growth cost when it is 0
     "flush_donated": 0,
     "realloc_bytes": 0,
+    # rooms this flush wrote to the device as rows of a block and not
+    # link by link (a room loaded whole into a slot that held no row: a
+    # room bound, reloaded after a release, or recovered), and the bytes
+    # staged for them (allocation = transfer); 0 in a flush of rooms
+    # that held rows
+    "rooms_row_loaded": 0,
+    "row_block_bytes": 0,
     # bytes the compactions and hydrations since the previous flush
     # staged for scatter_rows (host allocation = transfer = device
     # writes), and the bytes of rebuilt rows the rooms in those blocks
@@ -559,6 +566,12 @@ class EngineObs:
             "planned since the look before",
             unit="rooms",
         )
+        self._flush_rooms_row_loaded = r.counter(
+            "ytpu_flush_rooms_row_loaded_total",
+            "Rooms flushes wrote to the device as rows of a block: rooms "
+            "loaded whole into slots that held no row",
+            unit="rooms",
+        )
         self._release_blanked_bytes = r.counter(
             "ytpu_release_blanked_bytes_total",
             "Bytes of device rows blanked in place by room releases "
@@ -605,6 +618,8 @@ class EngineObs:
             self._flush_rows_staged_blocks.inc(metrics["rows_staged_blocks"])
         self._flush_rooms_dirty.inc(metrics["rooms_dirty"])
         self._flush_rooms_compact_looked.inc(metrics["rooms_compact_looked"])
+        if metrics["rooms_row_loaded"]:
+            self._flush_rooms_row_loaded.inc(metrics["rooms_row_loaded"])
         if metrics["rows_planned"]:
             for kind, child in self._flush_rows_by_kind.items():
                 child.inc(metrics[f"rows_{kind}"])

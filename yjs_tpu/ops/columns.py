@@ -456,6 +456,8 @@ class StepPlan:
     # step deleted (a later writer's delete set, or the LWW pass)
     segs_before: int = 0
     lww_overwritten: int = 0
+    # the mirror held no row before this step
+    from_empty: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -1191,7 +1193,9 @@ class DocMirror:
             need_start(client, clock)
             need_start(client, clock + ln)
 
-        plan = StepPlan(n_rows=0, segs_before=self.n_segs)
+        plan = StepPlan(
+            n_rows=0, segs_before=self.n_segs, from_empty=self.n_rows == 0
+        )
         plan._dl = set()  # rows whose list_next changed this step
         plan._dh = set()  # segs whose head changed this step
 

@@ -41,6 +41,7 @@ from .native_mirror import (
     encode_steps_many,
     native_plan_available,
     pack_apply_lanes,
+    pack_row_blocks,
     plan_segment_stats,
     prepare_many,
 )
@@ -120,6 +121,14 @@ def _kind_counts(counts: np.ndarray) -> dict:
         "lww_overwritten": (c5 >> 2 * _KIND_BITS) & mask,
         "format_deleted": (c3 >> _KIND_BITS) & mask,
     }
+
+
+def _dense_links(p) -> bool:
+    """A Python-lane plan writes every row its room has: links for rows
+    ``0..k-1``, all of them (the core's twin: plancore.cpp
+    ``plan_shape``, bit 0)."""
+    k = len(p.link_rows)
+    return bool(k) and k == p.n_rows and int(p.link_rows[-1]) == k - 1
 
 
 def _kind_counts_py(m, p) -> dict:
@@ -517,6 +526,8 @@ class BatchEngine:
         self._sharded_sv: dict[int, object] = {}
         # cached sharded bulk-apply callables keyed by lane bucket shape
         self._sharded_apply: dict[tuple, object] = {}
+        # the sharded row load: one jitted callable for every block shape
+        self._sharded_load = None
         # lane keys the packer has issued and may issue again (_covering_key)
         self._mesh_keys: set[tuple] = set()
         # explicit placement: a meshed engine pins EVERY host->device
@@ -1094,6 +1105,61 @@ class BatchEngine:
         )
         return [stats[i] for i in todo]
 
+    def _block_shapes(self, n_rows, n_segs):
+        """The blocks that rooms holding ``n_rows[j]`` rows and
+        ``n_segs[j]`` list heads are staged in, narrowest first, as
+        ``(w, ws, k_min, members)``: a block ``w`` wide (its heads
+        ``ws``) of ``k_min`` rows at least, for the rooms ``members``
+        (indices into ``n_rows``).  The one staging rule of every whole-row write:
+        compactions and hydrations (``_scatter_rebuilt``) and the load
+        of rooms into empty slots (``_stage_row_loads``).
+
+        The rooms are staged in width classes, one block a class.  A
+        room's own width is ``_bucket(n_rows[j])`` (the power-of-two
+        rule of the table widths, never more than the table's
+        ``cap + 1``); a class is anchored at the widest room not yet in
+        one and takes the rooms of that width and of half of it, so a
+        block is at most twice as wide as any room in it, rooms that
+        differ by a row across a power of two still share one (the
+        widths a process meets, and the programs it compiles, stay few),
+        and a long room widens the block of no room but its like.  A
+        block's heads are as wide as its own rooms need.
+
+        One device, a handful of short rooms (``_SERVED_ROOMS`` at most,
+        none wider than ``_SERVED_WIDTH``: the amortized compactions of a
+        served process, a room now and then as it doubles, or a room
+        bound while others type): one block of exactly that shape, its
+        spare rows aimed past the last slot, where the write drops them,
+        its heads as wide as the table's.  A block's shape is a program,
+        and which rooms double or arrive in one flush, and how long they
+        are, is the traffic's: a process would go on meeting new shapes
+        for as long as it serves (some hundred KB staged where some
+        dozen would do, against seconds of compiling)."""
+        own = [min(_bucket(n), self._cap + 1) for n in n_rows]
+        if (
+            self.mesh is None and len(own) <= _SERVED_ROOMS
+            and max(own) <= _SERVED_WIDTH <= self._cap
+        ):
+            yield (
+                _SERVED_WIDTH, self._seg_cap + 1, _SERVED_ROOMS,
+                list(range(len(own))),
+            )
+            return
+        width_of: dict[int, int] = {}
+        for w in sorted(set(own), reverse=True):
+            # half an anchor's width joins it; anything else anchors
+            width_of[w] = 2 * w if width_of.get(2 * w) == 2 * w else w
+        classes: dict[int, list[int]] = {}
+        for j, w in enumerate(own):
+            classes.setdefault(width_of[w], []).append(j)
+        for w in sorted(classes):
+            members = classes[w]
+            ws = min(
+                _bucket(max(n_segs[j] for j in members), 8),
+                self._seg_cap + 1,
+            )
+            yield w, ws, 0, members
+
     def _scatter_rebuilt(self, todo, rebuild, n_rows, n_segs) -> None:
         """Stage rebuilt rooms and scatter them into the device tables:
         the one staging path of compactions and hydrations.
@@ -1101,59 +1167,19 @@ class BatchEngine:
         ``rebuild(doc)`` gives a doc of ``todo`` its ``(right, deleted,
         heads)`` as ``rebuild_compacted_self`` does; ``n_rows[j]`` and
         ``n_segs[j]`` bound the rows and list heads slot ``todo[j]`` has
-        held since it was last blanked.  The rooms are staged in width
-        classes, one block a class, ``len(class) x w``.  A room's own
-        width is ``_bucket(n_rows[j])`` (the power-of-two rule of the
-        table widths, never more than the table's ``cap + 1``); a class
-        is anchored at the widest room not yet in one and takes the
-        rooms of that width and of half of it, so a block is at most
-        twice as wide as any room in it, rooms that differ by a row
-        across a power of two still share one (the widths a process
-        meets, and the ``scatter_rows`` programs it compiles, stay few),
-        and a long room widens the block of no room but its like.  Each
-        block is allocated, rebuilt, put and scattered by itself,
-        narrowest first, its heads as wide as its own rooms need: host
-        allocation, transfer and device scatter scale with what each
-        room holds.  A room's block is at least as wide as the cells it
-        has written, whatever class the others are in, so the tables
-        come out as one block as wide as the widest room would leave
-        them.
-
-        One device, a handful of short rooms (``_SERVED_ROOMS`` at most,
-        none wider than ``_SERVED_WIDTH``: the amortized compactions of a
-        served process, a room now and then as it doubles): one block of
-        exactly that shape, its spare rows aimed past the last slot,
-        where the scatter drops them, its heads as wide as the table's.
-        A block's shape is a ``scatter_rows`` program, and which rooms
-        double in one flush, and how long they are, is the traffic's: a
-        process would go on meeting new shapes for as long as it serves
-        (some hundred KB staged where some dozen would do, against
-        seconds of compiling)."""
+        held since it was last blanked.  The rooms are staged in the
+        blocks ``_block_shapes`` gives, a row a room (the served block:
+        ``k_min`` rows).  Each block is
+        allocated, rebuilt, put and scattered by itself, narrowest
+        first: host allocation, transfer and device scatter scale with
+        what each room holds.  A room's block is at least as wide as the
+        cells it has written, whatever class the others are in, so the
+        tables come out as one block as wide as the widest room would
+        leave them."""
         span = self._phase_ctx
-        own = [min(_bucket(n), self._cap + 1) for n in n_rows]
-        served = (
-            self.mesh is None and len(todo) <= _SERVED_ROOMS
-            and max(own) <= _SERVED_WIDTH <= self._cap
-        )
-        width_of: dict[int, int] = {}
-        for w in sorted(set(own), reverse=True):
-            # half an anchor's width joins it; anything else anchors
-            width_of[w] = 2 * w if width_of.get(2 * w) == 2 * w else w
-        classes: dict[int, list[int]] = {}
-        for j, w in enumerate(own):
-            classes.setdefault(
-                _SERVED_WIDTH if served else width_of[w], []
-            ).append(j)
-        for w in sorted(classes):
-            members = classes[w]
+        for w, ws, k_min, members in self._block_shapes(n_rows, n_segs):
             docs = [todo[j] for j in members]
-            k = len(docs)
-            ws = min(
-                _bucket(max(n_segs[j] for j in members), 8),
-                self._seg_cap + 1,
-            )
-            if served:
-                k, ws = _SERVED_ROOMS, self._seg_cap + 1
+            k = max(k_min, len(docs))
             with span("compact.alloc"):
                 new_right = np.full((k, w), NULL, np.int32)
                 new_deleted = np.zeros((k, w), bool)
@@ -1765,11 +1791,16 @@ class BatchEngine:
 
         kinds:
           "lanes"   (lanes, key)                    bulk-apply scatter
+          "load"    (idx, right, deleted, starts, sums)
+                                                    bulk apply of rooms
+                                                    loaded into empty
+                                                    slots, as rows
           "rows"    (idx, right, deleted, starts)   whole-row rebuild
 
         ``slot`` ties the dispatch to the staging buffer it consumes (the
-        double-buffered pair's reuse fence).  All array args are already
-        device-placed by the caller (_put_b/_put_r)."""
+        double-buffered pair's reuse fence).  A "rows" block is
+        device-placed by the caller (_put_r); lanes and "load" blocks
+        are placed here, each shard's part on its own device."""
         dyn = (self._right, self._deleted, self._starts)
         if kind == "lanes":
             lanes, key = args
@@ -1788,6 +1819,22 @@ class BatchEngine:
             else:
                 dyn = kernels.apply_plan2(
                     dyn, self._put_r(lanes[0]), k_dn, k_sp, k_h, k_d
+                )
+        elif kind == "load":
+            self._metrics_dev = None
+            if self.mesh is not None:
+                if self._sharded_load is None:
+                    from ..parallel.mesh import sharded_load_rows
+
+                    self._sharded_load = sharded_load_rows(
+                        self.mesh, self.mesh.axis_names[0]
+                    )
+                dyn, self._metrics_dev = self._sharded_load(
+                    dyn, *(self._put_b(a) for a in args)
+                )
+            else:
+                dyn = kernels.apply_plan2_rows(
+                    dyn, *(self._put_r(a) for a in args[:4])
                 )
         elif kind == "rows":
             idx, new_right, new_deleted, new_starts = args
@@ -1829,6 +1876,8 @@ class BatchEngine:
         seg_base = plan_segment_stats() if native else (0, 0)
         stats_tot = np.zeros(4, np.int64)
         lanes_padded_tot = 0
+        rooms_row_loaded = row_links = row_block_bytes = 0
+        row_real = row_cells = 0  # what the row blocks hold, of their cells
         work_ok: list = []  # native: (doc, mirror, counts); py: (doc, plan)
         max_rows_all = 0
         acc = SimpleNamespace(
@@ -1864,28 +1913,37 @@ class BatchEngine:
             if not chunk_ok:
                 continue
             with self._phase_ctx("pack"), pl.pack():
-                if native:
-                    slot, key, stats, max_rows = self._pack_chunk_native(
-                        chunk_ok, b_loc, n_shards
-                    )
-                else:
-                    slot, key, stats, max_rows = self._pack_chunk_py(
-                        chunk_ok, b_loc, n_shards
-                    )
+                pack = (
+                    self._pack_chunk_native if native else self._pack_chunk_py
+                )
+                slot, key, stats, max_rows, loads = pack(
+                    chunk_ok, b_loc, n_shards
+                )
                 stats_tot += stats
                 max_rows_all = max(max_rows_all, max_rows)
                 # capacity is per shard; real lane counts (stats) sum across
                 # shards, so the denominator must too or meshed runs report
                 # occupancy inflated by n_shards (ADVICE r4)
-                lanes_padded_tot += n_shards * sum(key)
+                lanes_padded_tot += n_shards * sum(key or ())
+                for idx, *tables, sums in loads:
+                    rooms_row_loaded += int((idx < b_loc).sum())
+                    row_links += int(sums[:, 0].sum())
+                    row_real += int(sums.sum())
+                    row_cells += sum(t.size for t in tables)
+                    row_block_bytes += sum(t.nbytes for t in tables)
                 work_ok.extend(chunk_ok)
             t2 = time.perf_counter()
             t_pack_acc += t2 - t1
             # async dispatch: the device consumes this chunk's staged lanes
-            # while the next loop iteration plans and packs on the host
-            # (the staging slot fences its buffer against premature reuse)
+            # and row blocks (they write different rooms) while the next
+            # loop iteration plans and packs on the host (the staging slot
+            # fences its buffer against premature reuse; a row block is
+            # staged anew a chunk)
             with self._phase_ctx("dispatch"):
-                self._dispatch("lanes", slot.buf, key, slot=slot)
+                if slot is not None:
+                    self._dispatch("lanes", slot.buf, key, slot=slot)
+                for block in loads:
+                    self._dispatch("load", *block)
             t_disp_acc += time.perf_counter() - t2
         metrics["n_demoted"] = acc.demoted
         metrics["n_rolled_back"] = acc.rolled_back
@@ -1973,12 +2031,18 @@ class BatchEngine:
         metrics.update({
             "n_docs_flushed": n_flushed,
             "n_rows_max": max_rows_all,
-            "n_sched_entries": n_dense + n_sparse,
+            # real links, whichever way they went: lanes or row blocks
+            "n_sched_entries": n_dense + n_sparse + row_links,
             "n_levels": 1,
-            "level_width": n_dense + n_sparse,
-            # bulk path: fraction of dispatched scatter lanes that are real
+            "level_width": n_dense + n_sparse + row_links,
+            "rooms_row_loaded": rooms_row_loaded,
+            "row_block_bytes": row_block_bytes,
+            # bulk path: fraction of what was staged that is real: of
+            # the scatter lanes, and of the row blocks' cells the links,
+            # tombstones and heads they hold
             "schedule_occupancy": (
-                lanes_real / lanes_padded_tot if lanes_padded_tot else 0.0
+                (lanes_real + row_real) / (lanes_padded_tot + row_cells)
+                if lanes_padded_tot + row_cells else 0.0
             ),
             "n_pending_docs": n_pending,
             "pending_depth": pending_depth,
@@ -2186,22 +2250,98 @@ class BatchEngine:
         self._mesh_keys.add(key)
         return key
 
+    def _stage_row_loads(self, doc_idx, sizes, b_loc, n_shards, fill):
+        """Stage the rooms a chunk loads whole into empty slots as row
+        blocks: what ``_dispatch("load", ...)`` writes, one block a width
+        class of ``_block_shapes`` (so a 100,000-row room widens no
+        block but its like).
+
+        A room is taken this way only where its plan says both that it
+        writes every row the room has (dense links: rows ``0..k-1``) and
+        that the mirror held no row before the step (``from_empty``: a
+        room bound, reloaded after ``reset_doc``, or recovered).  A slot
+        whose mirror holds no row is at fill on the device in every cell
+        (``kernels.apply_plan2_rows`` says why), so the ``NULL`` a block
+        carries behind a room's rows changes nothing, and the tombstones
+        of the plan are all the room has.  A room that had rows keeps
+        the element lanes: a row write would wipe its older tombstones.
+
+        ``doc_idx``: ascending slots; ``sizes``: a room a row, its rows,
+        segments, links, tombstones and heads; ``fill(members, pos, right,
+        deleted, starts)`` writes every cell of rows ``pos`` from
+        the rooms ``members`` (indices into ``doc_idx``).  A block holds a part of
+        ``k`` rows for each shard (one device: one part), ``k`` the
+        widest shard's rooms rounded up as lane widths are
+        (``_bucket_lanes``: the shapes a process meets stay few where a
+        chunk's long rooms come to one more or less); its links and
+        heads travel int16 where it is no wider than 32767 (each is a
+        row of its own room).  Returns ``(idx, right, deleted, starts,
+        sums)`` per block: ``idx`` the slots local to their shard, a
+        spare row aimed past the shard's last; ``sums`` ``[n_shards,
+        3]``, the links, tombstones and heads of each shard's part."""
+        blocks = []
+        shapes = self._block_shapes(sizes[:, 0], sizes[:, 1])
+        for w, ws, k_min, members in shapes:
+            members = np.asarray(members)
+            shard, local = np.divmod(doc_idx[members], b_loc)
+            per = np.bincount(shard, minlength=n_shards)
+            k = max(k_min, _bucket_lanes(int(per.max()), 1))
+            # a room's row: its shard's part, then its rank in the part
+            first = np.cumsum(per) - per
+            pos = shard * k + np.arange(len(members)) - first[shard]
+            idx = np.full(n_shards * k, b_loc, np.int32)
+            idx[pos] = local
+            dtype = np.int16 if w <= 32767 else np.int32
+            right = np.empty((n_shards * k, w), dtype)
+            deleted = np.empty((n_shards * k, w), bool)
+            starts = np.empty((n_shards * k, ws), dtype)
+            fill(members, pos, right, deleted, starts)
+            sums = np.zeros((n_shards, 3), np.int32)
+            np.add.at(sums, shard, sizes[members, 2:])
+            blocks.append((idx, right, deleted, starts, sums))
+        return blocks
+
     def _pack_chunk_native(self, chunk_ok, b_loc, n_shards):
-        """Stage one planned native chunk: grow capacity, size the
-        per-shard lane widths, pick the int16 downshift, and run the
-        native pack (ymx_pack_apply) writing straight into the acquired
-        staging buffer.  Returns ``(slot, key, stats, max_rows)``."""
+        """Stage one planned native chunk: grow capacity, stage the
+        rooms loaded whole into empty slots as row blocks
+        (``_stage_row_loads``), and for the others size the per-shard
+        lane widths, pick the int16 downshift, and run the native pack
+        (ymx_pack_apply) writing straight into the acquired staging
+        buffer.  Returns ``(slot, key, stats, max_rows, loads)``;
+        ``slot`` and ``key`` are None where every room went as a row."""
         counts = np.stack([c for _, _, c in chunk_ok])
         doc_idx = np.asarray([i for i, _, _ in chunk_ok], np.int64)
         max_rows = int(counts[:, 0].max(initial=0))
         self._ensure_capacity(
             max_rows, int(counts[:, 11].max(initial=0))
         )
+        # the step's shape (plancore.cpp plan_shape): 3 is dense links
+        # into a mirror that held no row
+        whole = counts[:, 14] == 3
+        loads = []
+        if whole.any():
+            rooms = np.flatnonzero(whole)
+
+            def fill_rows(members, pos, right, deleted, starts):
+                pack_row_blocks(
+                    [chunk_ok[j] for j in rooms[members]], pos,
+                    right, deleted, starts, int(NULL),
+                )
+
+            loads = self._stage_row_loads(
+                doc_idx[rooms], counts[rooms][:, [0, 11, 12, 6, 13]],
+                b_loc, n_shards, fill_rows,
+            )
+            if whole.all():
+                return None, None, np.zeros(4, np.int64), max_rows, loads
+            rest = np.flatnonzero(~whole)
+            chunk_ok = [chunk_ok[j] for j in rest]
+            counts, doc_idx = counts[rest], doc_idx[rest]
         oob_r = int(self._cap + 1)
         oob_s = int(self._seg_cap + 1)
         shard = doc_idx // b_loc
         link = counts[:, 12]
-        dense = counts[:, 14].astype(bool)
+        dense = (counts[:, 14] & 1).astype(bool)
 
         def shard_max(values, mask, minimum, shard=shard):
             sums = np.bincount(
@@ -2232,14 +2372,15 @@ class BatchEngine:
             oob_r, oob_s, int(NULL), lane_dtype, out=slot.buf,
         )
         slot.buf = lanes
-        return slot, key, stats, max_rows
+        return slot, key, stats, max_rows, loads
 
     def _pack_chunk_py(self, chunk_ok, b_loc, n_shards):
         """Python-mirror twin of :meth:`_pack_chunk_native`: bin one
         chunk of ``(doc, plan)`` pairs into the same counts-header +
         lanes layout (host-resolved YATA; see DocMirror._list_insert /
         plancore.cpp list_insert), packing into the acquired staging
-        buffer.  Returns ``(slot, key, stats, max_rows)``.
+        buffer; the rooms loaded whole into empty slots go as row blocks
+        there too.  Returns ``(slot, key, stats, max_rows, loads)``.
 
         Per-doc counts ride in the lanes header; doc ids and dense row
         indices are derived ON DEVICE (kernels.apply_plan2), so the
@@ -2252,6 +2393,35 @@ class BatchEngine:
             (self.mirrors[i].n_segs for i, _ in chunk_ok), default=0
         )
         self._ensure_capacity(max_rows, max_segs)
+        is_whole = [p.from_empty and _dense_links(p) for _, p in chunk_ok]
+        whole = [t for t, yes in zip(chunk_ok, is_whole) if yes]
+        loads = []
+        if whole:
+
+            def fill_rows(members, pos, right, deleted, starts):
+                right[pos] = NULL
+                deleted[pos] = False
+                starts[pos] = NULL
+                for j, at in zip(members, pos):
+                    p = whole[j][1]
+                    right[at, : len(p.link_vals)] = p.link_vals
+                    deleted[at, np.asarray(p.delete_rows, np.int64)] = True
+                    starts[at, np.asarray(p.head_segs, np.int64)] = (
+                        p.head_vals
+                    )
+
+            loads = self._stage_row_loads(
+                np.asarray([i for i, _ in whole], np.int64),
+                np.asarray([
+                    (p.n_rows, self.mirrors[i].n_segs, len(p.link_rows),
+                     len(p.delete_rows), len(p.head_segs))
+                    for i, p in whole
+                ], np.int64),
+                b_loc, n_shards, fill_rows,
+            )
+            if len(whole) == len(chunk_ok):
+                return None, None, np.zeros(4, np.int64), max_rows, loads
+            chunk_ok = [t for t, yes in zip(chunk_ok, is_whole) if not yes]
         oob_r = np.int32(self._cap + 1)
         counts = np.zeros((n_shards, 4, b_loc), np.int32)
         dense = [[] for _ in range(n_shards)]
@@ -2265,7 +2435,7 @@ class BatchEngine:
             k = len(p.link_rows)
             rows = np.asarray(p.link_rows, np.int32)
             vals = np.asarray(p.link_vals, np.int32)
-            if k and k == p.n_rows and rows[-1] == k - 1:
+            if _dense_links(p):
                 counts[s, 0, li] = k
                 dense[s].append(vals)
             elif k:
@@ -2324,7 +2494,7 @@ class BatchEngine:
             o += 2 * k_h
             n_dels += fill(lanes[s, o : o + k_d], dl_r[s], oob_r)
         stats = np.asarray([n_dense, n_sparse, n_heads, n_dels], np.int64)
-        return slot, (k_dn, k_sp, k_h, k_d), stats, max_rows
+        return slot, (k_dn, k_sp, k_h, k_d), stats, max_rows, loads
 
     @property
     def last_flush_metrics(self) -> dict | None:
@@ -2336,7 +2506,7 @@ class BatchEngine:
 
     @property
     def last_metrics(self) -> dict | None:
-        """Global psum'd counters from the last sharded flush (syncs)."""
+        """Global psum'd counters of the last sharded dispatch (syncs)."""
         if self._metrics_dev is None:
             return None
         return {k: int(v) for k, v in self._metrics_dev.items()}

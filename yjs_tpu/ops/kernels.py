@@ -4,12 +4,14 @@ The reference integrates one Item at a time into a pointer-chased linked list
 (reference src/structs/Item.js:403-517).  Here YATA runs on the host: the
 planner (``ops/native_mirror.py``, or ``ops/columns.py`` without a compiler)
 resolves every conflict and hands the device the FINAL link values, so a
-flush is one conflict-free scatter over the whole doc batch
-(``apply_plan2``; ``parallel.mesh.sharded_apply_plan`` on a mesh), and a
-compaction, a hydration or a release is one whole-row write
-(``scatter_rows``, ``blank_rows``).  The read side ranks document order from
-the right links (``list_ranks``) and answers state vectors and diffs as
-segment reductions.
+flush chunk is one conflict-free scatter over the whole doc batch
+(``apply_plan2``; ``parallel.mesh.sharded_apply_plan`` on a mesh) for the
+rooms that held rows, one whole-row write a width class for the rooms it
+loads into empty slots (``apply_plan2_rows``;
+``parallel.mesh.sharded_load_rows``), and a compaction, a hydration or a
+release is one whole-row write too (``scatter_rows``, ``blank_rows``).
+The read side ranks document order from the right links (``list_ranks``)
+and answers state vectors and diffs as segment reductions.
 
 All row arrays carry one extra trailing scratch row (index N); its contents
 are never read meaningfully.
@@ -111,6 +113,56 @@ def apply_lanes(dyn, lanes, k_dn, k_sp, k_h, k_d):
     return right_link, deleted, starts
 
 
+def _put_rows(table, idx, block):
+    """``table[idx, :w] = block`` for a block ``w`` wide; columns ``>= w``
+    of those docs are left as they are, and a row aimed past the last doc
+    is dropped (a padded block's spare rows).  A block may arrive
+    narrower than the table's cells (int16): widened here."""
+    return table.at[idx, : block.shape[1]].set(
+        block.astype(table.dtype), mode="drop"
+    )
+
+
+def load_rows(dyn, idx, new_right, new_deleted, new_starts):
+    """The whole-row write as a plain traceable function: the body of
+    ``apply_plan2_rows`` and of ``scatter_rows``, reused by the sharded
+    mesh step (each shard writes its own block locally)."""
+    right_link, deleted, starts = dyn
+    return (
+        _put_rows(right_link, idx, new_right),
+        _put_rows(deleted, idx, new_deleted),
+        _put_rows(starts, idx, new_starts),
+    )
+
+
+@profiled("apply_plan2_rows")
+@functools.partial(jax.jit, donate_argnums=(0,))
+def apply_plan2_rows(dyn, idx, new_right, new_deleted, new_starts):
+    """Bulk apply of rooms loaded whole into empty slots: the row form of
+    ``apply_plan2``.  A room whose plan writes every row it has, into a
+    slot that held none, is one row of a block: its final right links
+    with ``NULL`` behind them, its ``deleted`` row with its tombstones
+    set, its ``starts`` row with its list heads, and the device writes
+    ``table[idx, :w] = block`` for the three tables, where the element
+    lanes would write the same cells one link at a time.
+
+    The invariant this rests on: a slot that holds no row is at fill in
+    every cell (``NULL`` links and heads, ``False`` tombstones): a new
+    engine allocates it so, table growth fills the new columns so, and
+    ``blank_rows`` returns a released slot to it.  So the ``NULL`` and
+    ``False`` a block carries past a room's last row, and in the heads it
+    does not set, are what those cells hold already, and the tables come
+    out bit for bit as the lanes leave them.  It must never be given a
+    room that had rows before the flush: the plan does not name that
+    room's older tombstones (``BatchEngine._stage_row_loads`` reads both
+    facts from the plan).
+
+    ``idx``: the slots, one a block row; a spare row is aimed past the
+    last slot and dropped.  Blocks of rooms no longer than 32767 rows
+    arrive int16 (every link and head is a row of its own room)."""
+    return load_rows(dyn, idx, new_right, new_deleted, new_starts)
+
+
 @profiled("apply_plan_shared")
 @functools.partial(jax.jit, static_argnums=(2, 3, 4), donate_argnums=(0,))
 def apply_plan_shared(dyn, lanes, k_l, k_h, k_d):
@@ -161,15 +213,8 @@ def scatter_rows(right, deleted, starts, idx, new_right, new_deleted,
     in place instead of materializing a second B x cap copy per array —
     the same donation contract as the flush dispatch kernels (ISSUE 12)."""
 
-    def put(table, block):
-        # a row aimed past the last doc is dropped (a padded block's
-        # spare rows, BatchEngine._scatter_rebuilt)
-        return table.at[idx, : block.shape[1]].set(block, mode="drop")
-
-    return (
-        put(right, new_right),
-        put(deleted, new_deleted),
-        put(starts, new_starts),
+    return load_rows(
+        (right, deleted, starts), idx, new_right, new_deleted, new_starts
     )
 
 

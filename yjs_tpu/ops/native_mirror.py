@@ -110,6 +110,8 @@ class NativePlan:
         # overwritten by the mirror's next prepare
         self._mirror = mirror
         self._seq = int(counts[15])
+        # the mirror held no row before this step (plancore.cpp plan_shape)
+        self.from_empty = bool(int(counts[14]) & 2)
         self._n_sched = n_sched
         # hot-path sections fetched eagerly (the bulk apply + split count)
         self.splits = np.empty((n_splits, 2), np.int64)
@@ -780,8 +782,9 @@ def prepare_many(work, want_sched: bool = True, obs=None):
 
     ``work`` is a list of ``(doc_idx, NativeMirror)``.  Returns
     ``(counts, rcs, staged_info, pool_times)`` where ``counts`` is an
-    ``(n, 16)`` int64 array (ymx_prepare layout + ``[14]`` = dense-link
-    flag; ``[15]`` numbers the plan, see ``NativeMirror._plan_seq``),
+    ``(n, 16)`` int64 array (ymx_prepare layout: ``[14]`` is the step's
+    shape, bit 0 dense links, bit 1 the mirror held no row before it;
+    ``[15]`` numbers the plan, see ``NativeMirror._plan_seq``),
     ``rcs`` the per-doc return codes, ``staged_info`` the per-doc
     ``(staged, ids)`` needed by ``_finish_prepare``, and ``pool_times``
     the call's own clock and counts, a dict of seconds under
@@ -1044,3 +1047,38 @@ def pack_apply_lanes(work, doc_ids, b_loc, n_shards, widths, oob_r, oob_s,
         lanes.ctypes.data_as(ctypes.c_void_p), _p64(stats),
     )
     return lanes, stats
+
+
+def pack_row_blocks(work, pos, right, deleted, starts, null_val):
+    """Fill the rows ``pos`` of one staged block natively from ``work``
+    (post-prepare ``(doc_idx, NativeMirror, ...)`` entries, each a room
+    loaded whole into an empty slot): a room's dense links with
+    ``null_val`` behind them, its tombstones, its list heads among
+    ``null_val`` — every cell of its row.  ``right`` and ``starts`` are
+    int16 or int32 alike, ``deleted`` bool, all C-contiguous; the native
+    twin of ``BatchEngine._pack_chunk_py``'s ``fill_rows``."""
+    n = len(work)
+    lib = work[0][1]._lib
+    handles = (ctypes.c_void_p * n)()
+    for k, (_i, m, *_rest) in enumerate(work):
+        handles[k] = m._h
+    w, ws = right.shape[1], starts.shape[1]
+    if not (
+        right.dtype == starts.dtype and deleted.shape == right.shape
+        and deleted.dtype == np.bool_ and starts.shape[0] == right.shape[0]
+        and all(a.flags.c_contiguous for a in (right, deleted, starts))
+        and 0 <= int(pos.min()) and int(pos.max()) < right.shape[0]
+    ):
+        raise ValueError("pack_row_blocks: the staged block is misshapen")
+    fn = lib.ymx_pack_rows16 if right.dtype == np.int16 else lib.ymx_pack_rows
+    outside = fn(
+        handles, _p64(np.ascontiguousarray(pos, np.int64)), n, w, ws,
+        ctypes.c_int32(null_val),
+        right.ctypes.data_as(ctypes.c_void_p),
+        deleted.ctypes.data_as(ctypes.c_void_p),
+        starts.ctypes.data_as(ctypes.c_void_p),
+    )
+    if outside:
+        raise RuntimeError(
+            f"pack_row_blocks: {outside} writes outside a {w} x {ws} row"
+        )

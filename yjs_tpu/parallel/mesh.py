@@ -134,6 +134,39 @@ def sharded_apply_plan(mesh: Mesh, axis: str, k_dn: int, k_sp: int,
     )
 
 
+def sharded_load_rows(mesh: Mesh, axis: str):
+    """The row form of the bulk apply (``kernels.apply_plan2_rows``)
+    sharded over the doc axis: each shard writes its own
+    ``[rows_loc, w]`` block into its dyn shard locally, nothing
+    replicated, nothing gathered.
+
+    idx: [n_shards * rows_loc] slots local to their shard (a spare row:
+    the shard's doc count, dropped); the blocks [n_shards * rows_loc, w];
+    sums: [n_shards, 3] the links, tombstones and heads each shard's
+    block holds, the first two psum'd into the progress counters as the
+    lanes' counts are.
+    All sharded on axis 0; one jitted function for every block shape."""
+    spec = P(axis)
+
+    def local_apply_rows(dyn, idx, new_right, new_deleted, new_starts, sums):
+        out = kernels.load_rows(dyn, idx, new_right, new_deleted, new_starts)
+        metrics = {
+            "integrated": lax.psum(sums[0, 0], axis),
+            "deleted": lax.psum(sums[0, 1], axis),
+        }
+        return out, metrics
+
+    sharded = shard_map(
+        local_apply_rows,
+        mesh=mesh,
+        in_specs=((spec, spec, spec), spec, spec, spec, spec, spec),
+        out_specs=((spec, spec, spec), P()),
+    )
+    return profiled("sharded_load_rows")(
+        jax.jit(sharded, donate_argnums=(0,))
+    )
+
+
 def sharded_state_vectors(mesh: Mesh, n_slots: int, axis: str = "docs", row_axis: str | None = None):
     """State vectors over a sharded doc batch; with a 2-D mesh the item-table
     axis is also sharded and reduced with pmax over ICI (the segment-max of
