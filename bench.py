@@ -19,7 +19,7 @@ host transcode, distinct-vs-broadcast, B4 scale):
    pre-split, pack, dispatch).  No broadcast amortization — this is the
    honest per-doc host cost, and it is host-bound (see detail timers).
 3. **sync**: batched sync-step-2 (encodeStateAsUpdate against a remote
-   state vector) across all distinct docs in one diff_mask_kernel dispatch.
+   state vector) across all distinct docs in one batched native call.
 
 Baseline: the repo's own single-threaded CPU reference core measures
 `cpu_py_*` on the same traces.  Node.js is NOT available in this image, so
@@ -37,6 +37,7 @@ default 1500), YTPU_NODE_PROXY_FACTOR (default 20).
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -613,37 +614,28 @@ def bench_planner(
     return res
 
 
-def _seg_lane_env(mode: str | None):
-    """Set/restore YTPU_PLAN_SEGMENT + disable the plan cache for an A/B
-    lane; returns the previous values for the finally block."""
-    prev = (
-        os.environ.get("YTPU_PLAN_SEGMENT"),
-        os.environ.get("YTPU_PLAN_CACHE"),
-    )
-    if mode is None:
-        os.environ.pop("YTPU_PLAN_SEGMENT", None)
-    else:
-        os.environ["YTPU_PLAN_SEGMENT"] = mode
-    return prev
-
-
-def _seg_lane_restore(prev):
-    for key, val in zip(("YTPU_PLAN_SEGMENT", "YTPU_PLAN_CACHE"), prev):
-        if val is None:
-            os.environ.pop(key, None)
+@contextlib.contextmanager
+def _plan_cache_env(cache_on: bool):
+    """YTPU_PLAN_CACHE set for one run, and put back after it."""
+    prev = os.environ.get("YTPU_PLAN_CACHE")
+    os.environ["YTPU_PLAN_CACHE"] = "1" if cache_on else "0"
+    try:
+        yield
+    finally:
+        if prev is None:
+            os.environ.pop("YTPU_PLAN_CACHE", None)
         else:
-            os.environ[key] = val
+            os.environ["YTPU_PLAN_CACHE"] = prev
 
 
 def bench_planner_cold_unique(n_docs: int = 1024, n_ops: int = 1500) -> dict:
     """Cold-unique-frontier lane (ISSUE 15): 1024 DISTINCT traces with
     the plan cache disabled — the frontier-keyed cache cannot hit by
-    construction, so the device-authoritative cold planner is the only
-    accelerator.  Records ``cold_device_ms_per_doc`` (plan phase, cache
-    off) and ``fastpath_residue_fraction`` (residue share of segment-
+    construction, so every room is planned cold.  Records
+    ``cold_device_ms_per_doc`` (plan phase, cache off) and
+    ``fastpath_residue_fraction`` (residue share of segment-
     partitioned structs), plus a cache-warm per-doc rate for the
-    acceptance ratio and the ``YTPU_PLAN_SEGMENT=off`` A/B byte-identity
-    verdict."""
+    acceptance ratio."""
     import gc
 
     from yjs_tpu.ops import BatchEngine
@@ -651,10 +643,8 @@ def bench_planner_cold_unique(n_docs: int = 1024, n_ops: int = 1500) -> dict:
 
     updates = load_distinct_traces(n_docs, n_ops)
 
-    def one_run(mode, cache_on, prewarm=False):
-        prev = _seg_lane_env(mode)
-        os.environ["YTPU_PLAN_CACHE"] = "1" if cache_on else "0"
-        try:
+    def one_run(cache_on, prewarm=False):
+        with _plan_cache_env(cache_on):
             plan_cache.reset_cache()
             # two passes: a key is snapshotted at its second sighting
             for _ in range(2 if prewarm else 0):
@@ -675,19 +665,15 @@ def bench_planner_cold_unique(n_docs: int = 1024, n_ops: int = 1500) -> dict:
             np.asarray(eng._right[:, 0])
             dt = time.perf_counter() - t0
             m = dict(eng.last_flush_metrics or {})
-            states = [eng.encode_state_as_update(i) for i in range(n_docs)]
             del eng
             gc.collect()
             if not cache_on:
                 plan_cache.reset_cache()
-            return dt, m, states
-        finally:
-            _seg_lane_restore(prev)
+            return dt, m
 
-    one_run("device", cache_on=False)  # warmup/compile
-    dt_dev, m_dev, s_dev = one_run("device", cache_on=False)
-    _dt_off, m_off, s_off = one_run("off", cache_on=False)
-    dt_warm, m_warm, _ = one_run("device", cache_on=True, prewarm=True)
+    one_run(cache_on=False)  # warmup/compile
+    dt_dev, m_dev = one_run(cache_on=False)
+    dt_warm, m_warm = one_run(cache_on=True, prewarm=True)
     seg_f = m_dev.get("plan_segment_fast", 0)
     seg_r = m_dev.get("plan_segment_residue", 0)
     cold_ms = m_dev.get("t_plan_s", 0.0) / n_docs * 1e3
@@ -698,9 +684,6 @@ def bench_planner_cold_unique(n_docs: int = 1024, n_ops: int = 1500) -> dict:
         "cold_unique_n_docs": n_docs,
         "cold_unique_trace_ops": n_ops,
         "cold_device_ms_per_doc": round(cold_ms, 3),
-        "cold_walk_ms_per_doc": round(
-            m_off.get("t_plan_s", 0.0) / n_docs * 1e3, 3
-        ),
         "cold_e2e_ms_per_doc": round(cold_e2e, 3),
         "warm_e2e_ms_per_doc": round(warm_e2e, 3),
         "warm_cache_plan_ms_per_doc": round(warm_ms, 3),
@@ -712,7 +695,6 @@ def bench_planner_cold_unique(n_docs: int = 1024, n_ops: int = 1500) -> dict:
         ),
         "plan_segment_fast": seg_f,
         "plan_segment_residue": seg_r,
-        "off_lane_byte_identical": s_dev == s_off,
     }
 
 
@@ -722,8 +704,8 @@ def bench_planner_prepend(n_docs: int = 64, n_chars: int = 100000) -> dict:
     The monotone chain must plan without re-sorting the whole anchor
     column per flush — r5's `bench_fragmented` (default env: plan cache
     ON, 64 identical docs) measured 37.281 ms/doc; the acceptance bar
-    is a >=3x drop under the SAME conditions, with harsher cache-off
-    lanes alongside and the ``off`` planner lane byte-identical."""
+    is a >=3x drop under the SAME conditions, with the harsher
+    cache-off lane alongside."""
     import gc
 
     from yjs_tpu.ops import BatchEngine
@@ -731,10 +713,8 @@ def bench_planner_prepend(n_docs: int = 64, n_chars: int = 100000) -> dict:
 
     update = load_prepend_fixture(n_chars)
 
-    def one_run(mode, cache_on=False):
-        prev = _seg_lane_env(mode)
-        os.environ["YTPU_PLAN_CACHE"] = "1" if cache_on else "0"
-        try:
+    def one_run(cache_on=False):
+        with _plan_cache_env(cache_on):
             plan_cache.reset_cache()
             gc.collect()
             time.sleep(2)  # let prior lane's buffer deletes drain
@@ -746,20 +726,15 @@ def bench_planner_prepend(n_docs: int = 64, n_chars: int = 100000) -> dict:
             np.asarray(eng._right[:, 0])
             dt = time.perf_counter() - t0
             m = dict(eng.last_flush_metrics or {})
-            state = eng.encode_state_as_update(0)
             del eng
             gc.collect()
             plan_cache.reset_cache()
-            return dt, m, state
-        finally:
-            _seg_lane_restore(prev)
+            return dt, m
 
-    _ = one_run("device")  # warmup/compile
-    dt_dev, m_dev, s_dev = one_run("device")
-    _dt_off, m_off, s_off = one_run("off")
-    _dt_r5, m_r5, _ = one_run("device", cache_on=True)  # r5-parity lane
+    _ = one_run()  # warmup/compile
+    _dt_dev, m_dev = one_run()
+    _dt_r5, m_r5 = one_run(cache_on=True)  # r5-parity lane
     dev_ms = m_dev.get("t_plan_s", 0.0) / n_docs * 1e3
-    off_ms = m_off.get("t_plan_s", 0.0) / n_docs * 1e3
     r5p_ms = m_r5.get("t_plan_s", 0.0) / n_docs * 1e3
     return {
         "prepend_n_docs": n_docs,
@@ -770,13 +745,8 @@ def bench_planner_prepend(n_docs: int = 64, n_chars: int = 100000) -> dict:
         "prepend_planner_ms_per_doc": round(r5p_ms, 3),
         "prepend_r5_baseline_ms_per_doc": 37.281,
         "prepend_speedup_vs_r5": round(37.281 / max(1e-9, r5p_ms), 2),
-        # harsher cache-off lanes: every doc plans cold
+        # harsher cache-off lane: every doc plans cold
         "prepend_cold_ms_per_doc": round(dev_ms, 3),
-        "prepend_cold_walk_ms_per_doc": round(off_ms, 3),
-        "prepend_cold_speedup_vs_walk": round(
-            off_ms / max(1e-9, dev_ms), 2
-        ),
-        "prepend_off_lane_byte_identical": s_dev == s_off,
     }
 
 
@@ -920,8 +890,8 @@ def bench_flush(
 
 def bench_sync(eng, n_docs: int) -> dict:
     # every doc answers a fresh peer (empty SV -> full-state diff): one
-    # diff_mask_kernel dispatch + per-doc native wire encode.  First call
-    # warms the kernel compile; median of 3 windows (single windows read
+    # native call a slice of 256 requests.  First call
+    # warms up; median of 3 windows (single windows read
     # low while the distinct loop's freed engines are still draining).
     requests = [(i, {}) for i in range(n_docs)]
     eng.sync_step2_batch(requests)

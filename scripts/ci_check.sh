@@ -95,17 +95,6 @@ echo "== planner smoke (marker: planner) =="
 # surface fast and isolated
 python -m pytest tests/ -q -m 'planner and not slow' -p no:cacheprovider
 
-echo "== planner oracle corpus under np and jax backends (ISSUE 15) =="
-# the device-authoritative cold planner defaults to the fused "device"
-# lane; rerun the seeded oracle corpus with each fallback backend pinned
-# so a kernels-only or numpy-only regression can't hide behind the
-# default — the corpus asserts device-planned ranks == sequential YATA
-# walk ranks struct-for-struct, byte-identical states included
-YTPU_PLAN_SEGMENT=np python -m pytest tests/test_segment_planner.py -q \
-    -m 'not slow' -p no:cacheprovider
-YTPU_PLAN_SEGMENT=jax python -m pytest tests/test_segment_planner.py -q \
-    -m 'not slow' -p no:cacheprovider
-
 echo "== failover smoke (marker: failover) =="
 # the replication + failure-detection suite (ISSUE 8) is the newest
 # subsystem: fan-out, detector, promotion, and fencing regressions

@@ -655,18 +655,16 @@ def test_sync_step2_batch_is_one_native_call_and_counts_fallbacks(monkeypatch):
     )
 
 
-@pytest.mark.parametrize("how", ["v2", "device", "python-mirror"])
+@pytest.mark.parametrize("how", ["v2", "python-mirror"])
 def test_other_paths_answer_as_before_and_count_as_fallbacks(monkeypatch, how):
-    """``v2=True``, ``YTPU_SYNC_DEVICE=1`` and Python-mirror engines never
-    reach the batched call; their answers are the single-request path's."""
+    """``v2=True`` and Python-mirror engines never reach the batched
+    call; their answers are the single-request path's."""
     if how == "python-mirror":
         monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
     eng, requests = _handshake_engine()
     if how == "python-mirror":
         assert not any(isinstance(m, NativeMirror) for m in eng.mirrors)
     calls = _count_native_calls(monkeypatch)
-    if how == "device":
-        monkeypatch.setenv("YTPU_SYNC_DEVICE", "1")
     v2 = how == "v2"
     replies = eng.sync_step2_batch(requests, v2=v2)
     m = eng.last_sync_metrics
@@ -674,6 +672,59 @@ def test_other_paths_answer_as_before_and_count_as_fallbacks(monkeypatch, how):
     assert (m["encode_batched"], m["encode_fallback"]) == (0, len(requests))
     for (i, sv), u in zip(requests, replies):
         assert u == eng.encode_state_as_update(i, _sv_bytes(sv), v2=v2)
+
+
+def test_room_both_native_encoders_decline_is_answered_from_the_host_mask(
+    monkeypatch,
+):
+    """A room fed V2-framed formats asked for V1 answers: the batched
+    call and the room's own ``encode_diff_update`` both decline every
+    answer that selects a row, and the mirror's host mask over its
+    columns gives it (no device program is dispatched for it): a
+    session at that state vector that applies the answer holds what the
+    CPU core holds."""
+    from yjs_tpu.obs.prof import kernel_profiler
+    from yjs_tpu.ops.columns import DocMirror
+
+    ups = session("nested", 2, 40, v2=True)
+    core = Y.Doc(gc=False)
+    stale = Y.Doc(gc=False)  # a session that left half way
+    for j, u in enumerate(ups):
+        Y.apply_update_v2(core, u)
+        if j < len(ups) // 2:
+            Y.apply_update_v2(stale, u)
+    eng = BatchEngine(2)
+    for u in ups:
+        eng.queue_update(0, u, v2=True)
+    eng.flush()
+    masks = []
+    real = DocMirror._diff_mask
+
+    def spy(self, remote_sv):
+        masks.append(dict(remote_sv))
+        return real(self, remote_sv)
+
+    monkeypatch.setattr(DocMirror, "_diff_mask", spy)
+    calls = _count_native_calls(monkeypatch)
+    stale_sv = Y.decode_state_vector(Y.encode_state_vector(stale))
+    requests = [(0, None), (0, stale_sv), (0, eng.state_vector(0))]
+    programs = kernel_profiler().snapshot()
+    replies = eng.sync_step2_batch(requests)
+    assert kernel_profiler().snapshot() == programs
+    m = eng.last_sync_metrics
+    # the reload and the stale session select rows: declined twice, then
+    # masked on the host; the current session is owed the delete set alone
+    assert calls == [3]
+    assert masks == [{}, stale_sv]
+    assert (m["encode_batched"], m["encode_fallback"]) == (1, 2)
+    assert m["encode_buffer_bytes"] >= len(replies[2])
+    for (_i, sv), u, peer in zip(requests, replies, (Y.Doc(gc=False), stale)):
+        assert eng.mirrors[0].encode_diff_update(sv) is None
+        Y.apply_update(peer, u)
+        assert Y.encode_state_as_update(peer) == Y.encode_state_as_update(core)
+        assert peer.get_text("text").to_delta() == (
+            core.get_text("text").to_delta()
+        )
 
 
 def test_encode_states_batched_over_more_rooms_than_a_slice(monkeypatch):

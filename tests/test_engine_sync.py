@@ -119,7 +119,7 @@ class TestMirrorEmission:
 
 
 class TestBatchedSyncKernels:
-    """Sync step 1 + 2 across many docs in single kernel dispatches
+    """Sync step 1 + 2 across many docs, from the host mirrors
     (reference encoding.js:490-526,94-116 batched)."""
 
     def _make_engine(self, n):
@@ -139,11 +139,14 @@ class TestBatchedSyncKernels:
         eng.flush()
         return docs, eng
 
-    def test_state_vectors_batched_matches_per_doc(self):
+    def test_state_vectors_match_cpu_core(self):
+        import yjs_tpu as Y
+
         docs, eng = self._make_engine(6)
-        svs = eng.state_vectors_batched(list(range(6)))
         for i in range(6):
-            assert svs[i] == eng.state_vector(i)
+            want = Y.decode_state_vector(Y.encode_state_vector(docs[i]))
+            assert eng.state_vector(i) == want
+            assert Y.decode_state_vector(eng.encode_state_vector(i)) == want
 
     def test_sync_step2_batch_matches_per_doc_and_cpu(self):
         import yjs_tpu as Y

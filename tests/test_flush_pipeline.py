@@ -155,11 +155,10 @@ def test_schema_complete_on_every_path(planner, pipeline, monkeypatch):
         assert m["pipeline_depth"] == 0
 
 
-def _distinct_doc_engine(n_docs, monkeypatch, mode="device"):
+def _distinct_doc_engine(n_docs, monkeypatch):
     """One engine whose docs each carry a DISTINCT trace (no cache
     dedup), flushed once cold — the fan-out shape plan_threads must
     report (ISSUE 15 satellite: it used to report 1 on batched paths)."""
-    monkeypatch.setenv("YTPU_PLAN_SEGMENT", mode)
     monkeypatch.setenv("YTPU_PLAN_CACHE", "0")
     eng = BatchEngine(n_docs)
     for i in range(n_docs):
@@ -169,16 +168,15 @@ def _distinct_doc_engine(n_docs, monkeypatch, mode="device"):
     return eng.last_flush_metrics
 
 
-def test_plan_threads_reports_py_chunk_fanout(monkeypatch):
-    """Python path, device mode: the whole-chunk segment planner
-    co-plans every cold doc in one call — plan_threads reports that
-    fan-out, not 1."""
+def test_plan_threads_is_one_on_the_python_lane(monkeypatch):
+    """The Python planner plans a room at a time on the flushing
+    thread, whatever width the native pool is allowed: plan_threads
+    reads 1 and the pool's counters 0."""
     monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+    monkeypatch.setenv("YTPU_PLAN_THREADS", "3")
     m = _distinct_doc_engine(4, monkeypatch)
-    assert m["plan_threads"] == 4
-    # the off lane plans per doc, serially
-    m_off = _distinct_doc_engine(4, monkeypatch, mode="off")
-    assert m_off["plan_threads"] == 1
+    assert (m["plan_threads"], m["plan_pool_woken"]) == (1, 0)
+    assert m["n_docs_flushed"] == 4
 
 
 def test_plan_threads_reports_native_pool_width(monkeypatch):

@@ -247,21 +247,34 @@ def test_compaction_parity(rng):
 
 
 @pytest.mark.parametrize("flush_every", [1, 7])
-@pytest.mark.parametrize("session", ["plain", "rich", "astral"])
+@pytest.mark.parametrize(
+    "session",
+    ["plain", "rich", "astral", "prepend_storm", "interleaved", "storm",
+     "b4_head"],
+)
 def test_device_tables_equal_across_planners(
     rng, monkeypatch, session, flush_every
 ):
     """The one device write path under both planners: the resident tables
     (``_right``, ``_deleted``, ``_starts``) the native and the Python
     planner leave are equal, equal the planner's own host mirror, and the
-    text, map and list read back equal the CPU core's (``core.py``)."""
+    text, map and list read back equal the CPU core's (``core.py``).  The
+    last four sessions are the segment pass's shapes (chained runs,
+    conflict storms: ``test_segment_planner.corpus``), the traffic both
+    planners' fast sets are built for."""
     import numpy as np
 
     from yjs_tpu.ops import BatchEngine
 
-    updates, a, _ = two_client_session(
-        rng, 50, rich=session == "rich", astral=session == "astral"
-    )
+    if session in ("plain", "rich", "astral"):
+        updates, a, _ = two_client_session(
+            rng, 50, rich=session == "rich", astral=session == "astral"
+        )
+    else:
+        from test_segment_planner import core_doc, corpus
+
+        updates = corpus(session, seed=71)
+        a = core_doc(updates)
     states = {}
     for planner in ("native", "python"):
         if planner == "python":

@@ -8,7 +8,7 @@ import jax
 
 import yjs_tpu as Y
 from yjs_tpu.ops import BatchEngine
-from yjs_tpu.parallel import doc_mesh, sharded_state_vectors
+from yjs_tpu.parallel import doc_mesh
 
 
 @pytest.fixture(scope="module")
@@ -70,23 +70,26 @@ def test_sharded_incremental_concurrent(mesh8):
         assert eng.text(i) == d.get_text("text").to_string()
 
 
-def test_engine_batched_svs_use_sharded_kernel(mesh8):
-    # state_vectors_batched on a meshed engine routes through
-    # sharded_state_vectors (padding the doc subset to the mesh axis)
+def test_meshed_engine_state_vectors_are_the_host_mirrors(mesh8):
+    # a meshed engine answers state vectors as an unmeshed one does: from
+    # the host mirrors, equal to the CPU core's, with no device program
     n = 8
     docs = build_docs(n)
     eng = BatchEngine(n, mesh=mesh8)
     for i, d in enumerate(docs):
         eng.queue_update(i, Y.encode_state_as_update(d))
     eng.flush()
-    subset = [0, 3, 5]  # not a multiple of the axis size: exercises padding
-    svs = eng.state_vectors_batched(subset)
-    for j, i in enumerate(subset):
-        assert svs[j] == {
+    from yjs_tpu.obs.prof import kernel_profiler
+
+    before = kernel_profiler().snapshot()
+    for i in (0, 3, 5):
+        assert eng.state_vector(i) == {
             c: v for c, v in Y.get_state_vector(docs[i].store).items() if v > 0
         }
-    # the sharded shard_map kernel actually served the request
-    assert eng._sharded_sv
+        assert Y.decode_state_vector(eng.encode_state_vector(i)) == (
+            eng.state_vector(i)
+        )
+    assert kernel_profiler().snapshot() == before
 
 
 def test_meshed_tables_equal_unmeshed(mesh8):
@@ -161,26 +164,11 @@ def test_meshed_engine_arrays_stay_on_mesh(mesh8):
         eng.queue_update(i, Y.encode_state_as_update(d, sv))
     eng.flush()
     check_all()
-    # sync kernels on a meshed engine must also stay on-mesh
-    eng.state_vectors_batched(list(range(n)))
+    # a batch of handshakes on a meshed engine leaves the tables placed
     eng.sync_step2_batch([(i, None) for i in range(n)])
     check_all()
     for i, d in enumerate(docs):
         assert eng.text(i) == d.get_text("text").to_string()
-
-
-def test_sharded_state_vector_kernel(mesh8):
-    b, n, slots = 8, 16, 4
-    rng = np.random.RandomState(0)
-    row_slot = rng.randint(-1, slots, size=(b, n)).astype(np.int32)
-    row_end = rng.randint(1, 100, size=(b, n)).astype(np.int32)
-    sv_fn = sharded_state_vectors(mesh8, slots)
-    sv = np.asarray(sv_fn(row_slot, row_end))
-    for bi in range(b):
-        for s in range(slots):
-            mask = row_slot[bi] == s
-            expect = row_end[bi][mask].max() if mask.any() else 0
-            assert sv[bi, s] == expect
 
 
 def test_meshed_provider_full_surface(mesh8):

@@ -133,13 +133,12 @@ def test_cache_on_off_byte_identical(shape, monkeypatch):
 
 def use_planner(native, monkeypatch):
     """Pin the planner a test runs on: the native core, or the Python
-    mirror planning doc by doc (no whole-chunk grouping of its own)."""
+    mirror planning doc by doc."""
     if native:
         if not native_plan_available():
             pytest.skip("native plan core unavailable")
     else:
         monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
-        monkeypatch.setenv("YTPU_PLAN_SEGMENT", "np")
 
 
 PLANNERS = pytest.mark.parametrize(
@@ -336,11 +335,13 @@ def test_failover_promotion_byte_identical(tmp_path, monkeypatch):
     assert fleet.text("room-0") == d.get_text("text").to_string()
 
 
-# -- segment-sorted planning kernels ------------------------------------------
+# -- the Python planner's segment pass ----------------------------------------
 
 
 def test_anchor_lookup_np_matches_jax_and_bruteforce(rng):
-    from yjs_tpu.ops import kernels
+    """The composed-key searchsorted against a brute-force scan of each
+    slot's run."""
+    from yjs_tpu.ops import segment_planner
 
     n_slots, per_slot, n_q = 5, 40, 64
     flat_slot = np.repeat(np.arange(n_slots), per_slot)
@@ -357,25 +358,29 @@ def test_anchor_lookup_np_matches_jax_and_bruteforce(rng):
     q_clock = np.asarray(
         [rng.randrange(1100) for _ in range(n_q)], np.int64
     )
-    got_np = kernels.plan_anchor_lookup(
-        flat_slot, starts, q_slot, q_clock, backend="np"
+    got = segment_planner.plan_anchor_lookup(
+        flat_slot, starts, q_slot, q_clock
     )
-    got_jax = kernels.plan_anchor_lookup(
-        flat_slot, starts, q_slot, q_clock, backend="jax"
-    )
-    assert (np.asarray(got_np) == np.asarray(got_jax)).all()
     key = flat_slot * 2000 + starts  # clocks < 1100 < 2000: no overlap
     for i in range(n_q):
         if q_slot[i] < 0:
-            assert got_np[i] == -1
+            assert got[i] == -1
             continue
         qk = q_slot[i] * 2000 + q_clock[i]
         expect = int(np.searchsorted(key, qk, side="right")) - 1
-        assert got_np[i] == expect
+        assert got[i] == expect
+        # brute force: the last fragment of the flat index that sorts at
+        # or before (slot, clock)
+        brute = -1
+        for f in range(len(flat_slot)):
+            if (flat_slot[f], starts[f]) <= (q_slot[i], q_clock[i]):
+                brute = f
+        assert got[i] == brute
 
 
 def test_conflict_scan_np_matches_jax(rng):
-    from yjs_tpu.ops import kernels
+    """The vectorized chain masks against a plain loop over the batch."""
+    from yjs_tpu.ops import segment_planner
 
     n = 96
     client = np.asarray([rng.randrange(3) for _ in range(n)], np.int64)
@@ -386,26 +391,42 @@ def test_conflict_scan_np_matches_jax(rng):
     # degrade a third of the chain links to foreign origins
     for i in range(0, n, 3):
         o_cl[i] = -1
+    # and give a third a rightOrigin inside the ref before
     r_cl = np.full(n, -1, np.int64)
     r_ck = np.zeros(n, np.int64)
-    a = kernels.plan_conflict_scan(
-        client, clock, length, o_cl, o_ck, r_cl, r_ck, backend="np"
+    for i in range(1, n, 3):
+        r_cl[i] = client[i - 1]
+        r_ck[i] = clock[i - 1] + rng.randrange(0, 4)
+    left, right, run_id = segment_planner.plan_conflict_scan(
+        client, clock, length, o_cl, o_ck, r_cl, r_ck
     )
-    b = kernels.plan_conflict_scan(
-        client, clock, length, o_cl, o_ck, r_cl, r_ck, backend="jax"
-    )
-    for x, y in zip(a, b):
-        assert (np.asarray(x) == np.asarray(y)).all()
+
+    def inside(cl, ck, j):  # (cl, ck) lies in ref j's id range
+        return (
+            cl >= 0 and cl == client[j]
+            and clock[j] <= ck < clock[j] + length[j]
+        )
+
+    runs = 0
+    for j in range(n):
+        want_l = j > 0 and inside(o_cl[j], o_ck[j], j - 1)
+        want_r = j > 0 and inside(r_cl[j], r_ck[j], j - 1)
+        assert (left[j], right[j]) == (want_l, want_r), j
+        runs += not (want_l or want_r)
+        assert run_id[j] == runs, j
+    assert left.any() and right.any()
 
 
 @pytest.mark.parametrize("shape", ["prepend", "interleaved", "storm"])
 def test_segment_hints_do_not_change_plans(shape, monkeypatch):
-    """The segment fast path is a pure accelerator: hints on vs off must
-    yield identical plans and identical mirror state."""
+    """The segment fast path is a pure accelerator: with it and without
+    (the walk alone, steered from the test) the plans and the mirror
+    state are identical, and the room reads as the CPU core's."""
+    from yjs_tpu.ops import segment_planner
+
     updates = make_trace(shape, seed=5, n_ops=80)
 
-    def drive(segment):
-        monkeypatch.setenv("YTPU_PLAN_SEGMENT", segment)
+    def drive():
         m = DocMirror("text")
         plans = []
         for j, u in enumerate(updates):
@@ -418,15 +439,26 @@ def test_segment_hints_do_not_change_plans(shape, monkeypatch):
                 )
         return plans, m.encode_state_as_update(), m.plan_frontier
 
-    p_on, s_on, f_on = drive("np")
-    p_off, s_off, f_off = drive("off")
+    p_on, s_on, f_on = drive()
+    with monkeypatch.context() as mp:
+        mp.setattr(
+            segment_planner, "plan_doc", lambda q, snapshot=None: None
+        )
+        p_off, s_off, f_off = drive()
     assert p_on == p_off
     assert s_on == s_off
     assert f_on == f_off
+    got, want = Y.Doc(gc=False), Y.Doc(gc=False)
+    apply_update(got, s_on)
+    for u in updates:
+        apply_update(want, u)
+    assert (
+        got.get_text("text").to_string()
+        == want.get_text("text").to_string()
+    )
 
 
-def test_fastpath_structs_counted(monkeypatch):
-    monkeypatch.setenv("YTPU_PLAN_SEGMENT", "np")
+def test_fastpath_structs_counted():
     updates = make_trace("prepend", seed=9, n_ops=60)
     m = DocMirror("text")
     for u in updates:

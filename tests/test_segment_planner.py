@@ -1,15 +1,15 @@
-"""Device-authoritative cold planning suite (ISSUE 15).
+"""The planners' segment pass (ISSUE 15): fast set and conflict residue.
 
 The correctness bar: under every seeded corpus shape (prepend-storm,
-interleaved, 4-client conflict storm, B4-texture trace head) the
-device-planned integration must equal the sequential YATA walk
-**struct-for-struct** — identical sched/link/head/delete plans — and
-the engine must converge byte-identically `YTPU_PLAN_SEGMENT=device`
-vs `off` on both native and pure-Python mirrors, including across
-demotion→promotion and kill-primary failover.  Plus the ISSUE 15
-satellite pins: snapshot reuse on monotone prepend runs (the
-`plan_snapshot` host op must stay cold) and the fast-set/residue
-metrics accounting.
+interleaved, 4-client conflict storm, B4-texture trace head) a plan made
+with the segment pass must equal the sequential YATA walk
+**struct-for-struct** — identical sched/link/head/delete plans — the
+native core's plans must equal the Python planner's, and the engine must
+converge on what the CPU core (``yjs_tpu/core.py``) holds on both native
+and pure-Python mirrors, including across demotion→promotion and
+kill-primary failover.  Plus the ISSUE 15 satellite pins: snapshot reuse
+on monotone prepend runs (the `plan_snapshot` host op must stay cold)
+and the fast-set/residue metrics accounting.
 """
 
 import random
@@ -92,72 +92,97 @@ def corpus(shape: str, seed: int, n_ops: int = 90) -> list[bytes]:
     return out
 
 
-# -- oracle: device-planned ranks == sequential YATA walk ---------------------
+def core_doc(updates) -> Y.Doc:
+    """The CPU core fed the same updates: the oracle that planned
+    nothing."""
+    d = Y.Doc(gc=False)
+    for u in updates:
+        apply_update(d, u)
+    return d
+
+
+def canonical(update: bytes) -> bytes:
+    return Y.merge_updates([update])
+
+
+# -- oracle: plans with the segment pass == sequential YATA walk --------------
 
 
 def plan_tuple(p):
     return (
-        p.sched, p.splits, p.link_rows, p.link_vals,
-        p.head_segs, p.head_vals, sorted(p.delete_rows),
+        [tuple(int(x) for x in e) for e in p.sched],
+        [tuple(int(x) for x in e) for e in p.splits],
+        [int(x) for x in p.link_rows], [int(x) for x in p.link_vals],
+        [int(x) for x in p.head_segs], [int(x) for x in p.head_vals],
+        sorted(int(r) for r in p.delete_rows),
     )
+
+
+def drive_mirror(m, updates, every=6):
+    """``(plans, state, frontier)`` and the structs the Python planner's
+    fast set placed (0 for the native core, which counts its own)."""
+    plans, fast = [], 0
+    for j, u in enumerate(updates):
+        m.ingest(u, False)
+        if (j + 1) % every == 0 or j == len(updates) - 1:
+            p = m.prepare_step()
+            fast += getattr(p, "segment_fast", 0)
+            plans.append(plan_tuple(p))
+    return (plans, m.encode_state_as_update(), m.plan_frontier), fast
+
+
+def walk_alone(mp, updates):
+    """The sequential walk with no segment pass before it: every struct
+    is residue.  Steered from the test; the program has no such lane."""
+    mp.setattr(segment_planner, "plan_doc", lambda q, snapshot=None: None)
+    ref, fast = drive_mirror(DocMirror("text"), updates)
+    assert fast == 0
+    return ref
 
 
 @pytest.mark.parametrize("shape", SHAPES)
 def test_device_ranks_match_sequential_walk(shape, monkeypatch):
     """Struct-for-struct: every flush's sched entries, link writes, head
-    writes and delete rows must be identical between the authoritative
-    device plan and the pure sequential walk."""
+    writes and delete rows must be identical between a plan whose fast
+    set was spliced in bulk from its ranks and the pure sequential walk,
+    and the room must read as the CPU core's."""
     updates = corpus(shape, seed=15)
-
-    def drive(mode):
-        monkeypatch.setenv("YTPU_PLAN_SEGMENT", mode)
-        m = DocMirror("text")
-        plans = []
-        for j, u in enumerate(updates):
-            m.ingest(u, False)
-            if (j + 1) % 6 == 0 or j == len(updates) - 1:
-                plans.append(plan_tuple(m.prepare_step()))
-        return plans, m.encode_state_as_update(), m.plan_frontier
-
-    ref = drive("off")
-    for mode in ("device", "np", "jax"):
-        assert drive(mode) == ref, f"mode={mode} diverged from walk"
+    got, _fast = drive_mirror(DocMirror("text"), updates)
+    with monkeypatch.context() as mp:
+        ref = walk_alone(mp, updates)
+    assert got == ref, "segment pass diverged from walk"
+    back = core_doc([got[1]])
+    assert (
+        back.get_text("text").to_string()
+        == core_doc(updates).get_text("text").to_string()
+    )
 
 
 @pytest.mark.parametrize("shape", SHAPES)
 def test_native_plans_match_walk(shape, monkeypatch):
     """The native core's chain-run anchor adoption must not change one
-    plan array either."""
+    plan array either: held to the Python planner's sequential walk."""
     from yjs_tpu.ops.native_mirror import NativeMirror, native_plan_available
 
     if not native_plan_available():
         pytest.skip("native plancore unavailable")
     updates = corpus(shape, seed=23)
-
-    def drive(mode):
-        monkeypatch.setenv("YTPU_PLAN_SEGMENT", mode)
-        m = NativeMirror("text")
-        plans = []
-        for j, u in enumerate(updates):
-            m.ingest(u, False)
-            if (j + 1) % 6 == 0 or j == len(updates) - 1:
-                p = m.prepare_step()
-                plans.append((
-                    p.sched.tolist(), p.splits.tolist(),
-                    p.link_rows.tolist(), p.link_vals.tolist(),
-                    p.head_segs.tolist(), p.head_vals.tolist(),
-                    sorted(int(r) for r in p.delete_rows),
-                ))
-        return plans, m.encode_state_as_update(), m.plan_frontier
-
-    assert drive("device") == drive("off")
+    (plans, state, _frontier), _ = drive_mirror(NativeMirror("text"), updates)
+    with monkeypatch.context() as mp:
+        ref_plans, ref_state, _f = walk_alone(mp, updates)
+    assert plans == ref_plans
+    want = core_doc(updates)
+    for s in (state, ref_state):
+        assert (
+            core_doc([s]).get_text("text").to_string()
+            == want.get_text("text").to_string()
+        )
 
 
-# -- engine-level byte identity: device vs off --------------------------------
+# -- engine-level identity: either planner vs the CPU core --------------------
 
 
-def run_engine(updates, n_docs, mode, monkeypatch, py=False, flush_every=6):
-    monkeypatch.setenv("YTPU_PLAN_SEGMENT", mode)
+def run_engine(updates, n_docs, monkeypatch, py=False, flush_every=6):
     if py:
         monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
     eng = BatchEngine(n_docs)
@@ -186,30 +211,33 @@ def run_engine(updates, n_docs, mode, monkeypatch, py=False, flush_every=6):
 @pytest.mark.parametrize("py", [False, True], ids=["native", "python"])
 @pytest.mark.parametrize("shape", ["prepend_storm", "storm", "b4_head"])
 def test_engine_device_vs_off_byte_identical(shape, py, monkeypatch):
+    """An engine on either planner against the CPU core: every room's
+    text and state, and the broadcasts replayed on a new ``Y.Doc``."""
     updates = corpus(shape, seed=31)
     monkeypatch.setenv("YTPU_PLAN_CACHE", "0")
-    s_dev, t_dev, d_dev, sums_dev, keys_dev = run_engine(
-        updates, 3, "device", monkeypatch, py=py
+    states, texts, deltas, sums, keys = run_engine(
+        updates, 3, monkeypatch, py=py
     )
-    s_off, t_off, d_off, sums_off, keys_off = run_engine(
-        updates, 3, "off", monkeypatch, py=py
-    )
-    assert (t_dev, s_dev, d_dev) == (t_off, s_off, d_off)
-    # the off lane really is the pure walk: zero fast-set structs
-    assert sums_off["plan_segment_fast"] == 0
-    assert sums_off["plan_segment_residue"] == 0
+    want = core_doc(updates)
+    want_text = want.get_text("text").to_string()
+    want_state = canonical(encode_state_as_update(want))
+    assert texts == [want_text] * 3
+    assert [canonical(s) for s in states] == [want_state] * 3
+    for i in range(3):
+        assert core_doc(deltas[i]).get_text("text").to_string() == want_text
+    # the Python planner's lane plans a room at a time
+    if py:
+        assert sums["plan_threads"] == 1
     # ONE metrics schema either way
-    assert keys_dev == keys_off == {frozenset(FLUSH_METRICS_SCHEMA)}
+    assert keys == {frozenset(FLUSH_METRICS_SCHEMA)}
 
 
 def test_device_mode_counts_fast_set(monkeypatch):
     """Typing/prepend-heavy traffic must actually exercise the fast set
-    (bulk integration from device ranks), not silently fall back."""
+    (bulk integration of chained runs), not silently fall back."""
     updates = corpus("prepend_storm", seed=47)
     monkeypatch.setenv("YTPU_PLAN_CACHE", "0")
-    _s, _t, _d, sums, _k = run_engine(
-        updates, 2, "device", monkeypatch, py=True
-    )
+    _s, _t, _d, sums, _k = run_engine(updates, 2, monkeypatch, py=True)
     assert sums["plan_segment_fast"] > 0
 
 
@@ -217,27 +245,28 @@ def test_device_mode_counts_fast_set(monkeypatch):
 
 
 def test_device_plans_fold_same_frontier_as_walk(monkeypatch):
-    """Cache interop is exact: a device-planned prepare folds the same
-    frontier digest as the walk, so warm cache hits replay states that
-    are byte-identical across planner modes."""
+    """Cache interop is exact: a prepare with a fast set folds the same
+    frontier digest as the walk (``drive_mirror`` returns it, above), so
+    warm cache hits replay states that are byte-identical with the cache
+    off."""
     updates = corpus("interleaved", seed=7)
     monkeypatch.setenv("YTPU_PLAN_CACHE", "1")
     plan_cache.reset_cache()
-    s_on, t_on, d_on, _s1, _k1 = run_engine(
-        updates, 2, "device", monkeypatch, py=True
-    )
+    s_on, t_on, d_on, _s1, _k1 = run_engine(updates, 2, monkeypatch, py=True)
     plan_cache.reset_cache()
     monkeypatch.setenv("YTPU_PLAN_CACHE", "0")
     s_off, t_off, d_off, _s2, _k2 = run_engine(
-        updates, 2, "device", monkeypatch, py=True
+        updates, 2, monkeypatch, py=True
     )
     assert (t_on, s_on, d_on) == (t_off, s_off, d_off)
 
 
-# -- lifecycle: demotion→promotion and failover with the planner on -----------
+# -- lifecycle: demotion→promotion and failover ------------------------------
 
 
-def test_demotion_promotion_device_vs_off(monkeypatch):
+def test_demotion_promotion_device_vs_off():
+    """A room demoted and promoted on demand, then typed into again,
+    holds what the CPU core holds after the same two updates."""
     from yjs_tpu.provider import TpuProvider
     from yjs_tpu.tiering import TierConfig
 
@@ -247,29 +276,27 @@ def test_demotion_promotion_device_vs_off(monkeypatch):
         d.get_text("text").insert(at, text)
         return encode_state_as_update(d)
 
-    def drive(mode):
-        monkeypatch.setenv("YTPU_PLAN_SEGMENT", mode)
-        plan_cache.reset_cache()
-        p = TpuProvider(2, tier_config=TierConfig(enabled=True))
-        p.receive_update("r", upd("round trip "))
-        p.flush()
-        assert p.demote_doc("r", "warm")
-        assert p.text("r") == "round trip "  # demand promotion
-        p.receive_update("r", upd("second", cid=2))
-        p.flush()
-        return Y.merge_updates([p.encode_state_as_update("r")]), p.text("r")
+    first, second = upd("round trip "), upd("second", cid=2)
+    p = TpuProvider(2, tier_config=TierConfig(enabled=True))
+    p.receive_update("r", first)
+    p.flush()
+    assert p.demote_doc("r", "warm")
+    assert p.text("r") == "round trip "  # demand promotion
+    p.receive_update("r", second)
+    p.flush()
+    want = core_doc([first, second])
+    assert p.text("r") == want.get_text("text").to_string()
+    assert canonical(p.encode_state_as_update("r")) == canonical(
+        encode_state_as_update(want)
+    )
 
-    assert drive("device") == drive("off")
 
-
-def test_failover_promotion_with_planner_on(tmp_path, monkeypatch):
-    """Kill-primary failover with the segment planner on (the default):
-    promoted slots rebuild from journals and must converge to the
-    uninterrupted reference byte-for-byte."""
+def test_failover_promotion_with_planner_on(tmp_path):
+    """Kill-primary failover: promoted slots rebuild from journals and
+    must converge to the uninterrupted reference byte-for-byte."""
     from yjs_tpu.fleet import FailoverConfig, FleetRouter
     from yjs_tpu.persistence import WalConfig
 
-    monkeypatch.setenv("YTPU_PLAN_SEGMENT", "device")
     fleet = FleetRouter(
         3, 4, backend="cpu", wal_dir=tmp_path,
         wal_config=WalConfig(segment_bytes=256, fsync="never"),
@@ -316,11 +343,10 @@ def _snapshot_ops() -> int:
     )["count"]
 
 
-def test_monotone_prepend_skips_snapshot_rebuild(monkeypatch):
+def test_monotone_prepend_skips_snapshot_rebuild():
     """A pure head-prepend run is one monotone chain: the planner must
     reuse the prior sorted segment instead of re-sorting (rebuilding)
     the whole fragment snapshot every flush."""
-    monkeypatch.setenv("YTPU_PLAN_SEGMENT", "device")
     d = Y.Doc(gc=False)
     d.client_id = 9
     t = d.get_text("text")
@@ -340,10 +366,9 @@ def test_monotone_prepend_skips_snapshot_rebuild(monkeypatch):
     assert ref.get_text("text").to_string() == t.to_string()
 
 
-def test_conflicted_runs_still_build_snapshot(monkeypatch):
+def test_conflicted_runs_still_build_snapshot():
     """The reuse shortcut must not swallow real anchor lookups: a
     conflicted corpus with many non-chained anchors rebuilds."""
-    monkeypatch.setenv("YTPU_PLAN_SEGMENT", "device")
     updates = corpus("interleaved", seed=3, n_ops=120)
     m = DocMirror("text")
     before = _snapshot_ops()
@@ -354,44 +379,44 @@ def test_conflicted_runs_still_build_snapshot(monkeypatch):
     assert _snapshot_ops() > before
 
 
-# -- whole-chunk planner internals --------------------------------------------
+# -- what is left of the device's programs ------------------------------------
 
 
-def test_plan_chunk_matches_per_doc_plans(monkeypatch):
-    """plan_chunk's doc-composed global keys must resolve the same
-    hints/chains as independent per-doc plan_doc calls."""
-    monkeypatch.setenv("YTPU_PLAN_SEGMENT", "device")
-    shapes = ["prepend_storm", "storm", "interleaved", "b4_head"]
-    tokens = []
-    for k, shape in enumerate(shapes):
-        m = DocMirror("text")
-        for u in corpus(shape, seed=60 + k, n_ops=40):
-            m.ingest(u, False)
-        tokens.append((m, m.prepare_step_begin()))
-    items = [(tok.queries, m._segment_snapshot) for m, tok in tokens]
-    chunked = segment_planner.plan_chunk(items, mode="device")
-    solo = [
-        segment_planner.plan_doc(q, mode="jax", snapshot=snap)
-        for q, snap in items
-    ]
-    assert len(chunked) == len(solo)
-    for c, s in zip(chunked, solo):
-        if c is None or s is None:
-            assert c is None and s is None
-            continue
-        assert c.spans == s.spans
-        assert (c.chain_l == s.chain_l).all()
-        assert (c.chain_r == s.chain_r).all()
-        if c.hint_l is None or s.hint_l is None:
-            assert c.snapshot_reused == s.snapshot_reused
-        else:
-            assert (c.hint_l == s.hint_l).all()
-            assert (c.hint_r == s.hint_r).all()
-    # the mirrors are mid-prepare; finish them so nothing leaks poisoned
-    for (m, tok), sp in zip(tokens, chunked):
-        m.prepare_step_finish(tok, sp)
+def test_device_programs_are_the_writers_and_one_reader():
+    """The device holds the tables and computes nothing the host sends it
+    to compute: ``ops/kernels.py`` jits the four programs that write the
+    tables, ``list_ranks`` that reads them back and (until ``bench.py``
+    goes) ``apply_plan_shared``, no other, and the segment pass is the
+    host's: ``ops/segment_planner.py`` imports no ``jax``."""
+    import ast
+    import inspect
 
+    from yjs_tpu.ops import kernels
 
-def test_modes_table_is_closed():
-    assert set(segment_planner.MODES) == {"device", "np", "jax", "off"}
-    assert segment_planner.plan_segment_mode() in segment_planner.MODES
+    def jitted(f):  # a jax.jit program, bare or under ``profiled``
+        return hasattr(f, "lower") or hasattr(
+            getattr(f, "__wrapped__", None), "lower"
+        )
+
+    programs = {
+        name for name, f in vars(kernels).items()
+        if callable(f) and jitted(f)
+    }
+    assert programs == {
+        "apply_plan2", "apply_plan2_rows", "scatter_rows", "blank_rows",
+        "list_ranks", "apply_plan_shared",
+    }
+    tree = ast.parse(inspect.getsource(kernels))
+    defined = {
+        n.name for n in tree.body if isinstance(n, ast.FunctionDef)
+    }
+    assert defined == programs | {
+        "apply_lanes", "load_rows", "_put_rows", "_doc_lanes",
+    }
+    imported = set()
+    for node in ast.walk(ast.parse(inspect.getsource(segment_planner))):
+        if isinstance(node, ast.Import):
+            imported.update(a.name.split(".")[0] for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            imported.add((node.module or "").split(".")[0])
+    assert "jax" not in imported

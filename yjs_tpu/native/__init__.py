@@ -228,11 +228,8 @@ def load():
     # post-prepare mirror state onto another doc's handle
     lib.ymx_clone_state.restype = i64
     lib.ymx_clone_state.argtypes = [vp, vp]
-    # emit_row chain-run anchor adoption: Python mirrors the
-    # YTPU_PLAN_SEGMENT knob into the lib and diffs the hit/lookup
+    # emit_row chain-run anchor adoption: Python diffs the hit/lookup
     # totals around each flush for the shared metrics schema
-    lib.ymx_set_plan_segment.restype = None
-    lib.ymx_set_plan_segment.argtypes = [ctypes.c_int]
     lib.ymx_plan_segment_stats.restype = None
     lib.ymx_plan_segment_stats.argtypes = [i64p]
     _lib = lib

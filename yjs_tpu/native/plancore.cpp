@@ -73,9 +73,7 @@ constexpr int kErrInternal = -8;
 // fast set, ISSUE 15): when a scheduled ref's origin/rightOrigin sits
 // inside the row emit_row just produced — typing and prepend chains —
 // the anchor is adopted in O(1) instead of re-running the per-slot
-// fragment binary search.  Gated by YTPU_PLAN_SEGMENT=off through
-// ymx_set_plan_segment; hit/lookup totals feed the flush metrics.
-std::atomic<int> g_plan_segment{1};
+// fragment binary search.  Hit/lookup totals feed the flush metrics.
 std::atomic<long long> g_seg_fast{0};
 std::atomic<long long> g_seg_lookup{0};
 
@@ -1503,7 +1501,6 @@ struct Mirror {
     // again within the pass (all cuts were applied in pre-split or
     // inline), so containment against it is exact — chained refs adopt
     // their anchor without the fragment binary search
-    const bool seg_on = g_plan_segment.load(std::memory_order_relaxed) != 0;
     bool em_last_valid = false;
     int64_t em_last_row = kNull, em_last_slot = kNull;
     int64_t em_last_clock = 0, em_last_len = 0;
@@ -1525,7 +1522,7 @@ struct Mirror {
       bool degrade = false;
       if (ref.oc >= 0) {
         oslot = slot(ref.oc);
-        if (seg_on && em_last_valid && oslot == em_last_slot &&
+        if (em_last_valid && oslot == em_last_slot &&
             ref.ok >= em_last_clock &&
             ref.ok < em_last_clock + em_last_len) {
           left_row = em_last_row;
@@ -1534,13 +1531,13 @@ struct Mirror {
           int64_t fi = frag_containing(oslot, ref.ok);
           if (fi == kNull) return kErrInternal;
           left_row = frag_row[oslot][(size_t)fi];
-          if (seg_on) seg_lookup_n++;
+          seg_lookup_n++;
         }
         if (r_is_gc[left_row]) degrade = true;
       }
       if (ref.rc >= 0) {
         rslot = slot(ref.rc);
-        if (seg_on && em_last_valid && rslot == em_last_slot &&
+        if (em_last_valid && rslot == em_last_slot &&
             ref.rk >= em_last_clock &&
             ref.rk < em_last_clock + em_last_len) {
           right_row = em_last_row;
@@ -1549,7 +1546,7 @@ struct Mirror {
           int64_t fi = frag_containing(rslot, ref.rk);
           if (fi == kNull) return kErrInternal;
           right_row = frag_row[rslot][(size_t)fi];
-          if (seg_on) seg_lookup_n++;
+          seg_lookup_n++;
         }
         if (r_is_gc[right_row]) degrade = true;
       }
@@ -2754,11 +2751,6 @@ static int plan_pool_width() {
 }
 
 int ymx_plan_threads() { return plan_pool_width(); }
-
-// YTPU_PLAN_SEGMENT gate for the emit_row chain-run anchor adoption —
-// Python sets it from the env knob so the A/B `off` lane disables every
-// segment-planning shortcut, host and native alike
-void ymx_set_plan_segment(int on) { g_plan_segment.store(on != 0); }
 
 // cumulative [fast adoptions, fragment-search lookups] across every
 // prepare in the process; callers diff around a flush

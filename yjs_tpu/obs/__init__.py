@@ -92,7 +92,7 @@ from .capacity import (  # noqa: F401
 SNAPSHOT_SCHEMA_VERSION = 1
 
 # -- the per-flush metrics schema -------------------------------------------
-# ONE constructor for every flush exit (apply / levels / seq / batched /
+# ONE constructor for every flush exit (batched / per-doc /
 # empty-early-return): the paths previously shared these keys by
 # convention only, and a drift was silent until a consumer KeyError'd.
 # tests/test_obs.py pins identical key sets across all modes.
@@ -105,18 +105,14 @@ FLUSH_METRICS_SCHEMA: dict = {
     "n_fallback_docs": 0,
     "n_rows_max": 0,
     "n_sched_entries": 0,
-    "n_levels": 0,
-    "level_width": 0,
     "schedule_occupancy": 0.0,
     "n_pending_docs": 0,
     "pending_depth": 0,
     # planner fan-out this flush actually used: the most threads one
     # native call planned on, the caller included (the core's own rule:
     # a thread for each share of the work the call is reckoned to hold,
-    # at most YTPU_PLAN_THREADS and the call's docs), or — on the Python
-    # path under YTPU_PLAN_SEGMENT=device — the number of cold docs
-    # co-planned by one whole-chunk segment-planner call (ISSUE 15).
-    # 1 = fully serial per-doc planning.
+    # at most YTPU_PLAN_THREADS and the call's docs).  1 = fully serial
+    # per-doc planning, as the Python planner's lane always is.
     "plan_threads": 1,
     # the native planner's pool outlives the call: workers the flush's
     # calls woke, and threads they had to construct (0 in every flush
@@ -161,9 +157,9 @@ FLUSH_METRICS_SCHEMA: dict = {
     "plan_cache_misses": 0,
     "plan_cache_admitted": 0,
     "plan_fastpath_structs": 0,
-    # device-authoritative segment planner (ISSUE 15): structs
-    # integrated straight from device-computed ranks (fast set) vs
-    # handed to the sequential YATA conflict fallback (residue)
+    # the planners' segment pass (ISSUE 15): structs placed as chained
+    # runs straight from their ranks (fast set) vs handed to the
+    # sequential YATA conflict fallback (residue)
     "plan_segment_fast": 0,
     "plan_segment_residue": 0,
     "t_compact_s": 0.0,
@@ -364,7 +360,7 @@ class EngineObs:
         )
         # segment-planner residue (ISSUE 16 satellite): the live number
         # the residue-elimination work drives against — fraction of
-        # planned structs the device fast path could NOT place and
+        # planned structs the planner's fast set could NOT place and
         # handed to the sequential YATA conflict fallback
         self._segment_residue_fraction = r.gauge(
             "ytpu_plan_segment_residue_fraction",

@@ -43,6 +43,7 @@ from ..lib0.binary import BIT6, BIT7, BIT8, BITS5
 from ..lib0.decoding import Decoder
 from ..native import SRC_DELETED, SRC_FRAMED, SRC_NONE, SRC_SPILL, SRC_UTF8
 from . import plan_cache as _pc
+from . import segment_planner as _sp
 
 NULL = -1  # null id / null row sentinel in every int column
 
@@ -419,11 +420,9 @@ class UnsupportedUpdate(Exception):
 
 
 class _PlanCtx:
-    """Opaque phase A -> phase B carrier for the split cold plan
-    (ISSUE 15): the engine holds these while the segment planner
-    co-plans a whole chunk of cold docs in one batched kernel call."""
+    """What phase A of ``DocMirror.prepare_step`` hands phase B."""
 
-    __slots__ = ("plan", "frag_sched", "applicable", "queries", "sd")
+    __slots__ = ("plan", "frag_sched", "applicable", "queries")
 
 
 @dataclass
@@ -944,17 +943,15 @@ class DocMirror:
                     self._delete_row(r, plan)
 
     def _segment_queries(self, frag_sched):
-        """Anchor-query columns for the segment planner (ISSUE 15),
+        """Anchor-query columns for the segment pass (ISSUE 15),
         built AFTER the pre-split pass and BEFORE any row is added:
         per-ref id/origin/rightOrigin columns plus the facts span
         eligibility needs (GC flag, content kind, explicit parent).
         Returns a :class:`~yjs_tpu.ops.segment_planner.SegmentQueries`
-        of fresh arrays, or None when planning is off or the batch is
-        too small to pay for kernel dispatch."""
-        from . import segment_planner as _sp  # deferred: imports kernels
-
+        of fresh arrays, or None when the batch is too small to pay
+        for the pass."""
         n = len(frag_sched)
-        if _sp.plan_segment_mode() == "off" or n < _sp.MIN_RUN:
+        if n < _sp.MIN_RUN:
             return None
         q = _sp.SegmentQueries()
         q.n = n
@@ -1044,56 +1041,24 @@ class DocMirror:
 
     def prepare_step(self) -> StepPlan:
         """Consume queued updates and produce the device step plan — the
-        cold planning path; advances the plan frontier on success and
-        poisons it on any failure (the mirror may be mid-step then, see
-        the inner docstring).  Equivalent to ``prepare_step_begin()``
-        followed by ``prepare_step_finish(token, "auto")`` — the
-        engine uses the split form to co-plan whole chunks of cold docs
-        in one segment-planner call (ISSUE 15)."""
-        token = self.prepare_step_begin()
-        return self.prepare_step_finish(token, "auto")
-
-    def prepare_step_begin(self):
-        """Phase A of the cold plan: decode, causal scheduling, DS
-        clamping, the pre-split pass, and the segment-planner query
-        build.  Returns an opaque token for ``prepare_step_finish``;
-        ``token.queries`` (may be None) and the mirror's
-        ``_segment_snapshot`` are what :func:`segment_planner.plan_chunk`
-        consumes to co-plan many docs at once.  Poisons the plan
-        frontier on failure, exactly like ``prepare_step``."""
+        cold planning path: phase A (decode, causal scheduling, DS
+        clamping, the pre-split pass, the segment pass's queries), the
+        segment pass, phase B (integration, delete resolution, plan
+        finalization).  Folds the plan frontier on success and poisons
+        it on any failure (the mirror may be mid-step then, see phase
+        A's docstring)."""
         sd = _pc.staged_digest(self._incoming)
         try:
             ctx = self._prepare_phase_a()
+            seg_plan = _sp.plan_doc(
+                ctx.queries, snapshot=self._segment_snapshot
+            )
+            plan = self._prepare_phase_b(ctx, seg_plan)
         except BaseException:
             self.plan_frontier = _pc.poison_frontier()
             _pc.note_invalidation("plan-error")
             raise
-        ctx.sd = sd
-        return ctx
-
-    def prepare_step_finish(self, token, seg_plan) -> StepPlan:
-        """Phase B of the cold plan: integration (bulk fast-set runs +
-        the sequential YATA fallback for the conflict residue), delete
-        resolution and plan finalization.  ``seg_plan`` is the
-        :class:`~yjs_tpu.ops.segment_planner.SegmentPlan` computed for
-        this doc (possibly within a chunk), ``None`` to run the pure
-        host walk, or ``"auto"`` to plan per-doc here.  Folds the plan
-        frontier on success and poisons it on failure — together with
-        ``prepare_step_begin`` this preserves ``prepare_step``'s cache
-        interop exactly (device-planned results fold the same digest)."""
-        try:
-            if isinstance(seg_plan, str):  # "auto": per-doc planning
-                from . import segment_planner as _sp
-
-                seg_plan = _sp.plan_doc(
-                    token.queries, snapshot=self._segment_snapshot
-                )
-            plan = self._prepare_phase_b(token, seg_plan)
-        except BaseException:
-            self.plan_frontier = _pc.poison_frontier()
-            _pc.note_invalidation("plan-error")
-            raise
-        self.plan_frontier = _pc.fold(self.plan_frontier, b"u", token.sd)
+        self.plan_frontier = _pc.fold(self.plan_frontier, b"u", sd)
         return plan
 
     def _prepare_phase_a(self):
@@ -1257,16 +1222,16 @@ class DocMirror:
         ctx.frag_sched = frag_sched
         ctx.applicable = applicable
         ctx.queries = self._segment_queries(frag_sched)
-        ctx.sd = None
         return ctx
 
     def _prepare_phase_b(self, ctx, seg_plan) -> StepPlan:
         """Integration + finalization (phase B of the cold plan).
 
-        ``seg_plan`` carries the device-computed answer: verified anchor
-        hints, chain masks, and the fast-set spans integrated in bulk
-        straight from the ranks; every struct it cannot place falls to
-        the sequential YATA walk below — the conflict residue."""
+        ``seg_plan`` carries the segment pass's answer (None for a batch
+        too small for one): verified anchor hints, chain masks, and the
+        fast-set spans integrated in bulk straight from the ranks; every
+        struct it cannot place falls to the sequential YATA walk below —
+        the conflict residue."""
         plan = ctx.plan
         frag_sched = ctx.frag_sched
         applicable = ctx.applicable
@@ -1407,7 +1372,7 @@ class DocMirror:
             if ref.content_ref == 1:  # ContentDeleted
                 applicable.append((ref.client, ref.clock, ref.length))
             # fast-set bulk integration (ISSUE 15): ref j starts a
-            # chained run the device ranks fully determine — verify the
+            # chained run its ranks fully determine — verify the
             # live-state preconditions once, then splice the interior
             # without per-struct anchor resolution or walk.  Any miss
             # falls back to the scalar loop (placement cannot differ).
@@ -1466,7 +1431,7 @@ class DocMirror:
     def _integrate_run(self, frag_sched, s, e, d, seg, row_s, hint_r,
                        plan):
         """Bulk-integrate the interior of a chained run straight from
-        the device ranks (the ISSUE 15 fast set).
+        its ranks (the ISSUE 15 fast set).
 
         ``frag_sched[s]`` was just integrated as ``row_s`` through the
         normal sequential path; refs ``s+1 .. e-1`` chain purely in
@@ -1835,8 +1800,11 @@ class DocMirror:
         return self.encode_masked_update(needed, offset, v2=v2)
 
     def _diff_mask(self, remote_sv: dict[int, int]):
-        """Vectorized host twin of kernels.diff_mask_kernel: rows (or row
-        suffixes) beyond a remote state vector (encoding.js:94-116)."""
+        """Rows (or row suffixes) beyond a remote state vector, in one
+        vectorized pass over the columns: the columnar filter of
+        writeClientsStructs (encoding.js:94-116).  ``offset > 0`` means
+        the row is written from that element (the partial-first-struct
+        rule, encoding.js:71-84)."""
         n = self.n_rows
         if n == 0:
             return np.zeros(0, bool), np.zeros(0, np.int64)
@@ -1865,9 +1833,8 @@ class DocMirror:
     def encode_masked_update(self, needed, offset, v2: bool = False,
                              ds_ranges=None) -> bytes:
         """Wire-encode the rows selected by ``needed`` (bool [n_rows]) from
-        element ``offset`` — the writer half of sync step 2, fed either by
-        the host mask above or by the device ``diff_mask_kernel`` for the
-        engine's batched path.  ``ds_ranges`` overrides the DS section
+        element ``offset`` — the writer half of sync step 2, fed by the
+        host mask above.  ``ds_ranges`` overrides the DS section
         (defaults to the doc's full derived DeleteSet)."""
         from ..coding import UpdateEncoderV1, UpdateEncoderV2
         from ..core import write_delete_set

@@ -31,7 +31,6 @@ from ..updates import InvalidUpdate, validate_update
 from ..updates import apply_update, apply_update_v2
 from .columns import NULL, DocMirror, UnsupportedUpdate
 from . import plan_cache
-from . import segment_planner
 from .native_mirror import (
     PLAN_POOL_COUNTS,
     PLAN_TIMES,
@@ -151,7 +150,7 @@ def _kind_counts_py(m, p) -> dict:
 # of a flush's metrics, those that are a level and not a sum: the second
 # round of a flush (engine._finish_flush) keeps the larger
 _FLUSH_LEVELS = (
-    "n_rows_max", "n_levels", "level_width", "schedule_occupancy",
+    "n_rows_max", "schedule_occupancy",
     "n_fallback_docs", "n_pending_docs", "pending_depth", "plan_threads",
     "plan_room_max_s", "pipeline_depth", "n_segs_max", "seg_cap",
 )
@@ -521,10 +520,8 @@ class BatchEngine:
         # observe/observeDeep, AbstractType.js:360-389)
         self._event_listeners: dict[int, list] = {}
         self._metrics_dev: dict | None = None
-        # cached sharded state-vector callables keyed by n_slots (jit's
-        # cache is per function identity — rebuilding retraces every call)
-        self._sharded_sv: dict[int, object] = {}
         # cached sharded bulk-apply callables keyed by lane bucket shape
+        # (jit's cache is per function identity — rebuilding retraces)
         self._sharded_apply: dict[tuple, object] = {}
         # the sharded row load: one jitted callable for every block shape
         self._sharded_load = None
@@ -1449,7 +1446,6 @@ class BatchEngine:
         rolled_back = 0
         cache_hits = cache_misses = cache_admitted = 0
         t_plan_cached = t_plan_cold = 0.0
-        plan_fanout = 1  # docs co-planned by one whole-chunk planner call
         emitting = bool(self._update_listeners)
         observing = self._event_listeners
         # ONE device write path: the planner's final link values go up in
@@ -1485,19 +1481,8 @@ class BatchEngine:
                 plans = dict(work)  # presence for the empty-flush check
                 self._compact_look.update(plans)
             else:
+                # the Python planner's lane: a room at a time
                 cache = plan_cache.get_cache()
-                seg_mode = segment_planner.plan_segment_mode()
-                # device mode co-plans every cold DocMirror's anchors in
-                # ONE batched kernel call (ISSUE 15): phase A runs per
-                # doc in the loop, the whole-chunk segment plan lands
-                # between, phase B finishes per doc below
-                chunk_cold: list = []  # (doc, mirror, cache key, phase-A token)
-                chunk_keys: set = set()
-                # intra-flush duplicates of a chunked doc's key wait for
-                # the leader's cache insert and replay it (the per-doc
-                # loop got this for free by inserting before the next
-                # lookup)
-                chunk_dup: list = []  # (doc, mirror, cache key)
                 with self._phase_ctx("plan.walk"):
                     for i in dirty:
                         m = self.mirrors[i]
@@ -1528,27 +1513,6 @@ class BatchEngine:
                             cache_hits += 1
                             t_plan_cached += time.perf_counter() - t_d0
                             continue
-                        if seg_mode == "device" and type(m) is DocMirror:
-                            if key is not None and key in chunk_keys:
-                                chunk_dup.append((i, m, key))
-                                continue
-                            try:
-                                token = m.prepare_step_begin()
-                            except UnsupportedUpdate as e:
-                                self._demote(i, pre_svs.get(i), reason=str(e))
-                                demoted_now += 1
-                            except Exception as e:
-                                if self._strict:
-                                    raise
-                                self._isolate_failure(i, e, pre_svs.get(i))
-                                demoted_now += 1
-                                rolled_back += 1
-                            else:
-                                chunk_cold.append((i, m, key, token))
-                                if key is not None:
-                                    chunk_keys.add(key)
-                            t_plan_cold += time.perf_counter() - t_d0
-                            continue
                         try:
                             plans[i] = m.prepare_step()
                         except UnsupportedUpdate as e:
@@ -1575,72 +1539,6 @@ class BatchEngine:
                                         key, m, plans[i]
                                     )
                         t_plan_cold += time.perf_counter() - t_d0
-                if chunk_cold:
-                    t_d0 = time.perf_counter()
-                    try:
-                        seg_plans = segment_planner.plan_chunk(
-                            [
-                                (t.queries, m._segment_snapshot)
-                                for (_i, m, _k, t) in chunk_cold
-                            ],
-                            mode=seg_mode,
-                            mesh=self.mesh,
-                        )
-                    except Exception:
-                        # planner fault: fall back to per-doc planning
-                        # in finish (a doc-level fault there still
-                        # poisons/demotes only its own doc)
-                        seg_plans = ["auto"] * len(chunk_cold)
-                    co_planned = sum(
-                        1 for (_i, _m, _k, t) in chunk_cold
-                        if t.queries is not None
-                    )
-                    plan_fanout = max(plan_fanout, co_planned)
-                    for (i, m, key, token), sp in zip(chunk_cold, seg_plans):
-                        try:
-                            plans[i] = m.prepare_step_finish(token, sp)
-                        except UnsupportedUpdate as e:
-                            self._demote(i, pre_svs.get(i), reason=str(e))
-                            demoted_now += 1
-                        except Exception as e:
-                            if self._strict:
-                                raise
-                            self._isolate_failure(i, e, pre_svs.get(i))
-                            demoted_now += 1
-                            rolled_back += 1
-                        else:
-                            if key is not None:
-                                cache_misses += 1
-                                cache_admitted += cache.insert_py(
-                                    key, m, plans[i]
-                                )
-                    t_plan_cold += time.perf_counter() - t_d0
-                for i, m, key in chunk_dup:
-                    t_d0 = time.perf_counter()
-                    ent = cache.lookup(key) if cache is not None else None
-                    if ent is not None:
-                        m2, plans[i] = ent.clone()
-                        m.__dict__.clear()
-                        m.__dict__.update(m2.__dict__)
-                        cache_hits += 1
-                        t_plan_cached += time.perf_counter() - t_d0
-                        continue
-                    # leader demoted/failed before inserting: plan solo
-                    try:
-                        plans[i] = m.prepare_step()
-                    except UnsupportedUpdate as e:
-                        self._demote(i, pre_svs.get(i), reason=str(e))
-                        demoted_now += 1
-                    except Exception as e:
-                        if self._strict:
-                            raise
-                        self._isolate_failure(i, e, pre_svs.get(i))
-                        demoted_now += 1
-                        rolled_back += 1
-                    else:
-                        cache_misses += 1
-                        cache_admitted += cache.insert_py(key, m, plans[i])
-                    t_plan_cold += time.perf_counter() - t_d0
         t_plan = time.perf_counter()
         # ONE schema (obs.FLUSH_METRICS_SCHEMA) for every exit: each path
         # overwrites only the fields it measures, so the key set cannot
@@ -1656,7 +1554,6 @@ class BatchEngine:
             plan_cache_hits=cache_hits,
             plan_cache_misses=cache_misses,
             plan_cache_admitted=cache_admitted,
-            plan_threads=plan_fanout,
             rooms_dirty=len(dirty),
             rooms_compact_looked=n_looked,
             plan_fastpath_structs=sum(
@@ -2033,8 +1930,6 @@ class BatchEngine:
             "n_rows_max": max_rows_all,
             # real links, whichever way they went: lanes or row blocks
             "n_sched_entries": n_dense + n_sparse + row_links,
-            "n_levels": 1,
-            "level_width": n_dense + n_sparse + row_links,
             "rooms_row_loaded": rooms_row_loaded,
             "row_block_bytes": row_block_bytes,
             # bulk path: fraction of what was staged that is real: of
@@ -3233,81 +3128,7 @@ class BatchEngine:
             target = decode_state_vector(encoded_target_sv)
         return self.mirrors[doc].encode_state_as_update(target, v2=v2)
 
-    # -- batched sync kernels ----------------------------------------------
-
-    def _sync_columns(self, docs: list[int]):
-        """Stacked (row_slot, row_clock, row_end) columns for a doc subset,
-        padded to the widest doc (NULL rows are masked by the kernels).
-        Served from each mirror's cached numpy columns."""
-        n = max((self.mirrors[i].n_rows for i in docs), default=0)
-        n = max(n, 1)
-        k = len(docs)
-        row_slot = np.full((k, n), NULL, np.int32)
-        row_clock = np.zeros((k, n), np.int32)
-        row_end = np.zeros((k, n), np.int32)
-        for j, i in enumerate(docs):
-            m = self.mirrors[i]
-            r = m.n_rows
-            if r:
-                c = m._np_cols()
-                row_slot[j, :r] = c["slot"]
-                row_clock[j, :r] = c["clock"]
-                row_end[j, :r] = c["row_end"]
-        return row_slot, row_clock, row_end
-
-    def state_vectors_batched(self, docs: list[int]) -> list[dict[int, int]]:
-        """State vectors for many docs in ONE ``state_vector_kernel``
-        dispatch (the segment-max of StructStore.getStateVector batched
-        over the doc axis — SURVEY.md §2 sync-protocol row).  Results align
-        positionally with ``docs``; fallback docs are served by the CPU
-        core."""
-        out: list[dict[int, int] | None] = [None] * len(docs)
-        dev = [(j, i) for j, i in enumerate(docs) if i not in self.fallback]
-        for j, i in enumerate(docs):
-            if i in self.fallback:
-                out[j] = self.state_vector(i)
-        if dev:
-            dev_docs = [i for _, i in dev]
-            row_slot, _clock, row_end = self._sync_columns(dev_docs)
-            # bucket n_slots so client-count growth compiles O(log) variants
-            n_slots = _bucket(
-                max(1, max(len(self.mirrors[i].client_of_slot) for i in dev_docs)),
-                4,
-            )
-            if self.mesh is not None:
-                # the sharded segment-max path: pad the doc subset to the
-                # mesh axis, compute shard-locally, gather over ICI
-                axis = self.mesh.axis_names[0]
-                size = self.mesh.shape[axis]
-                pad = (-len(dev_docs)) % size
-                if pad:
-                    row_slot = np.pad(
-                        row_slot, ((0, pad), (0, 0)), constant_values=NULL
-                    )
-                    row_end = np.pad(row_end, ((0, pad), (0, 0)))
-                f = self._sharded_sv.get(n_slots)
-                if f is None:
-                    from ..parallel.mesh import sharded_state_vectors
-
-                    f = sharded_state_vectors(self.mesh, n_slots, axis)
-                    self._sharded_sv[n_slots] = f
-                sv = np.asarray(
-                    f(self._put_b(row_slot), self._put_b(row_end))
-                )
-            else:
-                sv = np.asarray(
-                    kernels.state_vector_kernel(
-                        jnp.asarray(row_slot), jnp.asarray(row_end), n_slots
-                    )
-                )
-            for r, (j, i) in enumerate(dev):
-                m = self.mirrors[i]
-                out[j] = {
-                    m.client_of_slot[s]: int(sv[r, s])
-                    for s in range(len(m.client_of_slot))
-                    if sv[r, s] > 0
-                }
-        return out
+    # -- batched sync ------------------------------------------------------
 
     def sync_step2_batch(
         self, requests: list[tuple[int, dict[int, int] | None]], v2: bool = False
@@ -3323,12 +3144,11 @@ class BatchEngine:
         C++ columns into one arena sized by the diffs' own bounds; the
         replies are cut out of it.  A request that call refuses (V2-framed
         or spilled payloads) and every ``v2=True`` request take the
-        mirror's own ``encode_diff_update``, one native call and one
-        buffer of the room's whole bound each.  The ``diff_mask_kernel``
-        dispatch (one for the whole batch, from columns copied out of the
-        host mirrors) serves what that declines too, Python-mirror
-        engines, and every request under ``YTPU_SYNC_DEVICE=1``.
-        Fallback docs are served by the CPU core.
+        mirror's own ``encode_state_as_update``: for a NativeMirror one
+        native call and one buffer of the room's whole bound, and where
+        that declines too, as for every request of a Python-mirror
+        engine, the mirror's host mask over its columns and its own
+        writer.  Fallback docs are served by the CPU core.
 
         The whole call is the ``ytpu.sync.encode`` span;
         ``last_sync_metrics`` says what it did: ``encode_batched`` requests
@@ -3355,9 +3175,8 @@ class BatchEngine:
         how many of them the batched native call gave."""
         replies: list[bytes | None] = [None] * len(requests)
         buffer_bytes = n_batched = 0
-        dev = [
-            (j, i, sv) for j, (i, sv) in enumerate(requests) if i not in self.fallback
-        ]
+        mirrors = self.mirrors
+        batch, one_by_one = [], []
         for j, (i, sv) in enumerate(requests):
             if i in self.fallback:
                 enc_sv = None
@@ -3369,68 +3188,34 @@ class BatchEngine:
                     write_state_vector(e, sv)
                     enc_sv = e.to_bytes()
                 replies[j] = self.encode_state_as_update(i, enc_sv, v2=v2)
-        if not os.environ.get("YTPU_SYNC_DEVICE"):
-            mirrors = self.mirrors
-            batch, one_by_one = [], []
-            for r in dev:
-                if not v2 and isinstance(mirrors[r[1]], NativeMirror):
-                    batch.append(r)
-                else:
-                    one_by_one.append(r)
-            if batch:
-                updates, arena_bytes = encode_diffs_many(
-                    [(mirrors[i], sv) for _j, i, sv in batch]
-                )
-                buffer_bytes += arena_bytes
-                for r, u in zip(batch, updates):
-                    if u is None:
-                        one_by_one.append(r)
-                    else:
-                        replies[r[0]] = u
-                        n_batched += 1
-            rest = []
-            for j, i, sv in one_by_one:
-                m = mirrors[i]
-                enc = getattr(m, "encode_diff_update", None)
-                u = enc(sv, v2=v2) if enc is not None else None
-                if u is None:
-                    rest.append((j, i, sv))
-                else:
-                    replies[j] = u
-                    buffer_bytes += m.encode_buffer_bytes
-            dev = rest
-        if dev:
-            docs = [i for _, i, _ in dev]
-            row_slot, row_clock, row_end = self._sync_columns(docs)
-            n_slots = max(1, max(len(self.mirrors[i].client_of_slot) for i in docs))
-            sv_dense = np.zeros((len(dev), n_slots), np.int32)
-            for r, (_j, i, sv) in enumerate(dev):
-                m = self.mirrors[i]
-                for client, clock in (sv or {}).items():
-                    s = m.slot_of_client.get(client)
-                    if s is not None:
-                        sv_dense[r, s] = clock
-            needed, offset = kernels.diff_mask_kernel(
-                self._put_r(row_slot),
-                self._put_r(row_clock),
-                self._put_r(row_end),
-                self._put_r(sv_dense),
+            elif not v2 and isinstance(mirrors[i], NativeMirror):
+                batch.append((j, i, sv))
+            else:
+                one_by_one.append((j, i, sv))
+        if batch:
+            updates, arena_bytes = encode_diffs_many(
+                [(mirrors[i], sv) for _j, i, sv in batch]
             )
-            needed = np.asarray(needed)
-            offset = np.asarray(offset)
-            for r, (j, i, _sv) in enumerate(dev):
-                replies[j] = self.mirrors[i].encode_masked_update(
-                    needed[r], offset[r], v2=v2
-                )
+            buffer_bytes += arena_bytes
+            for r, u in zip(batch, updates):
+                if u is None:
+                    one_by_one.append(r)
+                else:
+                    replies[r[0]] = u
+                    n_batched += 1
+        for j, i, sv in one_by_one:
+            m = mirrors[i]
+            replies[j] = m.encode_state_as_update(sv, v2=v2)
+            buffer_bytes += getattr(m, "encode_buffer_bytes", 0)
         return replies, buffer_bytes, n_batched
 
     def encode_states_batched(
         self, docs: list[int], v2: bool = False
     ) -> list[bytes]:
-        """Full-state exports for many docs in ONE batched dispatch (a
+        """Full-state exports for many docs in ONE batched call (a
         sync-step-2 answer against the empty state vector) — the WAL
         checkpoint's snapshot producer (ISSUE 3): compacting a fleet
-        must not cost one device round trip per doc."""
+        must not cost one encode call per doc."""
         return self.sync_step2_batch([(i, None) for i in docs], v2=v2)
 
     def has_pending(self, doc: int) -> bool:

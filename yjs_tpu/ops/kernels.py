@@ -10,8 +10,9 @@ rooms that held rows, one whole-row write a width class for the rooms it
 loads into empty slots (``apply_plan2_rows``;
 ``parallel.mesh.sharded_load_rows``), and a compaction, a hydration or a
 release is one whole-row write too (``scatter_rows``, ``blank_rows``).
-The read side ranks document order from the right links (``list_ranks``)
-and answers state vectors and diffs as segment reductions.
+The one program that reads the tables back ranks document order from the
+right links (``list_ranks``): the export that verification holds the tables
+to.  State vectors, diffs and every other read are the host mirrors'.
 
 All row arrays carry one extra trailing scratch row (index N); its contents
 are never read meaningfully.
@@ -24,7 +25,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ..obs.prof import profiled
@@ -241,180 +241,7 @@ def blank_rows(right, deleted, starts, doc):
 
 
 # ---------------------------------------------------------------------------
-# segment-sorted planning kernels (ISSUE 9)
-# ---------------------------------------------------------------------------
-# The host planner's per-struct cost is anchor resolution: three binary
-# searches per ref against the per-client fragment index.  These kernels
-# hoist that into sorted-segment array ops over the whole flush batch:
-#
-# - `plan_anchor_lookup`: ONE searchsorted over the slot-major
-#   concatenated fragment index resolves every ref's origin/rightOrigin
-#   candidate at once (the composed (slot, clock) key trick — per-slot
-#   runs are clock-sorted, so slot*B+clock is globally sorted);
-# - `plan_conflict_scan`: adjacent-ref chain detection — a ref whose
-#   origin (or rightOrigin) lands inside the PREVIOUS ref's id range
-#   chains onto it (typing runs, prepend runs), so its anchor is the
-#   previous ref's row with no index lookup at all.  `run_id` numbers the
-#   maximal chained runs (cumsum over chain breaks).
-#
-# Hints are *candidates*, not answers: the planner verifies containment
-# against the live columns and falls back to the sequential bisect walk
-# on any miss, so a wrong hint can never change placement.  Both kernels
-# have NumPy twins (the default host path, YTPU_PLAN_SEGMENT=np) and
-# jitted JAX versions (YTPU_PLAN_SEGMENT=jax) whose retraces/compiles the
-# kernel profiler attributes like any other device kernel.
-
-
-def _compose_keys(flat_slot, flat_clock, q_slot, q_clock):
-    """(slot, clock) pairs -> one sortable int64 key space; invalid
-    queries (slot < 0) map below every real key."""
-    base = int(max(flat_clock.max() if flat_clock.size else 0,
-                   q_clock.max() if q_clock.size else 0)) + 2
-    flat_key = flat_slot * base + flat_clock
-    q_key = np.where(q_slot >= 0, q_slot * base + q_clock, -1)
-    return flat_key, q_key
-
-
-@profiled("plan_anchor_lookup")
-@jax.jit
-def _anchor_lookup_jax(flat_key, q_key):
-    return jnp.searchsorted(flat_key, q_key, side="right") - 1
-
-
-def plan_anchor_lookup(flat_slot, flat_clock, q_slot, q_clock,
-                       backend: str = "np"):
-    """Candidate fragment-index position for each (q_slot, q_clock): the
-    last fragment starting at or before the queried clock, or -1.  The
-    caller must verify slot match + containment before trusting it."""
-    flat_key, q_key = _compose_keys(flat_slot, flat_clock, q_slot, q_clock)
-    if backend == "jax":
-        return np.asarray(_anchor_lookup_jax(flat_key, q_key))
-    return np.searchsorted(flat_key, q_key, side="right") - 1
-
-
-@profiled("plan_conflict_scan")
-@jax.jit
-def _conflict_scan_jax(client, clock, length, o_client, o_clock,
-                       r_client, r_clock):
-    p_client, p_clock = client[:-1], clock[:-1]
-    p_end = p_clock + length[:-1]
-    left = (
-        (o_client[1:] == p_client)
-        & (o_client[1:] >= 0)
-        & (o_clock[1:] >= p_clock)
-        & (o_clock[1:] < p_end)
-    )
-    right = (
-        (r_client[1:] == p_client)
-        & (r_client[1:] >= 0)
-        & (r_clock[1:] >= p_clock)
-        & (r_clock[1:] < p_end)
-    )
-    pad = jnp.zeros(1, bool)
-    left = jnp.concatenate([pad, left])
-    right = jnp.concatenate([pad, right])
-    run_id = jnp.cumsum(~(left | right))
-    return left, right, run_id
-
-
-def plan_conflict_scan(client, clock, length, o_client, o_clock,
-                       r_client, r_clock, backend: str = "np"):
-    """Chain masks over a clock-sorted flush batch: ``left[j]`` /
-    ``right[j]`` mean ref j's origin / rightOrigin lies inside ref j-1's
-    id range (so its anchor row IS ref j-1's row); ``run_id`` groups the
-    maximal chained (conflict-free) runs."""
-    if backend == "jax":
-        l, r, g = _conflict_scan_jax(
-            client, clock, length, o_client, o_clock, r_client, r_clock
-        )
-        return np.asarray(l), np.asarray(r), np.asarray(g)
-    p_client, p_clock = client[:-1], clock[:-1]
-    p_end = p_clock + length[:-1]
-    left = np.zeros(len(client), bool)
-    right = np.zeros(len(client), bool)
-    left[1:] = (
-        (o_client[1:] == p_client)
-        & (o_client[1:] >= 0)
-        & (o_clock[1:] >= p_clock)
-        & (o_clock[1:] < p_end)
-    )
-    right[1:] = (
-        (r_client[1:] == p_client)
-        & (r_client[1:] >= 0)
-        & (r_clock[1:] >= p_clock)
-        & (r_clock[1:] < p_end)
-    )
-    run_id = np.cumsum(~(left | right))
-    return left, right, run_id
-
-
-@profiled("plan_chunk_conflict_scan")
-@jax.jit
-def _chunk_conflict_scan_jax(doc_id, client, clock, length, o_client,
-                             o_clock, r_client, r_clock):
-    p_client, p_clock = client[:-1], clock[:-1]
-    p_end = p_clock + length[:-1]
-    same_doc = doc_id[1:] == doc_id[:-1]
-    left = (
-        same_doc
-        & (o_client[1:] == p_client)
-        & (o_client[1:] >= 0)
-        & (o_clock[1:] >= p_clock)
-        & (o_clock[1:] < p_end)
-    )
-    right = (
-        same_doc
-        & (r_client[1:] == p_client)
-        & (r_client[1:] >= 0)
-        & (r_clock[1:] >= p_clock)
-        & (r_clock[1:] < p_end)
-    )
-    pad = jnp.zeros(1, bool)
-    left = jnp.concatenate([pad, left])
-    right = jnp.concatenate([pad, right])
-    run_id = jnp.cumsum(~(left | right))
-    return left, right, run_id
-
-
-def plan_chunk_conflict_scan(doc_id, client, clock, length, o_client,
-                             o_clock, r_client, r_clock,
-                             backend: str = "np"):
-    """Doc-aware twin of :func:`plan_conflict_scan` for whole-chunk
-    planning (ISSUE 15): one scan over the doc-major concatenation of
-    every cold doc's flush batch.  ``doc_id`` breaks chains at doc
-    boundaries so a run can never span two documents — the rest of the
-    semantics match the per-doc kernel exactly."""
-    if backend == "jax":
-        l, r, g = _chunk_conflict_scan_jax(
-            doc_id, client, clock, length, o_client, o_clock,
-            r_client, r_clock
-        )
-        return np.asarray(l), np.asarray(r), np.asarray(g)
-    p_client, p_clock = client[:-1], clock[:-1]
-    p_end = p_clock + length[:-1]
-    same_doc = doc_id[1:] == doc_id[:-1]
-    left = np.zeros(len(client), bool)
-    right = np.zeros(len(client), bool)
-    left[1:] = (
-        same_doc
-        & (o_client[1:] == p_client)
-        & (o_client[1:] >= 0)
-        & (o_clock[1:] >= p_clock)
-        & (o_clock[1:] < p_end)
-    )
-    right[1:] = (
-        same_doc
-        & (r_client[1:] == p_client)
-        & (r_client[1:] >= 0)
-        & (r_clock[1:] >= p_clock)
-        & (r_clock[1:] < p_end)
-    )
-    run_id = np.cumsum(~(left | right))
-    return left, right, run_id
-
-
-# ---------------------------------------------------------------------------
-# export / sync kernels
+# export: the one program that reads the tables back
 # ---------------------------------------------------------------------------
 
 
@@ -447,39 +274,3 @@ def list_ranks(right_link, valid):
 
 
 list_ranks = profiled("list_ranks")(jax.jit(list_ranks))
-
-
-@profiled("state_vector_kernel")
-@functools.partial(jax.jit, static_argnums=(2,))
-def state_vector_kernel(row_slot, row_end, n_slots):
-    """Dense per-doc state vectors: sv[b, slot] = max(clock+len) over rows —
-    the segment-max recast of getStateVector (StructStore.js:49-56).
-
-    row_slot: [B, N] i32 (NULL for unused rows), row_end: [B, N] i32.
-    """
-    seg = jnp.where(row_slot >= 0, row_slot, n_slots)
-    f = jax.vmap(
-        lambda s, e: jax.ops.segment_max(
-            e, s, num_segments=n_slots + 1, indices_are_sorted=False
-        )
-    )
-    sv = f(seg, row_end)
-    sv = jnp.maximum(sv, 0)
-    return sv[:, :n_slots]
-
-
-@profiled("diff_mask_kernel")
-@jax.jit
-def diff_mask_kernel(row_slot, row_clock, row_end, sv):
-    """Rows (or row suffixes) missing from a remote state vector: the
-    columnar filter of writeClientsStructs (encoding.js:94-116).
-
-    Returns (needed[B,N] bool, offset[B,N] i32): offset>0 means the row must
-    be written from that element offset (the partial-first-struct rule,
-    encoding.js:71-84).
-    """
-    safe_slot = jnp.where(row_slot >= 0, row_slot, 0)
-    remote = jnp.take_along_axis(sv, safe_slot, axis=1)
-    needed = (row_slot >= 0) & (row_end > remote)
-    offset = jnp.clip(remote - row_clock, 0, None)
-    return needed, jnp.where(needed, offset, 0)

@@ -60,17 +60,6 @@ def native_plan_available() -> bool:
     return not os.environ.get("YTPU_NO_NATIVE_PLAN") and load() is not None
 
 
-def _sync_plan_segment(lib) -> None:
-    """Mirror the YTPU_PLAN_SEGMENT knob into the core's emit_row gate
-    so the ``off`` A/B lane also disables the native chain-run anchor
-    adoption (ISSUE 15)."""
-    from . import segment_planner
-
-    lib.ymx_set_plan_segment(
-        0 if segment_planner.plan_segment_mode() == "off" else 1
-    )
-
-
 def plan_segment_stats() -> tuple[int, int]:
     """Cumulative (chain-run adoptions, fragment-search lookups) across
     every native prepare in the process; callers diff around a flush.
@@ -314,7 +303,6 @@ class NativeMirror:
 
     def prepare_step(self) -> NativePlan:
         lib, h = self._lib, self._h
-        _sync_plan_segment(lib)
         staged, ids, v2s = self._stage_bufs()
         counts = np.zeros(16, np.int64)
         rc = lib.ymx_prepare(
@@ -813,7 +801,6 @@ def prepare_many(work, want_sched: bool = True, obs=None):
     n = len(work)
     lib = work[0][1]._lib
     with span("ytpu.plan.stage"):
-        _sync_plan_segment(lib)
         handles = (ctypes.c_void_p * n)()
         buf_ofs = np.zeros(n + 1, np.int64)
         # batched staging: ONE native call registers every staged buffer.

@@ -166,10 +166,10 @@ _ENTRIES_G = _reg.gauge(
 _BYTES_G = _reg.gauge(
     "ytpu_plan_cache_bytes", "Approximate host bytes held by the plan cache"
 )
-# segment-planner families (ISSUE 15): the device-authoritative cold
-# planner partitions every flush batch into a fast set (integrated
-# straight from device-computed ranks) and a conflict residue (the only
-# structs handed to the sequential YATA walk, now a fallback)
+# segment-planner families (ISSUE 15): a planner partitions every flush
+# batch into a fast set (chained runs, integrated straight from their
+# ranks) and a conflict residue (the only structs handed to the
+# sequential YATA walk)
 _SEG_FAST = _reg.counter(
     "ytpu_plan_segment_fast_total",
     "Structs integrated directly from segment-planner ranks (no "
@@ -178,11 +178,6 @@ _SEG_FAST = _reg.counter(
 _SEG_RESIDUE = _reg.counter(
     "ytpu_plan_segment_residue_total",
     "Conflict-residue structs handed to the sequential YATA fallback",
-)
-_SEG_CHUNKS = _reg.counter(
-    "ytpu_plan_segment_chunks_total",
-    "Whole-chunk segment-planner invocations (cold docs co-planned in "
-    "one batched kernel call)",
 )
 _SEG_SNAP_SKIP = _reg.counter(
     "ytpu_plan_segment_snapshot_reuse_total",
@@ -221,10 +216,6 @@ def note_segment(fast: int, residue: int) -> None:
         _SEG_FAST.inc(fast)
     if residue:
         _SEG_RESIDUE.inc(residue)
-
-
-def note_segment_chunk() -> None:
-    _SEG_CHUNKS.inc()
 
 
 def note_snapshot_reuse() -> None:

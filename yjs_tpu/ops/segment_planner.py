@@ -1,34 +1,21 @@
-"""Device-authoritative cold planning (ISSUE 15).
+"""The Python planner's segment pass: its fast set (ISSUE 9, ISSUE 15).
 
-The PR 9 segment-sorted kernels resolved anchors as verified *hints*
-feeding the sequential host walk.  This module promotes them to the
-authoritative cold planner:
+``DocMirror`` (``ops/columns.py``) is the planner of a host without a
+compiler and the reference the native core is held to.  Before it walks
+a room's flush batch struct by struct it makes one NumPy pass over the
+(client, clock)-sorted batch:
 
-- one conflict scan over the (doc, client, clock)-sorted flush batch
-  detects chained runs (typing runs, prepend storms) — the device rank
-  of each chained struct IS its placement, no per-struct walk;
-- one composed-key searchsorted resolves every remaining anchor in the
-  whole flush chunk at once (all cold docs co-planned in a single
-  batched kernel call, sharded over the doc mesh via ``shard_map`` when
-  the engine runs meshed);
-- the structs the scan cannot chain form the *conflict residue* — the
-  only structs handed to the sequential YATA walk, now a fallback.
+- one conflict scan detects chained runs (typing runs, prepend storms):
+  the rank of each chained struct IS its placement, no per-struct walk;
+- one composed-key searchsorted resolves the remaining anchors against
+  the slot-major snapshot of the fragment index;
+- the structs the scan cannot chain form the *conflict residue*, the
+  only structs handed to the sequential YATA walk.
 
-Modes (``YTPU_PLAN_SEGMENT``):
-
-========  ==================================================
-device    default: whole-chunk planning on the jitted kernels,
-          sharded over the doc mesh when one is configured
-np        per-doc planning on the NumPy kernel twins
-jax       per-doc planning on the jitted kernels
-off       pure sequential host walk (the A/B lane)
-========  ==================================================
-
-Donation safety: every array this module returns is freshly allocated
-host memory (``np.asarray`` copies of kernel outputs, ``np.full``
-pads) — never a view of the engine's donated column tables, so a plan
-outliving its flush can never alias a buffer the device has since
-repurposed.
+Hints are *candidates*, not answers: they are verified against the live
+columns, and the walk falls back to its bisect on any miss, so a wrong
+hint can never change placement.  Every array this module returns is
+freshly allocated host memory.
 
 Monotone-run snapshot reuse (ISSUE 15 bugfix): when the conflict scan
 chains all but a handful of anchors (pure head-prepend / typing runs),
@@ -41,18 +28,11 @@ already clock-sorted per slot), skipping the snapshot entirely.
 
 from __future__ import annotations
 
-import functools
-import os
-
 import numpy as np
 
-from . import kernels
 from . import plan_cache as _pc
 
 NULL = -1  # must match yjs_tpu.ops.columns.NULL
-
-MODES = ("device", "np", "jax", "off")
-_DEFAULT_MODE = "device"
 
 # at or below this many unresolved anchors the planner reuses the
 # per-slot sorted fragment segments directly (caller-side bisect per
@@ -61,30 +41,6 @@ SNAPSHOT_SKIP_MAX = 8
 
 # a chained run shorter than this is not worth bulk integration
 MIN_RUN = 4
-
-
-def plan_segment_mode() -> str:
-    """Resolve ``YTPU_PLAN_SEGMENT`` to a known mode (default: device)."""
-    mode = os.environ.get("YTPU_PLAN_SEGMENT", _DEFAULT_MODE)
-    return mode if mode in MODES else _DEFAULT_MODE
-
-
-def _bucket_pow2(n: int, minimum: int = 64) -> int:
-    """Next power-of-two lane width >= n: query/snapshot lengths are
-    unique per chunk, so jitted kernel shapes must quantize or every
-    flush retraces."""
-    b = minimum
-    while b < n:
-        b <<= 1
-    return b
-
-
-def _pad_pow2(arr: np.ndarray, n_pad: int, fill) -> np.ndarray:
-    """``arr`` padded to the bucketed length with ``fill`` (fresh
-    allocation — never a view of caller memory)."""
-    out = np.full(n_pad, fill, arr.dtype)
-    out[: arr.shape[0]] = arr
-    return out
 
 
 class SegmentQueries:
@@ -106,7 +62,7 @@ class SegmentQueries:
 
 
 class SegmentPlan:
-    """One doc's device-planned cold-path answer.
+    """One doc's segment pass.
 
     ``hint_l`` / ``hint_r`` are verified candidate anchor rows
     (``NULL`` = resolve by bisect) or ``None`` when the snapshot was
@@ -124,34 +80,48 @@ class SegmentPlan:
     )
 
 
-def _scan_doc(q: SegmentQueries, backend: str):
-    """Per-doc conflict scan (bucketed when jitted)."""
-    if backend != "jax":
-        return kernels.plan_conflict_scan(
-            q.client, q.clock, q.length, q.o_cl, q.o_ck, q.r_cl, q.r_ck,
-            backend="np",
+def plan_anchor_lookup(flat_slot, flat_clock, q_slot, q_clock):
+    """Candidate fragment-index position for each (q_slot, q_clock): the
+    last fragment starting at or before the queried clock, or -1.  ONE
+    searchsorted over the slot-major fragment index: per-slot runs are
+    clock-sorted, so the composed key ``slot * base + clock`` is globally
+    sorted; an invalid query (slot < 0) maps below every real key.  The
+    caller must verify slot match + containment before trusting it."""
+    base = int(max(flat_clock.max() if flat_clock.size else 0,
+                   q_clock.max() if q_clock.size else 0)) + 2
+    flat_key = flat_slot * base + flat_clock
+    q_key = np.where(q_slot >= 0, q_slot * base + q_clock, -1)
+    return np.searchsorted(flat_key, q_key, side="right") - 1
+
+
+def plan_conflict_scan(client, clock, length, o_client, o_clock,
+                       r_client, r_clock):
+    """Chain masks over a clock-sorted flush batch: ``left[j]`` /
+    ``right[j]`` mean ref j's origin / rightOrigin lies inside ref j-1's
+    id range (so its anchor row IS ref j-1's row); ``run_id`` groups the
+    maximal chained (conflict-free) runs."""
+    p_client, p_clock = client[:-1], clock[:-1]
+    p_end = p_clock + length[:-1]
+
+    def inside_previous(a_client, a_clock):
+        out = np.zeros(len(client), bool)
+        out[1:] = (
+            (a_client[1:] == p_client)
+            & (a_client[1:] >= 0)
+            & (a_clock[1:] >= p_clock)
+            & (a_clock[1:] < p_end)
         )
-    nb = _bucket_pow2(q.n)
-    l, r, g = kernels._conflict_scan_jax(
-        _pad_pow2(q.client, nb, -1),
-        _pad_pow2(q.clock, nb, 0),
-        _pad_pow2(q.length, nb, 0),
-        _pad_pow2(q.o_cl, nb, -1),
-        _pad_pow2(q.o_ck, nb, 0),
-        _pad_pow2(q.r_cl, nb, -1),
-        _pad_pow2(q.r_ck, nb, 0),
-    )
-    n = q.n
-    return (
-        np.asarray(l)[:n],
-        np.asarray(r)[:n],
-        np.asarray(g)[:n],
-    )
+        return out
+
+    left = inside_previous(o_client, o_clock)
+    right = inside_previous(r_client, r_clock)
+    run_id = np.cumsum(~(left | right))
+    return left, right, run_id
 
 
 def _chain_spans(q: SegmentQueries, chain_l, chain_r, run_id):
     """Maximal single-direction chained spans eligible for bulk
-    integration straight from device ranks.
+    integration straight from their ranks.
 
     A span ``(s, e, d)`` promises: refs ``s+1 .. e-1`` chain purely in
     direction ``d`` onto their predecessor, are non-GC non-delete
@@ -234,20 +204,17 @@ def _needed(q: SegmentQueries, chain_l, chain_r) -> int:
     return need_l + need_r
 
 
-def plan_doc(q: SegmentQueries | None, mode: str | None = None,
-             snapshot=None) -> SegmentPlan | None:
+def plan_doc(q: SegmentQueries | None, snapshot=None) -> SegmentPlan | None:
     """Plan one doc's flush batch.  ``snapshot`` is a zero-arg callable
     returning ``(flat_slot, flat_clock, flat_row, row_len, n_slots)``
     (the slot-major fragment-index snapshot); it is only invoked when
     the chain masks leave enough anchors unresolved to justify the
     rebuild."""
-    if q is None:
+    if q is None or q.n < MIN_RUN:
         return None
-    mode = mode or plan_segment_mode()
-    if mode == "off" or q.n < MIN_RUN:
-        return None
-    backend = "np" if mode == "np" else "jax"
-    chain_l, chain_r, run_id = _scan_doc(q, backend)
+    chain_l, chain_r, run_id = plan_conflict_scan(
+        q.client, q.clock, q.length, q.o_cl, q.o_ck, q.r_cl, q.r_ck
+    )
     plan = SegmentPlan()
     plan.chain_l, plan.chain_r, plan.run_id = chain_l, chain_r, run_id
     plan.spans = _chain_spans(q, chain_l, chain_r, run_id)
@@ -262,193 +229,9 @@ def plan_doc(q: SegmentQueries | None, mode: str | None = None,
     flat_slot, flat_clock, flat_row, row_len, _n_slots = snapshot()
     q_slot = np.concatenate([q.o_slot, q.r_slot])
     q_ck = np.concatenate([q.o_ck, q.r_ck])
-    if backend == "jax":
-        fk, qk = kernels._compose_keys(flat_slot, flat_clock, q_slot, q_ck)
-        fb = _bucket_pow2(max(1, fk.shape[0]))
-        nb = _bucket_pow2(qk.shape[0])
-        cand = np.asarray(
-            kernels._anchor_lookup_jax(
-                _pad_pow2(fk, fb, np.iinfo(np.int64).max),
-                _pad_pow2(qk, nb, -1),
-            )
-        )[: 2 * q.n]
-    else:
-        cand = kernels.plan_anchor_lookup(
-            flat_slot, flat_clock, q_slot, q_ck, backend="np"
-        )
+    cand = plan_anchor_lookup(flat_slot, flat_clock, q_slot, q_ck)
     hint = _verify_hints(
         cand, q_slot, q_ck, flat_slot, flat_clock, flat_row, row_len
     )
     plan.hint_l, plan.hint_r = hint[: q.n], hint[q.n :]
     return plan
-
-
-@functools.lru_cache(maxsize=8)
-def _sharded_lookup(mesh, axis: str):
-    """Chunk anchor lookup sharded over the doc mesh: the query axis
-    splits across devices, the flat snapshot replicates (it is the
-    search *haystack* — every shard binary-searches its own query
-    block).  Follows the ``sharded_apply_plan`` idiom so the kernel
-    profiler attributes retraces/compiles the same way."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..obs.prof import profiled
-    from ..parallel.mesh import P, shard_map
-
-    def local(flat_key, q_key):
-        return jnp.searchsorted(flat_key, q_key, side="right") - 1
-
-    sharded = shard_map(
-        local, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis)
-    )
-    return profiled("plan_chunk_anchor_lookup")(jax.jit(sharded))
-
-
-def plan_chunk(items, mode: str | None = None, mesh=None):
-    """Plan a whole flush chunk of cold docs in one batched kernel pass.
-
-    ``items`` is a list of ``(queries, snapshot)`` pairs (either may be
-    ``None``); returns a same-length list of :class:`SegmentPlan` (or
-    ``None``) per doc.  Doc boundaries break chains via the doc-aware
-    conflict scan; anchor lookups for every doc that still needs its
-    snapshot compose ``(doc, slot, clock)`` into one key space so a
-    single searchsorted — sharded over ``mesh`` when given — resolves
-    the entire chunk.
-    """
-    mode = mode or plan_segment_mode()
-    out = [None] * len(items)
-    if mode == "off":
-        return out
-    live = [
-        i for i, (q, _s) in enumerate(items)
-        if q is not None and q.n >= MIN_RUN
-    ]
-    if not live:
-        return out
-    if mode != "device" or len(live) == 1:
-        for i in live:
-            q, snap = items[i]
-            out[i] = plan_doc(q, mode=mode, snapshot=snap)
-        return out
-    _pc.note_segment_chunk()
-    # ---- one conflict scan over the doc-major concatenation ----------
-    qs = [items[i][0] for i in live]
-    ns = np.array([q.n for q in qs], np.int64)
-    doc_id = np.repeat(np.arange(len(qs), dtype=np.int64), ns)
-    cat = {
-        name: np.concatenate([getattr(q, name) for q in qs])
-        for name in ("client", "clock", "length", "o_cl", "o_ck",
-                     "r_cl", "r_ck")
-    }
-    total_q = int(ns.sum())
-    nb = _bucket_pow2(total_q)
-    l, r, g = kernels._chunk_conflict_scan_jax(
-        _pad_pow2(doc_id, nb, -1),
-        _pad_pow2(cat["client"], nb, -1),
-        _pad_pow2(cat["clock"], nb, 0),
-        _pad_pow2(cat["length"], nb, 0),
-        _pad_pow2(cat["o_cl"], nb, -1),
-        _pad_pow2(cat["o_ck"], nb, 0),
-        _pad_pow2(cat["r_cl"], nb, -1),
-        _pad_pow2(cat["r_ck"], nb, 0),
-    )
-    l = np.asarray(l)[:total_q]
-    r = np.asarray(r)[:total_q]
-    g = np.asarray(g)[:total_q]
-    offs = np.concatenate([[0], np.cumsum(ns)])
-    for k, i in enumerate(live):
-        q = qs[k]
-        plan = SegmentPlan()
-        sl = slice(int(offs[k]), int(offs[k + 1]))
-        plan.chain_l = l[sl].copy()
-        plan.chain_r = r[sl].copy()
-        plan.run_id = g[sl].copy()
-        plan.spans = _chain_spans(q, plan.chain_l, plan.chain_r, plan.run_id)
-        plan.hint_l = plan.hint_r = None
-        plan.snapshot_reused = False
-        out[i] = plan
-    # ---- one composed-key lookup for every doc still needing one -----
-    lookup = []
-    for k, i in enumerate(live):
-        q, snap = items[i]
-        if snap is None or _needed(q, out[i].chain_l, out[i].chain_r) \
-                <= SNAPSHOT_SKIP_MAX:
-            out[i].snapshot_reused = True
-            _pc.note_snapshot_reuse()
-            continue
-        lookup.append((k, i, snap()))
-    if not lookup:
-        return out
-    slot_base = 0
-    f_parts, qk_parts, meta = [], [], []
-    base_clock = 2
-    for _k, _i, (fs, fc, _fr, _rl, n_slots) in lookup:
-        if fc.shape[0]:
-            base_clock = max(base_clock, int(fc.max()) + 2)
-    for k, i, (fs, fc, fr, rl, n_slots) in lookup:
-        q = qs[k]
-        q_slot = np.concatenate([q.o_slot, q.r_slot])
-        q_ck = np.concatenate([q.o_ck, q.r_ck])
-        base_clock = max(
-            base_clock, (int(q_ck.max()) + 2) if q_ck.shape[0] else 2
-        )
-        f_parts.append((fs + slot_base, fc))
-        qk_parts.append(
-            (np.where(q_slot >= 0, q_slot + slot_base, -1), q_ck, q_slot)
-        )
-        meta.append((k, i, fr, rl, q_ck))
-        slot_base += n_slots
-    flat_gkey = np.concatenate(
-        [gs * base_clock + fc for gs, fc in f_parts]
-    ) if f_parts else np.empty(0, np.int64)
-    q_gkey = np.concatenate(
-        [np.where(gs >= 0, gs * base_clock + ck, -1)
-         for gs, ck, _ls in qk_parts]
-    )
-    fb = _bucket_pow2(max(1, flat_gkey.shape[0]))
-    qb = _bucket_pow2(q_gkey.shape[0])
-    fk_pad = _pad_pow2(flat_gkey, fb, np.iinfo(np.int64).max)
-    if mesh is not None and mesh.devices.size > 1:
-        axis = mesh.axis_names[0]
-        size = int(mesh.shape[axis])
-        if qb % size:
-            qb = ((qb + size - 1) // size) * size
-        qk_pad = _pad_pow2(q_gkey, qb, -1)
-        cand_all = np.asarray(_sharded_lookup(mesh, axis)(fk_pad, qk_pad))
-    else:
-        qk_pad = _pad_pow2(q_gkey, qb, -1)
-        cand_all = np.asarray(kernels._anchor_lookup_jax(fk_pad, qk_pad))
-    cand_all = cand_all[: q_gkey.shape[0]]
-    # ---- split hints back per doc ------------------------------------
-    flat_rows = np.concatenate([fr for _k, _i, fr, _rl, _q in meta]) \
-        if meta else np.empty(0, np.int64)
-    flat_slots_g = np.concatenate([gs for gs, _fc in f_parts]) \
-        if f_parts else np.empty(0, np.int64)
-    flat_clocks = np.concatenate([fc for _gs, fc in f_parts]) \
-        if f_parts else np.empty(0, np.int64)
-    qoff = 0
-    # per-doc row_len tables differ, so verify per doc over its block
-    foff = 0
-    for (k, i, fr, rl, _q_ck), (gs_q, q_ck, _ls) in zip(meta, qk_parts):
-        q = qs[k]
-        nq = 2 * q.n
-        nf = fr.shape[0]
-        # global candidate -> doc-local index; a query whose key sorts
-        # before this doc's flat block lands in a previous doc's region
-        # (cand < 0 after the shift) and verifies to NULL
-        cand = cand_all[qoff : qoff + nq] - foff
-        hint = _verify_hints(
-            cand,
-            gs_q,
-            q_ck,
-            flat_slots_g[foff : foff + nf],
-            flat_clocks[foff : foff + nf],
-            fr,
-            rl,
-        )
-        out[i].hint_l = hint[: q.n]
-        out[i].hint_r = hint[q.n :]
-        qoff += nq
-        foff += nf
-    return out

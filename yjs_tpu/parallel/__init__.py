@@ -3,5 +3,4 @@
 from .mesh import (  # noqa: F401
     doc_mesh,
     shard_meshes,
-    sharded_state_vectors,
 )

@@ -3,15 +3,11 @@
 The reference has no cross-process parallelism — docs are independent, so the
 TPU-native scaling story (SURVEY.md §2 parallelism table) is: shard the *doc
 batch* axis across the device mesh with ``shard_map``; ICI collectives are
-used for global metrics and state-vector gathers, not for integration itself
-(no cross-doc communication exists to translate).
+used for global metrics, not for integration itself (no cross-doc
+communication exists to translate).
 
-Axes:
-- ``docs``: the data-parallel axis — every [B, ...] array is sharded on its
-  leading dim.
-- ``rows`` (optional, 2D mesh): a sequence-parallel-style axis over the item
-  table for reduction kernels (state vectors via per-shard segment-max +
-  ``pmax``), the long-document analogue of sequence parallelism.
+One axis, ``docs``: the data-parallel axis — every [B, ...] array is sharded
+on its leading dim.
 """
 
 from __future__ import annotations
@@ -164,33 +160,4 @@ def sharded_load_rows(mesh: Mesh, axis: str):
     )
     return profiled("sharded_load_rows")(
         jax.jit(sharded, donate_argnums=(0,))
-    )
-
-
-def sharded_state_vectors(mesh: Mesh, n_slots: int, axis: str = "docs", row_axis: str | None = None):
-    """State vectors over a sharded doc batch; with a 2-D mesh the item-table
-    axis is also sharded and reduced with pmax over ICI (the segment-max of
-    StructStore.getStateVector, reference StructStore.js:49-56)."""
-
-    def local_sv(row_slot, row_end):
-        sv = kernels.state_vector_kernel(row_slot, row_end, n_slots)
-        if row_axis is not None:
-            sv = lax.pmax(sv, row_axis)
-        return sv
-
-    if row_axis is None:
-        in_spec = P(axis)
-        out_spec = P(axis)
-    else:
-        in_spec = P(axis, row_axis)
-        out_spec = P(axis)
-    return profiled("sharded_state_vectors")(
-        jax.jit(
-            shard_map(
-                local_sv,
-                mesh=mesh,
-                in_specs=(in_spec, in_spec),
-                out_specs=out_spec,
-            )
-        )
     )

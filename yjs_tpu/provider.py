@@ -941,7 +941,7 @@ class TpuProvider:
         ``engine.sync_step2_batch``, which on the default path encodes
         every diff from its room's native host mirror in one native call
         into one arena (its docstring says which requests take another
-        path, and when the device's ``diff_mask_kernel`` runs).
+        path).
         Returns the framed step-2 reply of each message, at its place.
 
         Frame by frame the contract is ``handle_sync_message``'s: a frame
@@ -1398,12 +1398,12 @@ class TpuProvider:
         DEFENSIVE COPY (mutating the returned dict cannot corrupt the
         engine's flush history; before this was the live dict).
 
-        The key set is stable across every flush mode (apply / levels /
-        seq / ``YTPU_NO_NATIVE_PLAN``) and is exactly
+        The key set is the same under either planner
+        (``YTPU_NO_NATIVE_PLAN``) and is exactly
         ``yjs_tpu.obs.FLUSH_METRICS_SCHEMA``: counts ``n_docs_flushed``,
         ``n_demoted``, ``n_rolled_back``, ``n_fallback_docs``, ``n_rows_max``,
-        ``n_sched_entries``, ``n_levels``, ``level_width``,
-        ``n_pending_docs``, ``pending_depth``, ``plan_threads``; the
+        ``n_sched_entries``, ``n_pending_docs``, ``pending_depth``,
+        ``plan_threads``; the
         ``schedule_occupancy`` ratio; and the per-phase second timers
         ``t_compact_s``, ``t_plan_s``, ``t_pack_s``, ``t_dispatch_s``,
         ``t_emit_s``, ``t_total_s``.  ``None`` before the first flush."""
