@@ -25,7 +25,9 @@ if str(ROOT) not in sys.path:  # the benchmark's reader, as tests/bench does
 
 import yjs_tpu as Y
 from yjs_tpu.admission import AdmissionConfig, AdmissionRejected
-from yjs_tpu.obs.trace import FORMAT_SPANS, LEAF_SPANS, RECOVER_SPANS
+from yjs_tpu.obs.trace import (
+    FORMAT_SPANS, LEAF_SPANS, PACK_SPANS, RECOVER_SPANS,
+)
 from yjs_tpu.persistence import WalConfig
 from yjs_tpu.provider import TpuProvider
 
@@ -40,6 +42,8 @@ PARENTS = {
     **LEAF_SPANS,
     # the look of the formatting clean-up, once a flush (PR 40)
     **FORMAT_SPANS,
+    # the pack phase by write path, at most once a chunk (PR 46)
+    **PACK_SPANS,
 }
 # spans the ring held before this PR (the journal's record of an append
 # among them, now a span the journal opens itself), spans it holds from

@@ -1227,10 +1227,18 @@ def test_a_mesh_reuses_a_program_whose_lanes_cover_the_chunk():
     assert one._covering_key((208, 64, 9, 64)) == (256, 64, 16, 64)
     assert one._covering_key((272, 72, 8, 64)) == (512, 128, 8, 64)
     assert one._covering_key((260, 64, 8, 64)) == (512, 128, 8, 64)
-    # a bulk load's rooms give the same key every time: kept as it is
-    bulk = (8192, 64, 8, 64)
-    assert one._covering_key(bulk) == bulk and bulk not in one._mesh_keys
-    assert one._covering_key((8000, 64, 8, 64)) == (8000, 64, 8, 64)
+    # a bulk merge's links and deletes give the same widths every time
+    # and keep them; its list heads, a Poisson count of some dozens, are
+    # given six times their root of room (PR 46), and an issued key
+    # covers at a quarter more lanes
+    bulk = (8192, 64, 30, 64)
+    assert one._covering_key(bulk) == (8192, 64, 60, 64)
+    for merge in (bulk, (8192, 64, 36, 64), (8000, 64, 8, 64)):
+        assert one._covering_key(merge) == (8192, 64, 60, 64)
+    # wider in a width, or too narrow for the padding: a key of its own
+    assert one._covering_key((9216, 64, 30, 64)) == (9216, 64, 60, 64)
+    assert one._covering_key((8192, 64, 64, 64)) == (8192, 64, 112, 64)
+    assert one._covering_key((6144, 4608, 8, 64)) == (6144, 4608, 20, 64)
 
 
 class TestChunkedFlushStress:

@@ -114,10 +114,11 @@ struct Plan {
   // rows it added, and of those the rows whose parent is a type item,
   // format rows, rows under a parentSub and type rows; segments before
   // it; map entries it deleted (a later writer's delete set, or the
-  // last-writer-wins pass); format rows it deleted
+  // last-writer-wins pass); format rows it deleted; rows the conflict
+  // scan of list_insert stepped over (a sibling in the same gap each)
   int64_t rows_new = 0, rows_nested = 0, rows_format = 0;
   int64_t rows_attr = 0, rows_type = 0, segs_before = 0;
-  int64_t lww_overwritten = 0, format_deleted = 0;
+  int64_t lww_overwritten = 0, format_deleted = 0, conflict_steps = 0;
   // the mirror held no row before this step: with dense links, every
   // cell the room has ever written is in this plan (plan_shape)
   bool from_empty = false;
@@ -131,7 +132,7 @@ struct Plan {
     n_rows = 0;
     rows_new = rows_nested = rows_format = 0;
     rows_attr = rows_type = segs_before = 0;
-    lww_overwritten = format_deleted = 0;
+    lww_overwritten = format_deleted = conflict_steps = 0;
     splits.clear();
     sched.clear();
     delete_rows.clear();
@@ -656,6 +657,7 @@ struct Mirror {
       uint64_t wid = ++walk_id;
       uint64_t idx = 0, conf_start = 0;
       while (o != kNull && o != right_row) {
+        plan.conflict_steps++;
         walk_mark[(size_t)o] = wid;
         walk_order[(size_t)o] = idx++;
         if (row_origin_eq(row, o)) {
@@ -2706,7 +2708,8 @@ static int64_t plan_shape(const Mirror* m) {
 static void plan_kind_counts(const Mirror* m, int64_t* c) {
   auto f = [](int64_t v) { return v < 0 ? 0 : (v > 0x1FFFFF ? 0x1FFFFF : v); };
   const Plan& p = m->plan;
-  c[3] = f(p.rows_type) | (f(p.format_deleted) << 21);
+  c[3] = f(p.rows_type) | (f(p.format_deleted) << 21) |
+         (f(p.conflict_steps) << 42);
   c[4] = f(p.rows_new) | (f(p.rows_nested) << 21) | (f(p.rows_format) << 42);
   c[5] = f(p.rows_attr) | (f(m->n_segs() - p.segs_before) << 21) |
          (f(p.lww_overwritten) << 42);

@@ -192,6 +192,19 @@ FLUSH_METRICS_SCHEMA: dict = {
     # that held rows
     "rooms_row_loaded": 0,
     "row_block_bytes": 0,
+    # which of the two write paths a flush's links took (their sum is
+    # n_sched_entries): the element lanes (rooms that held rows) or the
+    # row blocks (rooms loaded whole), and the lanes of the keys the
+    # flush dispatched, summed over chunks and shards: lane_links over
+    # lanes_dispatched is how full the lane keys were, padding, list
+    # heads and deletes counted against them
+    "lane_links": 0,
+    "row_links": 0,
+    "lanes_dispatched": 0,
+    # rows the native planner's conflict scan stepped over (list_insert:
+    # a sibling in the same gap each); 0 from the Python planner, which
+    # does not count its walk
+    "conflict_steps": 0,
     # bytes the compactions and hydrations since the previous flush
     # staged for scatter_rows (host allocation = transfer = device
     # writes), and the bytes of rebuilt rows the rooms in those blocks

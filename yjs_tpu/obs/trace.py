@@ -91,6 +91,20 @@ RECOVER_SPANS = {
     "ytpu.recover.queue": "ytpu.recover",
 }
 
+# The pack phase by write path (PR 46), each at most once a chunk inside
+# that chunk's ``ytpu.pack``: ``rows`` stages the rooms loaded whole into
+# empty slots as row blocks (``BatchEngine._stage_row_loads``), ``lanes``
+# sizes, keys and packs the element lanes of the rooms that held rows
+# (``_covering_key``, ``pack_apply_lanes``).  A chunk that holds only one
+# kind of room opens only that span; what is left of ``ytpu.pack`` is the
+# capacity look and the staging-slot wait.  tests/test_span_clock.py
+# holds the program to these names and the benchmark's
+# ``pack_lanes_share`` reads ``ytpu.pack.lanes``.
+PACK_SPANS = {
+    "ytpu.pack.rows": "ytpu.pack",
+    "ytpu.pack.lanes": "ytpu.pack",
+}
+
 _NO_SPAN = contextlib.nullcontext()
 
 

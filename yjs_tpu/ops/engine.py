@@ -14,6 +14,7 @@ Provider gating seam.
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 import time
 from types import SimpleNamespace
@@ -100,7 +101,7 @@ _SERVED_WIDTH = 4096
 
 _KIND_KEYS = (
     "rows_planned", "rows_nested", "rows_format", "rows_attr", "rows_type",
-    "segs_created", "lww_overwritten", "format_deleted",
+    "segs_created", "lww_overwritten", "format_deleted", "conflict_steps",
 )
 _KIND_BITS = 21  # plancore.cpp plan_kind_counts: three fields a word
 
@@ -119,6 +120,7 @@ def _kind_counts(counts: np.ndarray) -> dict:
         "segs_created": (c5 >> _KIND_BITS) & mask,
         "lww_overwritten": (c5 >> 2 * _KIND_BITS) & mask,
         "format_deleted": (c3 >> _KIND_BITS) & mask,
+        "conflict_steps": (c3 >> 2 * _KIND_BITS) & mask,
     }
 
 
@@ -144,6 +146,7 @@ def _kind_counts_py(m, p) -> dict:
         "segs_created": m.n_segs - p.segs_before,
         "lww_overwritten": p.lww_overwritten,
         "format_deleted": sum(1 for r in p.delete_rows if ref[int(r)] == 6),
+        "conflict_steps": 0,  # the Python walk keeps no count
     }
 
 
@@ -1930,6 +1933,9 @@ class BatchEngine:
             "n_rows_max": max_rows_all,
             # real links, whichever way they went: lanes or row blocks
             "n_sched_entries": n_dense + n_sparse + row_links,
+            "lane_links": n_dense + n_sparse,
+            "row_links": row_links,
+            "lanes_dispatched": lanes_padded_tot,
             "rooms_row_loaded": rooms_row_loaded,
             "row_block_bytes": row_block_bytes,
             # bulk path: fraction of what was staged that is real: of
@@ -2111,8 +2117,21 @@ class BatchEngine:
         same rooms wander over a bucket's edge from one flush to the
         next: an issued key covers at no more than a quarter more lanes
         (two steps of ``_bucket_lanes``).  On one device the same rooms
-        always give the same key, so a bulk load keeps its exact key;
-        but a served flush (``_SERVED_LANES`` lanes or fewer: some dozens
+        always give the same key, and since PR 44 a room loaded whole
+        goes as a row block and has no key at all: a bulk flush's lanes
+        (more than ``_SERVED_LANES``) are returning sessions' offline
+        histories merged into rooms that hold rows.  A width is a sum
+        over the rooms of that flush, and the next flush of the kind
+        sums other histories.  Links and deletes, sums of thousands a
+        room, come out within half a percent of the last and keep their
+        step of ``_bucket_lanes``; the list heads (the inserts that
+        landed before a room's first row: 23 to 30 in ten such flushes,
+        5 to 9 in the flush after) scatter as a Poisson count does,
+        over several steps, and each new key is a program of 10-26 s to
+        compile at these widths.  So there too an issued key covers at
+        a quarter more lanes, and a key that has to be new leaves its
+        list heads six times their square root of room.
+        A served flush (``_SERVED_LANES`` lanes or fewer: some dozens
         of rooms' keystrokes) wanders too, in each of its four widths by
         itself (links with the characters typed, list heads with the
         nodes split, deletes with the backspaces), and the product of
@@ -2123,9 +2142,8 @@ class BatchEngine:
         if key in self._mesh_keys:
             return key
         lanes = sum(key)
-        if self.mesh is None:
-            if lanes > _SERVED_LANES:
-                return key
+        bulk = self.mesh is None and lanes > _SERVED_LANES
+        if self.mesh is None and not bulk:
             # a new width is a power of two: the widest flush a process
             # has met keeps being outdone by a lane or two for as long
             # as it runs, and every such record would be a program
@@ -2142,6 +2160,9 @@ class BatchEngine:
         ]
         if fits:
             return min(fits, key=sum)
+        if bulk:
+            k_h = key[2]
+            key = (*key[:2], _bucket_lanes(k_h + 6 * math.isqrt(k_h), 8), key[3])
         self._mesh_keys.add(key)
         return key
 
@@ -2223,15 +2244,26 @@ class BatchEngine:
                     right, deleted, starts, int(NULL),
                 )
 
-            loads = self._stage_row_loads(
-                doc_idx[rooms], counts[rooms][:, [0, 11, 12, 6, 13]],
-                b_loc, n_shards, fill_rows,
-            )
+            with self._phase_ctx("pack.rows"):
+                loads = self._stage_row_loads(
+                    doc_idx[rooms], counts[rooms][:, [0, 11, 12, 6, 13]],
+                    b_loc, n_shards, fill_rows,
+                )
             if whole.all():
                 return None, None, np.zeros(4, np.int64), max_rows, loads
             rest = np.flatnonzero(~whole)
             chunk_ok = [chunk_ok[j] for j in rest]
             counts, doc_idx = counts[rest], doc_idx[rest]
+        with self._phase_ctx("pack.lanes"):
+            slot, key, stats = self._stage_lanes_native(
+                chunk_ok, counts, doc_idx, b_loc, n_shards
+            )
+        return slot, key, stats, max_rows, loads
+
+    def _stage_lanes_native(self, chunk_ok, counts, doc_idx, b_loc, n_shards):
+        """The element lanes of the rooms of a native chunk that held
+        rows: sized, keyed and packed into a staging buffer.  Returns
+        ``(slot, key, stats)``."""
         oob_r = int(self._cap + 1)
         oob_s = int(self._seg_cap + 1)
         shard = doc_idx // b_loc
@@ -2267,7 +2299,7 @@ class BatchEngine:
             oob_r, oob_s, int(NULL), lane_dtype, out=slot.buf,
         )
         slot.buf = lanes
-        return slot, key, stats, max_rows, loads
+        return slot, key, stats
 
     def _pack_chunk_py(self, chunk_ok, b_loc, n_shards):
         """Python-mirror twin of :meth:`_pack_chunk_native`: bin one
@@ -2305,18 +2337,25 @@ class BatchEngine:
                         p.head_vals
                     )
 
-            loads = self._stage_row_loads(
-                np.asarray([i for i, _ in whole], np.int64),
-                np.asarray([
-                    (p.n_rows, self.mirrors[i].n_segs, len(p.link_rows),
-                     len(p.delete_rows), len(p.head_segs))
-                    for i, p in whole
-                ], np.int64),
-                b_loc, n_shards, fill_rows,
-            )
+            with self._phase_ctx("pack.rows"):
+                loads = self._stage_row_loads(
+                    np.asarray([i for i, _ in whole], np.int64),
+                    np.asarray([
+                        (p.n_rows, self.mirrors[i].n_segs, len(p.link_rows),
+                         len(p.delete_rows), len(p.head_segs))
+                        for i, p in whole
+                    ], np.int64),
+                    b_loc, n_shards, fill_rows,
+                )
             if len(whole) == len(chunk_ok):
                 return None, None, np.zeros(4, np.int64), max_rows, loads
             chunk_ok = [t for t, yes in zip(chunk_ok, is_whole) if not yes]
+        with self._phase_ctx("pack.lanes"):
+            slot, key, stats = self._stage_lanes_py(chunk_ok, b_loc, n_shards)
+        return slot, key, stats, max_rows, loads
+
+    def _stage_lanes_py(self, chunk_ok, b_loc, n_shards):
+        """Python-mirror twin of :meth:`_stage_lanes_native`."""
         oob_r = np.int32(self._cap + 1)
         counts = np.zeros((n_shards, 4, b_loc), np.int32)
         dense = [[] for _ in range(n_shards)]
@@ -2389,7 +2428,7 @@ class BatchEngine:
             o += 2 * k_h
             n_dels += fill(lanes[s, o : o + k_d], dl_r[s], oob_r)
         stats = np.asarray([n_dense, n_sparse, n_heads, n_dels], np.int64)
-        return slot, (k_dn, k_sp, k_h, k_d), stats, max_rows, loads
+        return slot, (k_dn, k_sp, k_h, k_d), stats
 
     @property
     def last_flush_metrics(self) -> dict | None:
