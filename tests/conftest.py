@@ -206,16 +206,38 @@ PINNED_TO_AN_EARLIER_TAIL = (
 )
 
 
+# A test of the benchmark's that pins a line of the run's log to what a
+# compaction staged for rooms it rebuilt to themselves: the tiny long-tail
+# cell's keystroke flush "stages the group in its three width classes".
+# From PR 48 a room with nothing to merge is not rebuilt, and that flush
+# stages no block.  The file is a ``benchmark`` PR's to edit (PERF.md §7);
+# ``tests/test_compact_skip.py`` runs the test's body with the line as the
+# program prints it now.
+PINNED_TO_A_REBUILD_OF_NOTHING = (
+    "test_longtail_cell.py::test_the_tiny_cell_is_correct_and_plans_every_room_cold",
+)
+
+XFAIL = (
+    (
+        PINNED_TO_AN_EARLIER_TAIL,
+        "pins BENCHMARK.json's tail as of its own PR; held against the "
+        "manifest less the later cells by tests/bench/test_crash_cell.py",
+    ),
+    (
+        PINNED_TO_A_REBUILD_OF_NOTHING,
+        "pins the blocks staged by a compaction that changed nothing; held "
+        "to what the flush stages now by tests/test_compact_skip.py",
+    ),
+)
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         # a parametrised test is pinned with all its cases
-        if item.nodeid.split("[", 1)[0].endswith(PINNED_TO_AN_EARLIER_TAIL):
-            item.add_marker(pytest.mark.xfail(
-                reason="pins BENCHMARK.json's tail as of its own PR; held "
-                "against the manifest less the later cells by "
-                "tests/bench/test_crash_cell.py",
-                strict=False,
-            ))
+        test = item.nodeid.split("[", 1)[0]
+        for pinned, reason in XFAIL:
+            if test.endswith(pinned):
+                item.add_marker(pytest.mark.xfail(reason=reason, strict=False))
 
 
 @pytest.hookimpl(hookwrapper=True)

@@ -150,13 +150,16 @@ def test_the_sets_are_the_walks_at_every_flush(
             fed.add(to)
 
     eng.on_update(listener)
-    hydrated, compacted, engaged = set(), 0, set()
+    hydrated, compacted, skipped, engaged = set(), 0, 0, set()
     for rnd in range(28):
         live = [i for i in range(n) if i not in eng.fallback]
         for i in rng.sample(live, rng.randint(0, 9)):
             rooms.type(i, rng.randint(1, 3))
         if rnd in (2, 3):
             rooms.type(5, 300, prepend=True)  # no two rows merge: it doubles
+            # typed in anywhere, three keystrokes in ten backspaces: it
+            # doubles too, and has deleted content for ``gc`` to drop
+            rooms.type(6, 300)
         if rnd % 3 == 0:
             rooms.out_of_order(rng.choice(live))
         for i in [i for i in rooms.late if rng.random() < 0.3]:
@@ -176,16 +179,25 @@ def test_the_sets_are_the_walks_at_every_flush(
         scan = scan_of_every_slot(eng)
         assert [i for i in sorted(eng._compact_look) if i in scan] == scan
         look = len(eng._compact_look)
+        # of the rooms that have doubled, those a rebuild would change
+        # (tests/test_compact_skip.py holds the answer to the rebuild)
+        rebuilt = [
+            i for i in scan
+            if not isinstance(eng.mirrors[i], NativeMirror)
+            or NativeMirror.compact_changes_many([eng.mirrors[i]], eng.gc)[0]
+        ]
         eng.last_compaction = None
         eng.flush()
         m = eng.last_flush_metrics
         assert m["rooms_dirty"] == len(want) + len(hydrated - set(want))
         assert m["rooms_compact_looked"] == look
-        if scan:
-            assert [s["doc"] for s in eng.last_compaction] == scan
-            compacted += len(scan)
+        assert m["rooms_compact_skipped"] == len(scan) - len(rebuilt)
+        if rebuilt:
+            assert [s["doc"] for s in eng.last_compaction] == rebuilt
+            compacted += len(rebuilt)
         else:
             assert eng.last_compaction is None
+        skipped += len(scan) - len(rebuilt)
         engaged.add(m["rooms_dirty"])
         hydrated.clear()
         # what a flush leaves: rooms a listener fed, rooms that park structs
@@ -226,6 +238,13 @@ def test_the_sets_are_the_walks_at_every_flush(
         if rnd % 7 == 4:
             eng.compact_docs(rng.sample(range(n), 6))
     assert eng.fallback and compacted
+    # a native room with nothing to merge is asked and left (room 5, where
+    # the seed sends it no backspace before it has doubled); a Python
+    # mirror is never asked
+    if planner == "python":
+        assert not skipped
+    elif min_rows == 8:
+        assert skipped
     assert max(engaged) < n  # never every slot
 
 
@@ -264,6 +283,68 @@ def test_a_flush_costs_what_its_rooms_cost(monkeypatch):
     monkeypatch.undo()
     for i in typed:
         assert eng.text(i) == rooms.oracle[i].get_text("text").to_string()
+
+
+def _saved_room(client, n):
+    """One encoded state of ``n`` rows: ``n`` characters prepended in one
+    transaction (no two merge), as a server writes a room for a restart."""
+    d = Y.Doc(gc=False)
+    d.client_id = client
+    t = d.get_text("text")
+    d.transact(lambda _txn: [t.insert(0, "abcdefghij"[k % 10]) for k in range(n)])
+    return d
+
+
+def test_a_cold_start_compacts_nothing(monkeypatch):
+    """512 rooms loaded whole, then a keystroke in each: the look reads
+    512 rooms that have doubled (from nothing), asks them and rebuilds
+    none (``rooms_compact_skipped`` 512, nothing staged, ``last_compaction``
+    the object it was); a room that is then typed in until it has doubled
+    again has runs to merge and is rebuilt (skipped 0)."""
+    eng = _engine(monkeypatch, "native", 512, compact_min_rows=32)
+    reg = eng.obs.registry
+    docs = [_saved_room(100 + k, 40 + k) for k in range(4)]
+    saved = [Y.encode_state_as_update(d) for d in docs]
+    for i in range(512):
+        assert eng.queue_update(i, saved[i % 4])
+    eng.flush()
+    m = eng.last_flush_metrics
+    assert (m["rooms_compact_looked"], m["rooms_compact_skipped"]) == (0, 0)
+    keystroke = []
+    for d in docs:
+        sv = Y.encode_state_vector(d)
+        d.get_text("text").insert(len(d.get_text("text")), "!")
+        keystroke.append(Y.encode_state_as_update(d, sv))
+    before = eng.last_compaction
+    for i in range(512):
+        assert eng.queue_update(i, keystroke[i % 4])
+    eng.flush()  # the first keystroke's flush: the one that compacted
+    m = eng.last_flush_metrics
+    assert (m["rooms_compact_looked"], m["rooms_compact_skipped"]) == (512, 512)
+    assert m["rows_staged_blocks"] == m["rows_staged_bytes"] == 0
+    assert eng.last_compaction is before
+    assert eng._rows_at_compact == [40 + i % 4 for i in range(512)]
+    assert reg.get("ytpu_flush_rooms_compact_skipped_total").value == 512
+    assert reg.get("ytpu_flush_rooms_compact_looked_total").value == 512
+    # room 5 is typed in, a keystroke an update, until it has doubled
+    d = docs[1]
+    t = d.get_text("text")
+    for _ in range(45):
+        sv = Y.encode_state_vector(d)
+        t.insert(len(t), "x")
+        assert eng.queue_update(5, Y.encode_state_as_update(d, sv))
+    eng.flush()
+    m = eng.last_flush_metrics
+    assert (m["rooms_compact_looked"], m["rooms_compact_skipped"]) == (512, 0)
+    eng.flush()  # this look reads room 5 at 87 rows, twice 41 and more
+    m = eng.last_flush_metrics
+    assert (m["rooms_compact_looked"], m["rooms_compact_skipped"]) == (1, 0)
+    assert eng.last_compaction == [
+        {"doc": 5, "rows_before": 87, "rows_after": 42}
+    ]
+    assert reg.get("ytpu_flush_rooms_compact_skipped_total").value == 512
+    assert eng.text(5) == t.to_string()
+    assert eng.text(6) == docs[2].get_text("text").to_string()
 
 
 @pytest.mark.parametrize("planner", ["native", "python"])

@@ -17,6 +17,7 @@ from yjs_tpu.obs import FLUSH_METRICS_SCHEMA, global_registry, new_flush_metrics
 from yjs_tpu.obs.history import FlushHistory
 from yjs_tpu.obs.registry import Histogram, MetricsRegistry
 from yjs_tpu.ops import BatchEngine
+from yjs_tpu.ops.native_mirror import native_plan_available
 from yjs_tpu.provider import TpuProvider
 from yjs_tpu.updates import encode_state_as_update
 
@@ -490,7 +491,9 @@ def test_what_a_flush_looked_at_rides_the_flush_metrics(monkeypatch, planner):
     sets hold."""
     if planner == "python":
         monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
-    assert {"rooms_dirty", "rooms_compact_looked"} <= set(FLUSH_METRICS_SCHEMA)
+    assert {
+        "rooms_dirty", "rooms_compact_looked", "rooms_compact_skipped",
+    } <= set(FLUSH_METRICS_SCHEMA)
     eng = BatchEngine(8)
     reg = eng.obs.registry
     looked_at = []  # (rooms_dirty, rooms_compact_looked) a flush
@@ -507,3 +510,56 @@ def test_what_a_flush_looked_at_rides_the_flush_metrics(monkeypatch, planner):
     assert reg.get("ytpu_flush_rooms_compact_looked_total").value == 4
     names = [e["name"] for e in eng.obs.tracer.trace_events()]
     assert names.count("ytpu.compact.scan") == names.count("ytpu.flush") == 5
+
+
+@pytest.mark.parametrize("planner", ["native", "python"])
+def test_rooms_the_look_did_not_rebuild_are_counted(monkeypatch, planner):
+    """``rooms_compact_skipped`` and ``ytpu_flush_rooms_compact_skipped_
+    total``: a room that has doubled and has nothing to merge is counted
+    and not rebuilt; one that has runs to merge is rebuilt and not
+    counted.  A Python mirror is not asked: it is rebuilt, and the
+    counter stays 0."""
+    if planner == "python":
+        monkeypatch.setenv("YTPU_NO_NATIVE_PLAN", "1")
+    elif not native_plan_available():
+        pytest.skip("native plan core unavailable")
+    eng = BatchEngine(4, compact_min_rows=16)
+    reg = eng.obs.registry
+    skipped = reg.get("ytpu_flush_rooms_compact_skipped_total")
+    docs = {}
+    for i in (0, 2):  # 20 prepended characters, one update: 20 rows
+        d = docs[i] = Y.Doc(gc=False)
+        d.client_id = 7 + i
+        t = d.get_text("text")
+        d.transact(lambda _txn: [t.insert(0, "ab"[k % 2]) for k in range(20)])
+        eng.queue_update(i, encode_state_as_update(d))
+    eng.flush()
+    assert eng.last_flush_metrics["rooms_compact_skipped"] == 0
+
+    def type_at_end(i, n):
+        d = docs[i]
+        for _ in range(n):
+            sv = Y.encode_state_vector(d)
+            d.get_text("text").insert(len(d.get_text("text")), "x")
+            eng.queue_update(i, encode_state_as_update(d, sv))
+
+    type_at_end(0, 1)
+    eng.flush()  # looks at both loaded rooms
+    m = eng.last_flush_metrics
+    native = planner == "native"
+    assert m["rooms_compact_looked"] == 2
+    assert m["rooms_compact_skipped"] == (2 if native else 0)
+    assert (eng.last_compaction is None) == native
+    assert skipped.value == (2 if native else 0)
+    type_at_end(2, 30)  # 30 keystrokes, a row each until they are merged
+    eng.flush()
+    eng.flush()  # looks at room 2: 50 rows, its typing merges into one
+    m = eng.last_flush_metrics
+    assert (m["rooms_compact_looked"], m["rooms_compact_skipped"]) == (1, 0)
+    assert eng.last_compaction == [
+        {"doc": 2, "rows_before": 50, "rows_after": 21}
+    ]
+    assert skipped.value == (2 if native else 0)
+    assert set(m) == set(FLUSH_METRICS_SCHEMA)
+    for i, d in docs.items():
+        assert eng.text(i) == d.get_text("text").to_string()

@@ -182,8 +182,10 @@ def test_engine_cursor_survives_concurrent_edits():
 
 
 def test_engine_cursor_post_compaction():
-    # low compaction threshold: the flush after the second wave rebuilds
-    # the mirror's rows; anchors inside MERGED runs must still resolve
+    # low compaction threshold: the room is loaded whole and has nothing
+    # to merge, so the look leaves it alone until a tail typed a keystroke
+    # an update has doubled it; the flush after that rebuilds the mirror's
+    # rows; anchors inside MERGED runs must still resolve
     update, ref = _two_client_conflict_doc(seed=21)
     eng = BatchEngine(1, gc=False, compact_min_rows=4)
     eng.queue_update(0, update)
@@ -194,18 +196,30 @@ def test_engine_cursor_post_compaction():
         Y.create_relative_position_from_type_index(text, i)
         for i in range(0, n + 1, max(1, n // 11))
     ]
-    # more traffic to trigger another compaction cycle
+    # more traffic to trigger a compaction cycle: a row a keystroke
     c = Y.Doc(gc=False)
     c.client_id = 404
     Y.apply_update(c, update)
-    for k in range(40):
-        t2 = c.get_text("text")
-        t2.insert(len(t2.to_string()), f"tail{k} ")
-    wave = Y.encode_state_as_update(c, Y.encode_state_vector(ref))
-    Y.apply_update(ref, wave)
-    eng.queue_update(0, wave)
+    loaded = eng.mirrors[0].n_rows
+    t2 = c.get_text("text")
+    for k in range(loaded + 8):
+        sv = Y.encode_state_vector(c)
+        t2.insert(len(t2.to_string()), "tail "[k % 5])
+        key = Y.encode_state_as_update(c, sv)
+        Y.apply_update(ref, key)
+        eng.queue_update(0, key)
     eng.flush()
+    assert eng.mirrors[0].n_rows >= 2 * loaded
+    # anchors inside the tail, taken while it is a row a keystroke
+    n = len(text.to_string())
+    rposes += [
+        Y.create_relative_position_from_type_index(text, i)
+        for i in range(n - loaded, n + 1, 5)
+    ]
+    eng.flush()  # the look of the flush after the one that doubled it
     assert eng.last_compaction, "compaction must have run for this test"
+    (stats,) = eng.last_compaction
+    assert stats["rows_after"] <= loaded + 2 < stats["rows_before"]
     assert eng.text(0) == ref.get_text("text").to_string()
     for rp in rposes:
         a = Y.create_absolute_position_from_relative_position(rp, ref)

@@ -228,6 +228,10 @@ def test_a_served_compaction_is_one_staged_shape():
         for i, d in enumerate(docs[: 1 + rnd % 3]):
             t = d.get_text("text")
             t.insert(len(t.to_string()) // 2, "ab")
+            # and a tail of two keystrokes, an update each: rows that
+            # merge, or a room that has doubled is asked and left alone
+            for key in "yz":
+                t.insert(len(t.to_string()), key)
             if rnd % 4 == 3:
                 t.delete(0, 1)
         for i, u in sent:

@@ -215,6 +215,10 @@ def load():
     lib.ymx_format_cleanup.argtypes = [vp, i64, i64p, i64, i64p]
     lib.ymx_compact_self.restype = i64
     lib.ymx_compact_self.argtypes = [vp, ctypes.c_int, i32p, u8p, i32p, i64]
+    # the question before it, a look's candidates in one call: a byte a
+    # room, 1 where the compaction would change something
+    lib.ymx_compact_changes_many.restype = None
+    lib.ymx_compact_changes_many.argtypes = [vpp, i64, ctypes.c_int, u8p]
     # the most threads a ymx_prepare_many call may plan on (the width a
     # flush used is last_flush_metrics["plan_threads"], the call's own)
     lib.ymx_plan_threads.restype = ctypes.c_int

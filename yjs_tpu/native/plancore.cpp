@@ -1762,51 +1762,111 @@ struct Mirror {
 
   // ---- compaction (DocMirror.rebuild_compacted twin) --------------------
 
-  // merge content descriptors of rows a,b; returns false when not mergeable
-  bool desc_merge(int64_t a, int64_t b) {
-    ContentDesc& ca = r_c[(size_t)a];
-    ContentDesc& cb = r_c[(size_t)b];
+  // whether the content descriptors of rows a, b merge: the test alone,
+  // so the question before a compaction (compact_changes) and the merge
+  // (desc_merge) cannot come to differ
+  bool desc_can_merge(int64_t a, int64_t b) const {
+    const ContentDesc& ca = r_c[(size_t)a];
+    const ContentDesc& cb = r_c[(size_t)b];
     if (ca.kind != cb.kind) return false;
     switch (ca.kind) {
       case kKindDeleted:
-        return true;
       case kKindUtf8:
-      case kKindAnys:
-      case kKindJsons: {
-        if (ca.kind != kKindUtf8 && ca.v2 != cb.v2) return false;
-        if (ca.buf == cb.buf && ca.end == cb.ofs) {
-          ca.end = cb.end;  // naturally adjacent: extend in place
-        } else {
-          std::vector<uint8_t> merged(buf_ptr(ca.buf) + ca.ofs,
-                                      buf_ptr(ca.buf) + ca.end);
-          merged.insert(merged.end(), buf_ptr(cb.buf) + cb.ofs,
-                        buf_ptr(cb.buf) + cb.end);
-          int64_t nb = arena(std::move(merged));
-          ca.buf = nb;
-          ca.ofs = 0;
-          ca.end = (int64_t)buf_len(nb);
-        }
-        if (ca.kind != kKindUtf8) ca.count += cb.count;
         return true;
-      }
+      case kKindAnys:
+      case kKindJsons:
+        return ca.v2 == cb.v2;
       default:
         return false;  // Framed/V2Lazy: length-1 kinds never merge
     }
   }
 
-  bool try_merge(int64_t a, int64_t b, const uint8_t* deleted) {
+  // merge content descriptors of rows a,b, which desc_can_merge allows
+  void desc_merge(int64_t a, int64_t b) {
+    ContentDesc& ca = r_c[(size_t)a];
+    ContentDesc& cb = r_c[(size_t)b];
+    if (ca.kind == kKindDeleted) return;
+    if (ca.buf == cb.buf && ca.end == cb.ofs) {
+      ca.end = cb.end;  // naturally adjacent: extend in place
+    } else {
+      std::vector<uint8_t> merged(buf_ptr(ca.buf) + ca.ofs,
+                                  buf_ptr(ca.buf) + ca.end);
+      merged.insert(merged.end(), buf_ptr(cb.buf) + cb.ofs,
+                    buf_ptr(cb.buf) + cb.end);
+      int64_t nb = arena(std::move(merged));
+      ca.buf = nb;
+      ca.ofs = 0;
+      ca.end = (int64_t)buf_len(nb);
+    }
+    if (ca.kind != kKindUtf8) ca.count += cb.count;
+  }
+
+  // whether a compaction merges row b into row a, its left neighbour in a
+  // list (or, GC structs, in its client's clock order); writes nothing
+  bool can_merge(int64_t a, int64_t b, const uint8_t* deleted) const {
     if (r_slot[a] != r_slot[b]) return false;
     if (r_clock[a] + r_len[a] != r_clock[b]) return false;
     if ((deleted[a] != 0) != (deleted[b] != 0)) return false;
     if (r_is_gc[a] != r_is_gc[b]) return false;
-    if (segs_of_parent.count(a) || segs_of_parent.count(b)) return false;
-    if (r_is_gc[a]) return true;
-    if (r_oslot[b] != r_slot[a] ||
-        r_oclock[b] != r_clock[a] + r_len[a] - 1)
-      return false;
-    if (!row_right_eq(a, b)) return false;
-    if (r_ref[a] != r_ref[b]) return false;
-    return desc_merge(a, b);
+    if (!r_is_gc[a]) {  // GC structs merge on contiguity alone
+      if (r_oslot[b] != r_slot[a] ||
+          r_oclock[b] != r_clock[a] + r_len[a] - 1)
+        return false;
+      if (!row_right_eq(a, b)) return false;
+      if (r_ref[a] != r_ref[b]) return false;
+      if (!desc_can_merge(a, b)) return false;
+    }
+    // a nested type's row keeps its identity: its children name it
+    return segs_of_parent.empty() ||
+           !(segs_of_parent.count(a) || segs_of_parent.count(b));
+  }
+
+  bool try_merge(int64_t a, int64_t b, const uint8_t* deleted) {
+    if (!can_merge(a, b, deleted)) return false;
+    if (!r_is_gc[a]) desc_merge(a, b);
+    return true;
+  }
+
+  // the question a compaction look asks before it rebuilds: would
+  // compact(), fed the mirror's own lists (ymx_compact_self), change
+  // anything a reader can see?  What compact() decides, by the test it
+  // decides with (can_merge), and nothing built: a pair of list
+  // neighbours that merge, a pair of a client's GC structs that merge, a
+  // row whose content `gc` would turn into a tombstone.  Where none is
+  // found `keep` is every row, renumber() is the identity on every column
+  // and index, and the rows, deleted bits and heads compact() hands back
+  // are list_next, r_host_deleted and head_of_seg as they stand.  Two
+  // things it would still do are not counted: `gen` moves (it says "the
+  // rows were renumbered", and they were not), and each client's
+  // delete-set ranges are sorted and unioned in place, which no reader
+  // sees (ymx_ds's callers and build_diff_prep union what they read).
+  //
+  // compact() tries the neighbours of every list that is no map chain
+  // and, client by client, consecutive GC structs.  A pair merges only
+  // where b starts at the clock a ends at (can_merge's first two tests):
+  // where b follows a in their client's fragment index.  So the pairs
+  // are read off that index, in the order the rows lie in memory, and
+  // one that compact() would try (neighbours by list_next, or two GC
+  // structs) is put to can_merge; a walk of the lists would chase every
+  // column in document order to try the same pairs.  Returns at the first
+  // change found: only a room with nothing to merge is read to its end.
+  bool compact_changes(bool gc) const {
+    const uint8_t* deleted = r_host_deleted.data();
+    for (const auto& rows : frag_row) {
+      for (size_t k = 1; k < rows.size(); k++) {
+        int64_t a = rows[k - 1], b = rows[k];
+        bool tried = (r_is_gc[a] && r_is_gc[b]) ||
+                     (list_next[(size_t)a] == b && r_seg[a] != kNull &&
+                      !seg_is_map(r_seg[a]));
+        if (tried && can_merge(a, b, deleted)) return true;
+      }
+    }
+    if (gc) {
+      int64_t n = n_rows();
+      for (int64_t row = 0; row < n; row++)
+        if (deleted[row] && !r_is_gc[row] && r_ref[row] != 1) return true;
+    }
+    return false;
   }
 
   // renumber every host structure after compaction decided `keep`
@@ -3687,6 +3747,16 @@ int64_t ymx_compact_self(void* h, int gc, int32_t* new_right,
     heads[(size_t)s] = (int32_t)m->head_of_seg[(size_t)s];
   return m->compact(right.data(), del.data(), heads.data(), nseg, gc,
                     new_right, new_deleted, new_heads, new_heads_cap);
+}
+
+// the question before ymx_compact_self, every candidate of a compaction
+// look in one call: out[i] is 1 where compacting room hs[i] would change
+// something (Mirror::compact_changes), 0 where it would hand back the
+// rows the room holds.  Reads the mirrors, writes none, allocates nothing.
+void ymx_compact_changes_many(void** hs, int64_t n_docs, int gc,
+                              uint8_t* out) {
+  for (int64_t i = 0; i < n_docs; i++)
+    out[i] = static_cast<const Mirror*>(hs[i])->compact_changes(gc != 0);
 }
 
 }  // extern "C"

@@ -122,9 +122,12 @@ FLUSH_METRICS_SCHEMA: dict = {
     # what a flush looked at: slots the plan phase visited (the engine's
     # dirty set, fed by queue_update: over n_docs, the share of the
     # slots a flush pays for) and slots whose n_rows the compaction
-    # look read (the rooms planned since the look before)
+    # look read (the rooms planned since the look before), and the rooms
+    # among them that had doubled, were asked whether a rebuild would
+    # change them, answered no and were not rebuilt
     "rooms_dirty": 0,
     "rooms_compact_looked": 0,
+    "rooms_compact_skipped": 0,
     # the native pool's own clock (ymx_prepare_many, summed over the
     # flush's calls): the longest single room's prepare, and the sum
     # over rooms.  plan_pool_s / (threads x the ytpu.plan.native span)
@@ -575,6 +578,12 @@ class EngineObs:
             "planned since the look before",
             unit="rooms",
         )
+        self._flush_rooms_compact_skipped = r.counter(
+            "ytpu_flush_rooms_compact_skipped_total",
+            "Rooms that had doubled since their last compaction and were "
+            "not rebuilt, because the rebuild would have changed nothing",
+            unit="rooms",
+        )
         self._flush_rooms_row_loaded = r.counter(
             "ytpu_flush_rooms_row_loaded_total",
             "Rooms flushes wrote to the device as rows of a block: rooms "
@@ -627,6 +636,9 @@ class EngineObs:
             self._flush_rows_staged_blocks.inc(metrics["rows_staged_blocks"])
         self._flush_rooms_dirty.inc(metrics["rooms_dirty"])
         self._flush_rooms_compact_looked.inc(metrics["rooms_compact_looked"])
+        self._flush_rooms_compact_skipped.inc(
+            metrics["rooms_compact_skipped"]
+        )
         if metrics["rooms_row_loaded"]:
             self._flush_rooms_row_loaded.inc(metrics["rooms_row_loaded"])
         if metrics["rows_planned"]:

@@ -1037,13 +1037,14 @@ class BatchEngine:
 
     # -- compaction ---------------------------------------------------------
 
-    def _maybe_compact(self) -> int:
+    def _maybe_compact(self) -> tuple[int, int]:
         """Amortized run-merge + GC: when a doc's table doubles since its
-        last compaction, read back its links/deleted bits and rebuild the
-        mirror + device state with adjacent runs merged (the engine-side
-        analogue of the reference's per-transaction merge/GC passes,
-        Transaction.js:165-238,299-332).  Keeps row count bounded by the
-        doc's true run structure instead of its edit history.
+        last compaction, rebuild the mirror + device state with adjacent
+        runs merged (the engine-side analogue of the reference's
+        per-transaction merge/GC passes,
+        Transaction.js:165-238,299-332), if there is anything to merge.
+        Keeps row count bounded by the doc's true run structure instead
+        of its edit history.
 
         The look reads ``n_rows`` of the rooms planned since the last
         look (``_compact_look``) and of no other slot: a room's rows move
@@ -1055,26 +1056,64 @@ class BatchEngine:
         look; one case differs from a scan of every slot: lowering it
         under a room that was NOT planned since the last look does not
         catch that room, which is looked at after its next update.
-        Returns the number of slots looked at (``rooms_compact_looked``)."""
+
+        The rooms that have doubled are then asked, in one native call,
+        whether a rebuild would change them
+        (``NativeMirror.compact_changes_many``: a row whose content
+        ``gc`` would drop, two neighbours that merge).  A room that
+        answers no is not rebuilt: the rebuild would write the rows,
+        deleted bits and heads the tables already hold, under the same
+        row numbers.  It gets the ``_rows_at_compact`` such a rebuild
+        would have left it, so it is asked again when it has doubled from
+        here; nothing else of it is touched (its rows keep their numbers,
+        so its realized contents, its plan frontier and the plan cache's
+        entries stay good).  A room loaded whole from one encoded state
+        answers no, the encoder having merged its runs; a room that has
+        been typed in answers yes at the first two keystrokes that merge.
+        A room on a Python mirror is not asked and is rebuilt.  ``last_compaction`` is
+        assigned only by a look that rebuilt a room.
+        Returns the number of slots looked at (``rooms_compact_looked``)
+        and of rooms asked and not rebuilt (``rooms_compact_skipped``)."""
+        skipped = 0
         with self._phase_ctx("compact.scan"):
             looked = sorted(self._compact_look)
             self._compact_look.clear()
             floor = self.compact_min_rows
-            todo = [
-                i
+            n_rows = {
+                i: self.mirrors[i].n_rows
                 for i in looked
                 if i not in self.fallback
-                and self.mirrors[i].n_rows
-                >= max(floor, 2 * self._rows_at_compact[i])
+            }
+            todo = [
+                i
+                for i, n in n_rows.items()
+                if n >= max(floor, 2 * self._rows_at_compact[i])
             ]
-        if todo and self._right is not None:
+            if self._right is None:
+                todo = []  # no table yet: nothing a rebuild could write
+            ask = [
+                i for i in todo if isinstance(self.mirrors[i], NativeMirror)
+            ]
+            if ask:
+                changes = NativeMirror.compact_changes_many(
+                    [self.mirrors[i] for i in ask], self.gc
+                )
+                same = {i for i, c in zip(ask, changes.tolist()) if not c}
+                for i in same:
+                    self._rows_at_compact[i] = n_rows[i]
+                todo = [i for i in todo if i not in same]
+                skipped = len(same)
+        if todo:
             self.last_compaction = self._compact_rows(todo, self.gc)
-        return len(looked)
+        return len(looked), skipped
 
     def _compact_rows(self, todo: list[int], gc: bool) -> list[dict]:
         """Rebuild ``todo``'s mirrors compacted and scatter the new rows
         into the device tables; returns per-doc row stats, in ``todo``'s
-        order.
+        order.  Every room of ``todo`` is rebuilt, whether or not it has
+        anything to merge: the forced pass (``compact_docs``) and the
+        look's rooms that answered yes (``_maybe_compact``, which is
+        where a room with nothing to merge is left out).
 
         The mirror's host list/deleted state equals the device arrays by
         flush invariant (YTPU_EXPORT_DEVICE pins it), so merges are
@@ -1426,7 +1465,9 @@ class BatchEngine:
         ``n_docs``: the plan phase visits ``_dirty_docs`` (fed by
         ``queue_update``; ``rooms_dirty`` in the metrics) in ascending
         slot order, and the compaction look reads the rooms planned
-        since it last ran (``_maybe_compact``; ``rooms_compact_looked``).
+        since it last ran and rebuilds those of them that have doubled
+        and have something to merge (``_maybe_compact``;
+        ``rooms_compact_looked``, ``rooms_compact_skipped``).
         A room leaves the dirty set when a flush has taken its staged
         updates and it parks no struct; one that waits for a missing
         struct is visited by every flush until the struct arrives, and
@@ -1441,7 +1482,7 @@ class BatchEngine:
         # integrates on top of the device link tables (pipeline stage 0)
         self._apply_pending_hydrations()
         with self._phase_ctx("compact"):
-            n_looked = self._maybe_compact()
+            n_looked, n_skipped = self._maybe_compact()
         t_compact = time.perf_counter()
         plans = {}
         pre_svs: dict[int, dict[int, int]] = {}
@@ -1559,6 +1600,7 @@ class BatchEngine:
             plan_cache_admitted=cache_admitted,
             rooms_dirty=len(dirty),
             rooms_compact_looked=n_looked,
+            rooms_compact_skipped=n_skipped,
             plan_fastpath_structs=sum(
                 getattr(p, "fastpath_structs", 0) or 0
                 for p in plans.values()

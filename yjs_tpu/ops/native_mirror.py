@@ -466,6 +466,24 @@ class NativeMirror:
             new_heads,
         )
 
+    @staticmethod
+    def compact_changes_many(mirrors, gc: bool) -> np.ndarray:
+        """The question a compaction look asks before it rebuilds, of
+        every candidate in one native call: ``out[k]`` is True where
+        ``mirrors[k].rebuild_compacted_self(gc)`` would merge a row or
+        turn a deleted row's content into a tombstone, False where it
+        would hand back the rows, deleted bits and heads the room holds
+        (``Mirror::compact_changes``: the tests ``compact`` decides
+        with).  Reads the mirrors and writes none."""
+        n = len(mirrors)
+        out = np.zeros(n, np.uint8)
+        if n:
+            handles = (ctypes.c_void_p * n)(*[m._h for m in mirrors])
+            mirrors[0]._lib.ymx_compact_changes_many(
+                handles, n, int(bool(gc)), out.ctypes.data_as(_u8p)
+            )
+        return out.astype(bool)
+
     # -- native wire encodes -------------------------------------------------
 
     def encode_diff_update(
