@@ -10,6 +10,10 @@ rooms that held rows, one whole-row write a width class for the rooms it
 loads into empty slots (``apply_plan2_rows``;
 ``parallel.mesh.sharded_load_rows``), and a compaction, a hydration or a
 release is one whole-row write too (``scatter_rows``, ``blank_rows``).
+The lanes of that scatter ship no doc column: they lie room after room
+behind a header of per-doc counts, and the device derives each lane's
+doc from the doc boundaries (``_doc_lanes``: each lane compared with
+every doc's end in one fused pass, no search and no gather a lane).
 The one program that reads the tables back ranks document order from the
 right links (``list_ranks``): the export that verification holds the tables
 to.  State vectors, diffs and every other read are the host mirrors'.
@@ -40,12 +44,24 @@ NULL = -1
 def _doc_lanes(counts, k, cap_oob):
     """Per-lane (doc, within-doc index) derived on device from per-doc
     counts — the doc-id column never crosses the host->device link.
-    Lanes beyond the true total get an out-of-bounds index (dropped)."""
+    Lanes beyond the true total get an out-of-bounds index (dropped).
+
+    The lanes lie room after room, so lane ``i`` belongs to doc ``d``
+    exactly when ``cum[d-1] <= i < cum[d]``: a lane's doc is the number
+    of docs that end at or before it.  ``method="compare_all"`` counts
+    them outright: k x B compares that XLA fuses into their sum, with no
+    gather and no loop.  The default method is a binary search, which
+    runs on a TPU as ``log2(B)`` dependent rounds of one gather a lane
+    each: 90 ms of the 130 that a bulk merge's widest flush (852 k
+    lanes, B 4096) held the device, where the compares take 12, and
+    0.04 ms of a served flush's 0.09, where they take 0.002 (PERF.md,
+    PR 49, which also says why marks under a running sum were not
+    kept: 0.2 ms at the widest flush, and 0.18 ms at every other)."""
     b = counts.shape[0]
-    cum = jnp.cumsum(counts)
+    cum = jnp.cumsum(counts, dtype=jnp.int32)
     idx = jnp.arange(k, dtype=jnp.int32)
-    d = jnp.searchsorted(cum, idx, side="right").astype(jnp.int32)
-    d = jnp.minimum(d, b - 1)
+    d = jnp.searchsorted(cum, idx, side="right", method="compare_all")
+    d = jnp.minimum(d.astype(jnp.int32), b - 1)
     within = idx - (cum[d] - counts[d])
     within = jnp.where(idx < cum[b - 1], within, cap_oob)
     return d, within
